@@ -1,11 +1,9 @@
 """Verify-ahead: the cross-decision commit-verify pipeline for fast sync.
 
-BENCH r05: the host<->device round trip (`sync_floor_ms` ~104 ms) dominates
-every verify decision — a 20,480-sig commit costs 151 ms of which ~104 ms is
-the floor, marginal cost 4.34 us/sig. The serial fast-sync loop
-(blockchain/reactor.py `_try_sync`, v1.py `try_process_block`) pays that
-floor once per block, serialized with block save/apply, so throughput is
-floor-bound no matter how fast the kernel gets.
+Every verify decision pays one host<->device round trip (the sync floor)
+whatever its size. The serial fast-sync loop (blockchain/reactor.py
+`_try_sync`, v1.py `try_process_block`) pays it once per block, serialized
+with block save/apply.
 
 This module lifts the chunk-level pipelining of ops/ed25519_pallas
 (dispatch_items_pipelined, _start_host_copy) to DECISION granularity:
@@ -122,7 +120,7 @@ class VerifyAheadPipeline:
         host work (copy_to_host_async starts the D2H at dispatch), after
         which the kernel's marginal us/sig beats the host C verifier for
         any kernel-sized batch. On a CPU backend the "device" is this same
-        host — no tunnel to hide, kernel never pays off — and small
+        host — no round trip to hide, kernel never pays off — and small
         commits (tests, dev nets) stay on the adaptive host/scalar path."""
         depth = verify_ahead_depth()
         if depth <= 1 or os.environ.get("TM_TPU_DISABLE_BATCH") == "1":

@@ -1,10 +1,9 @@
 """Continuous-batching verify service: ONE device-owning executor for all
 signature-verification traffic (ROADMAP item 1).
 
-BENCH r05: the headline 20,480-sig commit verify is floor-bound — of the
-151 ms p50, ~104 ms is the fixed host<->device round trip
-(`sync_floor_ms`), paid once per DECISION no matter how the kernel
-improves. Verify-ahead (blockchain/pipeline.py) and the batched readback
+Every device verify pays a fixed host<->device round trip (the sync floor)
+once per DECISION, whatever the kernel's speed. Verify-ahead
+(blockchain/pipeline.py) and the batched readback
 (crypto/batch.prefetch) only amortize that floor across decisions ONE
 CALLER already has in flight; nothing shares it across CALLERS. A 50-node
 fabric, a consensus drain racing a fast-sync burst, or light range chunks

@@ -236,6 +236,8 @@ class Runner:
             write_config_toml(cfg, path)
 
     def _spawn(self, i: int) -> subprocess.Popen:
+        # JAX_PLATFORMS=cpu: a chip belongs to one process at a time, so N
+        # node processes cannot share it (and this parent may hold it)
         env = {**os.environ, "JAX_PLATFORMS": "cpu",
                "TM_TPU_DISABLE_BATCH": os.environ.get("TM_TPU_DISABLE_BATCH", ""),
                # serving nodes take app snapshots so late joiners can

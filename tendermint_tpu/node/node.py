@@ -518,6 +518,14 @@ class Node:
         # thread per stopped node, docs/INGEST.md)
         self.mempool._ingest.stop()
         self.proxy_app.stop()
+        # A warm-up compile still in flight must finish before the process
+        # may exit: the XLA runtime aborts at interpreter teardown under a
+        # live compile. (Its outcome is in crypto_batch.WARMUP; a failure
+        # was logged when it happened.)
+        from tendermint_tpu.crypto import batch as crypto_batch
+
+        if not crypto_batch.WARMUP.join(timeout=600.0) and self.logger:
+            self.logger.error("kernel warm-up still running after 600 s")
 
     def abort(self) -> None:
         """Power-loss teardown (docs/SOAK.md crash actions): release this

@@ -17,15 +17,23 @@ States:
              only when the probe reports success -- so a flapping device
              costs one probe per cooldown, never a consensus stall.
 
+The degradation is silent to callers by design, so it must be loud to the
+operator: the failure that opens the circuit is logged at error level with
+its traceback (``logging`` logger ``tendermint_tpu.ops.breaker``), and
+``failures``/``trips``/``last_error`` stay readable on the breaker.
+
 TM_TPU_BREAKER_COOLDOWN_S overrides the cooldown (read per trip, so tests
 can shrink it without re-importing).
 """
 
 from __future__ import annotations
 
+import logging
 import os
 import threading
 import time
+
+_log = logging.getLogger(__name__)
 
 
 class CircuitBreaker:
@@ -87,11 +95,16 @@ class CircuitBreaker:
         with self._lock:
             self.failures += 1
             self.last_error = exc
-            if not self._open:
+            tripped = not self._open
+            if tripped:
                 self.trips += 1
                 self._event(f"opened: {exc!r}")
             self._open = True
             self._open_until = time.monotonic() + self._cooldown()
+        if tripped:
+            # the first failure of each trip; later ones while open repeat it
+            _log.error("%s: device route failed, circuit opened, verifying "
+                       "on the host: %r", self.name, exc, exc_info=exc)
 
     def record_success(self) -> None:
         # A success observed on the device route while closed; nothing to

@@ -2,8 +2,9 @@
 fallback.
 
 The shared library is built lazily with g++ on first use. The output filename
-embeds a content hash of the C sources, so a stale binary can never be loaded
-silently (and no binary artifact is committed — csrc/*.so is gitignored). All
+(ops/cbuild) digests the C sources, the compile recipe and the host CPU's
+features, so neither a stale binary nor one built for another machine can be
+loaded (and no binary artifact is committed — csrc/*.so is gitignored). All
 entry points take/return numpy arrays so a 20k-signature commit pays ONE FFI
 crossing instead of 20k hashlib calls.
 """
@@ -18,21 +19,23 @@ import threading
 
 import numpy as np
 
-_CSRC = os.path.join(os.path.dirname(__file__), "..", "..", "csrc")
+from tendermint_tpu.ops import cbuild
+
 _SRC_PATHS = [
-    os.path.abspath(os.path.join(_CSRC, "hash_batch.c")),
-    os.path.abspath(os.path.join(_CSRC, "sr25519_strobe.c")),
+    os.path.join(cbuild.CSRC, "hash_batch.c"),
+    os.path.join(cbuild.CSRC, "sr25519_strobe.c"),
 ]
-_HDR_PATH = os.path.abspath(os.path.join(_CSRC, "hash_consts.h"))
+_HDR_PATH = os.path.join(cbuild.CSRC, "hash_consts.h")
+
+_CC = ["g++", "-O3", "-shared", "-fPIC", "-x", "c"]
+# -march=native unlocks the 4-way AVX2 SHA-512 lanes in hash_batch.c
+_FLAG_SETS = [["-fopenmp", "-march=native"], ["-march=native"],
+              ["-fopenmp"], []]
 
 
 def _lib_path() -> str:
-    h = hashlib.sha256()
-    for p in _SRC_PATHS + [_HDR_PATH]:
-        with open(p, "rb") as f:
-            h.update(f.read())
-    return os.path.abspath(
-        os.path.join(_CSRC, f"libhashbatch-{h.hexdigest()[:12]}.so"))
+    return cbuild.lib_path("libhashbatch", _SRC_PATHS + [_HDR_PATH],
+                           [_CC, _FLAG_SETS])
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -45,11 +48,8 @@ _I32P = ctypes.POINTER(ctypes.c_int32)
 
 def _build(lib_path: str) -> bool:
     tmp = lib_path + ".tmp"
-    # -march=native unlocks the 4-way AVX2 SHA-512 lanes in hash_batch.c
-    for flags in (["-fopenmp", "-march=native"], ["-march=native"],
-                  ["-fopenmp"], []):
-        cmd = ["g++", "-O3", "-shared", "-fPIC", "-x", "c", *_SRC_PATHS,
-               "-o", tmp] + flags
+    for flags in _FLAG_SETS:
+        cmd = _CC + [*_SRC_PATHS, "-o", tmp] + flags
         try:
             r = subprocess.run(cmd, capture_output=True, timeout=120)
             if r.returncode == 0:

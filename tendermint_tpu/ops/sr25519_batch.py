@@ -182,13 +182,8 @@ def _sr_verify_kernel(tab, k_win, s_win, r_limbs, valid, axis_name=None):
 
     acc0 = ed.identity((n,))
     if axis_name is not None:
-        # pvary deprecated for pcast in jax 0.9; jax < 0.5 needs no marking
-        # (varying-manual-axes tracking didn't exist) -- see the ed25519 twin.
-        pcast = getattr(jax.lax, "pcast", None)
-        if pcast is not None:
-            acc0 = pcast(acc0, axis_name, to="varying")
-        elif hasattr(jax.lax, "pvary"):
-            acc0 = jax.lax.pvary(acc0, axis_name)
+        # mark the loop carry device-varying under shard_map
+        acc0 = jax.lax.pcast(acc0, axis_name, to="varying")
     acc = jax.lax.fori_loop(0, 64, body, acc0)
 
     x_r, y_r, ok_r = _ristretto_decode_dev(r_limbs)

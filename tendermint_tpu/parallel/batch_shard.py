@@ -36,11 +36,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tendermint_tpu.ops import ed25519_batch
 
-try:
-    _shard_map = jax.shard_map  # jax >= 0.5
-except AttributeError:  # older jax ships it under experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 # ---------------------------------------------------------------------------
 # Shard-routing policy (shared by every kernel ops module)
@@ -99,7 +94,7 @@ def sharded_verify_tally(mesh: Mesh):
     Inputs are sharded on the signature axis; outputs: (bitmap (N,) sharded,
     global tally scalar, global all-valid-passed scalar)."""
     spec = P("dp")
-    fn = _shard_map(
+    fn = jax.shard_map(
         _local_verify_tally,
         mesh=mesh,
         in_specs=(spec, spec, spec, spec, spec, spec, spec, spec),
@@ -164,7 +159,7 @@ def _sharded_verify_fn(mesh: Mesh, kind: str = "ed25519"):
     key = (kind,) + tuple(id(d) for d in mesh.devices.flat)
     fn = _fn_cache.get(key)
     if fn is None:
-        fn = jax.jit(_shard_map(
+        fn = jax.jit(jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(),) + (P("dp"),) * n_item_args,

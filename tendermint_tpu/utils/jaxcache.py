@@ -1,19 +1,29 @@
 """Persistent XLA compilation cache.
 
-The batch-verify kernels compile in O(30s) cold (CPU backend is worse); a
-node must not pay that on every restart, and the test suite must not pay it
-on every run. jax's persistent compilation cache stores serialized
-executables keyed by HLO fingerprint; enabling it makes every compile after
-the first process-lifetime instantaneous.
+The batch-verify kernels take tens of seconds to compile cold; a node must
+not pay that on every restart, and the test suite must not pay it on every
+run. jax's persistent compilation cache stores serialized executables keyed
+by HLO fingerprint, so every compile after the first is a disk read.
 
-Called from ops/ed25519_batch import (any process that might touch a kernel)
-and from tests/conftest.py. No-op if the user set their own cache config or
-TM_TPU_JAX_CACHE=0.
+Where the cache lives:
+
+ * ``JAX_COMPILATION_CACHE_DIR`` set: jax reads it itself and this module
+   sets nothing -- the operator (or the machine image) owns the placement.
+ * otherwise: ``.jax_cache/`` at the root of the checkout (gitignored). The
+   path is part of the cache key, so it is one fixed directory: never under
+   ``~`` or a temp dir, never named after a pid or the time. A copy of the
+   tree carries its cache with it.
+
+Called from ops/ed25519_batch import (any process that might touch a
+kernel). TM_TPU_JAX_CACHE=0 turns the in-checkout default off.
 """
 
 from __future__ import annotations
 
 import os
+
+CACHE_DIR = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), "..", "..", ".jax_cache"))
 
 _done = False
 
@@ -28,15 +38,13 @@ def enable() -> None:
     import jax
 
     if jax.config.jax_compilation_cache_dir:
-        return  # user already configured one
-    cache_dir = os.environ.get(
-        "TM_TPU_JAX_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "tendermint_tpu", "jax"),
-    )
+        # JAX_COMPILATION_CACHE_DIR (jax read it at import), or set in code
+        # by an embedding program: theirs, untouched
+        return
     try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:  # noqa: BLE001 - cache is an optimization, never fatal
-        pass
+        os.makedirs(CACHE_DIR, exist_ok=True)
+    except OSError:
+        return  # read-only checkout: run uncached
+    jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)

@@ -1,8 +1,8 @@
 """Consensus flight recorder: per-node causal span tracing (SURVEY aux #36).
 
 The reference exposes pprof + Prometheus step histograms; a TPU build also
-needs to ATTRIBUTE the ~104 ms host<->device sync floor (ROADMAP item 1):
-of a decision's wall time, how much was host prep, queue wait, device
+needs to ATTRIBUTE a verify decision's wall time around the host<->device
+round trip: of a decision's wall time, how much was host prep, queue wait, device
 compute, readback, and bitmap replay — and WHERE in the block lifecycle a
 stalled node last made progress.
 

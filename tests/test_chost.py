@@ -289,3 +289,30 @@ def test_sr25519_bad_item_attribution():
             sig = sig[:12] + bytes([sig[12] ^ 2]) + sig[13:]
         items.append((p.pub_key().data, msg, sig))
     _check_sr(items)
+
+
+def test_library_built_for_another_cpu_is_not_loaded(monkeypatch):
+    """Both C libraries compile with -march=native, so their file names
+    digest the host CPU's features and the compile recipe beside the
+    source: a copy of the tree made on another machine finds no library
+    under its own name and rebuilds from csrc/*.c instead of dying on an
+    illegal instruction in someone else's binary."""
+    import os
+
+    from tendermint_tpu.ops import cbuild, chash
+
+    here = {mod: mod._lib_path() for mod in (chost, chash)}
+    assert os.path.exists(here[chost])  # the one this module loaded
+    assert "flags" in cbuild.host_cpu_tag() or "|" in cbuild.host_cpu_tag()
+
+    monkeypatch.setattr(cbuild, "host_cpu_tag",
+                        lambda: "x86_64|avx2 but no avx512f")
+    for mod, path in here.items():
+        other = mod._lib_path()
+        assert other != path and not os.path.exists(other)
+        assert os.path.dirname(other) == os.path.dirname(path)
+    monkeypatch.undo()
+
+    # ... and so does a changed compile line
+    monkeypatch.setattr(chost, "_RECIPE", [["gcc", "-O2", "-shared"]])
+    assert chost._lib_path() != here[chost]

@@ -260,29 +260,47 @@ def _ed_items(n_valid=4, n_bad=1):
     return items, [True] * n_valid + [False] * n_bad
 
 
-def test_device_failure_falls_back_and_recloses(monkeypatch):
+def test_device_failure_falls_back_and_recloses(monkeypatch, caplog):
     """Injected device-dispatch failure: the batch is re-verified on the
-    host within the same dispatch, the circuit opens, and after the
-    cooldown the background probe re-closes it; the next batch takes the
-    device route again (stubbed here -- the real-kernel twin of this test
-    is slow-tier, tests/test_fault_matrix.py)."""
+    host within the same dispatch, the circuit opens -- loudly: one
+    error-level log line carrying the exception and its traceback -- and
+    after the cooldown the background probe re-closes it; the next batch
+    takes the device route again (stubbed here -- the real-kernel twin of
+    this test is slow-tier, tests/test_fault_matrix.py)."""
+    import logging
+
     from tendermint_tpu.ops import ed25519_batch as edb
+
+    caplog.set_level(logging.ERROR, logger="tendermint_tpu.ops.breaker")
 
     monkeypatch.setenv("TM_TPU_HOST_CROSSOVER", "0")  # force the device route
     monkeypatch.setenv("TM_TPU_BREAKER_COOLDOWN_S", "0.05")
     items, expect = _ed_items()
     edb.BREAKER.reset()
+    # a stub from the start: a degraded batch that lands after the cooldown
+    # must not launch the real probe (an XLA compile) under this test
+    device_back = []
+    monkeypatch.setattr(edb.BREAKER, "probe", lambda: bool(device_back))
     faults.configure(["ops.ed25519.device:raise@1"], seed=3)
 
     # same-dispatch fallback: correct bitmap despite the device failure
     assert edb.verify_batch(items).tolist() == expect
     assert edb.BREAKER.is_open and edb.BREAKER.trips >= 1
+    assert isinstance(edb.BREAKER.last_error, faults.FaultInjected)
 
     # while open: host fallback keeps verifying (the consensus guarantee)
     assert edb.verify_batch(items).tolist() == expect
+    # the trip was logged once (not once per degraded batch), with the
+    # exception a reader needs to tell a dead link from a compiler refusal
+    trips = [r for r in caplog.records
+             if r.name == "tendermint_tpu.ops.breaker"]
+    assert len(trips) == 1 and trips[0].levelno == logging.ERROR
+    assert "ed25519-device" in trips[0].getMessage()
+    assert "FaultInjected" in trips[0].getMessage()
+    assert trips[0].exc_info and trips[0].exc_info[1] is edb.BREAKER.last_error
 
     # after cooldown the background probe re-closes the circuit
-    monkeypatch.setattr(edb.BREAKER, "probe", lambda: True)
+    device_back.append(True)
     time.sleep(0.1)
     edb.verify_batch(items)  # allow() kicks the probe
     deadline = time.monotonic() + 10
@@ -317,6 +335,48 @@ def test_sr25519_device_failure_falls_back(monkeypatch):
     assert list(srb.verify_batch(items)) == [True, False]
     assert srb.BREAKER.is_open
     srb.BREAKER.reset()
+
+
+def test_failed_warmup_is_recorded_and_logged(monkeypatch, caplog):
+    """warmup() never kills a node, but its outcome is readable
+    (crypto_batch.WARMUP) and a failure is logged at error level -- both
+    when a step raises and when verify_batch only degraded to the host."""
+    import logging
+
+    from tendermint_tpu.crypto import batch as cbatch
+    from tendermint_tpu.ops import ed25519_batch as edb
+
+    monkeypatch.delenv("TM_TPU_SKIP_WARMUP", raising=False)
+    monkeypatch.setenv("TM_TPU_SHARD", "0")  # no mesh compile in tier-1
+    caplog.set_level(logging.ERROR, logger="tendermint_tpu.crypto.batch")
+
+    def boom():
+        raise RuntimeError("no calibration today")
+
+    monkeypatch.setattr(cbatch, "WARMUP", cbatch.WarmupStatus())
+    monkeypatch.setattr(edb, "calibrate_host_crossover", boom)
+    assert cbatch.warmup(background=False) is None
+    assert cbatch.WARMUP.state == "failed"
+    assert "no calibration today" in str(cbatch.WARMUP.error)
+    assert cbatch.warmup(background=False) is None  # once per process
+    logged = [r for r in caplog.records if r.levelno == logging.ERROR]
+    assert len(logged) == 1 and "no calibration today" in logged[0].getMessage()
+    assert logged[0].exc_info[1] is cbatch.WARMUP.error
+
+    # the device route fails, the breaker answers from the host: verify_batch
+    # returns normally, and the warm-up must still not report success
+    caplog.clear()
+    monkeypatch.setattr(cbatch, "WARMUP", cbatch.WarmupStatus())
+    monkeypatch.setattr(edb, "calibrate_host_crossover", lambda: 0)
+    edb.BREAKER.reset()
+    faults.configure(["ops.ed25519.device:raise"], seed=1)
+    t = cbatch.warmup()
+    assert cbatch.WARMUP.join(30) and not t.is_alive()
+    assert cbatch.WARMUP.state == "failed"
+    assert isinstance(cbatch.WARMUP.error.__cause__, faults.FaultInjected)
+    assert any("fell back to the host" in r.getMessage()
+               for r in caplog.records
+               if r.name == "tendermint_tpu.crypto.batch")
 
 
 # ---------------------------------------------------------------------------

@@ -272,3 +272,49 @@ def test_behaviour_reporter():
     rep.report(bad_message("p2", "evil"))
     assert sw.stopped and "bad_message" in sw.stopped[0]
     assert store.get_peer_trust_metric("p2").trust_value() < 1.0
+
+
+def test_jaxcache_placement(tmp_path):
+    """The persistent compile cache goes where JAX_COMPILATION_CACHE_DIR says
+    and then this module changes no config; unset, it is the one fixed
+    gitignored directory inside the checkout (a copy of the tree carries it;
+    nothing under ~ or a temp dir, nothing named after a pid)."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    probe = (
+        "import json, jax\n"
+        "keys = ('jax_compilation_cache_dir',"
+        " 'jax_persistent_cache_min_compile_time_secs',"
+        " 'jax_persistent_cache_min_entry_size_bytes')\n"
+        "before = [getattr(jax.config, k) for k in keys]\n"
+        "from tendermint_tpu.utils import jaxcache\n"
+        "jaxcache.enable()\n"
+        "print(json.dumps([before, [getattr(jax.config, k) for k in keys],"
+        " jaxcache.CACHE_DIR]))\n")
+
+    def run(env_dir):
+        env = {k: v for k, v in os.environ.items()
+               if k not in ("JAX_COMPILATION_CACHE_DIR", "TM_TPU_JAX_CACHE")}
+        env["JAX_PLATFORMS"] = "cpu"
+        if env_dir:
+            env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+        out = subprocess.run([sys.executable, "-c", probe], cwd=repo, env=env,
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr
+        return json.loads(out.stdout.strip().splitlines()[-1])
+
+    theirs = str(tmp_path / "operator-cache")
+    before, after, _ = run(theirs)
+    assert before == after and after[0] == theirs
+    assert not os.path.exists(theirs)  # nothing compiled, nothing written
+
+    before, after, fixed = run(None)
+    assert before[0] is None
+    assert after[0] == fixed == os.path.join(repo, ".jax_cache")
+    ignored = subprocess.run(["git", "check-ignore", "-q", ".jax_cache/x"],
+                             cwd=repo)
+    assert ignored.returncode in (0, 128)  # 128: not a git checkout

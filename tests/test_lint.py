@@ -99,7 +99,7 @@ def test_whole_tree_lints_clean_fast():
 
 
 def test_cli_acceptance_command_exits_zero():
-    """The documented invocation (docs/LINT.md, docs/QA.md):
+    """The documented invocation (docs/LINT.md):
     `python -m tools.tmlint tendermint_tpu tests` — subprocess-level so
     the CLI wiring itself is pinned, and timed (<~10 s acceptance)."""
     t0 = time.monotonic()

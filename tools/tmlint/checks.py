@@ -569,7 +569,7 @@ def _tarjan(graph: dict) -> list[set]:
 # single blocking fetch, itself routed through _device_get). Everything
 # else must go through PendingVerify/resolve_all or the service; a stray
 # device_get/block_until_ready anywhere else re-introduces an unshared
-# ~104 ms sync floor the ROADMAP-1 campaign just removed.
+# host<->device round trip.
 _DEVICE_ALLOW_DIRS = ("tendermint_tpu/ops/", "tendermint_tpu/parallel/")
 _DEVICE_CHOKE_FUNCS = (
     ("tendermint_tpu/crypto/batch.py", "_device_get"),
@@ -615,8 +615,8 @@ def check_device_sync(project: Project) -> list[Finding]:
                 sf.path, node.lineno, "device-sync-choke-point",
                 f"{hit} outside the audited sync sites — route through "
                 f"crypto/batch._device_get (PendingVerify/resolve_all) or "
-                f"the verify service's _readback so the ~104 ms sync floor "
-                f"stays at the audited choke points"))
+                f"the verify service's _readback so blocking round trips "
+                f"stay at the audited choke points"))
     return out
 
 

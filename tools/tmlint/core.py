@@ -23,7 +23,7 @@ _SKIP_DIRS = {"__pycache__", ".git", ".claude", ".pytest_cache"}
 # The ONE default scan set (CLI, __graft_entry__.lint_gate, the tier-1
 # gate in tests/test_lint.py all import this — hand-copied lists drift).
 DEFAULT_PATHS = ["tendermint_tpu", "tools", "tests",
-                 "bench.py", "__graft_entry__.py"]
+                 "bench.py", "chip_smoke.py", "__graft_entry__.py"]
 
 # Paths (relative, '/'-separated) treated as *production* code: the
 # concurrency/device rules apply here. Tests may spawn bare threads and
