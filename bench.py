@@ -120,67 +120,15 @@ class Rounds:
 
 # --------------------------------------------------------------------------
 # Phase attribution (docs/OBSERVABILITY.md): where does a decision's wall
-# time go — host prep, queue wait, device compute, readback, replay?
+# time go, from the production spans of the flight recorder?
 # --------------------------------------------------------------------------
-
-
-def _phase_attribution(items, p50_ms: float) -> dict:
-    """One instrumented pass of the headline workload, split into the
-    canonical verify phases (utils/trace.py CANONICAL_SPANS). Run OUTSIDE
-    the timed rounds: the extra `block_until_ready` sync that separates
-    device compute from readback would perturb the p50 (bench-level code
-    may call it — the tmlint device-sync-choke-point rule scopes to
-    tendermint_tpu/). TMTPU_TRACE_XPROF=<dir> additionally wraps the pass
-    in jax.profiler traces for TensorBoard/xprof."""
-    import contextlib
-
-    import jax
-
-    from tendermint_tpu.ops import ed25519_batch
-    from tendermint_tpu.utils import trace as tmtrace
-
-    xprof = os.environ.get("TMTPU_TRACE_XPROF")
-    with contextlib.ExitStack() as stack:
-        if xprof:
-            stack.enter_context(tmtrace.jax_profile(xprof))
-        t0 = time.monotonic()
-        dev, finish = ed25519_batch.dispatch_batch(items)
-        t1 = time.monotonic()
-        if dev is not None:
-            jax.block_until_ready(dev)
-        t2 = time.monotonic()
-        fetched = jax.device_get(dev) if dev is not None else None
-        t3 = time.monotonic()
-        out = finish(fetched)
-        t4 = time.monotonic()
-    assert all(bool(b) for b in out)
-    phases_us = {
-        "host_prep": (t1 - t0) * 1e6,
-        "queue": 0.0,  # sync pass: resolve follows dispatch immediately
-        "device": (t2 - t1) * 1e6,
-        "readback": (t3 - t2) * 1e6,
-        "replay": (t4 - t3) * 1e6,
-    }
-    wall_us = (t4 - t0) * 1e6
-    total_us = sum(phases_us.values())
-    p50_us = p50_ms * 1e3
-    # the coverage number is vs the INDEPENDENTLY measured p50 (the timed
-    # rounds), never vs this pass's own wall — the phases are consecutive
-    # deltas of that wall, so a self-ratio would be identically 100%
-    return {
-        "phases_us": {k: round(v, 1) for k, v in phases_us.items()},
-        "pct_of_p50": {k: round(100.0 * v / p50_us, 1)
-                       for k, v in phases_us.items()},
-        "wall_ms": round(wall_us / 1e3, 2),
-        "attributed_pct_of_p50": round(100.0 * total_us / p50_us, 1),
-    }
 
 
 def _span_phases_us(agg: dict) -> dict:
     """Tracer aggregation -> canonical phase table (us). The device phase
     is folded into readback on the production spans (the host blocks in
-    _device_get until the kernel finishes); the bench headline pass above
-    separates them with an explicit sync."""
+    _device_get until the kernel finishes); device time proper comes from
+    the profiler trace of a benchmark/run.py --trace 1 run."""
     def us(name):
         return agg.get(name, {}).get("total_s", 0.0) * 1e6
 
@@ -1193,11 +1141,6 @@ def main() -> None:
     # Headline: the north-star 20,480-sig commit.
     headline, hdetail = rr.run(lambda: ed25519_batch.verify_batch(items))
 
-    # Phase attribution (ISSUE 10): a separate instrumented pass so the
-    # extra device sync never lands inside a timed round. This is the
-    # measured target the ROADMAP-1 continuous-batching work shrinks.
-    attribution = _phase_attribution(items, headline)
-
     # Marginal cost with the fixed floor removed: (p50(N) - p50(N/4)) over
     # the extra signatures, both min-of-rounds. A quarter batch rides the
     # same sync floor, so the difference is pure per-signature cost.
@@ -1248,7 +1191,6 @@ def main() -> None:
         "marginal_us_per_sig": round(marginal_us, 2),
         "host_prep_ms": round(tprep, 1),
         "spread": hdetail["spread"],
-        "phase_attribution": attribution,
         "configs": {k: {kk: vv for kk, vv in v.items()
                         if kk in ("metric", "value", "unit", "vs_baseline",
                                   "spread", "error", "depth1_blocks_per_s",
@@ -1281,7 +1223,6 @@ def main() -> None:
     _log(f"# headline: rounds={hdetail['rounds_ms']}ms "
          f"spread={hdetail['spread']}x spins={hdetail['spins_ms']}ms "
          f"retries={hdetail['retries']}")
-    _log(f"# phase_attribution: {json.dumps(attribution)}")
     _log(f"# gen={gen_s:.1f}s warmup={warm_s:.1f}s sync_floor={floor_ms:.1f}ms "
          f"(fixed host<->device round-trip of this link, paid once per "
          f"decision) host_prep={tprep:.1f}ms "

@@ -222,11 +222,16 @@ class VerifyAheadPipeline:
         # host-resolved) are untouched; later resolves are then instant.
         head = self._entries.popleft()
         try:
-            if head.pending.pending is not None and head.pending.pending.has_device_output():
-                crypto_batch.prefetch(
-                    [e.pending.pending for e in [head, *self._entries]
-                     if e.pending.pending is not None])
-            head.pending.resolve()
+            # the head's wait, as the syncing node feels it: the batched
+            # fetch of everything in flight, then the head's own replay
+            with (_trace.current().span("fastsync.head_wait",
+                                        height=head.height)
+                  if _trace.ENABLED else _trace.NULL_SPAN):
+                if head.pending.pending is not None and head.pending.pending.has_device_output():
+                    crypto_batch.prefetch(
+                        [e.pending.pending for e in [head, *self._entries]
+                         if e.pending.pending is not None])
+                head.pending.resolve()
         except Exception as e:  # noqa: BLE001 - the serial invalid-block path
             self.discard()
             reactor._punish_invalid(head.height, e)

@@ -61,7 +61,7 @@ class PendingVerify:
     has_device_output()."""
 
     __slots__ = ("_devs", "_resolve", "_result", "_tracer", "_t_disp",
-                 "_t_height", "_children")
+                 "_t_height", "_t_decision", "_t_parent", "_children")
 
     def __init__(self, devs, resolve_fn, children=()):
         self._devs = list(devs)
@@ -70,11 +70,14 @@ class PendingVerify:
         self._children = tuple(children)
         # flight-recorder context captured at dispatch (utils/trace.py):
         # the dispatching node's tracer, the dispatch timestamp (queue-wait
-        # phase = resolve start - dispatch end), and the height context so
-        # phases land on the right timeline even when resolve happens later
+        # phase = resolve start - dispatch end), and the height, decision id
+        # and causing span, so phases land on the right timeline and in the
+        # right decision's tree even when resolve happens later, elsewhere
         self._tracer = None
         self._t_disp = 0.0
         self._t_height = None
+        self._t_decision = 0
+        self._t_parent = 0
 
     @property
     def resolved(self) -> bool:
@@ -99,8 +102,23 @@ class PendingVerify:
         self._devs = [None] * len(self._devs)
         self._resolve = None
 
+    def _capture(self, tracer) -> None:
+        """Flight-recorder context of the dispatching thread, now."""
+        self._tracer = tracer
+        self._t_disp = _time.monotonic()
+        self._t_height = tracer.current_height()
+        self._t_decision = tracer.current_decision()
+        self._t_parent = tracer.current_span()
+
     def _trace_tags(self) -> dict:
-        return {} if self._t_height is None else {"height": self._t_height}
+        return _trace.handle_tags(self._t_height, self._t_decision)
+
+    def _record_queue(self, tracer, now: float) -> None:
+        """verify.queue: dispatch -> now, caused by the dispatching span."""
+        if self._t_disp:
+            tracer.record("verify.queue", now - self._t_disp,
+                          start=self._t_disp, parent=self._t_parent or None,
+                          **self._trace_tags())
 
     def resolve(self) -> tuple[bool, list[bool]]:
         """Fetch (one _device_get when device outputs are pending) and
@@ -109,9 +127,7 @@ class PendingVerify:
             tr = self._tracer
             if tr is not None and tr.enabled:
                 tags = self._trace_tags()
-                if self._t_disp:
-                    tr.record("verify.queue",
-                              _time.monotonic() - self._t_disp, **tags)
+                self._record_queue(tr, _time.monotonic())
                 # _devs_pending, NOT has_device_output: a handle whose only
                 # in-flight work is service-backed children has nothing to
                 # fetch itself — a _device_get here would be a pointless
@@ -154,7 +170,15 @@ class ServicePending(PendingVerify):
 
     def _finish(self, _fetched) -> None:
         req = self._req
-        req.done.wait()
+        if req.tracer is not None and not req.done.is_set():
+            # the caller really sleeps on the executor: time its wake-up
+            # (done.set() on the executor -> this thread runs again)
+            req.done.wait()
+            req.tracer.record("verify.wake", _time.monotonic() - req.t_done,
+                              start=req.t_done, parent=req.parent or None,
+                              **req.tags())
+        else:
+            req.done.wait()
         if req.error is not None:
             raise req.error
         self._result = req.result
@@ -190,13 +214,13 @@ def prefetch(pendings) -> None:
         if tr.enabled:
             now = _time.monotonic()
             for p in unres:
-                if p._t_disp:
-                    pt = p._tracer if p._tracer is not None else tr
-                    pt.record("verify.queue", now - p._t_disp,
-                              **p._trace_tags())
-            with tr.span("verify.readback", batched=len(unres)):
+                p._record_queue(p._tracer if p._tracer is not None else tr, now)
+            # one fetch for several decisions: the spans name all of them
+            served = sorted({p._t_decision for p in unres if p._t_decision})
+            tags = {"decisions": served} if served else {}
+            with tr.span("verify.readback", batched=len(unres), **tags):
                 fetched = _device_get([p._devs for p in unres])
-            with tr.span("verify.replay", batched=len(unres)):
+            with tr.span("verify.replay", batched=len(unres), **tags):
                 for p, f in zip(unres, fetched):
                     p._finish(f)
             return
@@ -353,15 +377,16 @@ class _KernelBatchVerifier(BatchVerifier):
             out = [bool(b) for b in finish(fetched[0])]
             if tmmetrics.GLOBAL_NODE_METRICS is not None:
                 m = tmmetrics.GLOBAL_NODE_METRICS
-                m.batch_verify_seconds.observe(_t.monotonic() - started)
+                # the route is read after finish(): a fetch-time device
+                # failure turns it into breaker_fallback
+                m.batch_verify_seconds.observe(_t.monotonic() - started,
+                                               route=finish.route)
                 m.batch_verify_sigs.add(len(items))
             return all(out), out
 
         p = PendingVerify([dev], resolve)
         if tracer is not None and tracer.enabled:
-            p._tracer = tracer
-            p._t_disp = _t.monotonic()
-            p._t_height = tracer.current_height()
+            p._capture(tracer)
         return p
 
     def verify(self) -> tuple[bool, list[bool]]:
@@ -450,9 +475,7 @@ class MixedBatchVerifier(BatchVerifier):
             svc_children = any(isinstance(p, ServicePending)
                                for (_, p, _, _) in spans)
             if tracer.enabled and not svc_children:
-                mixed._tracer = tracer
-                mixed._t_disp = _time.monotonic()
-                mixed._t_height = tracer.current_height()
+                mixed._capture(tracer)
         return mixed
 
     def verify(self) -> tuple[bool, list[bool]]:
@@ -539,6 +562,11 @@ def warmup(sizes: tuple[int, ...] = (64,), background: bool = True):
                 raise RuntimeError(
                     "warm-up batch fell back to the host") from last_error
             WARMUP.state = "done"
+            # where the warm-up went, from the start-up ring (recorded with
+            # tracing off too; docs/OBSERVABILITY.md)
+            _log.info("kernel warm-up done: %s", ", ".join(
+                f"{name} {agg['total_s']:.3f}s x{agg['count']}"
+                for name, agg in sorted(_trace.STARTUP.summarize().items())))
         except Exception as e:  # noqa: BLE001 - warmup must never kill a node
             WARMUP.error = e
             WARMUP.state = "failed"

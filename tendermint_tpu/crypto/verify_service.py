@@ -39,8 +39,13 @@ the verify plane:
    tmlint ``device-sync-choke-point`` rule, and routed through
    crypto/batch._device_get so the perf-gate fetch spy still counts it);
  * queue/launch/readback/replay spans are recorded on the DISPATCHING
-   node's tracer (each request captures utils/trace.current() at submit),
-   so flight-recorder phase attribution stays per-node-accurate.
+   node's tracer (each request captures utils/trace.current() at submit,
+   with the decision id and the span that caused it), so flight-recorder
+   phase attribution stays per-node-accurate and every span of a commit
+   decision, on the caller's thread or this one, is one tree. The executor
+   works INSIDE real spans of the first request's tracer, so the ops
+   layer's prep.* spans nest under verify.host_prep; other nodes' tracers
+   that share a launch get a recorded copy.
 
 Knobs (docs/CONFIG.md): ``TMTPU_VERIFY_SERVICE=0`` restores direct
 per-caller dispatch; ``TMTPU_VERIFY_WINDOW_US`` sets the coalescing window
@@ -50,6 +55,7 @@ per-caller dispatch; ``TMTPU_VERIFY_WINDOW_US`` sets the coalescing window
 
 from __future__ import annotations
 
+import contextlib
 import importlib
 import os
 import queue
@@ -137,14 +143,44 @@ def _readback(tree):
     return _batch._device_get(tree)
 
 
-def _safe_record(tracer, name: str, duration_s: float, **tags) -> None:
+def _safe_record(req, name: str, duration_s: float, start: float,
+                 **tags) -> None:
     """Flight-recorder writes from the executor must never be able to
     strand a generation's waiters: a tracer/metric-mirror failure is
-    swallowed (the span is lost, the verification is not)."""
+    swallowed (the span is lost, the verification is not). `start` is when
+    the work began; `req` gives the tracer, the causing span, the decision
+    and the height captured on the submitting thread."""
     try:
-        tracer.record(name, duration_s, **tags)
+        req.tracer.record(name, duration_s, start=start,
+                          parent=req.parent or None, **req.tags(), **tags)
     except Exception:  # noqa: BLE001 - observability never blocks resolution
         pass
+
+
+@contextlib.contextmanager
+def _safe_span(req, name: str, **tags):
+    """The executor's work inside a REAL span of `req`'s tracer (activated
+    on this thread, so the ops layer's spans nest under it), or nothing
+    when no request of the launch is traced. Same promise as _safe_record:
+    the tracer's own failures are swallowed, the body's are not."""
+    stack = None
+    if req is not None:
+        stack = contextlib.ExitStack()
+        try:
+            stack.enter_context(req.tracer.activate())
+            stack.enter_context(req.tracer.span(
+                name, parent=req.parent or None, **req.tags(), **tags))
+        except Exception:  # noqa: BLE001 - the span is lost, the work is not
+            stack.close()
+            stack = None
+    try:
+        yield
+    finally:
+        if stack is not None:
+            try:
+                stack.close()
+            except Exception:  # noqa: BLE001
+                pass
 
 
 class _Request:
@@ -153,7 +189,8 @@ class _Request:
     captured on the submitting thread."""
 
     __slots__ = ("kind", "items", "force_device", "done", "result", "error",
-                 "tracer", "t_submit", "height")
+                 "tracer", "t_submit", "t_done", "height", "decision",
+                 "parent")
 
     def __init__(self, kind, items, force_device):
         self.kind = kind
@@ -164,7 +201,14 @@ class _Request:
         self.error: BaseException | None = None
         self.tracer = None
         self.t_submit = 0.0
+        self.t_done = 0.0     # stamped just before done.set() (verify.wake)
         self.height = None
+        self.decision = 0     # id of the commit decision this serves, or 0
+        self.parent = 0       # the span that submitted it
+
+    def tags(self) -> dict:
+        """height= / decision= for this request's spans, where known."""
+        return _trace.handle_tags(self.height, self.decision)
 
 
 class VerifyService:
@@ -194,6 +238,8 @@ class VerifyService:
             if tr.enabled:
                 req.tracer = tr
                 req.height = tr.current_height()
+                req.decision = tr.current_decision()
+                req.parent = tr.current_span()
         req.t_submit = _time.monotonic()
         self.requests += 1
         self._ensure_thread()
@@ -271,9 +317,7 @@ class VerifyService:
         t0 = _time.monotonic()
         for r in reqs:
             if r.tracer is not None:
-                _safe_record(r.tracer, "verify.queue", t0 - r.t_submit,
-                             **({} if r.height is None
-                                else {"height": r.height}))
+                _safe_record(r, "verify.queue", t0 - r.t_submit, r.t_submit)
         groups: dict[str, list[_Request]] = {}
         for r in reqs:
             groups.setdefault(r.kind, []).append(r)
@@ -290,13 +334,16 @@ class VerifyService:
         except Exception as e:  # noqa: BLE001 - unknown kind / import failure
             self._resolve_error(greqs, e)
             return None
+        lead, others, tags = self._traced(greqs)
+        tags.update(kind=kind, sigs=len(items))
         t0 = _time.monotonic()
         try:
             # Same entry the callers used directly: crossover routing,
             # sharding on the COALESCED size, ops.*.device fault site, and
             # the circuit breaker (a dispatch-time device failure already
             # comes back as the host fallback's (None, finish)).
-            dev, finish = mod.dispatch_batch(items, force_device=force)
+            with _safe_span(lead, "verify.host_prep", **tags):
+                dev, finish = mod.dispatch_batch(items, force_device=force)
         except Exception:  # noqa: BLE001 - belt and braces under the breaker
             self._resolve_scalar(mod, greqs)
             return None
@@ -304,12 +351,8 @@ class VerifyService:
         self.launches += 1
         self.coalesced_items += len(items)
         self.max_coalesced = max(self.max_coalesced, len(greqs))
-        for tr, height in self._unique_tracers(greqs):
-            tags = {} if height is None else {"height": height}
-            _safe_record(tr, "verify.host_prep", prep_s,
-                         coalesced=len(greqs), sigs=len(items), **tags)
-            _safe_record(tr, "verify.coalesce", 0.0, kind=kind,
-                         requests=len(greqs), sigs=len(items), **tags)
+        for r in others:
+            _safe_record(r, "verify.host_prep", prep_s, t0, **tags)
         return (kind, mod, greqs, items, dev, finish)
 
     def _complete(self, gen) -> None:
@@ -319,44 +362,63 @@ class VerifyService:
         the kind's breaker to the host fallback; every waiter resolves
         exactly once on every path."""
         for kind, mod, greqs, items, dev, finish in gen:
+            lead, others, tags = self._traced(greqs)
             t0 = _time.monotonic()
-            fetched = None
-            if dev is not None:
-                try:
-                    fetched = _readback(dev)
-                except Exception as e:  # noqa: BLE001 - dead device at fetch
-                    mod.BREAKER.record_failure(e)
-                    try:
-                        dev, finish = mod._host_fallback(items, len(items))
-                        fetched = None
-                    except Exception:  # noqa: BLE001
-                        self._resolve_scalar(mod, greqs)
-                        continue
-            t1 = _time.monotonic()
-            try:
-                bitmap = finish(fetched)
-            except Exception:  # noqa: BLE001 - finish_cb already fell back
-                self._resolve_scalar(mod, greqs)
+            with _safe_span(lead, "verify.readback", **tags):
+                got = self._fetch(mod, greqs, items, dev, finish)
+            if got is None:
                 continue
-            off = 0
-            for r in greqs:
-                n = len(r.items)
-                lanes = [bool(b) for b in bitmap[off:off + n]]
-                off += n
-                r.result = (all(lanes), lanes)
+            fetched, finish = got
+            t1 = _time.monotonic()
+            with _safe_span(lead, "verify.replay", **tags):
+                ok = self._replay(mod, greqs, finish, fetched)
+            if not ok:
+                continue
             t2 = _time.monotonic()
-            self._observe(greqs, t2)
-            for tr, height in self._unique_tracers(greqs):
-                tags = {} if height is None else {"height": height}
-                _safe_record(tr, "verify.readback", t1 - t0,
-                             coalesced=len(greqs), **tags)
-                _safe_record(tr, "verify.replay", t2 - t1,
-                             coalesced=len(greqs), **tags)
+            self._observe(greqs, t2, getattr(finish, "route", ""))
+            for r in others:
+                _safe_record(r, "verify.readback", t1 - t0, t0, **tags)
+                _safe_record(r, "verify.replay", t2 - t1, t1, **tags)
             # wake waiters LAST: a woken caller immediately contends for
             # the GIL, which would otherwise inflate the replay span with
             # the callers' own post-resolve work
             for r in greqs:
+                r.t_done = _time.monotonic()
                 r.done.set()
+
+    def _fetch(self, mod, greqs, items, dev, finish):
+        """The generation's one blocking readback. -> (fetched, finish), or
+        None when a dead device at fetch left nothing but the scalar floor
+        (every waiter then already resolved)."""
+        if dev is None:
+            return None, finish
+        try:
+            return _readback(dev), finish
+        except Exception as e:  # noqa: BLE001 - dead device at fetch
+            mod.BREAKER.record_failure(e)
+            try:
+                _, finish = mod._host_fallback(items, len(items),
+                                               route="breaker_fallback")
+                return None, finish
+            except Exception:  # noqa: BLE001
+                self._resolve_scalar(mod, greqs)
+                return None
+
+    def _replay(self, mod, greqs, finish, fetched) -> bool:
+        """Bitmap -> each request's (all_ok, lanes). False when finish
+        itself failed and the scalar floor resolved the waiters."""
+        try:
+            bitmap = finish(fetched)
+        except Exception:  # noqa: BLE001 - finish_cb already fell back
+            self._resolve_scalar(mod, greqs)
+            return False
+        off = 0
+        for r in greqs:
+            n = len(r.items)
+            lanes = [bool(b) for b in bitmap[off:off + n]]
+            off += n
+            r.result = (all(lanes), lanes)
+        return True
 
     # --- degradation floors -------------------------------------------------
 
@@ -376,33 +438,48 @@ class VerifyService:
                 r.result = (all(lanes), lanes)
             except Exception as e:  # noqa: BLE001
                 r.error = e
+            r.t_done = _time.monotonic()
             r.done.set()
 
     def _resolve_error(self, greqs: list[_Request], e: BaseException) -> None:
         for r in greqs:
             if not r.done.is_set():
                 r.error = e
+                r.t_done = _time.monotonic()
                 r.done.set()
 
     # --- helpers ------------------------------------------------------------
 
     @staticmethod
-    def _unique_tracers(greqs):
-        """(tracer, height) per distinct dispatching tracer: shared-phase
-        durations are recorded ONCE per node per generation, so a node with
-        several requests in one launch doesn't double-count the shared
-        prep/readback in its phase attribution."""
-        seen = {}
+    def _traced(greqs):
+        """-> (lead, others, tags). `lead` is the first traced request of
+        the launch: the executor works inside real spans of ITS tracer.
+        `others` is one request for every further tracer that shares the
+        launch; those get a recorded copy, so shared-phase durations land
+        ONCE per node per generation and a node with several requests in
+        one launch doesn't double-count the shared prep/readback. `tags`
+        says how many requests coalesced and, when the launch serves more
+        than one decision, which (`decisions`)."""
+        lead, others, seen = None, [], set()
         for r in greqs:
             if r.tracer is not None and id(r.tracer) not in seen:
-                seen[id(r.tracer)] = (r.tracer, r.height)
-        return seen.values()
+                seen.add(id(r.tracer))
+                if lead is None:
+                    lead = r
+                else:
+                    others.append(r)
+        tags = {"coalesced": len(greqs)}
+        served = sorted({r.decision for r in greqs if r.decision})
+        if len(served) > 1:
+            tags["decisions"] = served
+        return lead, others, tags
 
-    def _observe(self, greqs, t_done: float) -> None:
+    def _observe(self, greqs, t_done: float, route: str) -> None:
         """Per-REQUEST metrics, preserving the direct path's semantics:
         batch_verify_seconds spans dispatch(submit)->resolved — host prep,
         coalescing window, queue, device, and readback included — so the
-        histogram's meaning does not silently change with the service on."""
+        histogram's meaning does not silently change with the service on.
+        `route` is the one that answered the shared launch."""
         try:
             from tendermint_tpu.utils import metrics as tmmetrics
 
@@ -410,7 +487,8 @@ class VerifyService:
             if m is None:
                 return
             for r in greqs:
-                m.batch_verify_seconds.observe(t_done - r.t_submit)
+                m.batch_verify_seconds.observe(t_done - r.t_submit,
+                                               route=route)
                 m.batch_verify_sigs.add(len(r.items))
         except Exception:  # noqa: BLE001 - metrics must not strand waiters
             pass

@@ -119,11 +119,27 @@ class CircuitBreaker:
             self._open_until = 0.0
 
 
+# Which route answered a batch, decided where dispatch_batch decides it: the
+# `route` tag of the prep.launch / prep.host_verify spans and the label set
+# of batch_verify_seconds (utils/metrics.py seeds exactly these).
+ROUTES = ("pallas", "jnp", "sharded", "host_c", "host_scalar",
+          "breaker_fallback")
+
+
+def routed(finish, route: str):
+    """Name the route on a dispatch's ``finish`` callable, where the caller
+    that observes the batch's latency reads it (``finish.route``)."""
+    finish.route = route
+    return finish
+
+
 def guarded_dispatch(breaker: CircuitBreaker, dispatch_fn, fallback_fn):
     """The one degradation shape both kernel modules share: run
     ``dispatch_fn() -> (dev, finish)`` behind ``breaker``; any dispatch- or
     finish-time failure records on the breaker and re-verifies via
-    ``fallback_fn() -> (None, finish)`` in the same call."""
+    ``fallback_fn() -> (None, finish)`` in the same call. The returned
+    finish carries the route that answered (``.route``), which a
+    finish-time failure turns into the fallback's."""
     if not breaker.allow():
         return fallback_fn()
     try:
@@ -138,11 +154,12 @@ def guarded_dispatch(breaker: CircuitBreaker, dispatch_fn, fallback_fn):
         except Exception as e:  # noqa: BLE001
             breaker.record_failure(e)
             _, fb = fallback_fn()
+            finish_cb.route = getattr(fb, "route", "breaker_fallback")
             return fb(None)
         breaker.record_success()
         return out
 
-    return dev, finish_cb
+    return dev, routed(finish_cb, getattr(finish, "route", ""))
 
 
 def guarded_fetch(breaker: CircuitBreaker, dev, finish, fallback_fn):

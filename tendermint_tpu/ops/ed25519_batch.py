@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import os
 import threading
+import time as _time
 from collections import OrderedDict
 
 import jax
@@ -44,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tendermint_tpu.utils import faults, jaxcache
+from tendermint_tpu.utils import trace as _trace
 
 jaxcache.enable()
 
@@ -344,6 +346,7 @@ def _normalize_pubs(pubs: list[bytes]) -> tuple[bytes, np.ndarray]:
 
 def build_keyset(pubs: list[bytes], cache: OrderedDict, lock: threading.Lock,
                  decode_neg, uniq_cache: OrderedDict | None = None,
+                 kind: str = "ed25519",
                  ) -> tuple[KeySet, np.ndarray, np.ndarray]:
     """Shared key-set machinery for any Edwards-comb key type.
 
@@ -354,14 +357,30 @@ def build_keyset(pubs: list[bytes], cache: OrderedDict, lock: threading.Lock,
     verify-service launch — reuses the device-resident comb tables and
     only recomputes the item->row mapping. decode_neg: pubkey bytes ->
     extended limbs of -A or None (ed25519 uses RFC 8032 decompression,
-    sr25519 ristretto255 decode)."""
+    sr25519 ristretto255 decode). `kind` only names the key type on the
+    flight-recorder spans (prep.keyset; on a miss the start-up ring's
+    startup.key_decode and startup.table_build)."""
+    if _trace.ENABLED:
+        tr = _trace.current()
+        with tr.span("prep.keyset", keys=len(pubs), kind=kind):
+            ks, key_idx, pub_ok, hit = _build_keyset(
+                pubs, cache, lock, decode_neg, uniq_cache, kind)
+            tr.annotate(hit=hit)
+        return ks, key_idx, pub_ok
+    return _build_keyset(pubs, cache, lock, decode_neg, uniq_cache, kind)[:3]
+
+
+def _build_keyset(pubs, cache, lock, decode_neg, uniq_cache, kind):
+    """-> (KeySet, key_idx, pub_ok, hit): hit is "sequence" (level 1),
+    "set" (level 2: tables reused, the item->row mapping recomputed) or
+    "miss" (keys decompressed, tables built on the device)."""
     joined, pub_ok = _normalize_pubs(pubs)
     with lock:
         hit = cache.get(joined)
         if hit is not None:
             cache.move_to_end(joined)
             ks, key_idx = hit
-            return ks, key_idx, pub_ok
+            return ks, key_idx, pub_ok, "sequence"
 
     # dedupe in first-occurrence order, then canonicalize row order by
     # sorting the unique keys: the set digest (and the table row layout)
@@ -394,8 +413,12 @@ def build_keyset(pubs: list[bytes], cache: OrderedDict, lock: threading.Lock,
             ks = uniq_cache.get(set_key)
             if ks is not None:
                 uniq_cache.move_to_end(set_key)
+    how = "set"
     if ks is None:
-        # decompress unique keys, build comb tables on device
+        # decompress unique keys, build comb tables on device. A cold path:
+        # both halves go to the start-up ring, tracing on or off.
+        how = "miss"
+        t0 = _time.monotonic()
         a_neg = np.broadcast_to(ed.IDENTITY_LIMBS, (len(uniq), 4, 20)).copy()
         valid = np.zeros((max(_round_up(len(uniq), KEY_TILE), KEY_TILE),),
                          dtype=bool)
@@ -404,7 +427,15 @@ def build_keyset(pubs: list[bytes], cache: OrderedDict, lock: threading.Lock,
             if neg is not None:
                 a_neg[j] = neg
                 valid[j] = True
-        tab_ext = _build_comb_tables_tiled(a_neg)
+        t1 = _time.monotonic()
+        # waits for the tables: the build is timed to its result, and the
+        # first kernel over a new key set waits for them anyway
+        tab_ext = _build_comb_tables_tiled(a_neg).block_until_ready()
+        t2 = _time.monotonic()
+        _trace.STARTUP.record("startup.key_decode", t1 - t0, start=t0,
+                              keys=len(uniq), kind=kind)
+        _trace.STARTUP.record("startup.table_build", t2 - t1, start=t1,
+                              keys=len(uniq), kind=kind)
         ks = KeySet(len(uniq), valid, tab_ext, key_idx)
     with lock:
         cache[joined] = (ks, key_idx)
@@ -414,7 +445,7 @@ def build_keyset(pubs: list[bytes], cache: OrderedDict, lock: threading.Lock,
             uniq_cache[set_key] = ks
             while len(uniq_cache) > _KS_UNIQ_MAX:
                 uniq_cache.popitem(last=False)
-    return ks, key_idx, pub_ok
+    return ks, key_idx, pub_ok, how
 
 
 def get_keyset(pubs: list[bytes]) -> tuple[KeySet, np.ndarray, np.ndarray]:
@@ -443,6 +474,15 @@ def _r_to_limbs(r32: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
 
 def prepare_scalars(items, pub_ok: np.ndarray, windows: bool = True,
                     reduce: bool = True, host_hash: bool = True):
+    """:func:`_prepare_scalars` inside its flight-recorder span."""
+    with (_trace.current().span("prep.scalars", sigs=len(items),
+                                kind="ed25519")
+          if _trace.ENABLED else _trace.NULL_SPAN):
+        return _prepare_scalars(items, pub_ok, windows, reduce, host_hash)
+
+
+def _prepare_scalars(items, pub_ok: np.ndarray, windows: bool, reduce: bool,
+                     host_hash: bool):
     """Vectorized per-signature prep: scalars, R bytes, validity.
 
     items: [(pub, msg, sig)]; pub_ok from get_keyset. Returns dict of numpy
@@ -575,7 +615,7 @@ def calibrate_host_crossover(device_marginal_us: float = 2.5) -> int:
         if not chost.ensure_available():
             _HOST_CAL["crossover"] = 0
             return 0
-        import time as _t
+        t_cal = _time.monotonic()
 
         # host RLC rate on 256 items (64 unique sigs tiled; the A-decompress
         # cache makes the tiling realistic for steady-state consensus)
@@ -591,9 +631,9 @@ def calibrate_host_crossover(device_marginal_us: float = 2.5) -> int:
         if not out.all():  # self-check failed: never route here
             _HOST_CAL["crossover"] = 0
             return 0
-        t0 = _t.monotonic()
+        t0 = _time.monotonic()
         chost.ed25519_verify(*args, mode=1)
-        host_us = (_t.monotonic() - t0) * 1e6 / len(items)
+        host_us = (_time.monotonic() - t0) * 1e6 / len(items)
         # sync floor of one flush round trip
         tiny = jax.jit(lambda a: a * 2)
         floor_ms = min(
@@ -602,28 +642,37 @@ def calibrate_host_crossover(device_marginal_us: float = 2.5) -> int:
         margin = max(host_us - device_marginal_us, 1.0)
         cross = int(min(max(floor_ms * 1e3 / margin, 256), 16384))
         _HOST_CAL.update(crossover=cross, floor_ms=floor_ms, host_us=host_us)
+        _trace.STARTUP.record("startup.calibrate", _time.monotonic() - t_cal,
+                              start=t_cal, crossover=cross, floor_ms=floor_ms,
+                              host_us=host_us)
         return cross
 
 
 def _measure_once(fn) -> float:
-    import time as _t
-
-    t0 = _t.monotonic()
+    t0 = _time.monotonic()
     fn()
-    return (_t.monotonic() - t0) * 1e3
+    return (_time.monotonic() - t0) * 1e3
 
 
-def _dispatch_host(items, n):
+def _host_span(route: str, n: int, kind: str = "ed25519"):
+    """prep.host_verify around a host verifier's whole answer."""
+    return (_trace.current().span("prep.host_verify", route=route, sigs=n,
+                                  kind=kind)
+            if _trace.ENABLED else _trace.NULL_SPAN)
+
+
+def _dispatch_host(items, n, route: str = "host_c"):
     """Synchronous host-path dispatch: C serial/RLC verify (ops/chost).
     Returns the (device_out=None, finish) pair of the dispatch contract."""
     from tendermint_tpu.ops import chost
 
-    joined, pub_ok = _normalize_pubs([it[0] for it in items])
-    s = prepare_scalars(items, pub_ok, windows=False)
-    pubs_arr = np.frombuffer(joined, dtype=np.uint8).reshape(n, 32)
-    bitmap = chost.ed25519_verify(pubs_arr, s["h32"], s["s32"], s["r32"],
-                                  s["valid"])
-    return None, lambda _unused: bitmap
+    with _host_span(route, n):
+        joined, pub_ok = _normalize_pubs([it[0] for it in items])
+        s = prepare_scalars(items, pub_ok, windows=False)
+        pubs_arr = np.frombuffer(joined, dtype=np.uint8).reshape(n, 32)
+        bitmap = chost.ed25519_verify(pubs_arr, s["h32"], s["s32"], s["r32"],
+                                      s["valid"])
+    return None, _cbreaker.routed(lambda _unused: bitmap, route)
 
 
 def _scalar_fallback_bitmap(items) -> np.ndarray:
@@ -634,15 +683,27 @@ def _scalar_fallback_bitmap(items) -> np.ndarray:
                        dtype=bool, count=len(items))
 
 
-def _host_fallback(items, n):
+def _host_fallback(items, n, route: str | None = None):
     """(device_out=None, finish) via the best available host path: the C
-    verifier when loaded, else the pure-Python scalar loop."""
+    verifier when loaded, else the pure-Python scalar loop. `route` names
+    the answer when it is not the host's own choice (breaker_fallback)."""
     from tendermint_tpu.ops import chost
 
     if chost.available():
-        return _dispatch_host(items, n)
-    bitmap = _scalar_fallback_bitmap(items)
-    return None, lambda _unused: bitmap
+        return _dispatch_host(items, n, route or "host_c")
+    route = route or "host_scalar"
+    with _host_span(route, n):
+        bitmap = _scalar_fallback_bitmap(items)
+    return None, _cbreaker.routed(lambda _unused: bitmap, route)
+
+
+def launch_span(program: str, route: str, left: int, lanes: int):
+    """prep.launch around the host's enqueue of ONE device program: `left`
+    real signatures were still to launch, `lanes` is what the call holds,
+    so sigs over lanes is the share of launched lanes that did work."""
+    return (_trace.current().span("prep.launch", program=program, route=route,
+                                  sigs=max(0, min(left, lanes)), lanes=lanes)
+            if _trace.ENABLED else _trace.NULL_SPAN)
 
 
 def _dispatch_device(items, n: int, multichip: bool):
@@ -666,7 +727,8 @@ def _dispatch_device(items, n: int, multichip: bool):
 
         dev = batch_shard.dispatch_batch_sharded(ks, key_idx, items, pub_ok)
         _start_host_copy(dev)
-        return dev, lambda v: np.asarray(v)[:n].astype(bool)
+        return dev, _cbreaker.routed(
+            lambda v: np.asarray(v)[:n].astype(bool), "sharded")
     if _use_pallas():
         # Prep is done chunk-by-chunk inside the pipelined path so device
         # compute overlaps host prep of the next chunk.
@@ -675,7 +737,8 @@ def _dispatch_device(items, n: int, multichip: bool):
         dev = ed25519_pallas.pack_bitmap(
             ed25519_pallas.dispatch_items_pipelined(ks, key_idx, items, pub_ok))
         _start_host_copy(dev)
-        return dev, lambda v: ed25519_pallas.unpack_bitmap(np.asarray(v), n)
+        return dev, _cbreaker.routed(
+            lambda v: ed25519_pallas.unpack_bitmap(np.asarray(v), n), "pallas")
     s = prepare_scalars(items, pub_ok, windows=True)
 
     # Fixed-tile chunking: every batch runs through the one JNP_TILE-shaped
@@ -686,13 +749,17 @@ def _dispatch_device(items, n: int, multichip: bool):
     padded = _jnp_args(s, n, nb)
     outs = []
     for off in range(0, nb, JNP_TILE):
-        tab = jnp.take(ks.tab_ext, jnp.asarray(idx[off : off + JNP_TILE]), axis=0)
-        outs.append(_jnp_kernel(tab, **{
-            k: jnp.asarray(v[off : off + JNP_TILE]) for k, v in padded.items()
-        }))
+        with launch_span("jit__verify_kernel", "jnp", n - off, JNP_TILE):
+            tab = jnp.take(ks.tab_ext, jnp.asarray(idx[off : off + JNP_TILE]),
+                           axis=0)
+            outs.append(_jnp_kernel(tab, **{
+                k: jnp.asarray(v[off : off + JNP_TILE])
+                for k, v in padded.items()
+            }))
     ok = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
     _start_host_copy(ok)
-    return ok, lambda v: np.asarray(v)[:n].astype(bool)
+    return ok, _cbreaker.routed(
+        lambda v: np.asarray(v)[:n].astype(bool), "jnp")
 
 
 def _device_probe() -> bool:
@@ -731,7 +798,8 @@ def dispatch_batch(items: list[tuple[bytes, bytes, bytes]],
     background probe re-closes it -- consensus keeps committing with a dead
     accelerator. While open, even force_device callers are degraded."""
     if not items:
-        return None, lambda _: np.zeros((0,), dtype=bool)
+        return None, _cbreaker.routed(
+            lambda _: np.zeros((0,), dtype=bool), "host_scalar")
     from tendermint_tpu.parallel import batch_shard
 
     n = len(items)
@@ -756,7 +824,8 @@ def dispatch_batch(items: list[tuple[bytes, bytes, bytes]],
         return _dispatch_device(items, n, multichip)
 
     return _cbreaker.guarded_dispatch(
-        BREAKER, _device, lambda: _host_fallback(items, n))
+        BREAKER, _device,
+        lambda: _host_fallback(items, n, route="breaker_fallback"))
 
 
 def _start_host_copy(dev) -> None:
@@ -774,4 +843,5 @@ def verify_batch(items: list[tuple[bytes, bytes, bytes]],
     """Batched verify of [(pub, msg, sig)]; returns (len(items),) bool."""
     dev, finish = dispatch_batch(items, force_device=force_device)
     return _cbreaker.guarded_fetch(
-        BREAKER, dev, finish, lambda: _host_fallback(items, len(items)))
+        BREAKER, dev, finish,
+        lambda: _host_fallback(items, len(items), route="breaker_fallback"))

@@ -578,17 +578,18 @@ def dispatch_items_pipelined(ks, key_idx: np.ndarray, items, pub_ok):
             out[:, :cn] = x.T if x.ndim == 2 else x[None, :]
             return out
 
-        if h64_full is not None:
-            h64 = jax.lax.dynamic_slice_in_dim(h64_full, sl.start, CHUNK, 1)
-        else:
-            h64 = jnp.asarray(pad_cols(s["h64"], 64))
+        with edb.launch_span("jit__verify_chunk", "pallas", cn, CHUNK):
+            if h64_full is not None:
+                h64 = jax.lax.dynamic_slice_in_dim(h64_full, sl.start, CHUNK, 1)
+            else:
+                h64 = jnp.asarray(pad_cols(s["h64"], 64))
 
-        tab = ks.gathered_lane(idx)
-        outs.append(_verify_chunk(
-            tab,
-            h64,
-            jnp.asarray(pad_cols(s["s32"], 32)),
-            jnp.asarray(pad_cols(s["r32"], 32)),
-            jnp.asarray(pad_cols(s["valid"].astype(np.uint8), 1)),
-        ))
+            tab = ks.gathered_lane(idx)
+            outs.append(_verify_chunk(
+                tab,
+                h64,
+                jnp.asarray(pad_cols(s["s32"], 32)),
+                jnp.asarray(pad_cols(s["r32"], 32)),
+                jnp.asarray(pad_cols(s["valid"].astype(np.uint8), 1)),
+            ))
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)

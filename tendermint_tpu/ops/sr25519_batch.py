@@ -45,6 +45,7 @@ from tendermint_tpu.crypto import sr25519 as srref
 from tendermint_tpu.ops import breaker as _cbreaker
 from tendermint_tpu.ops import ed25519_batch as edb
 from tendermint_tpu.utils import faults
+from tendermint_tpu.utils import trace as _trace
 from tendermint_tpu.ops import edwards25519 as ed
 from tendermint_tpu.ops import field25519 as fe
 from tendermint_tpu.ops import scalar25519 as sc
@@ -232,7 +233,7 @@ def get_keyset(pubs: list[bytes]) -> tuple[edb.KeySet, np.ndarray, np.ndarray]:
     ristretto-decoded -A, device-resident, cached by pubkey byte sequence
     (level 1) and by unique-key-set digest (level 2)."""
     return edb.build_keyset(pubs, _KS_CACHE, _KS_LOCK, _decode_neg,
-                            uniq_cache=_KS_UNIQ_CACHE)
+                            uniq_cache=_KS_UNIQ_CACHE, kind="sr25519")
 
 
 # ---------------------------------------------------------------------------
@@ -279,19 +280,30 @@ def _scalar_fallback_bitmap(items) -> np.ndarray:
                        dtype=bool, count=len(items))
 
 
-def _host_fallback(items, n):
+def _scalars_span(n: int):
+    """prep.scalars around the merlin challenges and the comb windows."""
+    return (_trace.current().span("prep.scalars", sigs=n, kind="sr25519")
+            if _trace.ENABLED else _trace.NULL_SPAN)
+
+
+def _host_fallback(items, n, route: str | None = None):
     """(device_out=None, finish) via the C host verifier when loaded, else
-    the pure-Python scalar loop."""
+    the pure-Python scalar loop. `route` names the answer when it is not
+    the host's own choice (breaker_fallback)."""
     from tendermint_tpu.ops import chost
 
-    if chost.available():
-        sig_ok, marker_ok, r32, s32, pubs_arr, pub_size_ok = _parse_items(items, n)
-        k32 = challenges([it[1] for it in items], pubs_arr, r32)
-        bitmap = chost.sr25519_verify(
-            pubs_arr, k32, s32, r32, sig_ok & marker_ok & pub_size_ok)
-    else:
-        bitmap = _scalar_fallback_bitmap(items)
-    return None, lambda _unused: bitmap
+    c_verifier = chost.available()
+    route = route or ("host_c" if c_verifier else "host_scalar")
+    with edb._host_span(route, n, kind="sr25519"):
+        if c_verifier:
+            sig_ok, marker_ok, r32, s32, pubs_arr, pub_size_ok = _parse_items(items, n)
+            with _scalars_span(n):
+                k32 = challenges([it[1] for it in items], pubs_arr, r32)
+            bitmap = chost.sr25519_verify(
+                pubs_arr, k32, s32, r32, sig_ok & marker_ok & pub_size_ok)
+        else:
+            bitmap = _scalar_fallback_bitmap(items)
+    return None, _cbreaker.routed(lambda _unused: bitmap, route)
 
 
 def _dispatch_device(items, n: int, multichip: bool = False):
@@ -308,10 +320,10 @@ def _dispatch_device(items, n: int, multichip: bool = False):
     r_ok = _lt_p(r32) & ((r32[:, 0] & 1) == 0)
     valid = sig_ok & marker_ok & s_ok & r_ok & pub_ok
 
-    k32 = challenges([it[1] for it in items], pubs_arr, r32)
-
-    k_win = sc.comb_windows(k32).astype(np.int32)
-    s_win = sc.comb_windows(s32).astype(np.int32)
+    with _scalars_span(n):
+        k32 = challenges([it[1] for it in items], pubs_arr, r32)
+        k_win = sc.comb_windows(k32).astype(np.int32)
+        s_win = sc.comb_windows(s32).astype(np.int32)
     r_limbs = _bytes_to_limbs(r32)
 
     if multichip:
@@ -323,7 +335,7 @@ def _dispatch_device(items, n: int, multichip: bool = False):
         dev = batch_shard.dispatch_sharded(
             "sr25519", ks, key_idx, [k_win, s_win, r_limbs, valid], n)
         edb._start_host_copy(dev)
-        return dev, lambda v: np.asarray(v)[:n]
+        return dev, _cbreaker.routed(lambda v: np.asarray(v)[:n], "sharded")
 
     # Fixed-tile chunking through the one JNP_TILE-shaped executable.
     tile = edb.JNP_TILE
@@ -339,17 +351,18 @@ def _dispatch_device(items, n: int, multichip: bool = False):
     kw, sw, rl, va = pad(k_win), pad(s_win), pad(r_limbs), pad(valid)
     outs = []
     for off in range(0, nb, tile):
-        tab = jnp.take(ks.tab_ext, jnp.asarray(idx[off:off + tile]), axis=0)
-        outs.append(_kernel(
-            tab,
-            jnp.asarray(kw[off:off + tile]),
-            jnp.asarray(sw[off:off + tile]),
-            jnp.asarray(rl[off:off + tile]),
-            jnp.asarray(va[off:off + tile]),
-        ))
+        with edb.launch_span("jit__sr_verify_kernel", "jnp", n - off, tile):
+            tab = jnp.take(ks.tab_ext, jnp.asarray(idx[off:off + tile]), axis=0)
+            outs.append(_kernel(
+                tab,
+                jnp.asarray(kw[off:off + tile]),
+                jnp.asarray(sw[off:off + tile]),
+                jnp.asarray(rl[off:off + tile]),
+                jnp.asarray(va[off:off + tile]),
+            ))
     ok = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
     edb._start_host_copy(ok)
-    return ok, lambda v: np.asarray(v)[:n]
+    return ok, _cbreaker.routed(lambda v: np.asarray(v)[:n], "jnp")
 
 
 def _device_probe() -> bool:
@@ -376,7 +389,8 @@ def dispatch_batch(items: list[tuple[bytes, bytes, bytes]],
     sub-crossover chunks against device flights). The device route sits
     behind the same circuit-breaker degradation as the ed25519 twin."""
     if not items:
-        return None, lambda _: np.zeros((0,), dtype=bool)
+        return None, _cbreaker.routed(
+            lambda _: np.zeros((0,), dtype=bool), "host_scalar")
     from tendermint_tpu.parallel import batch_shard
 
     n = len(items)
@@ -397,7 +411,8 @@ def dispatch_batch(items: list[tuple[bytes, bytes, bytes]],
         return _dispatch_device(items, n, multichip)
 
     return _cbreaker.guarded_dispatch(
-        BREAKER, _device, lambda: _host_fallback(items, n))
+        BREAKER, _device,
+        lambda: _host_fallback(items, n, route="breaker_fallback"))
 
 
 def verify_batch(items: list[tuple[bytes, bytes, bytes]]) -> np.ndarray:
@@ -405,4 +420,5 @@ def verify_batch(items: list[tuple[bytes, bytes, bytes]]) -> np.ndarray:
     byte-identical accept/reject with crypto/sr25519.verify."""
     dev, finish = dispatch_batch(items)
     return _cbreaker.guarded_fetch(
-        BREAKER, dev, finish, lambda: _host_fallback(items, len(items)))
+        BREAKER, dev, finish,
+        lambda: _host_fallback(items, len(items), route="breaker_fallback"))

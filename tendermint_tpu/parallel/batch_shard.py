@@ -234,14 +234,16 @@ def _dispatch_sharded(kind: str, ks, key_idx, arrays: list, n: int):
     tab_full = replicated_tables(ks, mesh)
     fn = _sharded_verify_fn(mesh, kind)
     spec = NamedSharding(mesh, P("dp"))
+    program = "jit_" + _BODIES[kind][0].__name__
     outs = []
     for off in range(0, nb, chunk):
         sl = slice(off, off + chunk)
-        outs.append(fn(
-            tab_full,
-            jax.device_put(idx[sl], spec),
-            *(jax.device_put(v[sl], spec) for v in padded),
-        ))
+        with ed25519_batch.launch_span(program, "sharded", n - off, chunk):
+            outs.append(fn(
+                tab_full,
+                jax.device_put(idx[sl], spec),
+                *(jax.device_put(v[sl], spec) for v in padded),
+            ))
     _count_sharded_dispatch(ndev)
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
 

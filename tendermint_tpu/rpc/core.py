@@ -971,9 +971,14 @@ def unsafe_trace(env, enable=None, clear=False, dump=False):
     docs/OBSERVABILITY.md; no reference analogue — the reference exposes
     pprof, this build's host-side recorder is span-structured).
 
-    With no params: the tracer's state + per-span-name aggregation.
-    ``enable``: true/false flips this node's tracer live. ``clear`` drops
-    the ring. ``dump=true`` adds the raw span list (ring-bounded)."""
+    With no params: the tracer's state + per-span-name aggregation, and
+    the same aggregation of the process's start-up ring (``startup``: key
+    decompression, table builds, jit tracing and compiling; recorded with
+    tracing off too). ``enable``: true/false flips this node's tracer live.
+    ``clear`` drops the ring. ``dump=true`` adds the raw span lists
+    (ring-bounded): ``spans``, and ``startup_spans``."""
+    from tendermint_tpu.utils import trace as tmtrace
+
     _require_unsafe(env)
     tracer = getattr(env.node, "tracer", None)
     if tracer is None:
@@ -989,8 +994,10 @@ def unsafe_trace(env, enable=None, clear=False, dump=False):
         tracer.clear()
     out = dict(tracer.describe())
     out["summary"] = tracer.summarize()
+    out["startup"] = tmtrace.STARTUP.summarize()
     if dump in (True, "true", "1", 1):
         out["spans"] = [s.as_dict() for s in tracer.dump()]
+        out["startup_spans"] = [s.as_dict() for s in tmtrace.STARTUP.dump()]
     return out
 
 
@@ -999,6 +1006,8 @@ def unsafe_timeline(env, height=0):
     flight recorder (docs/OBSERVABILITY.md schema): lifecycle marks,
     verify-pipeline phase durations, causal-order verdict. Default
     height: the latest committed block."""
+    from tendermint_tpu.utils import trace as tmtrace
+
     _require_unsafe(env)
     tracer = getattr(env.node, "tracer", None)
     if tracer is None:

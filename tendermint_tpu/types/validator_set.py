@@ -17,6 +17,7 @@ semantics are then replayed over the returned bitmap:
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 
 from tendermint_tpu.crypto import batch as crypto_batch
@@ -29,6 +30,7 @@ from tendermint_tpu.types.validator import (
     Validator,
     clip_int64,
 )
+from tendermint_tpu.utils import trace as _trace
 
 # Implied validator-set size cap (reference: types/validator_set.go MaxVotesCount)
 MAX_VOTES_COUNT = 10000
@@ -80,14 +82,20 @@ class PendingCommitVerify:
     :class:`~tendermint_tpu.crypto.batch.PendingVerify` (None when the
     decision needed no device work) so callers with several decisions in
     flight can batch the readbacks into one device_get
-    (crypto_batch.prefetch)."""
+    (crypto_batch.prefetch).
 
-    __slots__ = ("pending", "_finalize", "_error")
+    A decision dispatched with the flight recorder on carries its tracer
+    and its decision id (the ``commit.assemble`` root span's id), so the
+    wait and the tally land in the same tree whoever resolves it, later."""
+
+    __slots__ = ("pending", "_finalize", "_error", "_tracer", "_decision")
 
     def __init__(self, pending=None, finalize=None, error: Exception | None = None):
         self.pending = pending
         self._finalize = finalize
         self._error = error
+        self._tracer = None
+        self._decision = 0
 
     def resolve(self) -> None:
         """Raises exactly what the synchronous verify would; returns None on
@@ -95,10 +103,22 @@ class PendingCommitVerify:
         decision replay is deterministic."""
         if self._error is not None:
             raise self._error
+        tr = self._tracer
+        if tr is not None and tr.enabled:
+            return self._resolve_traced(tr)
         bitmap: list[bool] = []
         if self.pending is not None:
             _, bitmap = self.pending.resolve()
         self._finalize(bitmap)
+
+    def _resolve_traced(self, tr) -> None:
+        did = self._decision
+        bitmap: list[bool] = []
+        if self.pending is not None:
+            with tr.span("commit.wait", decision=did, parent=did):
+                _, bitmap = self.pending.resolve()
+        with tr.span("commit.tally", decision=did, parent=did):
+            self._finalize(bitmap)
 
 
 class ValidatorSet:
@@ -355,24 +375,59 @@ class ValidatorSet:
         types/validator_set.go:660-715)."""
         self.verify_commit_async(chain_id, block_id, height, commit).resolve()
 
+    def _assemble_traced(self, tr, mode: str, assemble, *args) -> PendingCommitVerify:
+        """Run a decision's dispatch half inside its root span,
+        ``commit.assemble``, whose id is the decision id every later span of
+        the decision carries (docs/OBSERVABILITY.md)."""
+        with tr.span("commit.assemble", decision=True, mode=mode) as did:
+            pcv = assemble(*args, tr)
+        pcv._tracer, pcv._decision = tr, did
+        return pcv
+
     def verify_commit_async(self, chain_id: str, block_id: BlockID, height: int,
                             commit, force_device: bool = False) -> PendingCommitVerify:
         """Deferred verify_commit: host prep + device dispatch now, the
         serial decision replay (identical errors) on resolve()."""
+        if _trace.ENABLED:
+            tr = _trace.current()
+            if tr.enabled:
+                return self._assemble_traced(
+                    tr, "full", self._verify_commit_assemble, chain_id,
+                    block_id, height, commit, force_device)
+        return self._verify_commit_assemble(chain_id, block_id, height, commit,
+                                            force_device)
+
+    def _verify_commit_assemble(self, chain_id: str, block_id: BlockID,
+                                height: int, commit, force_device: bool,
+                                tr=None) -> PendingCommitVerify:
         err = self._commit_structural_error(block_id, height, commit)
         if err is not None:
             return PendingCommitVerify(error=err)
         verifier = crypto_batch.create_batch_verifier()
         queued: list[int] = []
-        for idx, cs in enumerate(commit.signatures):
-            if cs.absent():
-                continue
-            verifier.add(
-                self.validators[idx].pub_key,
-                commit.vote_sign_bytes(chain_id, idx),
-                cs.signature,
-            )
-            queued.append(idx)
+        if tr is None:
+            for idx, cs in enumerate(commit.signatures):
+                if cs.absent():
+                    continue
+                verifier.add(
+                    self.validators[idx].pub_key,
+                    commit.vote_sign_bytes(chain_id, idx),
+                    cs.signature,
+                )
+                queued.append(idx)
+        else:
+            # the traced twin of the loop above: two clock readings around
+            # each vote_sign_bytes, summed into the root span's sign_bytes_s
+            clock, sign_bytes_s = time.perf_counter, 0.0
+            for idx, cs in enumerate(commit.signatures):
+                if cs.absent():
+                    continue
+                t0 = clock()
+                msg = commit.vote_sign_bytes(chain_id, idx)
+                sign_bytes_s += clock() - t0
+                verifier.add(self.validators[idx].pub_key, msg, cs.signature)
+                queued.append(idx)
+            tr.annotate(sigs=len(queued), sign_bytes_s=sign_bytes_s)
         pending = verifier.dispatch(force_device=force_device)
         # Freeze the decision inputs at dispatch time.
         needed = self.total_voting_power() * 2 // 3
@@ -423,18 +478,41 @@ class ValidatorSet:
         (blockchain/pipeline.py) dispatches several heights' commits through
         this, overlapping the device round trips with block save/apply, and
         replays each height's serial decision in order on resolve()."""
+        if _trace.ENABLED:
+            tr = _trace.current()
+            if tr.enabled:
+                return self._assemble_traced(
+                    tr, "light", self._verify_commit_light_assemble, chain_id,
+                    block_id, height, commit, force_device)
+        return self._verify_commit_light_assemble(chain_id, block_id, height,
+                                                  commit, force_device)
+
+    def _verify_commit_light_assemble(self, chain_id: str, block_id: BlockID,
+                                      height: int, commit, force_device: bool,
+                                      tr=None) -> PendingCommitVerify:
         err = self._commit_structural_error(block_id, height, commit)
         if err is not None:
             return PendingCommitVerify(error=err)
         needed = self.total_voting_power() * 2 // 3
         prefix = self.commit_light_prefix(commit, needed)
         verifier = crypto_batch.create_batch_verifier()
-        for idx in prefix:
-            verifier.add(
-                self.validators[idx].pub_key,
-                commit.vote_sign_bytes(chain_id, idx),
-                commit.signatures[idx].signature,
-            )
+        if tr is None:
+            for idx in prefix:
+                verifier.add(
+                    self.validators[idx].pub_key,
+                    commit.vote_sign_bytes(chain_id, idx),
+                    commit.signatures[idx].signature,
+                )
+        else:
+            # traced twin, as in _verify_commit_assemble
+            clock, sign_bytes_s = time.perf_counter, 0.0
+            for idx in prefix:
+                t0 = clock()
+                msg = commit.vote_sign_bytes(chain_id, idx)
+                sign_bytes_s += clock() - t0
+                verifier.add(self.validators[idx].pub_key, msg,
+                             commit.signatures[idx].signature)
+            tr.annotate(sigs=len(prefix), sign_bytes_s=sign_bytes_s)
         pending = verifier.dispatch(force_device=force_device)
         powers = [self.validators[idx].voting_power for idx in prefix]
         signatures = list(commit.signatures)

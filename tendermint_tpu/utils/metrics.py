@@ -204,7 +204,8 @@ class NodeMetrics:
             buckets=(0.0001, 0.0005, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1))
         self.batch_verify_seconds = r.histogram(
             "consensus", "batch_verify_seconds",
-            "Latency of batched signature verification flushes (TPU-path).",
+            "Latency of batched signature verification flushes, by the "
+            "route that answered (ops/breaker.ROUTES).", labels=("route",),
             buckets=(0.0005, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1))
         self.batch_verify_sigs = r.counter(
             "consensus", "batch_verify_sigs_total",
@@ -381,6 +382,12 @@ class NodeMetrics:
         for kernel in ("ed25519", "sr25519"):
             self.breaker_open.set(0.0, kernel=kernel)
             self.breaker_trips.set(0.0, kernel=kernel)
+        # which route answered a batch: the closed set dispatch_batch
+        # chooses from, seeded so "the Pallas route never ran" is a zero
+        from tendermint_tpu.ops.breaker import ROUTES as _routes
+
+        for route in _routes:
+            self.batch_verify_seconds.seed(route=route)
         # the phase histogram's label universe IS trace.MIRRORED_SPANS:
         # seed every series so dashboards see zeros, not absence, and the
         # scrape-shape test can pin the full exposition

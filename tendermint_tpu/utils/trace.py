@@ -15,6 +15,15 @@ Three layers:
    parent/child ids, and a ``height=`` tag set by an enclosing span is
    inherited by its children (``current_height``), so the deferred verify
    phases dispatched inside a vote-drain span land on the right height.
+   A ``decision=`` tag is inherited the same way: the entry point of a
+   commit decision opens its root span with ``decision=True`` (the tag
+   becomes the span's own id), handles that outlive the call carry the id
+   and the causing span across threads (``current_decision`` /
+   ``current_span``), and a span or record on another thread names both
+   (``decision=``, ``parent=``), so one decision is one tree
+   (docs/OBSERVABILITY.md). While jax is imported every span also enters
+   a ``jax.profiler.TraceAnnotation`` of its name, so a profiler session
+   shows the program's spans on the profiler's own clock.
  - the module-level functions: ``span()/mark()/record()`` delegate to the
    thread's ACTIVE tracer (``Tracer.activate()``), falling back to the
    process :data:`DEFAULT` tracer; ``dump()/summarize()/enable()`` always
@@ -29,10 +38,13 @@ Three layers:
    auditor's stall annotations, and spans named in :data:`MIRRORED_SPANS`
    are mirrored into the pre-seeded ``trace_phase_seconds`` histogram.
 
+Beside the per-node rings there is :data:`STARTUP`, a small ring that is
+always on and that only cold paths write to (key decompression, table
+builds, jit tracing and compiling, the crossover calibration): what a
+process spent before its first decision, whether or not tracing is on.
+
 Knobs: ``TMTPU_TRACE=1`` enables every node's tracer at construction;
-``TMTPU_TRACE_CAP`` sets the per-tracer ring size (default 4096);
-``TMTPU_TRACE_XPROF=<dir>`` makes bench.py wrap its instrumented
-attribution pass in :func:`jax_profile` (TensorBoard/xprof traces).
+``TMTPU_TRACE_CAP`` sets the per-tracer ring size (default 4096).
 """
 
 from __future__ import annotations
@@ -40,6 +52,7 @@ from __future__ import annotations
 import contextlib
 import itertools
 import os
+import sys
 import threading
 import time
 from dataclasses import dataclass
@@ -69,14 +82,34 @@ CANONICAL_SPANS = {
     # attribution ROADMAP item 1 needs)
     "verify.host_prep": "host prep + kernel dispatch (ops dispatch_batch)",
     "verify.queue": "dispatch()->resolve() queue wait of a PendingVerify",
-    "verify.coalesce": "verify-service shared launch marker (requests/sigs "
-                       "coalesced into one kernel launch)",
-    "verify.device": "device compute (bench attribution pass only)",
     "verify.readback": "blocking D2H fetch (crypto/batch._device_get)",
     "verify.replay": "bitmap fetch -> serial accept/reject replay",
     "verify.shard_dispatch": "multi-device shard_map dispatch (parallel/batch_shard)",
+    "verify.wake": "executor's done.set() -> the waiting caller runs again",
+    # one commit decision, entry point to tally (types/validator_set.py);
+    # commit.assemble is the decision's root and its span id the decision id
+    "commit.assemble": "structural check, sign bytes + add per signature, "
+                       "verifier.dispatch (root span of a decision)",
+    "commit.wait": "PendingCommitVerify.resolve waiting for the bitmap",
+    "commit.tally": "serial accept/reject replay over the bitmap",
+    # below ops dispatch_batch (ops/ed25519_batch, sr25519_batch,
+    # ed25519_pallas, parallel/batch_shard)
+    "prep.keyset": "pubkey join, key-set cache lookup, on a miss the build",
+    "prep.scalars": "per-signature hash (SHA-512 / merlin in C), mod L, windows",
+    "prep.launch": "host time to enqueue one device program (route, real "
+                   "signatures, launched lanes)",
+    "prep.host_verify": "the C / scalar host verifier answered the batch",
+    # the start-up ring (STARTUP): cold paths, recorded with tracing off too
+    "startup.key_decode": "Python decompression of a key set's unique keys",
+    "startup.table_build": "device comb-table build until its result is ready",
+    "startup.jit_trace": "jax traced a function and lowered it to MLIR",
+    "startup.jit_compile": "backend compile, or its load from the cache",
+    "startup.cache_load": "persistent compile-cache retrieval (inside "
+                          "startup.jit_compile)",
+    "startup.calibrate": "host/device crossover calibration",
     # fast-sync verify-ahead (blockchain/pipeline.py)
     "fastsync.dispatch": "speculative commit-verify dispatch for one height",
+    "fastsync.head_wait": "the head block's wait: batched prefetch + resolve",
     "fastsync.apply": "block save + ABCI apply of a fast-synced height",
     # tx front door + gossip plane
     "mempool.check_tx": "ABCI CheckTx round trip of one tx",
@@ -170,14 +203,56 @@ _state_mtx = threading.Lock()
 _tl = threading.local()
 
 
+class _NullSpan:
+    """What a guarded site enters while tracing is off
+    (``trace.current().span(...) if trace.ENABLED else trace.NULL_SPAN``):
+    one shared object, so the disabled path allocates nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return 0
+
+    def __exit__(self, *_exc):
+        return False
+
+
+NULL_SPAN = _NullSpan()
+
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax is imported
+
+
+def _bridge(name: str, decision):
+    """Enter a profiler annotation of the span's name when jax is already
+    imported (never import it from here: the recorder serves jax-free
+    processes too). Outside a profiler session this is a TraceMe that
+    records nothing; inside one the program's spans sit on the profiler's
+    clock beside the device's programs."""
+    global _ANNOTATION
+    ann = _ANNOTATION
+    if ann is None:
+        prof = getattr(sys.modules.get("jax"), "profiler", None)
+        ann = _ANNOTATION = getattr(prof, "TraceAnnotation", None)
+        if ann is None:
+            return None
+    try:
+        entered = ann(name, decision=decision) if decision else ann(name)
+        entered.__enter__()
+    except Exception:  # noqa: BLE001 - the bridge never costs a span
+        return None
+    return entered
+
+
 class Tracer:
     """One bounded span ring + causality bookkeeping. Thread-safe: spans
-    may complete on any thread; parent/height context is per-thread."""
+    may complete on any thread; parent/height/decision context is
+    per-thread. ``cold=True`` makes an always-on ring that never raises
+    the module guard :data:`ENABLED` (the start-up ring)."""
 
     def __init__(self, name: str = "", cap: int | None = None,
-                 enabled: bool = False):
+                 enabled: bool = False, cold: bool = False):
         self.name = name
-        self.enabled = False
+        self.enabled = cold
         self.cap = cap if cap is not None else trace_cap()
         from collections import deque
 
@@ -185,6 +260,7 @@ class Tracer:
         self._mtx = threading.Lock()
         self._seq = itertools.count(1)
         self._ctx = threading.local()  # per-thread parent/height stacks
+        self._cold = cold
         if enabled:
             self.enable()
 
@@ -201,7 +277,7 @@ class Tracer:
     def disable(self) -> None:
         global ENABLED, _enabled_count
         with _state_mtx:
-            if self.enabled:
+            if self.enabled and not self._cold:
                 self.enabled = False
                 _enabled_count -= 1
                 ENABLED = _enabled_count > 0
@@ -227,6 +303,8 @@ class Tracer:
         if not hasattr(c, "parents"):
             c.parents = []
             c.heights = []
+            c.decisions = []
+            c.open = []      # the tag dicts of the open spans (annotate)
         return c
 
     def current_height(self):
@@ -234,54 +312,103 @@ class Tracer:
         c = self._stacks()
         return c.heights[-1] if c.heights else None
 
+    def current_decision(self) -> int:
+        """The decision id the enclosing spans of this thread work for, or
+        0: what a handle captures at dispatch to carry across threads."""
+        c = self._stacks()
+        return c.decisions[-1] if c.decisions else 0
+
+    def current_span(self) -> int:
+        """Id of the innermost open span on this thread (the cause of
+        whatever is dispatched now), or 0."""
+        c = self._stacks()
+        return c.parents[-1] if c.parents else 0
+
+    def _inherit(self, c, tags: dict):
+        """Fill height= and decision= from the enclosing spans."""
+        if "height" not in tags and c.heights:
+            tags["height"] = c.heights[-1]
+        if "decision" not in tags and c.decisions:
+            tags["decision"] = c.decisions[-1]
+
     @contextlib.contextmanager
-    def span(self, name: str, **tags):
+    def span(self, name: str, *, parent: int | None = None, **tags):
         """Timed causal region. Children started on this thread inside the
-        region get this span as parent and inherit its height tag."""
+        region get this span as parent and inherit its height and decision
+        tags. ``decision=True`` makes this span a decision's root: the tag
+        becomes its own id. ``parent=`` names the causing span when it is
+        not the enclosing one (work done on another thread)."""
         if not self.enabled:
             yield 0
             return
         c = self._stacks()
         sid = next(self._seq)
+        if tags.get("decision") is True:
+            tags["decision"] = sid
+        self._inherit(c, tags)
         h = tags.get("height")
-        if h is None and c.heights:
-            tags["height"] = h = c.heights[-1]
-        parent = c.parents[-1] if c.parents else 0
+        d = tags.get("decision")
+        if parent is None:
+            parent = c.parents[-1] if c.parents else 0
         c.parents.append(sid)
+        c.open.append(tags)
         if h is not None:
             c.heights.append(h)
+        if d is not None:
+            c.decisions.append(d)
+        ann = None if self._cold else _bridge(name, d)
         t0 = time.monotonic()
         try:
             yield sid
         finally:
             dur = time.monotonic() - t0
+            if ann is not None:
+                ann.__exit__(None, None, None)
             c.parents.pop()
+            c.open.pop()
             if h is not None:
                 c.heights.pop()
+            if d is not None:
+                c.decisions.pop()
             self._append(Span(name, t0, dur, tags, sid, parent))
+
+    def annotate(self, **tags) -> None:
+        """Add tags to the innermost open span of this thread: what is only
+        known once the work is done (a count, a cache verdict)."""
+        if self.enabled:
+            c = self._stacks()
+            if c.open:
+                c.open[-1].update(tags)
 
     def mark(self, name: str, **tags) -> None:
         """Zero-duration lifecycle event."""
         if not self.enabled:
             return
         c = self._stacks()
-        if "height" not in tags and c.heights:
-            tags["height"] = c.heights[-1]
+        self._inherit(c, tags)
         parent = c.parents[-1] if c.parents else 0
         self._append(Span(name, time.monotonic(), 0.0, tags,
                           next(self._seq), parent))
 
-    def record(self, name: str, duration_s: float, **tags) -> None:
+    def record(self, name: str, duration_s: float, *,
+               start: float | None = None, parent: int | None = None,
+               **tags) -> None:
         """An externally-timed span (e.g. a queue wait measured between
-        two events)."""
+        two events). ``start`` is the ``time.monotonic()`` reading taken
+        when the work began; without it the start is back-dated from now,
+        which is right only when the record is written the moment the work
+        ends. ``parent`` names the causing span when the record is written
+        on another thread than the one that caused it."""
         if not self.enabled:
             return
         c = self._stacks()
-        if "height" not in tags and c.heights:
-            tags["height"] = c.heights[-1]
-        parent = c.parents[-1] if c.parents else 0
-        self._append(Span(name, time.monotonic() - duration_s, duration_s,
-                          tags, next(self._seq), parent))
+        self._inherit(c, tags)
+        if parent is None:
+            parent = c.parents[-1] if c.parents else 0
+        if start is None:
+            start = time.monotonic() - duration_s
+        self._append(Span(name, start, duration_s, tags, next(self._seq),
+                          parent))
 
     def _append(self, s: Span) -> None:
         with self._mtx:
@@ -376,9 +503,26 @@ class Tracer:
                 "spans": self.size()}
 
 
+def handle_tags(height, decision: int) -> dict:
+    """height= / decision= for the spans of a handle that captured both at
+    dispatch (PendingVerify, the verify service's request), where known."""
+    tags = {} if height is None else {"height": height}
+    if decision:
+        tags["decision"] = decision
+    return tags
+
+
 # The process-default tracer: the module-level API's fallback target, and
 # what standalone harnesses (bench, tests) use without building a Node.
 DEFAULT = Tracer(name="default")
+
+# The start-up ring: always on, written by cold paths only (a key-set miss,
+# a jit trace or compile, the calibration), so a process can say where its
+# warm-up went without TMTPU_TRACE. It never raises ENABLED: the hot sites'
+# guard stays false. Served by the unsafe_trace route (`startup`), summed in
+# one log line when crypto.batch.warmup ends.
+STARTUP_CAP = 2048
+STARTUP = Tracer(name="startup", cap=STARTUP_CAP, cold=True)
 
 
 def current() -> Tracer:
@@ -410,8 +554,8 @@ def mark(name: str, **tags) -> None:
     current().mark(name, **tags)
 
 
-def record(name: str, duration_s: float, **tags) -> None:
-    current().record(name, duration_s, **tags)
+def record(name: str, duration_s: float, **kw) -> None:
+    current().record(name, duration_s, **kw)
 
 
 def dump(clear: bool = False) -> list[Span]:
@@ -420,13 +564,3 @@ def dump(clear: bool = False) -> list[Span]:
 
 def summarize() -> dict[str, dict]:
     return DEFAULT.summarize()
-
-
-@contextlib.contextmanager
-def jax_profile(log_dir: str):
-    """Device-side profiling via jax.profiler (xprof traces; open the
-    written directory in TensorBoard — recipe in docs/OBSERVABILITY.md)."""
-    import jax
-
-    with jax.profiler.trace(log_dir):
-        yield

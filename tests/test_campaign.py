@@ -88,7 +88,7 @@ def test_violation_kind_parsing():
     assert campaign._violation_kind("garbage") == "unknown"
 
 
-def test_last_phase_attribution_parsing():
+def test_last_phase_parsing():
     v = ("[liveness @12.3s] no node committed a block for 8.0s "
          "[lagging: node 1@h4 last_phase=consensus.precommit(h4), "
          "node 2@h0 last_phase=?]")

@@ -82,6 +82,139 @@ def test_causal_parent_child_and_height_inheritance(tracer):
             if s.name == "fastsync.apply"} == {6}
 
 
+def test_record_keeps_the_given_start_and_parent(tracer):
+    """ISSUE 23 fault 2: record() back-dated every start from the moment of
+    the call. With start= the span begins where the work began, whenever
+    the record is written; without it the old back-dating holds."""
+    t_began = time.monotonic() - 5.0
+    tracer.record("verify.readback", 0.25, start=t_began, parent=77,
+                  decision=9)
+    tracer.record("verify.replay", 0.5)
+    by_name = {s.name: s for s in tracer.dump()}
+    rb = by_name["verify.readback"]
+    assert rb.start == t_began and rb.duration_s == 0.25
+    assert rb.parent_id == 77 and rb.tags == {"decision": 9}
+    rp = by_name["verify.replay"]
+    assert abs(rp.start - (time.monotonic() - 0.5)) < 0.1
+    assert rp.parent_id == 0
+
+
+def test_decision_id_is_the_root_spans_id_and_children_inherit_it(tracer):
+    with tracer.span("commit.assemble", decision=True, mode="full") as did:
+        assert tracer.current_decision() == did
+        assert tracer.current_span() == did
+        with tracer.span("verify.host_prep") as prep:
+            tracer.record("verify.queue", 0.001)
+            assert tracer.current_span() == prep
+        tracer.annotate(sigs=3, sign_bytes_s=0.5)
+    assert tracer.current_decision() == 0 and tracer.current_span() == 0
+    # another thread's span names decision and parent explicitly
+    with tracer.span("commit.wait", decision=did, parent=did):
+        tracer.mark("consensus.precommit")
+    by_name = {s.name: s for s in tracer.dump()}
+    root = by_name["commit.assemble"]
+    assert root.span_id == did and root.parent_id == 0
+    assert root.tags == {"decision": did, "mode": "full", "sigs": 3,
+                         "sign_bytes_s": 0.5}
+    for name in ("verify.host_prep", "verify.queue", "commit.wait",
+                 "consensus.precommit"):
+        assert by_name[name].tags["decision"] == did, name
+    assert by_name["verify.host_prep"].parent_id == did
+    assert by_name["verify.queue"].parent_id == by_name["verify.host_prep"].span_id
+    assert by_name["commit.wait"].parent_id == did
+
+
+def test_spans_are_bridged_to_profiler_annotations(tracer, monkeypatch):
+    """While jax is imported every span also enters a TraceAnnotation of its
+    name (with the decision id); the start-up ring never does."""
+    seen = []
+
+    class Annotation:
+        def __init__(self, name, **kw):
+            self.what = (name, kw)
+
+        def __enter__(self):
+            seen.append(("enter",) + self.what)
+
+        def __exit__(self, *exc):
+            seen.append(("exit",) + self.what)
+
+    monkeypatch.setattr(trace, "_ANNOTATION", Annotation)
+    with tracer.span("commit.assemble", decision=True) as did:
+        with tracer.span("prep.keyset"):
+            pass
+    trace.STARTUP.record("startup.calibrate", 0.1)
+    assert seen == [
+        ("enter", "commit.assemble", {"decision": did}),
+        ("enter", "prep.keyset", {"decision": did}),
+        ("exit", "prep.keyset", {"decision": did}),
+        ("exit", "commit.assemble", {"decision": did})]
+
+
+def test_startup_ring_keeps_the_outermost_jit_trace_only():
+    """jax traces every jnp op inside a kernel as a function of its own,
+    thousands in one kernel: only the outermost trace may reach the ring,
+    or the ring is flooded before the kernel's own span arrives."""
+    import jax
+    import jax.numpy as jnp
+
+    from tendermint_tpu.utils import jaxcache
+
+    jaxcache.enable()
+
+    @jax.jit
+    def inner_fn(x):
+        return x + 1
+
+    @jax.jit
+    def outer_fn(x):
+        return inner_fn(x) * jnp.sin(x) + inner_fn(x + 2)
+
+    x = jnp.ones(3).block_until_ready()
+    at = time.monotonic()
+    outer_fn(x).block_until_ready()
+    mine = [s for s in trace.STARTUP.dump() if s.start >= at]
+    assert [(s.name, s.tags["fun"]) for s in mine] == [
+        ("startup.jit_trace", "outer_fn"),            # the trace, once
+        ("startup.jit_trace", "jit(outer_fn)"),       # its lowering to MLIR
+        ("startup.jit_compile", "jit(outer_fn)")]
+    # a warm call traces nothing
+    at = time.monotonic()
+    outer_fn(x).block_until_ready()
+    assert not [s for s in trace.STARTUP.dump() if s.start >= at]
+
+
+def test_startup_ring_is_always_on_and_never_raises_the_guard():
+    """The start-up ring records with tracing off (a key-set miss is a cold
+    path) and does not make the hot sites' guard true."""
+    from tendermint_tpu.crypto import ed25519
+    from tendermint_tpu.ops import ed25519_batch as edb
+
+    assert trace.STARTUP.enabled
+    base = trace.ENABLED
+    trace.STARTUP.enable()      # no-ops: a cold ring is not counted
+    trace.STARTUP.disable()
+    assert trace.STARTUP.enabled and trace.ENABLED == base
+    default_before = trace.DEFAULT.size()
+    pubs = [ed25519.gen_priv_key(b"startup-ring-%02d" % i + bytes(17))
+            .pub_key().data for i in range(3)]
+    at = time.monotonic()
+    ks, key_idx, pub_ok = edb.get_keyset(pubs)
+    assert pub_ok.all() and ks.n_keys == 3
+    mine = [s for s in trace.STARTUP.dump() if s.start >= at]
+    by_name = {s.name: s for s in mine}
+    assert {"startup.key_decode", "startup.table_build"} <= set(by_name)
+    assert by_name["startup.key_decode"].tags == {"keys": 3, "kind": "ed25519"}
+    decode, build = by_name["startup.key_decode"], by_name["startup.table_build"]
+    assert decode.start + decode.duration_s <= build.start + 1e-6
+    # a second look-up of the same keys is a hit: nothing cold happened
+    at = time.monotonic()
+    edb.get_keyset(pubs)
+    assert not [s for s in trace.STARTUP.dump() if s.start >= at
+                and s.name.startswith("startup.key")]
+    assert trace.ENABLED == base and trace.DEFAULT.size() == default_before
+
+
 def test_ring_bound_evicts_oldest():
     t = trace.Tracer("ring", cap=16, enabled=True)
     try:
@@ -157,6 +290,65 @@ def test_disabled_path_records_nothing_and_stays_cheap():
             raise AssertionError
     guard_s = time.perf_counter() - t0
     assert guard_s / n < 2e-6, f"{guard_s / n * 1e9:.0f} ns/guard"
+
+    # the `with` form of the guard (ISSUE 23's prep.* sites): one attribute
+    # load, then the shared NULL_SPAN -- no generator, no tags dict
+    assert not trace.ENABLED
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with (trace.current().span("prep.launch", sigs=1, lanes=1)
+              if trace.ENABLED else trace.NULL_SPAN) as sid:
+            if sid:
+                raise AssertionError
+    guard_s = time.perf_counter() - t0
+    assert guard_s / n < 2e-6, f"{guard_s / n * 1e9:.0f} ns/with-guard"
+
+
+def test_disabled_path_at_the_decision_sites_touches_no_tracer(monkeypatch):
+    """ISSUE 23: with tracing off, a whole commit decision -- entry point,
+    verify service, ops prep, host route and forced device launch --
+    allocates no span and takes no ring lock: every Tracer entry point of
+    the DEFAULT tracer is booby-trapped, and the decision id stays 0."""
+    from tendermint_tpu.crypto import batch as cbatch
+    from tendermint_tpu.crypto import verify_service
+    from tendermint_tpu.ops import ed25519_batch as edb
+    from tests.test_perf_gate import CHAIN_ID, _commit
+
+    assert not trace.ENABLED
+
+    def boom(*_a, **_kw):
+        raise AssertionError("a disabled site reached the tracer")
+
+    class NoLock:
+        def __enter__(self):
+            boom()
+
+        def __exit__(self, *exc):
+            return False
+
+    for name in ("span", "mark", "record", "annotate", "_append", "_stacks"):
+        monkeypatch.setattr(trace.DEFAULT, name, boom)
+    monkeypatch.setattr(trace.DEFAULT, "_mtx", NoLock())
+    monkeypatch.setenv("TMTPU_VERIFY_SERVICE", "1")
+    # the launch site is the host's; the kernel itself is the slow tier's
+    monkeypatch.setattr(edb, "_jnp_kernel", lambda tab, **kw: kw["valid"])
+    verify_service.reset()
+    try:
+        vals, commit = _commit(24)
+        for entry in (vals.verify_commit_async, vals.verify_commit_light_async):
+            pcv = entry(CHAIN_ID, commit.block_id, commit.height, commit)
+            assert isinstance(pcv.pending._children[0], cbatch.ServicePending)
+            assert pcv._decision == 0 and pcv._tracer is None
+            assert pcv.pending._children[0]._req.decision == 0
+            pcv.resolve()
+        # the ops layer's own sites, host route and forced device launch
+        items = [(vals.validators[i].pub_key.data,
+                  commit.vote_sign_bytes(CHAIN_ID, i),
+                  commit.signatures[i].signature) for i in range(24)]
+        assert edb.verify_batch(items).all()
+        assert edb.verify_batch(items, force_device=True).all()
+    finally:
+        verify_service.reset()
 
 
 def test_enabled_refcount_maintains_module_guard():

@@ -258,3 +258,271 @@ def test_keyset_unique_set_lru_survives_interleaving(monkeypatch):
     ks_a2, idx_a2, _ = edb.get_keyset(seq_a)
     assert ks_a2 is ks_a and (idx_a2 == idx_a).all()
     assert builds["n"] == 1
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 23: one causal trace per commit decision, across the executor thread
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def tracer():
+    from tendermint_tpu.utils import trace
+
+    t = trace.Tracer("svc-trace", cap=4096, enabled=True)
+    yield t
+    t.disable()
+
+
+def _commit_of(n):
+    from tests.test_perf_gate import CHAIN_ID, _commit
+
+    vals, commit = _commit(n)
+    return vals, commit, CHAIN_ID
+
+
+def _tree(spans, did):
+    """The spans of decision `did`, checked to be ONE tree under its root."""
+    mine = [s for s in spans if s.tags.get("decision") == did]
+    ids = {s.span_id for s in mine}
+    roots = [s for s in mine if s.parent_id == 0]
+    assert [r.name for r in roots] == ["commit.assemble"]
+    assert roots[0].span_id == did
+    for s in mine:
+        assert s.parent_id in ids or s is roots[0], (s.name, s.parent_id)
+    return mine
+
+
+@pytest.mark.parametrize("entry, mode", [("verify_commit_async", "full"),
+                                         ("verify_commit_light_async", "light")])
+def test_one_decision_is_one_tree_across_the_service_thread(tracer, entry, mode):
+    """verify_commit_async(...).resolve() through the service: every span
+    carries the root's decision id, only the root has parent_id 0, the
+    executor's verify.* spans are real spans with the ops layer's prep.*
+    spans nested under them, and starts are where the work began."""
+    import time
+
+    vals, commit, chain_id = _commit_of(40)
+    with tracer.activate():
+        t_call = time.monotonic()
+        pcv = getattr(vals, entry)(chain_id, commit.block_id, commit.height,
+                                   commit)
+        pcv.resolve()
+        t_done = time.monotonic()
+    spans = tracer.dump()
+    assert pcv._decision and all(
+        s.tags.get("decision") == pcv._decision for s in spans)
+    mine = _tree(spans, pcv._decision)
+    assert len(mine) == len(spans)
+    by_name = {}
+    for s in mine:
+        by_name.setdefault(s.name, []).append(s)
+    assert set(by_name) >= {"commit.assemble", "commit.wait", "commit.tally",
+                            "verify.queue", "verify.host_prep",
+                            "verify.readback", "verify.replay",
+                            "prep.host_verify", "prep.scalars"}
+    root = by_name["commit.assemble"][0]
+    sigs = 40 if mode == "full" else 27            # light stops at +2/3
+    assert root.tags["mode"] == mode and root.tags["sigs"] == sigs
+    assert 0.0 < root.tags["sign_bytes_s"] < root.duration_s
+    # no verify.* / prep.* span is a root, and the executor's are the
+    # decision's children though they ran on another thread
+    for s in mine:
+        if s.name.startswith(("verify.", "prep.", "commit.w", "commit.t")):
+            assert s.parent_id != 0, s.name
+    prep = by_name["verify.host_prep"][0]
+    assert prep.parent_id == root.span_id
+    assert prep.tags["kind"] == "ed25519" and prep.tags["sigs"] == sigs
+    host = by_name["prep.host_verify"][0]
+    assert host.parent_id == prep.span_id and host.tags["route"] == "host_c"
+    assert by_name["prep.scalars"][0].parent_id == host.span_id
+    # the queue wait starts at the submit (inside the root span), not at
+    # the moment the executor wrote it down; every span lies in the call
+    q = by_name["verify.queue"][0]
+    assert root.start <= q.start <= root.start + root.duration_s
+    assert abs((q.start + q.duration_s) - prep.start) < 0.05
+    rb, rp = by_name["verify.readback"][0], by_name["verify.replay"][0]
+    assert rb.start + rb.duration_s <= rp.start + 1e-6
+    for s in mine:
+        assert t_call <= s.start and s.start + s.duration_s <= t_done + 1e-6
+    for wake in by_name.get("verify.wake", []):
+        assert wake.start >= rp.start + rp.duration_s - 1e-6
+
+
+def test_interleaved_decisions_do_not_share_spans(tracer):
+    """Two decisions in flight at once, resolved out of order: each span
+    belongs to exactly one of the two trees."""
+    import time
+
+    vals, commit, chain_id = _commit_of(33)
+    with tracer.activate():
+        a = vals.verify_commit_async(chain_id, commit.block_id, commit.height,
+                                     commit)
+        deadline = time.monotonic() + 20
+        while a.pending.has_device_output() and time.monotonic() < deadline:
+            time.sleep(0.005)        # A's launch is over: B cannot join it
+        b = vals.verify_commit_light_async(chain_id, commit.block_id,
+                                           commit.height, commit)
+        b.resolve()
+        a.resolve()
+    spans = tracer.dump()
+    assert a._decision and b._decision and a._decision != b._decision
+    tree_a, tree_b = _tree(spans, a._decision), _tree(spans, b._decision)
+    assert len(tree_a) + len(tree_b) == len(spans)
+    assert not {s.span_id for s in tree_a} & {s.span_id for s in tree_b}
+    for tree in (tree_a, tree_b):
+        names = [s.name for s in tree]
+        for once in ("commit.assemble", "commit.wait", "commit.tally",
+                     "verify.host_prep"):
+            assert names.count(once) == 1, (once, names)
+        assert not any("decisions" in s.tags for s in tree)
+
+
+def test_a_coalesced_launch_names_every_decision_it_serves(tracer):
+    """Two decisions submitted inside one window share ONE launch: its
+    spans sit in the first decision's tree and carry decisions=[both]; the
+    other tracer-less bookkeeping (queue, wake, wait, tally) stays per
+    decision."""
+    vals, commit, chain_id = _commit_of(33)
+    with tracer.activate():
+        a = vals.verify_commit_async(chain_id, commit.block_id, commit.height,
+                                     commit)
+        b = vals.verify_commit_async(chain_id, commit.block_id, commit.height,
+                                     commit)
+        a.resolve()
+        b.resolve()
+    assert verify_service.get().max_coalesced == 2
+    spans = tracer.dump()
+    both = sorted([a._decision, b._decision])
+    shared = [s for s in spans if s.name in ("verify.host_prep",
+                                             "verify.readback",
+                                             "verify.replay")]
+    assert [s.name for s in shared].count("verify.host_prep") == 1
+    for s in shared:
+        assert s.tags["decisions"] == both and s.tags["coalesced"] == 2
+        assert s.tags["decision"] == a._decision
+        assert s.parent_id == a._decision
+    for pcv in (a, b):
+        names = [s.name for s in spans if s.tags.get("decision") == pcv._decision]
+        for once in ("commit.assemble", "verify.queue", "commit.wait",
+                     "commit.tally"):
+            assert names.count(once) == 1, (once, names)
+
+
+def test_another_nodes_tracer_gets_the_recorded_copy(tracer):
+    """Two nodes' requests in one launch: the first holds the real spans,
+    the other a recorded copy with the work's own start -- and the first
+    holds no duplicate."""
+    from tendermint_tpu.utils import trace
+
+    other = trace.Tracer("svc-trace-2", cap=1024, enabled=True)
+    try:
+        items = _ed_items(33, seed=51)
+        with tracer.activate():
+            pa = _dispatch("ed25519", items)
+        with other.activate():
+            pb = _dispatch("ed25519", items)
+        assert pa.resolve()[0] and pb.resolve()[0]
+        assert verify_service.get().max_coalesced == 2
+        real = {s.name: s for s in tracer.dump()}
+        copy = {s.name: s for s in other.dump()}
+        for name in ("verify.host_prep", "verify.readback", "verify.replay"):
+            assert [s.name for s in tracer.dump()].count(name) == 1
+            assert abs(copy[name].start - real[name].start) < 0.01, name
+            assert copy[name].tags["coalesced"] == 2
+        assert "prep.host_verify" in real and "prep.host_verify" not in copy
+    finally:
+        other.disable()
+
+
+def _launches(tracer):
+    return [s for s in tracer.dump() if s.name == "prep.launch"]
+
+
+def test_route_tag_names_the_route_taken_and_lanes_the_padded_size(
+        tracer, monkeypatch):
+    """A host-routed, a forced-device and a sharded batch on the CPU's
+    virtual devices: `route` is the route dispatch_batch took, the finish
+    carries it for batch_verify_seconds, and sum(lanes) is the padded size
+    actually launched."""
+    from tendermint_tpu.ops import ed25519_batch as edb
+    from tendermint_tpu.parallel import batch_shard
+    from tendermint_tpu.utils import metrics as tmmetrics
+
+    raw = [(pk.bytes(), m, s) for pk, m, s in _ed_items(40, seed=61)]
+    monkeypatch.setattr(tmmetrics, "GLOBAL_NODE_METRICS",
+                        tmmetrics.NodeMetrics())
+    # routing, tags and lane counts are the host's work: the kernels (slow
+    # tier: test_ed25519_batch, test_multichip) are stood in for by their
+    # `valid` argument, which keeps this test off ten tiles of XLA:CPU
+    monkeypatch.setattr(edb, "_jnp_kernel", lambda tab, **kw: kw["valid"])
+    monkeypatch.setattr(batch_shard, "_sharded_verify_fn",
+                        lambda mesh, kind: lambda tab, idx, *arrays: arrays[-1])
+    with tracer.activate():
+        # host: below the crossover the C verifier answers, nothing launches
+        dev, finish = edb.dispatch_batch(raw)
+        assert dev is None and finish.route == "host_c"
+        host = [s for s in tracer.dump() if s.name == "prep.host_verify"]
+        assert [s.tags["route"] for s in host] == ["host_c"]
+        assert host[0].tags["sigs"] == 40 and not _launches(tracer)
+        tracer.clear()
+
+        # forced device, one chip's worth: the jnp kernel in 256-lane tiles
+        monkeypatch.setenv("TM_TPU_SHARD", "0")
+        big = raw * 7                                     # 280 signatures
+        dev, finish = edb.dispatch_batch(big, force_device=True)
+        assert finish(cbatch._device_get(dev)).all() and finish.route == "jnp"
+        got = _launches(tracer)
+        assert [s.tags["route"] for s in got] == ["jnp", "jnp"]
+        assert {s.tags["program"] for s in got} == {"jit__verify_kernel"}
+        assert [s.tags["sigs"] for s in got] == [256, 24]
+        assert sum(s.tags["lanes"] for s in got) == 2 * edb.JNP_TILE == 512
+        keyset = [s for s in tracer.dump() if s.name == "prep.keyset"]
+        assert keyset[0].tags["hit"] == "miss" and keyset[0].tags["keys"] == 280
+        tracer.clear()
+
+        # sharded: the same batch over the 8 virtual devices
+        monkeypatch.delenv("TM_TPU_SHARD")
+        monkeypatch.setenv("TM_TPU_SHARD_MIN", "64")
+        assert batch_shard.should_shard(len(big))
+        p = _dispatch("ed25519", [(pk, m, s) for pk, m, s in
+                                  _ed_items(40, seed=61)] * 7)
+        assert p.resolve()[0]
+        got = _launches(tracer)
+        chunk = 8 * edb.JNP_TILE
+        assert [s.tags["route"] for s in got] == ["sharded"]
+        assert got[0].tags["program"] == "jit__local_verify"
+        assert got[0].tags["sigs"] == 280 and got[0].tags["lanes"] == chunk
+        shard = [s for s in tracer.dump() if s.name == "verify.shard_dispatch"]
+        assert got[0].parent_id == shard[0].span_id
+        assert [s.tags["hit"] for s in tracer.dump()
+                if s.name == "prep.keyset"] == ["sequence"]
+    # the service observed the sharded launch under its route's label
+    text = tmmetrics.GLOBAL_NODE_METRICS.registry.expose()
+    assert ('tendermint_consensus_batch_verify_seconds_count'
+            '{route="sharded"} 1') in text
+    for route in ("pallas", "jnp", "host_c", "host_scalar",
+                  "breaker_fallback"):
+        assert ('tendermint_consensus_batch_verify_seconds_count'
+                f'{{route="{route}"}} 0') in text, route
+
+
+def test_a_breaker_fallback_is_named_on_the_span_and_the_finish(
+        tracer, monkeypatch):
+    from tendermint_tpu.ops import ed25519_batch as edb
+    from tendermint_tpu.utils import faults
+
+    raw = [(pk.bytes(), m, s) for pk, m, s in _ed_items(9, seed=71)]
+    monkeypatch.setattr(edb.BREAKER, "probe", None)
+    edb.BREAKER.reset()
+    faults.configure(["ops.ed25519.device:raise@1"], seed=3)
+    try:
+        with tracer.activate():
+            dev, finish = edb.dispatch_batch(raw, force_device=True)
+        assert dev is None and finish(None).all()
+        assert finish.route == "breaker_fallback"
+        host = [s for s in tracer.dump() if s.name == "prep.host_verify"]
+        assert [s.tags["route"] for s in host] == ["breaker_fallback"]
+    finally:
+        faults.configure([], seed=0)
+        edb.BREAKER.reset()
