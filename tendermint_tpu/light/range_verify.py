@@ -101,13 +101,7 @@ def verify_header_range(trusted: LightBlock, chain: list[LightBlock],
                 sh.height, f"wrong set size: {vals.size()} vs {len(commit.signatures)}")
         needed = vals.total_voting_power() * 2 // 3
         prefix = vals.commit_light_prefix(commit, needed)
-        chain_id = sh.header.chain_id
-        validators = vals.validators
-        signatures = commit.signatures
-        add = verifier.add
-        for idx in prefix:
-            add(validators[idx].pub_key, commit.vote_sign_bytes(chain_id, idx),
-                signatures[idx].signature)
+        vals.add_commit_sigs(verifier, sh.header.chain_id, commit, prefix, prefix)
         plan.append((lb, prefix, needed))
         if len(verifier) >= chunk_sigs_target:
             pending.append((plan, verifier.dispatch(force_device=use_device)))

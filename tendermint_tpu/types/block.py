@@ -21,6 +21,8 @@ from tendermint_tpu.types.vote import (
     BLOCK_ID_FLAG_NIL,
     PRECOMMIT_TYPE,
     Vote,
+    canonical_vote_bytes,
+    canonical_vote_bytes_many,
 )
 
 MAX_HEADER_BYTES = 626  # reference: types/block.go MaxHeaderBytes
@@ -287,15 +289,36 @@ class Commit:
     def vote_sign_bytes(self, chain_id: str, val_idx: int) -> bytes:
         """Canonical sign bytes for the precommit in slot val_idx —
         equivalent to get_vote(val_idx).sign_bytes(chain_id) (differential-
-        tested). Rides canonical_vote_bytes' template cache, so
-        verify_commit-style loops pay one Writer build per (commit, flag)
-        instead of one per vote."""
-        from tendermint_tpu.types.vote import canonical_vote_bytes
-
+        tested): the per-vote source of truth. A loop over a commit's slots
+        takes sign_bytes_many instead."""
         cs = self.signatures[val_idx]
         return canonical_vote_bytes(chain_id, PRECOMMIT_TYPE, self.height,
                                     self.round, cs.block_id(self.block_id),
                                     cs.timestamp)
+
+    def sign_bytes_many(self, chain_id: str, idxs) -> tuple[list[bytes], int]:
+        """``[self.vote_sign_bytes(chain_id, i) for i in idxs]`` with the
+        work that is constant across a commit done once per call, and how
+        many of them that covered (the ``spliced`` tag of
+        ``commit.assemble``). The votes for the block differ in nothing but
+        their timestamps, so canonical_vote_bytes_many splices those into
+        one prefix; votes for nil (a zero BlockID has no fixed layout) and
+        a commit whose shape has no template take the per-index path.
+        Nothing is kept between calls: a pool of commits verified over and
+        over pays the same every time."""
+        sigs = self.signatures
+        for_block = [sigs[i].timestamp for i in idxs
+                     if sigs[i].block_id_flag == BLOCK_ID_FLAG_COMMIT]
+        msgs = canonical_vote_bytes_many(
+            chain_id, PRECOMMIT_TYPE, self.height, self.round, self.block_id,
+            for_block) if for_block else None
+        if msgs is None:
+            return [self.vote_sign_bytes(chain_id, i) for i in idxs], 0
+        if len(msgs) == len(idxs):
+            return msgs, len(msgs)
+        spliced = iter(msgs)
+        return [next(spliced) if sigs[i].block_id_flag == BLOCK_ID_FLAG_COMMIT
+                else self.vote_sign_bytes(chain_id, i) for i in idxs], len(msgs)
 
     def size(self) -> int:
         return len(self.signatures)

@@ -384,6 +384,26 @@ class ValidatorSet:
         pcv._tracer, pcv._decision = tr, did
         return pcv
 
+    def add_commit_sigs(self, verifier, chain_id: str, commit,
+                        idxs: list[int], val_idxs: list[int], tr=None) -> None:
+        """Queue on ``verifier``, in order, the signature in every commit
+        slot of ``idxs`` against the key of the validator at the same place
+        in ``val_idxs``. The one loop under every Verify* entry point and
+        light.range_verify: the sign bytes are assembled once for the commit
+        (Commit.sign_bytes_many), and under a tracer that one call is timed
+        into the open ``commit.assemble`` span."""
+        if tr is None:
+            msgs, _ = commit.sign_bytes_many(chain_id, idxs)
+        else:
+            t0 = time.perf_counter()
+            msgs, spliced = commit.sign_bytes_many(chain_id, idxs)
+            tr.annotate(sigs=len(idxs), spliced=spliced,
+                        sign_bytes_s=time.perf_counter() - t0)
+        add, validators, signatures = (
+            verifier.add, self.validators, commit.signatures)
+        for idx, val_idx, msg in zip(idxs, val_idxs, msgs):
+            add(validators[val_idx].pub_key, msg, signatures[idx].signature)
+
     def verify_commit_async(self, chain_id: str, block_id: BlockID, height: int,
                             commit, force_device: bool = False) -> PendingCommitVerify:
         """Deferred verify_commit: host prep + device dispatch now, the
@@ -403,31 +423,10 @@ class ValidatorSet:
         err = self._commit_structural_error(block_id, height, commit)
         if err is not None:
             return PendingCommitVerify(error=err)
+        queued = [idx for idx, cs in enumerate(commit.signatures)
+                  if not cs.absent()]
         verifier = crypto_batch.create_batch_verifier()
-        queued: list[int] = []
-        if tr is None:
-            for idx, cs in enumerate(commit.signatures):
-                if cs.absent():
-                    continue
-                verifier.add(
-                    self.validators[idx].pub_key,
-                    commit.vote_sign_bytes(chain_id, idx),
-                    cs.signature,
-                )
-                queued.append(idx)
-        else:
-            # the traced twin of the loop above: two clock readings around
-            # each vote_sign_bytes, summed into the root span's sign_bytes_s
-            clock, sign_bytes_s = time.perf_counter, 0.0
-            for idx, cs in enumerate(commit.signatures):
-                if cs.absent():
-                    continue
-                t0 = clock()
-                msg = commit.vote_sign_bytes(chain_id, idx)
-                sign_bytes_s += clock() - t0
-                verifier.add(self.validators[idx].pub_key, msg, cs.signature)
-                queued.append(idx)
-            tr.annotate(sigs=len(queued), sign_bytes_s=sign_bytes_s)
+        self.add_commit_sigs(verifier, chain_id, commit, queued, queued, tr)
         pending = verifier.dispatch(force_device=force_device)
         # Freeze the decision inputs at dispatch time.
         needed = self.total_voting_power() * 2 // 3
@@ -496,23 +495,7 @@ class ValidatorSet:
         needed = self.total_voting_power() * 2 // 3
         prefix = self.commit_light_prefix(commit, needed)
         verifier = crypto_batch.create_batch_verifier()
-        if tr is None:
-            for idx in prefix:
-                verifier.add(
-                    self.validators[idx].pub_key,
-                    commit.vote_sign_bytes(chain_id, idx),
-                    commit.signatures[idx].signature,
-                )
-        else:
-            # traced twin, as in _verify_commit_assemble
-            clock, sign_bytes_s = time.perf_counter, 0.0
-            for idx in prefix:
-                t0 = clock()
-                msg = commit.vote_sign_bytes(chain_id, idx)
-                sign_bytes_s += clock() - t0
-                verifier.add(self.validators[idx].pub_key, msg,
-                             commit.signatures[idx].signature)
-            tr.annotate(sigs=len(prefix), sign_bytes_s=sign_bytes_s)
+        self.add_commit_sigs(verifier, chain_id, commit, prefix, prefix, tr)
         pending = verifier.dispatch(force_device=force_device)
         powers = [self.validators[idx].voting_power for idx in prefix]
         signatures = list(commit.signatures)
@@ -560,12 +543,9 @@ class ValidatorSet:
                 break
 
         verifier = crypto_batch.create_batch_verifier()
-        for idx, val_idx in prefix:
-            verifier.add(
-                self.validators[val_idx].pub_key,
-                commit.vote_sign_bytes(chain_id, idx),
-                commit.signatures[idx].signature,
-            )
+        self.add_commit_sigs(verifier, chain_id, commit,
+                             [idx for idx, _ in prefix],
+                             [val_idx for _, val_idx in prefix])
         _, bitmap = verifier.verify()
 
         tallied = 0

@@ -49,28 +49,23 @@ def canonical_block_id_bytes(bid: BlockID) -> bytes | None:
 _CV_TEMPLATES: dict = {}
 
 
-def canonical_vote_bytes(chain_id: str, vtype: int, height: int, round_: int,
-                         block_id: BlockID, timestamp: Time) -> bytes:
-    """Delimited CanonicalVote marshal = the exact signed payload
-    (reference: types/vote.go:93 VoteSignBytes).
-
-    Fast path: for the ubiquitous shape (32-byte hashes, small part total,
-    non-nil block) the byte layout is fixed given (chain_id, vtype, round,
-    total) — height is sfixed64 — so a splice template fills in height,
-    hashes and timestamp with one join instead of a Writer build per call.
-    The template is SELF-CHECKED against the Writer construction when
-    built: layout drift disables the fast path for that key rather than
-    ever signing wrong bytes. Light-client range sync builds one of these
-    per header; a cache keyed on (height, block_id) missed every time
-    there."""
+def _cv_ends(chain_id: str, vtype: int, height: int, round_: int,
+             block_id: BlockID) -> tuple[bytes, bytes] | None:
+    """``(prefix, suffix)``: the sign bytes before the timestamp's length
+    and after its body, for the ubiquitous shape (32-byte hashes, small
+    part total, non-nil block); None where only the Writer knows the
+    layout. Given (chain_id, vtype, round, total) the byte layout is fixed
+    — height is sfixed64 — so a cached splice template fills in height and
+    hashes with one join. The template is SELF-CHECKED against the Writer
+    construction when built: layout drift disables the fast path for that
+    key rather than ever signing wrong bytes."""
     psh = block_id.part_set_header
     if not (len(block_id.hash) == 32 and len(psh.hash) == 32
             and 0 < psh.total < 128 and 0 < height < 2**63
             and 0 <= round_ < 2**63 and vtype != 0):
         # height 0 is never signed; zero-valued proto fields are omitted by
         # the Writer, so the fixed-layout assumption needs height > 0
-        return _canonical_vote_bytes_writer(
-            chain_id, vtype, height, round_, block_id, timestamp)
+        return None
     key = (chain_id, vtype, round_, psh.total)
     tmpl = _CV_TEMPLATES.get(key, False)
     if tmpl is False:
@@ -105,13 +100,90 @@ def canonical_vote_bytes(chain_id: str, vtype: int, height: int, round_: int,
             tmpl = None
         _CV_TEMPLATES[key] = tmpl
     if tmpl is None:
+        return None
+    head, mid1, mid2, suf = tmpl
+    return (head + height.to_bytes(8, "little") + mid1 + block_id.hash
+            + mid2 + psh.hash + b"\x2a"), suf
+
+
+def canonical_vote_bytes(chain_id: str, vtype: int, height: int, round_: int,
+                         block_id: BlockID, timestamp: Time) -> bytes:
+    """Delimited CanonicalVote marshal = the exact signed payload
+    (reference: types/vote.go:93 VoteSignBytes).
+
+    Fast path: where the shape has a splice template (`_cv_ends`), joins
+    fill in height, hashes and timestamp instead of a Writer build per
+    call. Light-client range sync builds one of these per header; a cache
+    keyed on (height, block_id) missed every time there."""
+    ends = _cv_ends(chain_id, vtype, height, round_, block_id)
+    if ends is None:
         return _canonical_vote_bytes_writer(
             chain_id, vtype, height, round_, block_id, timestamp)
-    head, mid1, mid2, suf = tmpl
+    prefix, suf = ends
     tsm = timestamp.marshal()
     return proto.delimited(
-        head + height.to_bytes(8, "little") + mid1 + block_id.hash
-        + mid2 + psh.hash + b"\x2a" + proto.encode_uvarint(len(tsm)) + tsm + suf)
+        prefix + proto.encode_uvarint(len(tsm)) + tsm + suf)
+
+
+# Stateless varint pieces for the nanos field of canonical_vote_bytes_many:
+# the two continuation bytes of a 14-bit group, and the one of a 7-bit group.
+_UV14C = tuple(bytes((k & 0x7F | 0x80, k >> 7 | 0x80)) for k in range(1 << 14))
+_UV7C = tuple(bytes((k | 0x80,)) for k in range(1 << 7))
+
+
+def canonical_vote_bytes_many(chain_id: str, vtype: int, height: int,
+                              round_: int, block_id: BlockID,
+                              timestamps) -> list[bytes] | None:
+    """``[canonical_vote_bytes(..., ts) for ts in timestamps]`` for votes
+    that differ in nothing but their timestamp (the precommits of one
+    commit), byte-identical, with the constant work done once per CALL: of
+    the ~120 bytes only the timestamp body and the two length prefixes
+    before it differ. None where the shape has no splice template; the
+    caller then takes the per-vote path.
+
+    Everything memoised here lives in this call's frame and dies with it.
+    A commit's validators sign within a few seconds of each other, so the
+    frame up to and including the ``seconds`` field is built once per
+    distinct second, for each length the nanos varint can have; per vote
+    that leaves the nanos varint, from the tables above, and one join."""
+    ends = _cv_ends(chain_id, vtype, height, round_, block_id)
+    if ends is None:
+        return None
+    prefix, suf = ends
+    uv, join = proto.encode_uvarint, b"".join
+    pair, cont = _UV14C, _UV7C
+    # the last nanos byte (no continuation bit) with the suffix behind it
+    tails = [uv(last) + suf for last in range(0x80)]
+    # all but the timestamp body; its length (at most 22) takes one byte
+    fixed = len(prefix) + 1 + len(suf)
+    frames: dict = {}
+    out = []
+    append = out.append
+    for ts in timestamps:
+        seconds, nanos = ts.seconds, ts.nanos
+        by_len = frames.get(seconds)
+        if by_len is None:
+            sec = proto.Writer().varint(1, seconds).out()
+            # by_len[k]: outer length, prefix, timestamp length, seconds
+            # field and the nanos tag, for a nanos varint of k bytes
+            body = len(sec)
+            by_len = frames[seconds] = (
+                [uv(fixed + body) + prefix + uv(body) + sec]
+                + [uv(fixed + body + 1 + k) + prefix + uv(body + 1 + k)
+                   + sec + b"\x10" for k in (1, 2, 3, 4, 5)])
+        if 0x10000000 <= nanos < 0x800000000:  # three nanos values in four
+            append(join((by_len[5], pair[nanos & 0x3FFF],
+                         pair[nanos >> 14 & 0x3FFF], tails[nanos >> 28])))
+        elif 0x200000 <= nanos < 0x10000000:
+            append(join((by_len[4], pair[nanos & 0x3FFF],
+                         cont[nanos >> 14 & 0x7F], tails[nanos >> 21])))
+        elif 0 <= nanos < 0x200000:  # 0: zero-valued field, omitted whole
+            low = uv(nanos) if nanos else b""
+            append(by_len[len(low)] + low + suf)
+        else:  # no valid Timestamp; whatever Time.marshal makes of it
+            tsm = ts.marshal()
+            append(proto.delimited(prefix + uv(len(tsm)) + tsm + suf))
+    return out
 
 
 def _canonical_vote_bytes_writer(chain_id: str, vtype: int, height: int,

@@ -88,8 +88,8 @@ CANONICAL_SPANS = {
     "verify.wake": "executor's done.set() -> the waiting caller runs again",
     # one commit decision, entry point to tally (types/validator_set.py);
     # commit.assemble is the decision's root and its span id the decision id
-    "commit.assemble": "structural check, sign bytes + add per signature, "
-                       "verifier.dispatch (root span of a decision)",
+    "commit.assemble": "structural check, the commit's sign bytes (once), add "
+                       "per signature, verifier.dispatch (root span of a decision)",
     "commit.wait": "PendingCommitVerify.resolve waiting for the bitmap",
     "commit.tally": "serial accept/reject replay over the bitmap",
     # below ops dispatch_batch (ops/ed25519_batch, sr25519_batch,

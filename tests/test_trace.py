@@ -308,16 +308,19 @@ def test_disabled_path_at_the_decision_sites_touches_no_tracer(monkeypatch):
     """ISSUE 23: with tracing off, a whole commit decision -- entry point,
     verify service, ops prep, host route and forced device launch --
     allocates no span and takes no ring lock: every Tracer entry point of
-    the DEFAULT tracer is booby-trapped, and the decision id stays 0."""
+    the DEFAULT tracer is booby-trapped, and the decision id stays 0. Nor
+    does the entry point read a clock (ISSUE 24: one loop serves both
+    paths, and only the traced one times its sign bytes)."""
     from tendermint_tpu.crypto import batch as cbatch
     from tendermint_tpu.crypto import verify_service
     from tendermint_tpu.ops import ed25519_batch as edb
+    from tendermint_tpu.types import validator_set as vset
     from tests.test_perf_gate import CHAIN_ID, _commit
 
     assert not trace.ENABLED
 
     def boom(*_a, **_kw):
-        raise AssertionError("a disabled site reached the tracer")
+        raise AssertionError("a disabled site reached the tracer or a clock")
 
     class NoLock:
         def __enter__(self):
@@ -326,9 +329,16 @@ def test_disabled_path_at_the_decision_sites_touches_no_tracer(monkeypatch):
         def __exit__(self, *exc):
             return False
 
+    class NoClock:
+        def __getattr__(self, name):
+            boom()
+
     for name in ("span", "mark", "record", "annotate", "_append", "_stacks"):
         monkeypatch.setattr(trace.DEFAULT, name, boom)
     monkeypatch.setattr(trace.DEFAULT, "_mtx", NoLock())
+    # ISSUE 24: the entry point's only clock readings are the two around
+    # sign_bytes_many, on the traced path
+    monkeypatch.setattr(vset, "time", NoClock())
     monkeypatch.setenv("TMTPU_VERIFY_SERVICE", "1")
     # the launch site is the host's; the kernel itself is the slow tier's
     monkeypatch.setattr(edb, "_jnp_kernel", lambda tab, **kw: kw["valid"])
@@ -349,6 +359,48 @@ def test_disabled_path_at_the_decision_sites_touches_no_tracer(monkeypatch):
         assert edb.verify_batch(items, force_device=True).all()
     finally:
         verify_service.reset()
+
+
+@pytest.mark.parametrize("entry, mode, nil, sigs, spliced", [
+    ("verify_commit_async", "full", (), 24, 24),
+    ("verify_commit_light_async", "light", (), 17, 17),   # stops at +2/3
+    ("verify_commit_async", "full", (1, 5, 20), 24, 21),  # nil votes fall back
+    ("verify_commit_light_async", "light", (1, 5, 20), 17, 17),  # skips them
+])
+def test_commit_assemble_tags_its_sign_bytes_once_a_decision(
+        tracer, monkeypatch, entry, mode, nil, sigs, spliced):
+    """ISSUE 24: commit.assemble carries `sigs`, `spliced` (how many sign
+    bytes the per-commit splice produced; the rest took the per-index path)
+    and `sign_bytes_s` from exactly two clock readings a decision, whatever
+    the number of signatures."""
+    from tendermint_tpu.types import validator_set as vset
+    from tendermint_tpu.types.vote import BLOCK_ID_FLAG_NIL
+    from tests.test_perf_gate import CHAIN_ID, _commit
+
+    vals, commit = _commit(24)
+    for i in nil:  # the verdict is not under test, so the flag alone moves
+        commit.signatures[i].block_id_flag = BLOCK_ID_FLAG_NIL
+
+    class CountingClock:
+        readings = 0
+
+        def perf_counter(self):
+            CountingClock.readings += 1
+            return time.perf_counter()
+
+    monkeypatch.setattr(vset, "time", CountingClock())
+    with tracer.activate():
+        pcv = getattr(vals, entry)(CHAIN_ID, commit.block_id, commit.height,
+                                   commit)
+    try:
+        pcv.resolve()
+    except vset.ValidatorSetError:
+        assert nil  # a re-flagged vote's signature covers other bytes
+    assert CountingClock.readings == 2
+    root, = [s for s in tracer.dump() if s.name == "commit.assemble"]
+    assert root.tags["mode"] == mode
+    assert (root.tags["sigs"], root.tags["spliced"]) == (sigs, spliced)
+    assert 0.0 < root.tags["sign_bytes_s"] < root.duration_s
 
 
 def test_enabled_refcount_maintains_module_guard():

@@ -332,6 +332,165 @@ def test_commit_vote_sign_bytes_template_differential():
                     == c.get_vote(i).sign_bytes(chain_id)), (chain_id, i)
 
 
+def _sb_commit(stamps, *, height=300, round_=0, total=1, hash_len=32):
+    """A Commit whose slot i carries stamps[i] = (flag, Time), signatures
+    unsigned: only the sign bytes are under test."""
+    from tendermint_tpu.types.vote import BLOCK_ID_FLAG_ABSENT
+
+    bid = BlockID(hash=b"\x11" * hash_len,
+                  part_set_header=PartSetHeader(total=total, hash=b"\x22" * 32))
+    sigs = [CommitSig.new_absent() if flag == BLOCK_ID_FLAG_ABSENT
+            else CommitSig(flag, bytes([i + 1]) * 20, ts, b"s" * 64)
+            for i, (flag, ts) in enumerate(stamps)]
+    return Commit(height=height, round=round_, block_id=bid, signatures=sigs)
+
+
+def _sb_cases():
+    from tendermint_tpu.types.ttime import GO_ZERO_SECONDS
+    from tendermint_tpu.types.vote import BLOCK_ID_FLAG_ABSENT as A
+
+    C, N = BLOCK_ID_FLAG_COMMIT, BLOCK_ID_FLAG_NIL
+    t0 = 1_700_000_000
+    spread = [(C, Time(t0 + i % 3, 123_456_789 * (i + 1) % 10**9))
+              for i in range(9)]
+    # id -> (commit, idxs, how many the splice covers)
+    cases = {
+        "all_commit": (_sb_commit(spread), list(range(9)), 9),
+        "commit_nil_mixed": (_sb_commit(
+            [(C, Time(t0, 5)), (N, Time(t0 + 1, 6)), (C, Time(t0, 7)),
+             (N, Time(t0, 0)), (C, Time(t0 + 2, 8))]), [0, 1, 2, 3, 4], 3),
+        "all_nil": (_sb_commit([(N, Time(t0, 5)), (N, Time(t0, 6))]), [0, 1], 0),
+        "absent_skipped": (_sb_commit(
+            [(C, Time(t0, 5)), (A, Time()), (C, Time(t0, 7)), (A, Time()),
+             (N, Time(t0, 9))]), [0, 2, 4], 2),
+        "light_prefix_out_of_order": (_sb_commit(spread), [7, 2, 5], 3),
+        "round_0": (_sb_commit(spread, round_=0), list(range(9)), 9),
+        "round_5": (_sb_commit(spread, round_=5), list(range(9)), 9),
+        "part_total_1": (_sb_commit(spread, total=1), list(range(9)), 9),
+        "part_total_127": (_sb_commit(spread, total=127), list(range(9)), 9),
+        "part_total_128_fallback": (_sb_commit(spread, total=128), list(range(9)), 0),
+        "hash_20_bytes_fallback": (_sb_commit(spread, hash_len=20), list(range(9)), 0),
+        "height_0_fallback": (_sb_commit(spread, height=0), list(range(9)), 0),
+        "empty_idxs": (_sb_commit(spread), [], 0),
+        # the precommit among test_vote_sign_bytes_golden_vectors, as a commit
+        # slot: nil block id, Go's zero time (checked against the golden
+        # bytes themselves in test_sign_bytes_many_golden_vector)
+        "golden_precommit_nil": (_sb_commit(
+            [(N, Time())], height=1, round_=1), [0], 0),
+    }
+    for nanos in (0, 1, 127, 128, 16_383, 16_384, 2**21 - 1, 2**21,
+                  2**28 - 1, 2**28, 999_999_999,
+                  # no valid Timestamp, but Time.unmarshal can hand them over
+                  2**30, 2**35 - 1, 2**35, -1):
+        cases[f"nanos_{nanos}"] = (_sb_commit(
+            [(C, Time(t0, nanos)), (C, Time(t0 + 1, nanos))]), [0, 1], 2)
+    for name, seconds in (("0", 0), ("2e31", 2**31), ("2e35", 2**35),
+                          ("go_zero", GO_ZERO_SECONDS)):
+        cases[f"seconds_{name}"] = (_sb_commit(
+            [(C, Time(seconds, 0)), (C, Time(seconds, 999_999_999)),
+             (C, Time(seconds, 77))]), [0, 1, 2], 3)
+    return cases
+
+
+_SB_CASES = _sb_cases()
+
+
+@pytest.mark.parametrize("case", sorted(_SB_CASES))
+def test_sign_bytes_many_differential(case):
+    """Commit.sign_bytes_many (the per-commit splice of ISSUE 24) equals,
+    byte for byte, the three per-vote constructions: vote_sign_bytes, the
+    rebuilt Vote's sign_bytes, and the Writer (the layout's source of
+    truth); and it says how many it spliced."""
+    from tendermint_tpu.types import vote as vmod
+
+    commit, idxs, want_spliced = _SB_CASES[case]
+    for chain_id in ("chain-x", "", "c" * 130):  # suffix of 0, 9 and 133 bytes
+        msgs, spliced = commit.sign_bytes_many(chain_id, idxs)
+        assert spliced == want_spliced
+        assert msgs == [commit.vote_sign_bytes(chain_id, i) for i in idxs]
+        assert msgs == [commit.get_vote(i).sign_bytes(chain_id) for i in idxs]
+        assert msgs == [vmod._canonical_vote_bytes_writer(
+            chain_id, PRECOMMIT_TYPE, commit.height, commit.round,
+            commit.signatures[i].block_id(commit.block_id),
+            commit.signatures[i].timestamp) for i in idxs]
+
+
+def test_sign_bytes_many_golden_vector():
+    """The reference's published precommit vector (types/vote_test.go:60-131,
+    case 1 of test_vote_sign_bytes_golden_vectors) through the commit path,
+    and the splice's own answer for the shapes of all five: none has a
+    template (nil block id), so it declines rather than guess."""
+    from tendermint_tpu.types.vote import canonical_vote_bytes_many
+
+    commit, idxs, _ = _SB_CASES["golden_precommit_nil"]
+    want = bytes([0x21, 0x8, 0x2, 0x11, 1, 0, 0, 0, 0, 0, 0, 0,
+                  0x19, 1, 0, 0, 0, 0, 0, 0, 0, 0x2A, 0xB, 0x8, 0x80, 0x92,
+                  0xB8, 0xC3, 0x98, 0xFE, 0xFF, 0xFF, 0xFF, 0x1])
+    assert commit.sign_bytes_many("", idxs) == ([want], 0)
+    for chain_id, vtype, height, round_ in (
+            ("", 0, 0, 0), ("", PRECOMMIT_TYPE, 1, 1), ("", PREVOTE_TYPE, 1, 1),
+            ("", 0, 1, 1), ("test_chain_id", 0, 1, 1)):
+        assert canonical_vote_bytes_many(
+            chain_id, vtype, height, round_, BlockID(), [Time()]) is None
+
+
+def test_sign_bytes_many_keeps_nothing_between_calls():
+    """ISSUE 24: all hoisting lives inside one call. The same Commit object
+    verified twice pays (and answers) the same; a timestamp or the block id
+    changed between two calls shows in the very next call's bytes, and in
+    verify_commit's verdict at that index; the only process-wide state that
+    grows is _CV_TEMPLATES, by the commit's one key."""
+    from tendermint_tpu.types import vote as vmod
+
+    chain_id = "no-memo-chain"
+    pairs = _mk_validators(7)
+    vs = ValidatorSet([v for _, v in pairs])
+    order = {v.address: p for p, v in pairs}
+    privs = [order[v.address] for v in vs.validators]
+    bid = _block_id()
+    commit = _mk_commit(chain_id, 9, 0, bid, vs.validators, privs)
+    idxs = list(range(7))
+
+    vmod._CV_TEMPLATES.clear()
+    first = commit.sign_bytes_many(chain_id, idxs)
+    assert first == commit.sign_bytes_many(chain_id, idxs) and first[1] == 7
+    assert list(vmod._CV_TEMPLATES) == [(chain_id, PRECOMMIT_TYPE, 0, 1)]
+    for obj in (commit, commit.signatures[3], commit.signatures[3].timestamp):
+        assert set(vars(obj)) == {f for f in obj.__dataclass_fields__}
+    vs.verify_commit(chain_id, bid, 9, commit)
+    vs.verify_commit(chain_id, bid, 9, commit)
+
+    # one timestamp moves by a nanosecond: new bytes, and the old signature
+    # no longer covers them
+    old_ts = commit.signatures[3].timestamp
+    commit.signatures[3].timestamp = Time(old_ts.seconds, old_ts.nanos + 1)
+    msgs, _ = commit.sign_bytes_many(chain_id, idxs)
+    assert msgs[3] != first[0][3] and msgs[:3] + msgs[4:] == first[0][:3] + first[0][4:]
+    assert msgs[3] == commit.get_vote(3).sign_bytes(chain_id)
+    for entry in (vs.verify_commit, vs.verify_commit_light):
+        with pytest.raises(ErrWrongSignature) as ei:
+            entry(chain_id, bid, 9, commit)
+        assert ei.value.index == 3
+    with pytest.raises(ErrWrongSignature) as ei:
+        vs.verify_commit_light_trusting(chain_id, commit, (2, 3))
+    assert ei.value.index == 3
+    commit.signatures[3].timestamp = old_ts
+    vs.verify_commit(chain_id, bid, 9, commit)
+
+    # the commit's block id changes under the same height: every message
+    # changes, and the first signature is the first to fail
+    other = BlockID(hash=b"\xcc" * 32,
+                    part_set_header=PartSetHeader(total=1, hash=b"\xbb" * 32))
+    commit.block_id = other
+    msgs, spliced = commit.sign_bytes_many(chain_id, idxs)
+    assert spliced == 7 and all(a != b for a, b in zip(msgs, first[0]))
+    assert msgs == [commit.get_vote(i).sign_bytes(chain_id) for i in idxs]
+    with pytest.raises(ErrWrongSignature) as ei:
+        vs.verify_commit(chain_id, other, 9, commit)
+    assert ei.value.index == 0
+    assert list(vmod._CV_TEMPLATES) == [(chain_id, PRECOMMIT_TYPE, 0, 1)]
+
+
 def test_canonical_vote_bytes_template_cache_differential():
     """canonical_vote_bytes' template cache must be invisible: byte-equal
     to a fresh construction across types, rounds, nil block ids, many
