@@ -52,8 +52,17 @@ class Driver:
         self.run, self.ds, self.traffic = run, dataset, traffic
         self.heights = len(dataset.blocks) - 1     # appliable heights
         needed = dataset.vals.total_voting_power() * 2 // 3
-        self.sigs = len(dataset.vals.commit_light_prefix(
-            dataset.commits[0], needed))
+        # decision j of a pass applies height j + 1 on the light commit
+        # blocks[j + 1] carries for it, whose signers differ height by height
+        # where the configuration has absent or nil votes. ``signer_sets``
+        # counts distinct light prefixes: what the chain holds, not what the
+        # program's key-set cache sees (it keys on a launch's union of the
+        # pipeline's requests): ``sync_keyset_miss_share`` says that
+        prefixes = [tuple(dataset.vals.commit_light_prefix(c, needed))
+                    for c in dataset.commits[:self.heights]]
+        self.sigs = [len(p) for p in prefixes]
+        run.notes["signer_sets"] = {"decisions": len(prefixes),
+                                    "distinct": len(set(prefixes))}
         self.app_hash = None
 
     def _pass(self, blocks, decide) -> ReactorSurface:
@@ -61,14 +70,15 @@ class Driver:
 
         surface = ReactorSurface(self.ds.vals, self.ds.chain_id, blocks)
         pipe = VerifyAheadPipeline()
-        while decide(lambda: pipe.process_next(surface)):
+        while decide(lambda: pipe.process_next(surface),
+                     self.sigs[len(surface.applied)]):
             if len(surface.applied) == self.heights:
                 break
         return surface
 
     def warm_up(self) -> None:
         for _ in range(self.traffic["warmup_passes"]):
-            s = self._pass(self.ds.blocks, lambda fn: fn())
+            s = self._pass(self.ds.blocks, lambda fn, _sigs: fn())
             self.app_hash = s.app_hash
 
     def measure(self) -> None:
@@ -76,7 +86,7 @@ class Driver:
         run.open_window("process_next")
         while run.elapsed() < run.seconds:
             t0 = time.monotonic()
-            s = self._pass(self.ds.blocks, lambda fn: run.decide(fn, self.sigs))
+            s = self._pass(self.ds.blocks, run.decide)
             t1 = time.monotonic()
             if len(s.applied) == self.heights and s.app_hash == self.app_hash:
                 run.passes.append((t0, t1, self.heights))
@@ -123,7 +133,7 @@ class Driver:
             cs.block_id_flag, cs.validator_address, cs.timestamp, bytes(flipped))
         carrier.last_commit = commit
         blocks[h] = carrier
-        s = self._pass(blocks, lambda fn: fn())
+        s = self._pass(blocks, lambda fn, _sigs: fn())
         err = s.rejected[1] if s.rejected else None
         if (s.applied != list(range(1, h)) or s.rejected is None
                 or s.rejected[0] != h or not isinstance(err, ErrWrongSignature)

@@ -7,12 +7,18 @@ chip_smoke.py's (PR 21): a flipped signature bit, S >= L, a truncated
 signature, and a validator whose registered key is no curve point. Lanes the
 generator left alone are valid by construction, so the reference runs on the
 corrupted lanes and on a seeded sample of the others, not on all of them.
+
+Since PR 25 a commit may flag validators Absent or Nil
+(``datagen.signer_pattern``): corruptions are drawn among the signatures that
+are present, one lands on a nil vote where the commit has any, and the light
+prefix of every pooled height is held to the benchmark's own rule
+(benchmark/reference/light_prefix.py).
 """
 
 from __future__ import annotations
 
 from benchmark.harness import datagen
-from benchmark.reference import ed25519_ref, sr25519_ref
+from benchmark.reference import ed25519_ref, light_prefix, sr25519_ref
 
 SAMPLE = {"ed25519": 256, "sr25519": 32}
 _REFERENCE = {"ed25519": ed25519_ref.verify, "sr25519": sr25519_ref.verify}
@@ -25,14 +31,18 @@ def reference_lane(ds, commit, idx: int) -> bool:
         commit.signatures[idx].signature)
 
 
-def corrupted_commit(ds, seed: int, k: int = 0):
-    """Clean commit k with seeded corruptions -> (commit, {idx: kind}). Two
-    land inside the +2/3 prefix, so the light entry point sees them too; one
-    lands on an sr25519 lane where the set has any."""
+def corrupted_commit(ds, seed: int, k: int | None = None):
+    """Clean commit k (the first pooled height that holds a nil vote, when
+    none is named) with seeded corruptions -> (commit, {idx: kind}). Two land
+    inside the +2/3 prefix, so the light entry point sees them too; one lands
+    on an sr25519 lane where the set has any and one on a nil vote where the
+    commit has any. All are drawn among the signatures that are present."""
     from tendermint_tpu.types.block import Commit, CommitSig
-    from tendermint_tpu.types.vote import BLOCK_ID_FLAG_COMMIT
+    from tendermint_tpu.types.vote import BLOCK_ID_FLAG_COMMIT, BLOCK_ID_FLAG_NIL
 
-    clean = ds.commits[k]
+    if k is None:
+        k = next((h for h, nil in enumerate(ds.nil) if nil.any()), 0)
+    clean, _absent = datagen.presented(ds, seed, k, "check")
     vals, n = ds.vals, ds.vals.size()
     sigs = list(clean.signatures)
     corrupted: dict[int, str] = {}
@@ -49,9 +59,13 @@ def corrupted_commit(ds, seed: int, k: int = 0):
     corrupt(off, "off-curve pubkey", ds.spare_sig)
     needed = vals.total_voting_power() * 2 // 3
     prefix = vals.commit_light_prefix(clean, needed)
-    free = [i for i in range(n) if i != off]
+    free = [i for i in range(n)
+            if i != off and not clean.signatures[i].absent()]
     sr = [i for i in free if ds.key_type(i) == "sr25519"]
-    pools = [prefix, prefix, free, free, free, free] + ([sr] if sr else [])
+    nil = [i for i in free
+           if clean.signatures[i].block_id_flag == BLOCK_ID_FLAG_NIL]
+    pools = ([prefix, prefix, free, free, free, free] + ([sr] if sr else [])
+             + ([nil] if nil else []))
     picks: list[int] = []
     for j, pool in enumerate(pools):
         pool = [i for i in pool if i not in picks and i != off]
@@ -97,7 +111,9 @@ def check_decisions(run, ds, entry_points: list) -> None:
 
     fail = run.failures.append
     bad, corrupted = corrupted_commit(ds, run.seed)
-    sample = sample_lanes(ds, run.seed, corrupted)
+    present = [i for i, cs in enumerate(bad.signatures) if not cs.absent()]
+    missing = set(range(ds.vals.size())) - set(present)
+    sample = sample_lanes(ds, run.seed, missing | set(corrupted))
     reference = {i: reference_lane(ds, bad, i) for i in list(corrupted) + sample}
     for i, kind in corrupted.items():
         if reference[i]:
@@ -106,13 +122,15 @@ def check_decisions(run, ds, entry_points: list) -> None:
         if not reference[i]:
             fail(f"reference rejects untouched lane {i}")
     run.notes["corruptions"] = {str(i): k for i, k in sorted(corrupted.items())}
+    run.notes["corrupted_nil_votes"] = sum(
+        1 for i in corrupted if not bad.signatures[i].for_block())
     run.notes["reference_lanes"] = len(reference)
 
     # (1) the same entry point rejects it at the reference's first bad index
     needed = ds.vals.total_voting_power() * 2 // 3
     for verify in entry_points:
         lanes = (ds.vals.commit_light_prefix(bad, needed)
-                 if verify.__name__.endswith("_light") else range(ds.vals.size()))
+                 if verify.__name__.endswith("_light") else present)
         want = next(i for i in lanes if i in corrupted)
         try:
             verify(ds.chain_id, bad.block_id, bad.height, bad)
@@ -130,19 +148,46 @@ def check_decisions(run, ds, entry_points: list) -> None:
     key_types = {v.pub_key.type for v in ds.vals.validators}
     verifier = crypto_batch.create_batch_verifier(
         next(iter(key_types)) if len(key_types) == 1 else None)
-    for i, v in enumerate(ds.vals.validators):
-        verifier.add(v.pub_key, bad.vote_sign_bytes(ds.chain_id, i),
+    for i in present:
+        verifier.add(ds.vals.validators[i].pub_key,
+                     bad.vote_sign_bytes(ds.chain_id, i),
                      bad.signatures[i].signature)
     all_ok, bitmap = verifier.dispatch().resolve()
-    if all_ok or len(bitmap) != ds.vals.size():
+    if all_ok or len(bitmap) != len(present):
         fail("registry bitmap: all_ok or length wrong")
     else:
-        wrong = [i for i in range(len(bitmap))
-                 if bitmap[i] != reference.get(i, True)]
+        wrong = [i for i, ok in zip(present, bitmap)
+                 if ok != reference.get(i, True)]
         if wrong:
             fail(f"registry bitmap differs from the reference at {wrong[:8]}")
 
+    check_light_prefix(run, ds)
     check_breakers(run)
+
+
+def check_light_prefix(run, ds) -> None:
+    """Every pooled height, as a caller presents it: the slots
+    ``ValidatorSet.commit_light_prefix`` would verify are those of the
+    benchmark's own rule, address for address."""
+    validators = [(v.address, v.voting_power) for v in ds.vals.validators]
+    needed = ds.vals.total_voting_power() * 2 // 3
+    lengths = []
+    for k in range(len(ds.commits)):
+        commit, _absent = datagen.presented(ds, run.seed, k, "prefix-check", k)
+        got = [ds.vals.validators[i].address
+               for i in ds.vals.commit_light_prefix(commit, needed)]
+        want = light_prefix.light_prefix(
+            validators, {cs.validator_address: cs.block_id_flag
+                         for cs in commit.signatures if not cs.absent()})
+        lengths.append(len(want))
+        if got != want:
+            at = next((j for j, (g, w) in enumerate(zip(got, want)) if g != w),
+                      min(len(got), len(want)))
+            run.failures.append(
+                f"light prefix of pooled height {commit.height}: the program "
+                f"takes {len(got)} signatures, the reference {len(want)}; "
+                f"they part at place {at}")
+    run.notes["light_prefix_sigs"] = [min(lengths), max(lengths)]
 
 
 def check_breakers(run) -> None:

@@ -34,6 +34,26 @@ def tag_ms_per_decision(run, name: str, tag: str) -> float | None:
     return total * 1e3 / len(run.decisions)
 
 
+def count_per_decision(run, name: str) -> float | None:
+    """Spans of this name written in the window, per decision."""
+    if not run.traced or not run.decisions or not _program_has(name):
+        return None
+    return len(run.span_durations(name)) / len(run.decisions)
+
+
+def tag_share(run, name: str, tag: str, value) -> float | None:
+    """Spans of this name whose ``tag`` reads ``value`` over all its spans
+    that carry the tag, %. None where none does (no such span in the window,
+    or a program from before the tag)."""
+    if not run.traced or not _program_has(name):
+        return None
+    tagged = [s["tags"][tag] for s in run.spans
+              if s["name"] == name and tag in s["tags"]]
+    if not tagged:
+        return None
+    return 100.0 * sum(1 for v in tagged if v == value) / len(tagged)
+
+
 def lane_fill(run) -> float | None:
     """Real signatures over launched lanes, %, over every ``prep.launch`` of
     the window. None where nothing was launched (the host answered)."""
@@ -74,3 +94,18 @@ def startup_s(run, name: str, minus: tuple = ()) -> float | None:
                  for iv in xplane.clip(others, outer)]
         total -= xplane.busy_seconds(inner, before)
     return total
+
+
+def window_ring_ms_per_decision(run, name: str) -> float | None:
+    """Time in the start-up ring's spans of this name that began inside the
+    window, per decision: the ring is always on, and a key-set miss writes
+    its two halves there whenever it happens. None without a ring."""
+    from tendermint_tpu.utils import trace
+
+    ring = getattr(trace, "STARTUP", None)
+    if ring is None or run.window is None or not run.decisions:
+        return None
+    t0, t1 = run.window
+    inside = [s.duration_s for s in ring.dump()
+              if s.name == name and t0 <= s.start and (t1 is None or s.start < t1)]
+    return sum(inside) * 1e3 / len(run.decisions)

@@ -1,0 +1,3 @@
+"""dispatch_ms's reader, where catchup_blocks_per_s is the metric."""
+
+from benchmark.layer_metrics.dispatch_ms import read  # noqa: F401

@@ -1,0 +1,3 @@
+"""sync_keyset_miss_share's reader, where catchup_blocks_per_s is the metric."""
+
+from benchmark.layer_metrics.sync_keyset_miss_share import read  # noqa: F401

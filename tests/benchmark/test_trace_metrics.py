@@ -103,24 +103,146 @@ def test_startup_seconds_take_the_union_before_the_window(monkeypatch):
     assert spans.startup_s(run, "startup.key_decode") == 0.0
 
 
-def test_every_listed_metric_has_a_reader_and_new_entries_only_append():
-    with open(os.path.join(spec.ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    names = [m["name"] for m in bench["per_layer"]]
+def _benchmark(path=os.path.join(spec.ROOT, "BENCHMARK.json")):
+    with open(path) as f:
+        return json.load(f)
+
+
+OLD_CELLS = TIP_CELLS + ["fastsync-1k-mixed.replay"]       # PR 22's four
+# BENCHMARK.json as PR 25 left it. A later PR appends to the file and edits
+# nothing in it, so this copy is what it must still start with.
+PINNED = _benchmark(os.path.join(os.path.dirname(__file__), "data",
+                                 "BENCHMARK.pr25.json"))
+
+
+@pytest.mark.parametrize("group", ["configs", "workloads", "end_to_end",
+                                   "per_layer"])
+def test_new_entries_only_append(group):
+    """The append-only rule: every entry PR 25 knew is there, in its place
+    and unchanged, and everything else comes after; only a metric's
+    ``workloads`` list may have grown, and only at its end."""
+    today, pinned = _benchmark()[group], PINNED[group]
+    assert [e["name"] for e in today[:len(pinned)]] == [e["name"] for e in pinned]
+    for now, then in zip(today, pinned):
+        was = then.get("workloads")
+        assert ("workloads" in now) == (was is not None), now["name"]
+        if was is not None:
+            assert now["workloads"][:len(was)] == was, now["name"]
+        assert ({k: v for k, v in now.items() if k != "workloads"}
+                == {k: v for k, v in then.items() if k != "workloads"}), now["name"]
+
+
+def test_the_pinned_copy_keeps_what_prs_22_and_23_listed():
+    names = [m["name"] for m in PINNED["per_layer"]]
     first_new = names.index("assemble_ms")
     assert names[:first_new][-1] == "compile_cache_misses"   # PR 22's last
-    assert set(names[first_new:]) == (
-        NEW_TIP | NEW_REPLAY | NEW_SETUP
-        | {"lane_fill", "sync_lane_fill", "span_clock_skew_us"})
-    by_name = {m["name"]: m for m in bench["per_layer"]}
+    pr23 = (NEW_TIP | NEW_REPLAY | NEW_SETUP
+            | {"lane_fill", "sync_lane_fill", "span_clock_skew_us"})
+    assert set(names[first_new:first_new + len(pr23)]) == pr23
+    by_name = {m["name"]: m for m in PINNED["per_layer"]}
     for name in NEW_TIP | {"lane_fill"}:
-        assert by_name[name]["workloads"] == TIP_CELLS, name
+        assert by_name[name]["workloads"][:3] == TIP_CELLS, name
     for name in NEW_SETUP:
         assert "workloads" not in by_name[name] and by_name[name]["moves"] == "setup_s"
+
+
+def test_every_listed_metric_has_a_reader():
+    bench = _benchmark()
     for cell in [w["name"] for w in bench["workloads"]]:
-        listed = {entry["name"] for entry, _read in spec.Cell(cell).per_layer()}
-        assert NEW_SETUP <= listed
-        assert ({"lane_fill"} | NEW_TIP <= listed) == (cell in TIP_CELLS)
+        loaded = spec.Cell(cell)
+        for entry, read in loaded.per_layer() + loaded.end_to_end():
+            assert callable(read), (cell, entry["name"])
+        assert NEW_SETUP <= {e["name"] for e, _read in loaded.per_layer()}
+
+
+CATCHUP = [m["name"] for m in PINNED["per_layer"]
+           if m["moves"] == "catchup_blocks_per_s"]
+
+
+@pytest.mark.parametrize("twin", ["catchup_blocks_per_s"] + CATCHUP)
+def test_a_catchup_twin_is_the_reader_of_the_metric_it_is_named_after(twin):
+    """hub-150.fastsync reports fast sync's quantities under names of its
+    own, because a name carries one bound and one ``moves``: each twin is the
+    original's reader, and the original no longer lists the cell."""
+    import importlib
+
+    fast = {m["name"]: m for m in PINNED["per_layer"] + PINNED["end_to_end"]
+            if "fastsync-1k-mixed.replay" in m.get("workloads", [])}
+    base = twin[len("catchup_"):]
+    orig = next(n for n in (base, "sync_" + base, "decisions_per_s")
+                if n in fast and (n != "decisions_per_s" or base == "blocks_per_s"))
+    group = "end_to_end" if base == "blocks_per_s" else "layer_metrics"
+    assert (importlib.import_module(f"benchmark.{group}.{twin}").read
+            is importlib.import_module(f"benchmark.{group}.{orig}").read)
+    assert "hub-150.fastsync" not in fast[orig]["workloads"]
+    assert len(CATCHUP) == 12
+
+
+@pytest.mark.parametrize("cell", OLD_CELLS)
+def test_a_cell_lists_lane_fill_and_the_tip_seven_iff_it_is_a_tip_cell(cell):
+    listed = {entry["name"] for entry, _read in spec.Cell(cell).per_layer()}
+    assert ({"lane_fill"} | NEW_TIP <= listed) == (cell in TIP_CELLS)
+
+
+def test_keyset_miss_share_reads_the_programs_own_hit_tag(monkeypatch):
+    """The reader on spans the program itself writes: two signer sets never
+    seen, then the first again in its order and in another. The table build
+    is replaced (no XLA in the quick tier); the tag is build_keyset's."""
+    import numpy as np
+
+    from benchmark.layer_metrics import keyset_miss_share, sync_keyset_miss_share
+    from tendermint_tpu.ops import ed25519_batch as edb
+
+    class Tables(np.ndarray):
+        def block_until_ready(self):
+            return self
+
+    monkeypatch.setattr(edb, "_build_comb_tables_tiled",
+                        lambda a_neg: np.zeros((256, 16, 4, 20), np.int32).view(Tables))
+    monkeypatch.setattr(edb, "_KS_CACHE", type(edb._KS_CACHE)())
+    monkeypatch.setattr(edb, "_KS_UNIQ_CACHE", type(edb._KS_UNIQ_CACHE)())
+    pubs = [bytes([i]) * 32 for i in range(1, 13)]
+    rec = trace.Tracer("t", cap=64, enabled=True)
+    try:
+        with rec.activate():
+            for batch in (pubs[:10], pubs[1:11], pubs[:10], pubs[9::-1]):
+                edb.build_keyset(batch, edb._KS_CACHE, edb._KS_LOCK,
+                                 lambda p: None, uniq_cache=edb._KS_UNIQ_CACHE)
+    finally:
+        rec.disable()
+    run = _synthetic_run([s.as_dict() for s in rec.dump()])
+    assert [s["tags"]["hit"] for s in run.spans] == ["miss", "miss",
+                                                    "sequence", "set"]
+    assert keyset_miss_share.read(run) == pytest.approx(50.0)
+    assert sync_keyset_miss_share.read(run) == pytest.approx(50.0)
+    # no lookup in the window (the host answered): nothing, not zero
+    assert keyset_miss_share.read(_synthetic_run([])) is None
+
+
+def test_a_rebuild_inside_the_window_is_read_from_the_ring(monkeypatch):
+    from benchmark.layer_metrics import rebuild_decode_ms, rebuild_tables_ms
+
+    ring = trace.Tracer("ring", cap=64, cold=True)
+    monkeypatch.setattr(trace, "STARTUP", ring)
+    run = _synthetic_run([])                       # window 10.0 .. 12.0
+    assert rebuild_tables_ms.read(run) == 0.0      # a ring and no miss: zero
+    ring.record("startup.table_build", 0.5, start=3.0, keys=9)    # set-up's
+    ring.record("startup.key_decode", 0.020, start=10.1, keys=9)
+    ring.record("startup.table_build", 0.300, start=10.2, keys=9)
+    ring.record("startup.table_build", 0.500, start=11.2, keys=9)
+    ring.record("startup.table_build", 0.7, start=12.5, keys=9)   # the check's
+    assert rebuild_decode_ms.read(run) == pytest.approx(10.0)
+    assert rebuild_tables_ms.read(run) == pytest.approx(400.0)
+    monkeypatch.delattr(trace, "STARTUP")          # a program without a ring
+    assert rebuild_tables_ms.read(run) is None
+
+
+def test_dispatches_per_decision_counts_spans_over_decisions():
+    from benchmark.layer_metrics import dispatches_per_decision
+
+    run = _synthetic_run([_span("fastsync.dispatch", 10.0 + 0.1 * k, 0.002)
+                          for k in range(5)], decisions=4)
+    assert dispatches_per_decision.read(run) == pytest.approx(1.25)
 
 
 @pytest.mark.parametrize("workload, new", [
