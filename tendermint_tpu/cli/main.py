@@ -272,7 +272,10 @@ def cmd_light(args) -> int:
         opts = TrustOptions(period_s=args.trust_period, height=lb.height,
                             hash=lb.hash())
         print(f"Trusting height {lb.height} hash {lb.hash().hex().upper()} (TOFU)")
+    from tendermint_tpu.light import SEQUENTIAL, SKIPPING
+
     client = Client(chain_id, opts, primary, witnesses, store,
+                    verification_mode=SEQUENTIAL if args.sequential else SKIPPING,
                     max_clock_drift_s=120.0)
     print(f"Light client running against {args.primary} "
           f"(latest trusted: {client.latest_trusted.height})")
@@ -535,6 +538,10 @@ def main(argv=None) -> int:
                     default=168 * 3600.0)
     sp.add_argument("--interval", type=float, default=1.0)
     sp.add_argument("--once", action="store_true", help="single update then exit")
+    sp.add_argument("--sequential", action="store_true",
+                    help="sequential verification: every header between the "
+                         "trusted one and the target, in batched windows "
+                         "(default: skipping, i.e. bisection)")
     sp.add_argument("--laddr", default="",
                     help="serve a verifying RPC proxy on this address")
     sp.set_defaults(fn=cmd_light)

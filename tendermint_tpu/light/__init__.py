@@ -6,8 +6,9 @@
  - detector: divergence detection + LightClientAttackEvidence construction
  - provider: Mock / local-node / JSON-RPC light-block providers
  - store: DB-backed trusted store
- - range_verify: whole-chain sequential verification in ONE BatchVerifier
-   flush (BASELINE config 3: 10k headers -> one TPU kernel launch)
+ - range_verify: the sequential client's windows: the light prefixes of a
+   bounded run of headers in chunk-sized launches, the per-header loop's
+   verdict and side effects (BASELINE config 3)
  - gateway: LightGateway serving many concurrent clients (verified-answer
    cache, provider failover/hedging/scoreboard, typed degradation)
 """

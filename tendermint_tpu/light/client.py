@@ -7,14 +7,17 @@ verified blocks in a trusted store.
 
 TPU angle: every commit check inside verify funnels through the batched
 BatchVerifier (types/validator_set.py), so one bisection step costs at most
-two kernel flushes; verify_header_range (range_verify.py) does whole-chain
-sequential verification in a single flush.
+two kernel flushes. Sequential mode walks its range in bounded windows: each
+is fetched, verified by range_verify.verify_window (the signatures of the
+whole window in a few chunk-sized launches, the per-header loop's verdict
+and side effects) and saved before the next is fetched.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
 
+from tendermint_tpu.light import range_verify
 from tendermint_tpu.light import verifier as lv
 from tendermint_tpu.light.detector import (
     compare_first_header_with_witnesses,
@@ -65,6 +68,15 @@ class TrustOptions:
 from tendermint_tpu.light.detector import ErrNoWitnesses  # noqa: E402  (re-export)
 
 
+def _count(metric: str, n: int = 1, **labels) -> None:
+    """Add to a NodeMetrics counter where a node exposes metrics."""
+    from tendermint_tpu.utils import metrics as tmmetrics
+
+    m = tmmetrics.GLOBAL_NODE_METRICS
+    if m is not None:
+        getattr(m, metric).add(n, **labels)
+
+
 class Client:
     """reference: light/client.go:174 (Client struct), :225 NewClient."""
 
@@ -108,6 +120,9 @@ class Client:
         self.divergences: list = []
         # dedup keys for Divergence records: (witness identity, header hash)
         self._divergence_keys: set = set()
+        # windows of a sequential sync that had to be re-run header by
+        # header for another reason than a refused header (0 when healthy)
+        self.range_fallbacks = 0
         self.latest_trusted: LightBlock | None = trusted_store.latest_light_block()
         if self.latest_trusted is None:
             self._initialize(trust_options)
@@ -191,8 +206,15 @@ class Client:
             lb = self.trusted_store.light_block(height)
             if lb is not None:
                 return lb
-            lb = self._light_block_from_primary(height)
-            self.verify_light_block(lb, now)
+            latest = self.latest_trusted
+            tr = range_verify.tracer()
+            with range_verify.span(
+                    tr, "light.sync", mode=self.verification_mode, to=height,
+                    **{"from": latest.height if latest is not None else 0}):
+                with range_verify.span(tr, "light.fetch", headers=1,
+                                       **{"from": height}):
+                    lb = self._light_block_from_primary(height)
+                self.verify_light_block(lb, now)
             return lb
 
     def verify_light_block(self, new_lb: LightBlock, now: Time) -> None:
@@ -242,7 +264,86 @@ class Client:
 
     def _verify_sequential(self, trusted: LightBlock, new_lb: LightBlock, now: Time) -> None:
         """Verify every header in (trusted, new] (reference:
-        light/client.go:613 verifySequential)."""
+        light/client.go:613 verifySequential), a window at a time: fetch a
+        window from the primary, verify it through the range path
+        (range_verify.verify_window), save it, then fetch the next. What
+        the caller sees and what the trusted store holds afterwards are
+        `_verify_sequential_per_header`'s."""
+        tr = range_verify.tracer()
+        store = self.trusted_store
+
+        def save(lb: LightBlock) -> None:
+            if lb is not new_lb:
+                store.save_light_block(lb)
+
+        verified = trusted
+        while verified.height < new_lb.height:
+            with range_verify.span(tr, "light.fetch", **{"from": verified.height + 1}):
+                window, fetch_error, promotions = self._fetch_window(
+                    verified.height + 1, new_lb)
+                if tr is not None:
+                    tr.annotate(headers=len(window))
+            try:
+                n, refusal = range_verify.verify_window(
+                    verified, window, self.trusting_period_s, now,
+                    self.max_clock_drift_s, save, tr)
+            except Exception:  # noqa: BLE001 - the machinery, not a verdict
+                self._undo_promotions(promotions, verified.height)
+                self.range_fallbacks += 1
+                _count("light_range_fallbacks")
+                with range_verify.span(tr, "light.range", fallback=1,
+                                       headers=new_lb.height - verified.height):
+                    return self._verify_sequential_per_header(verified, new_lb, now)
+            _count("light_headers_verified", n, mode=SEQUENTIAL)
+            if refusal is not None:
+                self._undo_promotions(promotions, window[n].height)
+                raise refusal
+            if fetch_error is not None:
+                raise fetch_error
+            verified = window[-1]
+
+    def _fetch_window(self, first: int, new_lb: LightBlock):
+        """The light blocks of heights first.. up to range_verify's window
+        size or new_lb -> (blocks, error, promotions). A fetch that fails
+        ends the window: ``error`` is raised by the caller once the blocks
+        below it verified, as the per-header loop would have reached it.
+        ``promotions``: (height, primary, witnesses) as they were before each
+        fetch that replaced the primary, for `_undo_promotions`."""
+        blocks: list[LightBlock] = []
+        promotions = []
+        room = range_verify.window_slots()
+        for height in range(first, new_lb.height + 1):
+            if height == new_lb.height:
+                lb = new_lb
+            else:
+                before = (height, self.primary, self.witnesses)
+                try:
+                    lb = self._light_block_from_primary(height)
+                except Exception as e:  # noqa: BLE001 - re-raised in its turn
+                    return blocks, e, promotions
+                if self.primary is not before[1]:
+                    promotions.append(before)
+            blocks.append(lb)
+            room -= max(1, len(lb.signed_header.commit.signatures))
+            if room <= 0:
+                break
+        return blocks, None, promotions
+
+    def _undo_promotions(self, promotions, height: int) -> None:
+        """A window is fetched before it is verified, so a primary that
+        failed above a refused header was replaced by a witness the
+        per-header loop never asked: put back who served at `height`."""
+        for at, primary, witnesses in promotions:
+            if at > height:
+                self.primary, self.witnesses = primary, witnesses
+                return
+
+    def _verify_sequential_per_header(self, trusted: LightBlock,
+                                      new_lb: LightBlock, now: Time) -> None:
+        """The reference's loop as it is written (light/client.go:613): one
+        verify_adjacent, and so one verify_commit_light, per header. The
+        rule `_verify_sequential` is held to by the differential tests, and
+        what re-runs a window whose machinery failed."""
         verified = trusted
         for height in range(trusted.height + 1, new_lb.height + 1):
             inter = new_lb if height == new_lb.height else self._light_block_from_primary(height)
@@ -312,6 +413,7 @@ class Client:
                 depth += 1
                 continue
             # Verified one step.
+            _count("light_headers_verified", mode=SKIPPING)
             if candidate.height == new_lb.height:
                 return verified_blocks
             verified = candidate

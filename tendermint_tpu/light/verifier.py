@@ -9,8 +9,11 @@ Core semantics preserved exactly:
 
 TPU angle: both commit checks funnel into the batched BatchVerifier used by
 ValidatorSet.verify_commit_light / verify_commit_light_trusting, so one
-header verification is at most two kernel flushes, and verify_header_range
-(range_verify.py) folds a whole header chain into one flush.
+header verification is at most two kernel flushes. A sequential client does
+not call verify_adjacent header by header: light.range_verify runs
+check_adjacent (everything below but the signatures) per header and verifies
+the signatures of a whole window of headers in a few wide launches, with
+this file's verdict. verify_adjacent stays as the rule that path is held to.
 """
 
 from __future__ import annotations
@@ -97,12 +100,14 @@ def _verify_new_header_and_vals(untrusted_header: SignedHeader,
         )
 
 
-def verify_adjacent(trusted_header: SignedHeader,
-                    untrusted_header: SignedHeader,
-                    untrusted_vals: ValidatorSet,
-                    trusting_period_s: float, now: Time,
-                    max_clock_drift_s: float) -> None:
-    """reference: light/verifier.go:93-135 VerifyAdjacent."""
+def check_adjacent(trusted_header: SignedHeader,
+                   untrusted_header: SignedHeader,
+                   untrusted_vals: ValidatorSet,
+                   trusting_period_s: float, now: Time,
+                   max_clock_drift_s: float) -> None:
+    """Everything VerifyAdjacent checks before it looks at a signature
+    (reference: light/verifier.go:93-126), in its order. Shared by
+    verify_adjacent and light.range_verify, so the two cannot drift."""
     if untrusted_header.height != trusted_header.height + 1:
         raise LightClientError("headers must be adjacent in height")
     if header_expired(trusted_header, trusting_period_s, now):
@@ -117,6 +122,16 @@ def verify_adjacent(trusted_header: SignedHeader,
             f"({trusted_header.header.next_validators_hash.hex()}) to match those "
             f"from new header ({untrusted_header.header.validators_hash.hex()})"
         )
+
+
+def verify_adjacent(trusted_header: SignedHeader,
+                    untrusted_header: SignedHeader,
+                    untrusted_vals: ValidatorSet,
+                    trusting_period_s: float, now: Time,
+                    max_clock_drift_s: float) -> None:
+    """reference: light/verifier.go:93-135 VerifyAdjacent."""
+    check_adjacent(trusted_header, untrusted_header, untrusted_vals,
+                   trusting_period_s, now, max_clock_drift_s)
     try:
         untrusted_vals.verify_commit_light(
             trusted_header.header.chain_id, untrusted_header.commit.block_id,

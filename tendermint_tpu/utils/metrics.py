@@ -224,6 +224,16 @@ class NodeMetrics:
         self.sigcache_misses = r.counter(
             "crypto", "sigcache_misses_total",
             "Vote-drain signature cache misses (verification paid).")
+        # light client (light/client.py, docs/LIGHT.md)
+        self.light_headers_verified = r.counter(
+            "light", "headers_verified_total",
+            "Headers a light client verified, by verification mode "
+            "(sequential: through range_verify's windows).", labels=("mode",))
+        self.light_range_fallbacks = r.counter(
+            "light", "range_fallbacks_total",
+            "Windows of a sequential sync re-run header by header because "
+            "the range path itself failed (not because a header was "
+            "refused).")
         # state
         self.block_processing_time = r.histogram(
             "state", "block_processing_time",
@@ -350,6 +360,9 @@ class NodeMetrics:
         for ch in ("vote", "proposal", "block_part", "rpc_tx"):
             self.shed.add(0.0, channel=ch)
         self.rate_limited.add(0.0, peer="", channel="")
+        for mode in ("sequential", "skipping"):
+            self.light_headers_verified.add(0.0, mode=mode)
+        self.light_range_fallbacks.add(0.0)
         # ingest front door: the result label universe is closed by
         # construction (docs/INGEST.md), seed it fully; the batch-size
         # histogram scrapes explicit zeros like the phase histogram

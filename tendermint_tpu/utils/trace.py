@@ -132,6 +132,26 @@ CANONICAL_SPANS = {
     "store.scrub": "one integrity-scrub pass over a node's stores (span)",
     "store.repair": "peer re-fetch + batch-verified rewrite of one damaged "
                     "height (span; height= tag)",
+    # light client (light/client.py, light/range_verify.py, docs/LIGHT.md)
+    "light.sync": "one verify_light_block_at_height, fetch of the target to "
+                  "the trusted-store update (span; mode=, from=, to= tags)",
+    "light.fetch": "light blocks from the primary, validate_basic included: "
+                   "one window of a sequential sync, or the target (span; "
+                   "from=, headers= tags)",
+    "light.range": "one window of a sequential sync through "
+                   "range_verify.verify_window, the decision root of its "
+                   "launches (span; headers=, sigs=, chunks=, verified= "
+                   "tags; fallback=1 on a per-header re-run)",
+    "light.assemble": "a window's light prefixes, sign bytes, add per "
+                      "signature, one dispatch per kernel chunk (span)",
+    "light.structure": "a window's linkage walk, verifier.check_adjacent "
+                       "per header (span)",
+    "light.wait": "blocked on the bitmap of one of a window's dispatches, "
+                  "taken in height order (span)",
+    "light.replay": "the serial tally of each header of one dispatch over "
+                    "its slice of the bitmap (span)",
+    "light.store": "trusted-store writes of one dispatch's verified "
+                   "headers (span)",
     # light-client serving gateway (light/gateway.py, docs/LIGHT.md)
     "light.gateway.serve": "one client query through the gateway: cache "
                            "lookup, coalesced verification, answer or "

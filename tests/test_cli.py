@@ -81,3 +81,29 @@ def test_cli_lifecycle(tmp_path, capsys):
     assert cli(["--home", home, "rollback"]) == 0
     out = capsys.readouterr().out
     assert "Rolled back state to height" in out
+
+
+def test_light_sequential_flag_reaches_the_client(tmp_path, monkeypatch):
+    """`light --sequential` (reference cmd/tendermint/commands/light.go)
+    builds the client in sequential mode; without it, skipping."""
+    import pytest
+
+    from tendermint_tpu import light
+
+    built = []
+
+    class Stop(Exception):
+        pass
+
+    def fake_client(*_a, **kw):
+        built.append(kw["verification_mode"])
+        raise Stop
+
+    monkeypatch.setattr(light, "Client", fake_client)
+    base = ["--home", str(tmp_path / "home"), "light", "some-chain",
+            "-p", "http://127.0.0.1:1", "--trusted-height", "1",
+            "--trusted-hash", "ab" * 32, "--once"]
+    for extra in (["--sequential"], []):
+        with pytest.raises(Stop):
+            cli(base + extra)
+    assert built == [light.SEQUENTIAL, light.SKIPPING]

@@ -13,7 +13,7 @@ from tendermint_tpu.light.provider import (
     ErrLightBlockNotFound,
     MockProvider,
 )
-from tendermint_tpu.light.range_verify import RangeVerifyError, verify_header_range
+from tendermint_tpu.light.range_verify import verify_header_range
 from tendermint_tpu.light.store import DBStore
 from tendermint_tpu.store.db import MemDB
 from tendermint_tpu.types.block import Commit, CommitSig, Header
@@ -293,15 +293,23 @@ def test_range_verify_matches_sequential_failure(keys):
     # Corrupt one signature inside the serial 2/3 prefix at height 9.
     bad_header = c[8].signed_header.header
     c[8].signed_header.commit = _sign_commit(bad_header, vs, privs, bad_sig=(0,))
-    with pytest.raises(RangeVerifyError) as ei:
-        verify_header_range(c[0], c[1:], TRUST_PERIOD, t(900), DRIFT)
-    assert ei.value.height == 9
+    store = DBStore(MemDB())
+    with pytest.raises(lv.ErrInvalidHeader) as ei:
+        verify_header_range(c[0], c[1:], TRUST_PERIOD, t(900), DRIFT, store=store)
+    assert ei.value.reason.index == 0
+    # what the per-header loop raises for height 9 after height 8
+    with pytest.raises(lv.ErrInvalidHeader) as want:
+        lv.verify_adjacent(c[7].signed_header, c[8].signed_header,
+                           c[8].validator_set, TRUST_PERIOD, t(900), DRIFT)
+    assert str(ei.value) == str(want.value)
+    # the headers below the refused one are saved, nothing at or above it
+    assert store.latest_light_block().height == 8 and store.size() == 7
 
 
 def test_range_verify_broken_linkage(keys):
     privs, vs = keys
     c = gen_chain(5, privs, vs)
-    with pytest.raises(RangeVerifyError):
+    with pytest.raises(lv.LightClientError, match="adjacent in height"):
         verify_header_range(c[0], [c[1], c[3]], TRUST_PERIOD, t(900), DRIFT)
 
 
