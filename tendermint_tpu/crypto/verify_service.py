@@ -31,10 +31,10 @@ the verify plane:
    unchanged, so bitmaps are byte-identical and a device failure
    mid-coalesce degrades to the host fallback with every waiter resolved
    exactly once;
- * hot validator KeySets stay device-resident across heights and across
-   interleavings via the unique-key-set LRU in ops/ed25519_batch
-   (build_keyset level 2): a coalesced launch's novel pubkey interleaving
-   reuses the cached comb tables, paying only the O(n) index mapping;
+ * validator keys' comb tables stay device-resident across heights and
+   across interleavings in the per-key table of ops/ed25519_batch
+   (KeyTable, a row a key): a coalesced launch's novel pubkey interleaving
+   pays only the O(n) key -> row mapping, and builds nothing;
  * the single blocking readback point is :func:`_readback` (audited by the
    tmlint ``device-sync-choke-point`` rule, and routed through
    crypto/batch._device_get so the perf-gate fetch spy still counts it);

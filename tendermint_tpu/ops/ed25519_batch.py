@@ -15,10 +15,12 @@ Comb method (t=4 teeth, d=64 columns): scalar bits split into 4 blocks of 64;
 T[w] = sum_j w_j * [2^(64j)] P for w in 0..15; evaluation
 acc <- 2*acc + T_A[wh_i] + T_B[ws_i] for i = 63..0. This quarters the
 doubling count vs per-signature Straus (256 -> 64), the dominant cost. The
-per-key tables T_A depend only on the pubkey, so they are built once per
-validator set ON DEVICE and cached in HBM across heights (steady-state
-consensus re-verifies the same keys every height); per call only the per-sig
-scalars/windows move host->device.
+per-key tables T_A depend only on the pubkey, so they are built ON DEVICE
+once per KEY and kept in HBM as rows of one table per key type (KeyTable:
+pubkey -> row). A batch is a list of row numbers: the validators of a live
+chain sign in another subset at every height, and that costs a mapping, not
+a build; per call only the per-sig scalars/windows and row numbers move
+host->device.
 
 Accept/reject is byte-identical with the scalar path (crypto/ed25519.py):
  - s >= L rejected (host);
@@ -35,6 +37,7 @@ libs/bits.BitArray vote bitmap).
 
 from __future__ import annotations
 
+import functools
 import os
 import threading
 import time as _time
@@ -238,7 +241,7 @@ def _to_niels(tab_ext):
 
 
 # ---------------------------------------------------------------------------
-# Key sets: per-validator-set comb tables, device-resident across heights
+# Key tables: one device-resident comb table per key type, a row per key
 # ---------------------------------------------------------------------------
 
 _decomp_cache: dict[bytes, np.ndarray | None] = {}
@@ -260,71 +263,187 @@ def _decompress_neg(pub: bytes) -> np.ndarray | None:
     return out
 
 
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_rows(tab, block, row0):
+    """tab with block's rows written at row0, in place (tab is donated).
+    The caller keeps row0 + len(block) <= len(tab): XLA clamps a start that
+    would overrun, which would shift the block onto other keys' rows."""
+    return jax.lax.dynamic_update_slice_in_dim(tab, block, row0, axis=0)
+
+
+@jax.jit
+def _read_tile(tab, row0):
+    return jax.lax.dynamic_slice_in_dim(tab, row0, KEY_TILE, axis=0)
+
+
+def _grown(tab, rows: int, row_shape: tuple):
+    """A zeroed (rows, *row_shape) table holding tab's rows (tab may be None)."""
+    new = jnp.zeros((rows,) + row_shape, jnp.int32)
+    return new if tab is None else _write_rows(new, tab, 0)
+
+
 class KeySet:
-    """Comb tables for an ordered multiset of pubkeys, cached on device.
+    """One generation of a key type's device-resident comb table: row r holds
+    the tables of the r-th key this generation met, and a signer set is a
+    list of row numbers (the key_idx that build_keyset returns). Rows are
+    written once and never move, so an index array stays right for as long
+    as its KeySet is alive; a KeyTable that overflows or is cleared starts a
+    new KeySet and leaves this one to the dispatches that still hold it.
 
-    `tab_ext` is (Kb, 16, 4, 20) on device (Kb = K padded to a bucket);
-    `tab_lane` is the same data in the Pallas lane-major layout (1280, Kb),
-    built lazily. `key_idx` maps item slot -> table row for the pubkey
-    sequence this KeySet was BUILT from; callers must use the per-sequence
-    key_idx returned by build_keyset/get_keyset (the unique-key-set cache
-    reuses one KeySet across many sequences)."""
+    The device arrays are (capacity, 16, 4, 20) extended points and, once the
+    Pallas route has asked for them, (capacity, 960) niels rows; capacity is
+    KEY_TILE times a power of two, so the gathers below compile for few
+    shapes. A built tile is written in place (the array is donated), which
+    deletes the array object that was current before: every read of the
+    arrays is therefore enqueued under `_lock`, the lock the writes take."""
 
-    __slots__ = ("n_keys", "valid", "tab_ext", "key_idx", "_gathered",
-                 "_niels", "replicated")
+    __slots__ = ("n_rows", "valid", "_lock", "_tab_ext", "_niels",
+                 "_replicated")
 
-    def __init__(self, n_keys, valid, tab_ext, key_idx):
-        self.n_keys = n_keys
-        self.valid = valid
-        self.tab_ext = tab_ext
-        self.key_idx = key_idx
-        self._gathered: OrderedDict = OrderedDict()
+    def __init__(self):
+        self.n_rows = 0
+        # per row: the key decoded to a curve point (a row that did not can
+        # never verify: its table is the identity's, its lanes are masked)
+        self.valid = np.zeros((0,), dtype=bool)
+        self._lock = threading.Lock()
+        self._tab_ext = None
         self._niels = None
-        # (mesh-devices key, mesh-replicated tab_ext) set by parallel/
-        # batch_shard.replicated_tables on the multi-device path.
-        self.replicated = None
+        self._replicated = None
 
-    def niels_rows(self):
-        """(Kb, 960) niels-form comb tables, built on device once per set."""
-        if self._niels is None:
-            self._niels = _to_niels(self.tab_ext)
-        return self._niels
+    def append(self, a_neg: np.ndarray, valid: np.ndarray) -> None:
+        """Build the tables of K new keys, a KEY_TILE at a time through the
+        one tile-shaped executable, into rows n_rows.. (the KeyTable's lock
+        is held: one appender at a time). Waits for the last tile: a build
+        is timed to its result, and the first kernel over new keys waits
+        for it anyway."""
+        k = a_neg.shape[0]
+        self._reserve(self.n_rows + _round_up(k, KEY_TILE))
+        for o in range(0, k, KEY_TILE):
+            tile = _build_comb_tables_tiled(a_neg[o : o + KEY_TILE])
+            kt = min(KEY_TILE, k - o)
+            self.valid[self.n_rows : self.n_rows + kt] = valid[o : o + kt]
+            with self._lock:
+                self._tab_ext = _write_rows(self._tab_ext, tile, self.n_rows)
+                if self._niels is not None:
+                    self._niels = _write_rows(self._niels, _to_niels(tile),
+                                              self.n_rows)
+                self.n_rows += kt
+        self._tab_ext.block_until_ready()
+
+    def _reserve(self, rows: int) -> None:
+        cap = self.valid.shape[0]
+        if rows <= cap:
+            return
+        new = max(cap, KEY_TILE)
+        while new < rows:
+            new *= 2
+        valid = np.zeros((new,), dtype=bool)
+        valid[:cap] = self.valid
+        with self._lock:
+            self.valid = valid
+            self._tab_ext = _grown(self._tab_ext, new, (16, 4, 20))
+            if self._niels is not None:
+                self._niels = _grown(self._niels, new, (960,))
+
+    def take(self, idx: np.ndarray):
+        """(nb,) row numbers -> (nb, 16, 4, 20) per-item extended tables."""
+        with self._lock:
+            return jnp.take(self._tab_ext, jnp.asarray(idx), axis=0)
 
     def gathered_lane(self, idx: np.ndarray):
-        """(960, nb) lane-major niels comb tables for a padded index pattern,
-        cached per pattern. Steady-state commit verification reuses the same
-        (validator-order) pattern every height, so the device-side gather +
-        transpose runs once per validator set, not once per call."""
-        key = idx.tobytes()
-        hit = self._gathered.get(key)
-        if hit is not None:
-            self._gathered.move_to_end(key)
-            return hit
-        tab = _gather_transpose(self.niels_rows(), jnp.asarray(idx))
-        self._gathered[key] = tab
-        # Large batches dispatch in fixed CHUNK slices (ed25519_pallas), so a
-        # steady-state 20k-sig commit needs ~5-8 resident chunk patterns.
-        while len(self._gathered) > 16:
-            self._gathered.popitem(last=False)
-        return tab
+        """(nb,) row numbers -> (960, nb) lane-major niels tables for the
+        Pallas kernel. The first call converts the rows built so far, tile
+        by tile; from then on append converts each tile it builds."""
+        with self._lock:
+            if self._niels is None:
+                niels = jnp.zeros((self.valid.shape[0], 960), jnp.int32)
+                for o in range(0, self.n_rows, KEY_TILE):
+                    niels = _write_rows(
+                        niels, _to_niels(_read_tile(self._tab_ext, o)), o)
+                self._niels = niels
+            return _gather_transpose(self._niels, jnp.asarray(idx))
+
+    def replicated(self, mesh_key: tuple, sharding):
+        """The extended table on every device of a mesh, copied once per
+        mesh and per append (parallel/batch_shard.replicated_tables)."""
+        with self._lock:
+            key = (mesh_key, self.n_rows)
+            if self._replicated is None or self._replicated[0] != key:
+                self._replicated = (
+                    key, jax.device_put(self._tab_ext, sharding))
+            return self._replicated[1]
+
+
+class KeyTable(dict):
+    """pubkey bytes -> row number of the current KeySet: what a key type's
+    device tables are keyed on. Every access happens under the lock that
+    build_keyset is given. `clear()` forgets every row (the next lookup
+    builds its keys again), as does a lookup that would pass MAX_ROWS: that
+    bounds HBM (8,960 bytes a row) against a peer that feeds a light client
+    key sets without end."""
+
+    MAX_ROWS = 1 << 16
+
+    def __init__(self):
+        super().__init__()
+        self.keyset = KeySet()
+        self.generation = 0
+
+    def clear(self) -> None:
+        super().clear()
+        self.keyset = KeySet()
+        self.generation += 1
+
+    def rows_of(self, keys: list[bytes]) -> np.ndarray | None:
+        """(len(keys),) int32 row numbers, or None if a key is not resident."""
+        try:
+            return np.fromiter(map(self.__getitem__, keys), dtype=np.int32,
+                               count=len(keys))
+        except (KeyError, TypeError):  # TypeError: a bytes-like, not bytes
+            return None
+
+    def admit(self, keys: list[bytes], decode_neg, kind: str) -> int:
+        """Give every key of `keys` a row, building tables only for those
+        that have none. -> the number of keys built. Both halves of a build
+        go to the start-up ring, tracing on or off."""
+        new = [p for p in dict.fromkeys(keys) if p not in self]
+        if not new:
+            return 0
+        if (self.keyset.n_rows
+                and self.keyset.n_rows + _round_up(len(new), KEY_TILE)
+                > self.MAX_ROWS):
+            self.clear()
+            new = list(dict.fromkeys(keys))
+        t0 = _time.monotonic()
+        a_neg = np.broadcast_to(ed.IDENTITY_LIMBS, (len(new), 4, 20)).copy()
+        valid = np.zeros((len(new),), dtype=bool)
+        for j, p in enumerate(new):
+            neg = decode_neg(p)
+            if neg is not None:
+                a_neg[j] = neg
+                valid[j] = True
+        t1 = _time.monotonic()
+        row0 = self.keyset.n_rows
+        self.keyset.append(a_neg, valid)
+        t2 = _time.monotonic()
+        self.update(zip(new, range(row0, row0 + len(new))))
+        _trace.STARTUP.record("startup.key_decode", t1 - t0, start=t0,
+                              keys=len(new), kind=kind)
+        _trace.STARTUP.record("startup.table_build", t2 - t1, start=t1,
+                              keys=len(new), kind=kind)
+        return len(new)
 
 
 _KS_LOCK = threading.Lock()
-# Level 1: exact pubkey SEQUENCE -> (KeySet, key_idx). Steady-state
-# consensus re-verifies the same validator order every height and hits
-# this without touching the items.
+# Exact pubkey SEQUENCE -> (KeySet, key_idx): a memo of row-number arrays.
+# Steady-state consensus verifies the same validator order every height and
+# hits this without touching the items.
 _KS_CACHE: OrderedDict[bytes, tuple[KeySet, np.ndarray]] = OrderedDict()
 _KS_MAX = 8
-# Level 2: unique-key-SET digest -> KeySet (the validator-set-content LRU
-# the continuous-batching verify service leans on). Coalesced launches
-# interleave several callers' items, so the full sequence is novel almost
-# every generation while the underlying key set is stable across heights;
-# this keeps the expensive device-resident comb tables keyed by SET
-# content, so a novel interleaving pays only the O(n) index mapping,
-# never a table rebuild. Unique keys are sorted before digesting/building
-# so the row order (and digest) is interleaving-independent.
-_KS_UNIQ_CACHE: OrderedDict[bytes, KeySet] = OrderedDict()
-_KS_UNIQ_MAX = 16
+# The per-key table. (Its name, like _KS_CACHE's, is pinned by
+# tests/benchmark/ and benchmark/drivers/lightsync.py, which empty both
+# under _KS_LOCK to start a session as a new client process would.)
+_KS_UNIQ_CACHE = KeyTable()
 
 
 def next_bucket(n: int) -> int:
@@ -345,107 +464,57 @@ def _normalize_pubs(pubs: list[bytes]) -> tuple[bytes, np.ndarray]:
 
 
 def build_keyset(pubs: list[bytes], cache: OrderedDict, lock: threading.Lock,
-                 decode_neg, uniq_cache: OrderedDict | None = None,
+                 decode_neg, uniq_cache: KeyTable | None = None,
                  kind: str = "ed25519",
                  ) -> tuple[KeySet, np.ndarray, np.ndarray]:
-    """Shared key-set machinery for any Edwards-comb key type.
+    """Shared key-table lookup for any Edwards-comb key type.
 
-    -> (KeySet, key_idx (N,) int32, pub_ok (N,) bool). Two cache levels:
-    the exact pubkey SEQUENCE (steady-state consensus hits this every
-    height), then the sorted unique-key SET digest (`uniq_cache`) so a
-    novel interleaving over known keys — the normal shape of a coalesced
-    verify-service launch — reuses the device-resident comb tables and
-    only recomputes the item->row mapping. decode_neg: pubkey bytes ->
-    extended limbs of -A or None (ed25519 uses RFC 8032 decompression,
-    sr25519 ristretto255 decode). `kind` only names the key type on the
-    flight-recorder spans (prep.keyset; on a miss the start-up ring's
-    startup.key_decode and startup.table_build)."""
+    -> (KeySet, key_idx (N,) int32 row numbers, pub_ok (N,) bool).
+    `uniq_cache` is the key type's KeyTable (None: a table of this call's
+    own), `cache` a memo from the exact pubkey SEQUENCE to its row numbers:
+    steady-state consensus hits the memo every height; a signer set never
+    seen before over resident keys -- a live chain's commit, a coalesced
+    verify-service launch -- pays one pass that maps keys to rows; only a
+    key the table does not hold is decoded (decode_neg: pubkey bytes ->
+    extended limbs of -A or None; RFC 8032 decompression for ed25519,
+    ristretto255 decode for sr25519) and has its tables built, for itself
+    alone. All state lives in `cache` and `uniq_cache`. `kind` only names
+    the key type on the flight-recorder spans (prep.keyset; on a build the
+    start-up ring's startup.key_decode and startup.table_build)."""
+    table = KeyTable() if uniq_cache is None else uniq_cache
     if _trace.ENABLED:
         tr = _trace.current()
         with tr.span("prep.keyset", keys=len(pubs), kind=kind):
-            ks, key_idx, pub_ok, hit = _build_keyset(
-                pubs, cache, lock, decode_neg, uniq_cache, kind)
-            tr.annotate(hit=hit)
+            ks, key_idx, pub_ok, hit, built = _build_keyset(
+                pubs, cache, lock, decode_neg, table, kind)
+            tr.annotate(hit=hit, built=built, resident=ks.n_rows)
         return ks, key_idx, pub_ok
-    return _build_keyset(pubs, cache, lock, decode_neg, uniq_cache, kind)[:3]
+    return _build_keyset(pubs, cache, lock, decode_neg, table, kind)[:3]
 
 
-def _build_keyset(pubs, cache, lock, decode_neg, uniq_cache, kind):
-    """-> (KeySet, key_idx, pub_ok, hit): hit is "sequence" (level 1),
-    "set" (level 2: tables reused, the item->row mapping recomputed) or
-    "miss" (keys decompressed, tables built on the device)."""
+def _build_keyset(pubs, cache, lock, decode_neg, table, kind):
+    """-> (KeySet, key_idx, pub_ok, hit, built): hit is "sequence" (the memo
+    answered), "set" (every key was resident; rows mapped anew) or "miss"
+    (`built` keys, at least one, were decoded and had their tables built)."""
     joined, pub_ok = _normalize_pubs(pubs)
     with lock:
         hit = cache.get(joined)
-        if hit is not None:
+        # a memo entry of a KeySet the table has left behind is stale
+        if hit is not None and hit[0] is table.keyset:
             cache.move_to_end(joined)
-            ks, key_idx = hit
-            return ks, key_idx, pub_ok, "sequence"
-
-    # dedupe in first-occurrence order, then canonicalize row order by
-    # sorting the unique keys: the set digest (and the table row layout)
-    # must not depend on how callers' items happened to interleave
-    n = len(pubs)
-    seen: dict[bytes, int] = {}
-    uniq: list[bytes] = []
-    key_slot = np.empty(n, dtype=np.int32)
-    for i in range(n):
-        p = joined[32 * i : 32 * i + 32]
-        j = seen.get(p)
-        if j is None:
-            j = seen[p] = len(uniq)
-            uniq.append(p)
-        key_slot[i] = j
-    order = sorted(range(len(uniq)), key=uniq.__getitem__)
-    rank = np.empty(len(uniq), dtype=np.int32)
-    for r, j in enumerate(order):
-        rank[j] = r
-    uniq = [uniq[j] for j in order]
-    key_idx = rank[key_slot] if n else key_slot
-
-    ks = None
-    set_key = None
-    if uniq_cache is not None:
-        import hashlib
-
-        set_key = hashlib.sha256(b"".join(uniq)).digest()
-        with lock:
-            ks = uniq_cache.get(set_key)
-            if ks is not None:
-                uniq_cache.move_to_end(set_key)
-    how = "set"
-    if ks is None:
-        # decompress unique keys, build comb tables on device. A cold path:
-        # both halves go to the start-up ring, tracing on or off.
-        how = "miss"
-        t0 = _time.monotonic()
-        a_neg = np.broadcast_to(ed.IDENTITY_LIMBS, (len(uniq), 4, 20)).copy()
-        valid = np.zeros((max(_round_up(len(uniq), KEY_TILE), KEY_TILE),),
-                         dtype=bool)
-        for j, p in enumerate(uniq):
-            neg = decode_neg(p)
-            if neg is not None:
-                a_neg[j] = neg
-                valid[j] = True
-        t1 = _time.monotonic()
-        # waits for the tables: the build is timed to its result, and the
-        # first kernel over a new key set waits for them anyway
-        tab_ext = _build_comb_tables_tiled(a_neg).block_until_ready()
-        t2 = _time.monotonic()
-        _trace.STARTUP.record("startup.key_decode", t1 - t0, start=t0,
-                              keys=len(uniq), kind=kind)
-        _trace.STARTUP.record("startup.table_build", t2 - t1, start=t1,
-                              keys=len(uniq), kind=kind)
-        ks = KeySet(len(uniq), valid, tab_ext, key_idx)
-    with lock:
+            return hit[0], hit[1], pub_ok, "sequence", 0
+        built = 0
+        key_idx = table.rows_of(pubs) if pub_ok.all() else None
+        if key_idx is None:
+            keys = [joined[i : i + 32] for i in range(0, len(joined), 32)]
+            built = table.admit(keys, decode_neg, kind)
+            key_idx = table.rows_of(keys)
+        ks = table.keyset
         cache[joined] = (ks, key_idx)
+        cache.move_to_end(joined)
         while len(cache) > _KS_MAX:
             cache.popitem(last=False)
-        if uniq_cache is not None:
-            uniq_cache[set_key] = ks
-            while len(uniq_cache) > _KS_UNIQ_MAX:
-                uniq_cache.popitem(last=False)
-    return ks, key_idx, pub_ok, how
+    return ks, key_idx, pub_ok, "miss" if built else "set", built
 
 
 def get_keyset(pubs: list[bytes]) -> tuple[KeySet, np.ndarray, np.ndarray]:
@@ -563,7 +632,7 @@ def prepare(items):
     idx = np.zeros((nb,), dtype=np.int32)
     idx[:n] = key_idx
     out = _jnp_args(s, n, nb)
-    out["tab"] = np.asarray(jnp.take(ks.tab_ext, jnp.asarray(idx), axis=0))
+    out["tab"] = np.asarray(ks.take(idx))
     return out, n
 
 
@@ -750,8 +819,7 @@ def _dispatch_device(items, n: int, multichip: bool):
     outs = []
     for off in range(0, nb, JNP_TILE):
         with launch_span("jit__verify_kernel", "jnp", n - off, JNP_TILE):
-            tab = jnp.take(ks.tab_ext, jnp.asarray(idx[off : off + JNP_TILE]),
-                           axis=0)
+            tab = ks.take(idx[off : off + JNP_TILE])
             outs.append(_jnp_kernel(tab, **{
                 k: jnp.asarray(v[off : off + JNP_TILE])
                 for k, v in padded.items()

@@ -13,8 +13,8 @@ the point state never leaves VMEM. Layout choices:
    by worst-case bound analysis (see _carry_n).
  * per-key comb tables come in NIELS form (16 entries x 3 field elements
    y+x | y-x | 2dxy = 60 rows/entry, 960 rows x T lanes), gathered from the
-   device-resident KeySet cache by validator index - nothing per-key is
-   rebuilt per call, and each table addition is a 7-mul mixed add. The
+   device-resident per-key table (edb.KeySet) by row number - nothing per-key
+   is rebuilt per call, and each table addition is a 7-mul mixed add. The
    fixed-base comb table for B is baked in as niels constants the same way.
 
 Bound discipline matches ops/field25519: all stored limbs < 9500, products

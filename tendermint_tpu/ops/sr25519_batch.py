@@ -28,8 +28,10 @@ validity conditions (host-checked canonical field element + device-checked
 square/t-sign/y-zero), the same transcript bytes (differential test in
 tests/test_sr25519_batch.py).
 
-Pubkey comb tables are cached per validator-set byte sequence exactly like
-the ed25519 KeySet (device-resident across heights).
+Pubkey comb tables live in one device-resident table keyed per key, a row a
+key, exactly like ed25519's and through the same code (edb.build_keyset,
+edb.KeyTable): a batch is a list of row numbers, and only a key the table
+has not met is decoded and has its tables built.
 """
 
 from __future__ import annotations
@@ -222,16 +224,16 @@ def _decode_neg(pub: bytes) -> np.ndarray | None:
 
 
 _KS_LOCK = threading.Lock()
+# sequence -> row numbers memo, and this key type's per-key table (see
+# edb.build_keyset: all of its state lives in these two objects)
 _KS_CACHE: OrderedDict[bytes, tuple[edb.KeySet, np.ndarray]] = OrderedDict()
-# unique-key-SET level (see edb.build_keyset): coalesced verify-service
-# launches reuse device tables across novel interleavings
-_KS_UNIQ_CACHE: OrderedDict[bytes, edb.KeySet] = OrderedDict()
+_KS_UNIQ_CACHE = edb.KeyTable()
 
 
 def get_keyset(pubs: list[bytes]) -> tuple[edb.KeySet, np.ndarray, np.ndarray]:
-    """-> (KeySet, key_idx (N,) int32, pub_ok (N,) bool); comb tables of the
-    ristretto-decoded -A, device-resident, cached by pubkey byte sequence
-    (level 1) and by unique-key-set digest (level 2)."""
+    """-> (KeySet, key_idx (N,) int32 row numbers, pub_ok (N,) bool): the
+    rows of sr25519's device-resident table that hold the comb tables of
+    each item's ristretto-decoded -A; a key it does not hold is built."""
     return edb.build_keyset(pubs, _KS_CACHE, _KS_LOCK, _decode_neg,
                             uniq_cache=_KS_UNIQ_CACHE, kind="sr25519")
 
@@ -329,7 +331,7 @@ def _dispatch_device(items, n: int, multichip: bool = False):
     if multichip:
         # Multi-chip: the signature axis shards over the ("dp",) mesh, the
         # same routing the ed25519 twin takes (policy in
-        # batch_shard.should_shard; comb tables replicate once per set).
+        # batch_shard.should_shard; the key table replicates once per append).
         from tendermint_tpu.parallel import batch_shard
 
         dev = batch_shard.dispatch_sharded(
@@ -352,7 +354,7 @@ def _dispatch_device(items, n: int, multichip: bool = False):
     outs = []
     for off in range(0, nb, tile):
         with edb.launch_span("jit__sr_verify_kernel", "jnp", n - off, tile):
-            tab = jnp.take(ks.tab_ext, jnp.asarray(idx[off:off + tile]), axis=0)
+            tab = ks.take(idx[off:off + tile])
             outs.append(_kernel(
                 tab,
                 jnp.asarray(kw[off:off + tile]),

@@ -132,7 +132,7 @@ def _local_verify(tab_full, idx, h_win, s_win, r_y, r_sign, valid):
     """Per-device ed25519 body: gather this shard's comb tables from the
     replicated key-set table, then run the verify kernel. Gathering INSIDE
     shard_map keeps the per-call H2D payload to indices + scalars; the
-    (heavy, height-persistent) tables replicate once per validator set."""
+    (heavy, height-persistent) table replicates once per append to it."""
     tab = jnp.take(tab_full, idx, axis=0)
     return ed25519_batch._verify_kernel(
         tab, h_win, s_win, r_y, r_sign, valid, axis_name="dp")
@@ -172,15 +172,11 @@ def _sharded_verify_fn(mesh: Mesh, kind: str = "ed25519"):
 
 
 def replicated_tables(ks, mesh: Mesh):
-    """The key set's comb tables replicated across the mesh, cached on the
-    KeySet (validator sets persist across heights; replication is one-time)."""
-    cached = ks.replicated
-    key = tuple(id(d) for d in mesh.devices.flat)
-    if cached is not None and cached[0] == key:
-        return cached[1]
-    tab = jax.device_put(ks.tab_ext, NamedSharding(mesh, P()))
-    ks.replicated = (key, tab)
-    return tab
+    """The key type's whole per-key comb table on every device of the mesh:
+    copied once per mesh and per append to the table (KeySet.replicated),
+    not once per signer set; a set is row numbers into it."""
+    return ks.replicated(tuple(id(d) for d in mesh.devices.flat),
+                         NamedSharding(mesh, P()))
 
 
 def _count_sharded_dispatch(ndev: int) -> None:

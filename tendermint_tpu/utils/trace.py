@@ -94,14 +94,16 @@ CANONICAL_SPANS = {
     "commit.tally": "serial accept/reject replay over the bitmap",
     # below ops dispatch_batch (ops/ed25519_batch, sr25519_batch,
     # ed25519_pallas, parallel/batch_shard)
-    "prep.keyset": "pubkey join, key-set cache lookup, on a miss the build",
+    "prep.keyset": "pubkey join, keys mapped to rows of the per-key device "
+                   "table, the build of keys it does not hold",
     "prep.scalars": "per-signature hash (SHA-512 / merlin in C), mod L, windows",
     "prep.launch": "host time to enqueue one device program (route, real "
                    "signatures, launched lanes)",
     "prep.host_verify": "the C / scalar host verifier answered the batch",
     # the start-up ring (STARTUP): cold paths, recorded with tracing off too
-    "startup.key_decode": "Python decompression of a key set's unique keys",
-    "startup.table_build": "device comb-table build until its result is ready",
+    "startup.key_decode": "Python decompression of keys the table did not hold",
+    "startup.table_build": "device build of those keys' comb tables, tile by "
+                           "tile, until the last is ready",
     "startup.jit_trace": "jax traced a function and lowered it to MLIR",
     "startup.jit_compile": "backend compile, or its load from the cache",
     "startup.cache_load": "persistent compile-cache retrieval (inside "

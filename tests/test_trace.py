@@ -200,7 +200,7 @@ def test_startup_ring_is_always_on_and_never_raises_the_guard():
             .pub_key().data for i in range(3)]
     at = time.monotonic()
     ks, key_idx, pub_ok = edb.get_keyset(pubs)
-    assert pub_ok.all() and ks.n_keys == 3
+    assert pub_ok.all() and len(set(key_idx)) == 3 <= ks.n_rows
     mine = [s for s in trace.STARTUP.dump() if s.start >= at]
     by_name = {s.name: s for s in mine}
     assert {"startup.key_decode", "startup.table_build"} <= set(by_name)

@@ -103,7 +103,7 @@ def test_concurrent_overlapping_batches_bit_identical_to_serial():
     and tampered lanes land on the right caller at the right index."""
     ed_a = _ed_items(40, seed=1, tampered={5})
     # overlaps ed_a's keys: same seed, shifted tamper — exercises the
-    # unique-key-set reuse inside one coalesced generation
+    # reuse of resident key rows inside one coalesced generation
     ed_b = _ed_items(40, seed=1, tampered={17})
     sr_a = _sr_items(9, seed=2, tampered={2})
     mixed = ed_a[:6] + sr_a[:3] + ed_a[6:12]
@@ -229,9 +229,11 @@ def test_service_off_restores_direct_dispatch(monkeypatch):
 
 
 def test_keyset_unique_set_lru_survives_interleaving(monkeypatch):
-    """The device-resident comb-table LRU keyed by key-set content: a novel
-    interleaving of already-known keys (the normal shape of a coalesced
-    generation) must reuse the cached KeySet, not rebuild tables."""
+    """The device-resident comb table keyed per key: a novel interleaving of
+    already-resident keys (the normal shape of a coalesced generation), a
+    subset of them, or sixteen other signer sets in between must map to the
+    rows the keys already have, not build tables again. (The name dates
+    from the 16-entry LRU of whole key sets that this table replaced.)"""
     from tendermint_tpu.ops import ed25519_batch as edb
 
     builds = {"n": 0}
@@ -242,6 +244,8 @@ def test_keyset_unique_set_lru_survives_interleaving(monkeypatch):
         return orig(a_neg)
 
     monkeypatch.setattr(edb, "_build_comb_tables_tiled", counting)
+    monkeypatch.setattr(edb, "_KS_CACHE", type(edb._KS_CACHE)())
+    monkeypatch.setattr(edb, "_KS_UNIQ_CACHE", type(edb._KS_UNIQ_CACHE)())
     pubs = [it[0].bytes() for it in _ed_items(6, seed=41)]
     seq_a = [pubs[0], pubs[1], pubs[2], pubs[0]]
     seq_b = [pubs[2], pubs[0], pubs[1], pubs[2], pubs[1]]  # same SET, new order
@@ -254,10 +258,19 @@ def test_keyset_unique_set_lru_survives_interleaving(monkeypatch):
     row = {p: idx_a[i] for i, p in enumerate(seq_a)}
     for i, p in enumerate(seq_b):
         assert idx_b[i] == row[p], "interleaved key_idx maps to wrong row"
-    # exact-sequence (level 1) hit returns the same mapping
+    # more signer sets than any whole-set cache held (16), each a subset of
+    # the resident keys in an order of its own: none builds, none evicts
+    for k in range(20):
+        subset = [pubs[(k + j) % 3] for j in range(1 + k % 3)]
+        _ks, idx, _ok = edb.get_keyset(subset)
+        assert [row[p] for p in subset] == list(idx)
+    # the exact sequence again (its memo entry long evicted): same rows
     ks_a2, idx_a2, _ = edb.get_keyset(seq_a)
     assert ks_a2 is ks_a and (idx_a2 == idx_a).all()
     assert builds["n"] == 1
+    # a key the table has not met builds one tile, for itself alone
+    _ks, idx_c, _ = edb.get_keyset([pubs[1], pubs[3]])
+    assert builds["n"] == 2 and idx_c[0] == row[pubs[1]] and idx_c[1] == 3
 
 
 # ---------------------------------------------------------------------------
