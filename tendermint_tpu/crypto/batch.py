@@ -310,9 +310,7 @@ class _KernelBatchVerifier(BatchVerifier):
     def _module(cls, spec_attr: str) -> object:
         """Resolve + cache cls.<spec_attr> per class: the hot addVote drain
         flushes thousands of times per second, and an importlib round trip
-        (sys.modules lookup + lock) per flush is pure overhead. Cached
-        separately per module so the pure-Python scalar path never imports
-        the ops module (whose top level pulls in jax)."""
+        (sys.modules lookup + lock) per flush is pure overhead."""
         cache_attr = spec_attr + "_cache"
         mod = cls.__dict__.get(cache_attr)
         if mod is None:
@@ -330,32 +328,36 @@ class _KernelBatchVerifier(BatchVerifier):
         crossover (pipelined callers whose chunks overlap other host
         work)."""
         items, self._items = self._items, []
-        from tendermint_tpu.ops import chost
+        # one decision for both key types, kept with the ed25519 kernel
+        from tendermint_tpu.ops import ed25519_batch
 
-        if (not force_device
-                and len(items) < batch_min(self._batch_min_default)
-                and not chost.available()):
+        route = ed25519_batch.route_batch(
+            len(items), force_device, batch_min(self._batch_min_default))
+        if route == "scalar":
             # Pure-Python scalar fallback only when the C host verifier is
             # missing: with it, the ops dispatch routes ANY size to the host
             # path below the measured crossover.
             scalar = self._module("_scalar_module")
             out = [scalar.verify(p, m, s) for (p, m, s) in items]
             return PendingVerify([None], lambda _f, _r=(all(out), out): _r)
-        # DEVICE-BOUND batches route through the continuous-batching verify
-        # service (crypto/verify_service.py): ONE device-owning executor
-        # coalesces concurrent dispatches into shared kernel launches, so N
-        # simultaneous callers pay one sync floor, not N. Sub-crossover
-        # host batches (inline C verify, no floor) stay direct — a thread
-        # hop + coalescing window per tiny flush is pure loss there. The
-        # service calls the same ops dispatch_batch below (same routing,
-        # fault sites, breaker), so the bitmap is byte-identical;
-        # TMTPU_VERIFY_SERVICE=0 restores direct dispatch for everything,
-        # =1 forces everything onto the service (tests/bench).
+        # DEVICE-BOUND batches ("device", "sharded": the routes that pay the
+        # host<->device sync floor) go through the continuous-batching
+        # verify service (crypto/verify_service.py): ONE device-owning
+        # executor coalesces concurrent dispatches into shared kernel
+        # launches, so N simultaneous callers pay one sync floor, not N.
+        # Sub-crossover host batches (inline C verify, no floor) stay direct
+        # -- a thread hop + coalescing window per tiny flush is pure loss
+        # there; at 50-node-fabric scale (tiny vote drains, thousands of
+        # threads on one core) that serialization point measurably stalls
+        # consensus. The service calls the same ops dispatch_batch below
+        # (same routing on the COALESCED size, fault sites, breaker), so the
+        # bitmap is byte-identical; TMTPU_VERIFY_SERVICE=0 restores direct
+        # dispatch for everything, =1 forces everything onto the service
+        # (tests/bench).
         from tendermint_tpu.crypto import verify_service
 
         if verify_service.enabled() and (
-                verify_service.force_all()
-                or verify_service.device_bound(len(items), force_device)):
+                verify_service.force_all() or route != "host"):
             return verify_service.get().submit(self._kind, items,
                                                force_device=force_device)
         import time as _t

@@ -25,8 +25,8 @@ the verify plane:
    executor host-preps and dispatches generation k+1, and only then blocks
    on k's readback;
  * the launch goes through the SAME ``ops.*.dispatch_batch`` the callers
-   used directly — host-crossover routing, multi-device sharding
-   (parallel/batch_shard.should_shard on the COALESCED size), the
+   used directly — the route (ops/ed25519_batch.route_batch, asked again
+   on the COALESCED size: crossover, multi-device sharding), the
    ``ops.*.device`` fault sites, and the circuit breaker all apply
    unchanged, so bitmaps are byte-identical and a device failure
    mid-coalesce degrades to the host fallback with every waiter resolved
@@ -49,8 +49,7 @@ the verify plane:
 
 Knobs (docs/CONFIG.md): ``TMTPU_VERIFY_SERVICE=0`` restores direct
 per-caller dispatch; ``TMTPU_VERIFY_WINDOW_US`` sets the coalescing window
-(default 150); ``TMTPU_VERIFY_MAX_BATCH`` caps the items per shared launch
-(default 65536).
+(default 150). A shared launch holds at most ``MAX_BATCH`` items.
 """
 
 from __future__ import annotations
@@ -86,32 +85,6 @@ def force_all() -> bool:
     return os.environ.get("TMTPU_VERIFY_SERVICE") == "1"
 
 
-def device_bound(n: int, force_device: bool) -> bool:
-    """Would a direct dispatch of n items take the DEVICE route — i.e. pay
-    the host<->device sync floor the service exists to share? Sub-crossover
-    batches with the C host verifier present verify inline with NO floor;
-    routing those through the executor buys nothing and costs a thread hop
-    plus the coalescing window per flush — at 50-node-fabric scale (tiny
-    vote drains, thousands of threads on one core) that serialization
-    point measurably stalls consensus. So by default the service owns
-    exactly the floor-paying traffic."""
-    if force_device:
-        return True
-    from tendermint_tpu.ops import ed25519_batch
-
-    if n >= ed25519_batch.host_crossover():
-        return True
-    from tendermint_tpu.ops import chost
-
-    if not chost.available() and not chost.building():
-        # no C host verifier: ops routes kernel-worthy batches to the
-        # device at any size, so they pay the floor and should share it
-        return True
-    from tendermint_tpu.parallel import batch_shard
-
-    return batch_shard.should_shard(n)
-
-
 def window_us(default: int = 150) -> int:
     """Coalescing window: how long the executor waits for more dispatches
     after the first before launching. Latency cost for a lone caller; the
@@ -124,14 +97,9 @@ def window_us(default: int = 150) -> int:
         return default
 
 
-def max_batch(default: int = 65536) -> int:
-    """Item cap per shared launch (bounds worst-case host-prep latency and
-    device memory of one generation). TMTPU_VERIFY_MAX_BATCH overrides."""
-    v = os.environ.get("TMTPU_VERIFY_MAX_BATCH")
-    try:
-        return max(1, int(v)) if v else default
-    except ValueError:
-        return default
+# Item cap per shared launch: bounds the worst-case host-prep latency and
+# the device memory of one generation.
+MAX_BATCH = 65536
 
 
 def _readback(tree):
@@ -294,12 +262,11 @@ class VerifyService:
     def _collect(self, first: _Request) -> list[_Request]:
         """The continuous-batching step: drain requests arriving within the
         coalescing window (or already queued) into one generation, bounded
-        by max_batch items."""
+        by MAX_BATCH items."""
         reqs = [first]
         n = len(first.items)
-        cap = max_batch()
         deadline = _time.monotonic() + window_us() / 1e6
-        while n < cap:
+        while n < MAX_BATCH:
             remaining = deadline - _time.monotonic()
             try:
                 r = (self._q.get(timeout=remaining) if remaining > 0

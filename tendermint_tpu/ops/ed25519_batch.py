@@ -185,8 +185,8 @@ _build_comb_tables = jax.jit(_build_comb_tables_impl)
 # (chip_smoke.py prints what it measured). Chunking every batch through ONE
 # (tile-sized) executable makes compilation a one-time cost per process
 # regardless of batch size.
-KEY_TILE = int(os.environ.get("TM_TPU_KEY_TILE", "256"))
-JNP_TILE = int(os.environ.get("TM_TPU_JNP_TILE", "256"))
+KEY_TILE = 256
+JNP_TILE = 256
 
 
 def _build_comb_tables_tiled(a_neg: np.ndarray):
@@ -542,16 +542,15 @@ def _r_to_limbs(r32: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
 
 
 def prepare_scalars(items, pub_ok: np.ndarray, windows: bool = True,
-                    reduce: bool = True, host_hash: bool = True):
+                    reduce: bool = True):
     """:func:`_prepare_scalars` inside its flight-recorder span."""
     with (_trace.current().span("prep.scalars", sigs=len(items),
                                 kind="ed25519")
           if _trace.ENABLED else _trace.NULL_SPAN):
-        return _prepare_scalars(items, pub_ok, windows, reduce, host_hash)
+        return _prepare_scalars(items, pub_ok, windows, reduce)
 
 
-def _prepare_scalars(items, pub_ok: np.ndarray, windows: bool, reduce: bool,
-                     host_hash: bool):
+def _prepare_scalars(items, pub_ok: np.ndarray, windows: bool, reduce: bool):
     """Vectorized per-signature prep: scalars, R bytes, validity.
 
     items: [(pub, msg, sig)]; pub_ok from get_keyset. Returns dict of numpy
@@ -559,10 +558,7 @@ def _prepare_scalars(items, pub_ok: np.ndarray, windows: bool, reduce: bool,
     path) the comb windows are left to the device and only raw h32/s32
     scalars are produced -- 40% less H2D payload. With reduce=False the
     mod-L reduction is ALSO left to the device: the dict carries the raw
-    (N, 64) SHA-512 digests as "h64" and no "h32". With host_hash=False
-    even the SHA-512 stays off host: no "h64"; the dict carries "pubs32"
-    so the caller can pack R||A||M for the device hasher
-    (ops/sha512_jax)."""
+    (N, 64) SHA-512 digests as "h64" and no "h32"."""
     n = len(items)
     sig_ok = np.fromiter(
         (len(it[2]) == ref.SIGNATURE_SIZE for it in items), dtype=bool, count=n
@@ -585,9 +581,6 @@ def _prepare_scalars(items, pub_ok: np.ndarray, windows: bool, reduce: bool,
     s_lt = sc.lt_l(s32)
     valid = sig_ok & s_lt & pub_ok
     out = dict(s32=s32, r32=r32, valid=valid)
-    if not host_hash:
-        out["pubs32"] = np.ascontiguousarray(pubs_arr)
-        return out
     digests = chash.sha512_rab(r32, np.ascontiguousarray(pubs_arr),
                                [it[1] for it in items])
     if not reduce:
@@ -637,11 +630,6 @@ def prepare(items):
 
 
 def _use_pallas() -> bool:
-    mode = os.environ.get("TM_TPU_ED25519_KERNEL", "auto")
-    if mode == "pallas":
-        return True
-    if mode == "jnp":
-        return False
     return jax.default_backend() == "tpu"  # the Pallas kernel lowers for TPU only
 
 
@@ -668,6 +656,55 @@ def host_crossover() -> int:
         return int(v)
     c = _HOST_CAL["crossover"]
     return c if c is not None else HOST_CROSSOVER_DEFAULT
+
+
+def _batch_shard():
+    """parallel/batch_shard, imported late because it imports this module:
+    the one place where ops reaches up into parallel (the routing policy
+    and the sharded launch, for both key types)."""
+    from tendermint_tpu.parallel import batch_shard
+
+    return batch_shard
+
+
+def route_batch(n: int, force_device: bool = False, scalar_min: int = 0) -> str:
+    """THE routing decision of the verify path: which of four routes a batch
+    of n signatures (either key type) takes. Both dispatch_batch entry
+    points branch on it, the registry (crypto/batch) asks it whether the
+    verify service owns the launch, and nothing else decides
+    (docs/PARALLEL.md has the table). In order:
+
+      "scalar"   not forced, n < scalar_min, C library not loaded: the
+                 registry's pure-Python loop. A kernel launch never pays off
+                 for a handful of signatures, and on a cold process it would
+                 pay an XLA compile. scalar_min is the registry's per-kind
+                 batch_min; direct callers of dispatch_batch pass 0.
+      "sharded"  batch_shard.should_shard(n): shard_map over the local mesh.
+      "host"     not forced, n < host_crossover(), C library loaded or
+                 building: a kernel flush loses to the CPU there, the sync
+                 floor alone exceeds the C verifier's whole runtime. While
+                 the gcc build is in flight this is the scalar loop (~2
+                 ms/sig, bounded by the build window): the device route on a
+                 cold process means a fresh XLA compile, an order of
+                 magnitude worse.
+      "device"   otherwise: the one-chip kernel (Pallas on a TPU backend,
+                 jnp elsewhere). The route that pays the host<->device sync
+                 floor, with "sharded"; those two the verify service shares
+                 between callers.
+
+    host_crossover is looked up in this module's globals at call time:
+    tests replace the module attribute."""
+    from tendermint_tpu.ops import chost
+
+    loaded = chost.available()
+    if not force_device and n < scalar_min and not loaded:
+        return "scalar"
+    if _batch_shard().should_shard(n):
+        return "sharded"
+    if (not force_device and n < host_crossover()
+            and (loaded or chost.building())):
+        return "host"
+    return "device"
 
 
 def calibrate_host_crossover(device_marginal_us: float = 2.5) -> int:
@@ -789,12 +826,8 @@ def _dispatch_device(items, n: int, multichip: bool):
     if multichip:
         # Multi-chip: shard the signature axis over the device mesh
         # (BASELINE.json north_star: validator sets sharded across TPU
-        # cores, pass/fail bitmap all-reduced). Routing policy and knobs
-        # (TM_TPU_SHARD / TM_TPU_SHARD_MIN) live in batch_shard.should_shard;
-        # batches below the threshold stay on the single-device path.
-        from tendermint_tpu.parallel import batch_shard
-
-        dev = batch_shard.dispatch_batch_sharded(ks, key_idx, items, pub_ok)
+        # cores, pass/fail bitmap all-reduced).
+        dev = _batch_shard().dispatch_batch_sharded(ks, key_idx, items, pub_ok)
         _start_host_copy(dev)
         return dev, _cbreaker.routed(
             lambda v: np.asarray(v)[:n].astype(bool), "sharded")
@@ -855,10 +888,11 @@ def dispatch_batch(items: list[tuple[bytes, bytes, bytes]],
     kernels in ONE device_get: two sequential fetches cost two host<->device
     round trips, one batched fetch costs one.
 
-    Routes to the C host verifier below the measured crossover (ops/chost),
-    else the fused Pallas kernel on TPU (ops/ed25519_pallas), the shard_map
-    multi-device path when a mesh is present, or the pure-jnp CPU fallback.
-    force_device=True skips the host route (kernel warmup, kernel tests).
+    :func:`route_batch` names the route: the C host verifier below the
+    measured crossover (ops/chost), the shard_map multi-device path when a
+    mesh is present, else the fused Pallas kernel on TPU
+    (ops/ed25519_pallas) or the pure-jnp CPU fallback. force_device=True
+    skips the host route (kernel warmup, kernel tests).
 
     The device route sits behind a circuit breaker (ops/breaker): a device
     dispatch failure is re-verified on the host within the same call, the
@@ -868,28 +902,16 @@ def dispatch_batch(items: list[tuple[bytes, bytes, bytes]],
     if not items:
         return None, _cbreaker.routed(
             lambda _: np.zeros((0,), dtype=bool), "host_scalar")
-    from tendermint_tpu.parallel import batch_shard
-
     n = len(items)
-    multichip = batch_shard.should_shard(n)
-    if not multichip and not force_device and n < host_crossover():
-        # Below the measured crossover a kernel flush loses to the CPU: the
-        # sync floor alone exceeds the C verifier's whole runtime. No device
-        # tables are built on this path (host verification is self-contained).
-        from tendermint_tpu.ops import chost
+    route = route_batch(n, force_device)
+    if route == "host":
+        # No device tables are built on this path (host verification is
+        # self-contained).
+        return _host_fallback(items, n)
 
-        if chost.available():
-            return _dispatch_host(items, n)
-        if chost.building():
-            # The gcc build is in flight: serial Python (~2 ms/sig, bounded
-            # by the build window) beats the alternative -- on a cold
-            # process the device route here means a fresh XLA compile, an
-            # order of magnitude worse than scalar-verifying these batches.
-            # (_host_fallback resolves to the scalar loop while building.)
-            return _host_fallback(items, n)
     def _device():
         faults.fire("ops.ed25519.device")
-        return _dispatch_device(items, n, multichip)
+        return _dispatch_device(items, n, route == "sharded")
 
     return _cbreaker.guarded_dispatch(
         BREAKER, _device,

@@ -37,17 +37,13 @@ from tendermint_tpu.ops import edwards25519 as ed
 from tendermint_tpu.ops import field25519 as fe
 from tendermint_tpu.ops import scalar25519 as sc_mod
 
-import os
-
 MASK = fe.MASK
 FOLD = fe.FOLD
 NLIMB = fe.NLIMB
 P = fe.P
 # Lanes per grid step (multiple of 128). 256 measured best on v5e; larger
 # tiles spill VMEM (TILE=512 benched 2.6x slower end to end).
-TILE = int(os.environ.get("TM_TPU_PALLAS_TILE", "256"))
-if TILE % 128 != 0 or TILE <= 0:
-    raise ValueError(f"TM_TPU_PALLAS_TILE must be a positive multiple of 128, got {TILE}")
+TILE = 256
 
 _PSUB = np.asarray(fe.PSUB_LIMBS, dtype=np.int32).reshape(NLIMB, 1)
 _P_CANON = np.asarray(fe.P_LIMBS, dtype=np.int32).reshape(NLIMB, 1)
@@ -491,12 +487,9 @@ def _verify_chunk(tab, h64, s32, r32, valid):
 # pallas call always runs at a multiple of CHUNK lanes (small batches pad to
 # one CHUNK; large ones loop). A fresh batch size must never trigger a cold
 # compile inside the consensus loop.
-CHUNK = int(os.environ.get("TM_TPU_PALLAS_CHUNK", str(16 * TILE)))  # 4096
-if CHUNK % TILE != 0 or CHUNK <= 0:
-    # A non-multiple silently truncates the pallas grid and leaves trailing
-    # output lanes unwritten -- wrong verify results, not an error.
-    raise ValueError(
-        f"TM_TPU_PALLAS_CHUNK must be a positive multiple of TILE={TILE}, got {CHUNK}")
+# CHUNK is a multiple of TILE: a non-multiple would silently truncate the
+# pallas grid and leave trailing output lanes unwritten.
+CHUNK = 16 * TILE  # 4096
 
 
 @jax.jit
@@ -521,54 +514,12 @@ def dispatch_items_pipelined(ks, key_idx: np.ndarray, items, pub_ok):
     device array WITHOUT fetching -- callers batch the readback. On the
     1-core host this hides min(prep, device) per chunk versus the
     prep-everything-then-dispatch path."""
-    from tendermint_tpu.ops import ed25519_batch as edb
-
-    from tendermint_tpu.ops import sha512_jax
-
     n = len(items)
-    use_dev_sha = sha512_jax.enabled()
-    if use_dev_sha and any(
-            sha512_jax.n_blocks(len(it[1])) > sha512_jax.MAX_DEVICE_BLOCKS
-            for it in items):
-        # One over-long message would force a C fallback AFTER the eager
-        # prep phase — the worst of both paths. Decide up front and keep
-        # the interleaved default pipeline instead.
-        import warnings
-
-        warnings.warn(
-            "TM_TPU_DEVICE_SHA=1 but a message exceeds the device hash's "
-            f"{sha512_jax.MAX_DEVICE_BLOCKS * 128}-byte limit; using the "
-            "C host hash for this batch", stacklevel=2)
-        use_dev_sha = False
-
-    h64_full = None
-    preps = None
-    if use_dev_sha:
-        # Opt-in (TM_TPU_DEVICE_SHA=1): hash the WHOLE batch in one device
-        # call and slice digest columns per chunk. Measured slower than the
-        # C host hash on the bench host (see ops/sha512_jax docstring) —
-        # kept for hosts whose CPU, not the device link, is the bottleneck.
-        # This path preps every chunk up front (no prep/compute overlap);
-        # the default path below keeps the interleaved pipeline.
-        preps = []
-        for off in range(0, n, CHUNK):
-            sl = slice(off, min(off + CHUNK, n))
-            preps.append((sl, edb.prepare_scalars(
-                items[sl], pub_ok[sl], windows=False, reduce=False,
-                host_hash=False)))
-        lanes = max(((n + CHUNK - 1) // CHUNK) * CHUNK, CHUNK)
-        r32 = np.concatenate([p["r32"] for _, p in preps])
-        pubs = np.concatenate([p["pubs32"] for _, p in preps])
-        h64_full = sha512_jax.sha512_rab_device(
-            r32, pubs, [it[1] for it in items], lanes)
-        assert h64_full is not None  # lengths prechecked above
-
     outs = []
-    for ci, off in enumerate(range(0, n, CHUNK)):
+    for off in range(0, n, CHUNK):
         sl = slice(off, min(off + CHUNK, n))
-        s = (preps[ci][1] if preps is not None
-             else edb.prepare_scalars(items[sl], pub_ok[sl], windows=False,
-                                      reduce=False))
+        s = edb.prepare_scalars(items[sl], pub_ok[sl], windows=False,
+                                reduce=False)
         cn = sl.stop - sl.start
         idx = np.zeros((CHUNK,), dtype=np.int32)
         idx[:cn] = key_idx[sl]
@@ -579,11 +530,7 @@ def dispatch_items_pipelined(ks, key_idx: np.ndarray, items, pub_ok):
             return out
 
         with edb.launch_span("jit__verify_chunk", "pallas", cn, CHUNK):
-            if h64_full is not None:
-                h64 = jax.lax.dynamic_slice_in_dim(h64_full, sl.start, CHUNK, 1)
-            else:
-                h64 = jnp.asarray(pad_cols(s["h64"], 64))
-
+            h64 = jnp.asarray(pad_cols(s["h64"], 64))
             tab = ks.gathered_lane(idx)
             outs.append(_verify_chunk(
                 tab,

@@ -329,12 +329,9 @@ def _dispatch_device(items, n: int, multichip: bool = False):
     r_limbs = _bytes_to_limbs(r32)
 
     if multichip:
-        # Multi-chip: the signature axis shards over the ("dp",) mesh, the
-        # same routing the ed25519 twin takes (policy in
-        # batch_shard.should_shard; the key table replicates once per append).
-        from tendermint_tpu.parallel import batch_shard
-
-        dev = batch_shard.dispatch_sharded(
+        # Multi-chip: the signature axis shards over the ("dp",) mesh, as
+        # the ed25519 twin's does (the key table replicates once per append).
+        dev = edb._batch_shard().dispatch_sharded(
             "sr25519", ks, key_idx, [k_win, s_win, r_limbs, valid], n)
         edb._start_host_copy(dev)
         return dev, _cbreaker.routed(lambda v: np.asarray(v)[:n], "sharded")
@@ -386,31 +383,21 @@ def dispatch_batch(items: list[tuple[bytes, bytes, bytes]],
                    force_device: bool = False):
     """Async batched verify (same contract as ed25519_batch.dispatch_batch):
     returns (device_out, finish) with nothing fetched, so mixed-key commits
-    overlap the ed25519 and sr25519 readbacks in one device_get.
-    force_device=True skips the host route (callers that pipeline
-    sub-crossover chunks against device flights). The device route sits
-    behind the same circuit-breaker degradation as the ed25519 twin."""
+    overlap the ed25519 and sr25519 readbacks in one device_get. The route
+    is edb.route_batch's, one decision for both key types; the device route
+    sits behind the same circuit-breaker degradation as the ed25519 twin."""
     if not items:
         return None, _cbreaker.routed(
             lambda _: np.zeros((0,), dtype=bool), "host_scalar")
-    from tendermint_tpu.parallel import batch_shard
-
     n = len(items)
-    multichip = batch_shard.should_shard(n)
+    route = edb.route_batch(n, force_device)
+    if route == "host":
+        # ops/chost does its own ristretto decodes + s<L
+        return _host_fallback(items, n)
 
-    if not multichip and not force_device and n < edb.host_crossover():
-        # Same crossover as ed25519: a kernel flush below it loses to the C
-        # host verifier (ops/chost does its own ristretto decodes + s<L).
-        from tendermint_tpu.ops import chost
-
-        if chost.available() or chost.building():
-            # While the C build is in flight this degrades to the pure-
-            # Python loop: bounded by the build window, and still cheaper
-            # than a cold-process XLA compile of the kernel.
-            return _host_fallback(items, n)
     def _device():
         faults.fire("ops.sr25519.device")
-        return _dispatch_device(items, n, multichip)
+        return _dispatch_device(items, n, route == "sharded")
 
     return _cbreaker.guarded_dispatch(
         BREAKER, _device,

@@ -8,9 +8,9 @@ voting-power tally is all-reduced over ICI with psum - the on-device analogue
 of the reference's libs/bits.BitArray + talliedVotingPower loop
 (types/validator_set.go:685-714).
 
-Production routing (docs/PARALLEL.md): both kernel ops modules
-(ops/ed25519_batch, ops/sr25519_batch) ask :func:`should_shard` at dispatch
-time, so every caller of the BatchVerifier registry -- verify_commit_async,
+Production routing (docs/PARALLEL.md): ops/ed25519_batch.route_batch, the one
+routing decision of the verify path, asks :func:`should_shard` for both key
+types, so every caller of the BatchVerifier registry -- verify_commit_async,
 the fast-sync verify-ahead pipeline, the consensus vote drain, light
 range_verify -- gets multi-device sharding transparently through the deferred
 dispatch()/PendingVerify contract. With the continuous-batching verify
@@ -23,7 +23,6 @@ crosses the sharding threshold sooner than any single caller would. Knobs:
   TM_TPU_SHARD_MIN=N   batch-size floor for the sharded route (default
                        n_devices * MIN_BUCKET: below one kernel bucket per
                        device the fan-out cannot pay for itself)
-  TM_TPU_DISABLE_SHARD=1  legacy alias for TM_TPU_SHARD=0
 """
 
 from __future__ import annotations
@@ -38,16 +37,13 @@ from tendermint_tpu.ops import ed25519_batch
 
 
 # ---------------------------------------------------------------------------
-# Shard-routing policy (shared by every kernel ops module)
+# Shard-routing policy (asked by ed25519_batch.route_batch)
 # ---------------------------------------------------------------------------
 
 
 def shard_enabled() -> bool:
-    """False when the operator opted out (TM_TPU_SHARD=0, or the legacy
-    TM_TPU_DISABLE_SHARD=1 the dryrun harness has always used)."""
-    if os.environ.get("TM_TPU_SHARD") == "0":
-        return False
-    return os.environ.get("TM_TPU_DISABLE_SHARD") != "1"
+    """False when the operator opted out (TM_TPU_SHARD=0)."""
+    return os.environ.get("TM_TPU_SHARD") != "0"
 
 
 def shard_threshold(ndev: int) -> int:
@@ -61,7 +57,7 @@ def shard_threshold(ndev: int) -> int:
 
 
 def should_shard(n: int) -> bool:
-    """THE routing decision both kernel dispatch_batch entry points consult:
+    """The mesh's half of the routing decision (ed25519_batch.route_batch):
     >1 local device, sharding not opted out, and the batch at or above the
     threshold. On 1 device this is always False, so every path behaves
     exactly as the single-device build."""

@@ -83,7 +83,7 @@ def pytest_sessionfinish(session, exitstatus):
 # production adaptive routing.
 _KERNEL_PATH_MODULES = {
     "test_ed25519_batch", "test_sr25519_batch", "test_multichip",
-    "test_pallas_tpu", "test_sha512_device", "test_perf_gate",
+    "test_pallas_tpu", "test_perf_gate",
 }
 
 

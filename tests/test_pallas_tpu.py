@@ -42,42 +42,3 @@ def test_pallas_differential_vs_scalar():
     sample = list(range(0, len(items), 131))
     scal = np.array([ref.verify(*items[i]) for i in sample])
     assert (out[sample] == scal).all()
-
-
-def test_pipelined_device_sha_matches_default(monkeypatch):
-    """TM_TPU_DEVICE_SHA=1 routes digests through ops/sha512_jax and the
-    on-device column slicing; verdicts must equal the default C-hash path
-    bit for bit, including corruptions and mixed message lengths."""
-    from tendermint_tpu.crypto import ed25519 as ref
-    from tendermint_tpu.ops import ed25519_batch as edb
-
-    rng = np.random.default_rng(9)
-    privs = [ref.gen_priv_key(bytes([i % 250 + 1]) * 32) for i in range(64)]
-    items = []
-    for i in range(4200):  # > CHUNK so the slicing spans two chunks
-        p = privs[i % 64]
-        msg = b"ds%d" % i + rng.bytes(i % 200)  # mixed lengths, 1-2 blocks
-        sig = ref.sign(p.data, msg)
-        if i % 13 == 0:
-            sig = sig[:-1] + bytes([sig[-1] ^ 1])
-        items.append((p.pub_key().data, msg, sig))
-
-    monkeypatch.setenv("TM_TPU_DEVICE_SHA", "1")
-    dev = edb.verify_batch(items)
-    monkeypatch.setenv("TM_TPU_DEVICE_SHA", "0")
-    host = edb.verify_batch(items)
-    assert (dev == host).all()
-    assert not dev[0] and dev.sum() == sum(1 for i in range(4200) if i % 13)
-
-    # an over-long message must fall back to the C path with a warning,
-    # not degrade silently
-    import warnings
-
-    items.append((privs[0].pub_key().data, b"L" * 2000,
-                  ref.sign(privs[0].data, b"L" * 2000)))
-    monkeypatch.setenv("TM_TPU_DEVICE_SHA", "1")
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        out = edb.verify_batch(items)
-    assert out[-1] and (out[:-1] == dev).all()
-    assert any("C host hash" in str(x.message) for x in w)

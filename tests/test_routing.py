@@ -1,0 +1,237 @@
+"""The one routing decision of the verify path (ops/ed25519_batch.route_batch).
+
+Which route a batch of n signatures takes -- the registry's pure-Python
+loop, the C host verifier, the one-chip kernel, shard_map over the mesh --
+and whether the verify service owns the launch, all come from this one
+function. The table below is docs/PARALLEL.md's, case by case; the tests
+after it hold both dispatch_batch entry points, the registry and the
+service to the answer.
+
+Kernels are stood in for by their `valid` argument (the slow tier's
+test_ed25519_batch / test_sr25519_batch / test_multichip run the real
+ones): routing, the service's choice and `finish.route` are host work."""
+
+import jax
+import pytest
+
+from tendermint_tpu.crypto import batch as cbatch
+from tendermint_tpu.crypto import ed25519, sr25519, verify_service
+from tendermint_tpu.ops import chost
+from tendermint_tpu.ops import ed25519_batch as edb
+from tendermint_tpu.ops import sr25519_batch as srb
+from tendermint_tpu.parallel import batch_shard
+from tendermint_tpu.utils import faults
+
+SCALAR_MIN, CROSSOVER, SHARD_MIN = 32, 512, 64
+# one size on each side of every threshold: below scalar_min, between it
+# and the crossover (and above the shard floor), above the crossover
+SIZES = (8, 100, 2000)
+
+# (C library, force_device, mesh) -> the routes of SIZES, in order
+TABLE = {
+    ("loaded", False, "1dev"): ("host", "host", "device"),
+    ("loaded", False, "8dev"): ("host", "sharded", "sharded"),
+    ("loaded", False, "8dev_shard_off"): ("host", "host", "device"),
+    ("loaded", True, "1dev"): ("device", "device", "device"),
+    ("loaded", True, "8dev"): ("device", "sharded", "sharded"),
+    ("loaded", True, "8dev_shard_off"): ("device", "device", "device"),
+    ("building", False, "1dev"): ("scalar", "host", "device"),
+    ("building", False, "8dev"): ("scalar", "sharded", "sharded"),
+    ("building", False, "8dev_shard_off"): ("scalar", "host", "device"),
+    ("building", True, "1dev"): ("device", "device", "device"),
+    ("building", True, "8dev"): ("device", "sharded", "sharded"),
+    ("building", True, "8dev_shard_off"): ("device", "device", "device"),
+    ("absent", False, "1dev"): ("scalar", "device", "device"),
+    ("absent", False, "8dev"): ("scalar", "sharded", "sharded"),
+    ("absent", False, "8dev_shard_off"): ("scalar", "device", "device"),
+    ("absent", True, "1dev"): ("device", "device", "device"),
+    ("absent", True, "8dev"): ("device", "sharded", "sharded"),
+    ("absent", True, "8dev_shard_off"): ("device", "device", "device"),
+}
+
+
+def _set_chost(monkeypatch, state: str) -> None:
+    monkeypatch.setattr(chost, "available", lambda: state == "loaded")
+    monkeypatch.setattr(chost, "building", lambda: state == "building")
+
+
+def _set_mesh(monkeypatch, mesh: str) -> None:
+    monkeypatch.setattr(jax, "local_device_count",
+                        lambda: 1 if mesh == "1dev" else 8)
+    monkeypatch.setenv("TM_TPU_SHARD_MIN", str(SHARD_MIN))
+    if mesh == "8dev_shard_off":
+        monkeypatch.setenv("TM_TPU_SHARD", "0")
+    else:
+        monkeypatch.delenv("TM_TPU_SHARD", raising=False)
+
+
+@pytest.mark.parametrize(
+    "lib, force, mesh, n, want",
+    [(lib, force, mesh, n, want)
+     for (lib, force, mesh), wants in TABLE.items()
+     for n, want in zip(SIZES, wants)],
+    ids=str)
+def test_route_table(lib, force, mesh, n, want, monkeypatch):
+    _set_chost(monkeypatch, lib)
+    _set_mesh(monkeypatch, mesh)
+    # as tests/benchmark does: the module ATTRIBUTE, not the environment
+    monkeypatch.setattr(edb, "host_crossover", lambda: CROSSOVER)
+    assert edb.route_batch(n, force, SCALAR_MIN) == want
+
+
+@pytest.mark.parametrize("lib, want", [
+    ("loaded", "host"), ("building", "host"), ("absent", "device")])
+def test_no_scalar_route_for_direct_callers(lib, want, monkeypatch):
+    """scalar_min defaults to 0: the service and other direct callers of
+    dispatch_batch never get "scalar", whatever the size."""
+    _set_chost(monkeypatch, lib)
+    _set_mesh(monkeypatch, "1dev")
+    monkeypatch.setattr(edb, "host_crossover", lambda: CROSSOVER)
+    assert edb.route_batch(1) == want
+
+
+# --- the entry points take the route the function names ---------------------
+
+_KINDS = {"ed25519": (ed25519, edb), "sr25519": (sr25519, srb)}
+_FINISH_ROUTE = {"host": "host_c", "device": "jnp", "sharded": "sharded"}
+
+
+def _items(kind: str, n: int):
+    """n valid (PubKey, msg, sig): eight signed, tiled (sr25519 signs in
+    pure Python)."""
+    mod = _KINDS[kind][0]
+    base = []
+    for i in range(8):
+        priv = mod.gen_priv_key((b"route-%s-%d" % (kind.encode(), i))
+                                .ljust(32, b"\x07"))
+        msg = b"route-%d" % i
+        sig = (ed25519.sign(priv.data, msg) if kind == "ed25519"
+               else priv.sign(msg))
+        base.append((priv.pub_key(), msg, sig))
+    return (base * -(-n // 8))[:n]
+
+
+def _raw(items):
+    return [(pk.bytes(), m, s) for pk, m, s in items]
+
+
+@pytest.fixture
+def stand_ins(monkeypatch):
+    """Crossover 32, shard floor 64 on the 8 virtual devices, kernels
+    answering `valid`; -> the (n, finish) of every ops dispatch_batch."""
+    # build inline: the non-blocking available() answers False until a
+    # background build lands, and the host route here is the C verifier's
+    if not chost.ensure_available():
+        pytest.skip("C host verifier unavailable (no gcc?)")
+    monkeypatch.setattr(edb, "host_crossover", lambda: 32)
+    monkeypatch.setenv("TM_TPU_SHARD_MIN", "64")
+    monkeypatch.delenv("TM_TPU_SHARD", raising=False)
+    monkeypatch.delenv("TMTPU_VERIFY_SERVICE", raising=False)
+    monkeypatch.setattr(edb, "_jnp_kernel", lambda tab, **kw: kw["valid"])
+    monkeypatch.setattr(srb, "_kernel", lambda tab, *arrays: arrays[-1])
+    monkeypatch.setattr(batch_shard, "_sharded_verify_fn",
+                        lambda mesh, kind: lambda tab, idx, *arrays: arrays[-1])
+    seen = []
+    for mod in (edb, srb):
+        def spy(items, force_device=False, _real=mod.dispatch_batch):
+            dev, finish = _real(items, force_device=force_device)
+            seen.append((len(items), finish))
+            return dev, finish
+        monkeypatch.setattr(mod, "dispatch_batch", spy)
+    verify_service.reset()
+    yield seen
+    verify_service.reset()
+
+
+@pytest.mark.parametrize("kind", ["ed25519", "sr25519"])
+@pytest.mark.parametrize("n, want", [(16, "host"), (40, "device"),
+                                     (72, "sharded")])
+def test_entry_points_and_registry_take_the_named_route(kind, n, want,
+                                                        stand_ins):
+    """One batch on each side of the crossover and of the shard floor,
+    through ops.dispatch_batch and through the registry: the route on the
+    finish is the one route_batch names, and the service owns the launch
+    exactly when that route pays the sync floor."""
+    items = _items(kind, n)
+    assert edb.route_batch(n) == want
+    assert edb.route_batch(n, False, SCALAR_MIN) == want  # C library loaded
+
+    dev, finish = _KINDS[kind][1].dispatch_batch(_raw(items))
+    assert (dev is None) == (want == "host")
+    assert finish(cbatch._device_get(dev) if dev is not None else None).all()
+    assert finish.route == _FINISH_ROUTE[want]
+
+    del stand_ins[:]
+    v = cbatch.create_batch_verifier(kind)
+    for pk, m, s in items:
+        v.add(pk, m, s)
+    p = v.dispatch()
+    assert isinstance(p, cbatch.ServicePending) == (want != "host")
+    assert p.resolve() == (True, [True] * n)
+    assert [(got, f.route) for got, f in stand_ins] == [
+        (n, _FINISH_ROUTE[want])]
+
+
+@pytest.mark.parametrize("kind", ["ed25519", "sr25519"])
+def test_the_host_route_is_the_scalar_loop_while_the_library_builds(
+        kind, stand_ins, monkeypatch):
+    _set_chost(monkeypatch, "building")
+    items = _items(kind, 4)
+    assert edb.route_batch(4) == "host"
+    dev, finish = _KINDS[kind][1].dispatch_batch(_raw(items))
+    assert dev is None and finish(None).all()
+    assert finish.route == "host_scalar"
+    # the registry's own rung comes first: below batch_min, no library
+    del stand_ins[:]
+    v = cbatch.create_batch_verifier(kind)
+    for pk, m, s in items:
+        v.add(pk, m, s)
+    assert edb.route_batch(4, False, v._batch_min_default) == "scalar"
+    assert v.dispatch().resolve() == (True, [True] * 4) and not stand_ins
+
+
+# --- the service's guess and the dispatch agree -----------------------------
+
+
+@pytest.mark.parametrize("lib, n, force", [
+    ("loaded", 40, False),    # at or above the crossover
+    ("loaded", 72, False),    # above the shard floor
+    ("loaded", 9, True),      # below the crossover, pinned to the device
+    ("absent", 33, False),    # no C verifier: the device at any size
+], ids=["above_crossover", "above_shard_floor", "forced", "no_c_library"])
+def test_a_service_owned_request_is_not_answered_by_the_host(
+        lib, n, force, stand_ins, monkeypatch):
+    """What the registry hands to the service, the service's own dispatch
+    routes to the device too: nobody pays the thread hop and the window to
+    reach the C verifier."""
+    _set_chost(monkeypatch, lib)
+    v = cbatch.create_batch_verifier("ed25519")
+    for pk, m, s in _items("ed25519", n):
+        v.add(pk, m, s)
+    p = v.dispatch(force_device=force)
+    assert isinstance(p, cbatch.ServicePending)
+    assert p.resolve() == (True, [True] * n)
+    assert [f.route for _n, f in stand_ins] == [
+        "sharded" if n >= 64 else "jnp"]
+
+
+def test_only_an_open_breaker_sends_a_service_owned_request_to_the_host(
+        stand_ins, monkeypatch):
+    for field in ("failures", "trips", "last_error"):  # put back afterwards
+        monkeypatch.setattr(edb.BREAKER, field, getattr(edb.BREAKER, field))
+    monkeypatch.setattr(edb.BREAKER, "probe", None)
+    edb.BREAKER.reset()
+    failures = edb.BREAKER.failures
+    faults.configure(["ops.ed25519.device:raise@1"], seed=3)
+    try:
+        v = cbatch.create_batch_verifier("ed25519")
+        for pk, m, s in _items("ed25519", 40):
+            v.add(pk, m, s)
+        p = v.dispatch()
+        assert isinstance(p, cbatch.ServicePending)
+        assert p.resolve() == (True, [True] * 40)
+        assert edb.BREAKER.failures == failures + 1 and edb.BREAKER.is_open
+        assert [f.route for _n, f in stand_ins] == ["breaker_fallback"]
+    finally:
+        faults.configure([], seed=0)
+        edb.BREAKER.reset()
