@@ -24,30 +24,45 @@ WIRE_BYTES = 2
 WIRE_FIXED32 = 5
 
 
-_UV1 = tuple(bytes((i,)) for i in range(0x80))
+# Varint pieces, indexed by value: the last byte of a varint (no continuation
+# bit), one continuation byte of a 7-bit group, the two of a 14-bit group,
+# and the last two bytes of a negative int64 (bits 56..62, then bit 63).
+_UV1 = tuple(bytes((k,)) for k in range(1 << 7))
+UV7C = tuple(bytes((k | 0x80,)) for k in range(1 << 7))
+UV14C = tuple(bytes((k & 0x7F | 0x80, k >> 7 | 0x80)) for k in range(1 << 14))
+_NEG_TAIL = tuple(bytes((k | 0x80, 1)) for k in range(1 << 7))
 
 
 def encode_uvarint(n: int) -> bytes:
-    if 0 <= n < 0x80:  # single-byte fast path (tags, lengths, small ints)
+    if n < 0x80:  # tags, lengths, small ints
+        if n < 0:
+            raise ValueError("uvarint cannot be negative")
         return _UV1[n]
-    if n < 0:
-        raise ValueError("uvarint cannot be negative")
-    out = bytearray()
-    while True:
-        b = n & 0x7F
-        n >>= 7
-        if n:
-            out.append(b | 0x80)
-        else:
-            out.append(b)
-            return bytes(out)
+    if n < 0x200000:
+        if n < 0x4000:
+            return UV7C[n & 0x7F] + _UV1[n >> 7]
+        return UV14C[n & 0x3FFF] + _UV1[n >> 14]
+    if n < 0x800000000:  # a Unix time in seconds, most nanos
+        if n < 0x10000000:
+            return UV14C[n & 0x3FFF] + UV7C[n >> 14 & 0x7F] + _UV1[n >> 21]
+        return UV14C[n & 0x3FFF] + UV14C[n >> 14 & 0x3FFF] + _UV1[n >> 28]
+    pairs = []
+    while n >= 0x4000:
+        pairs.append(UV14C[n & 0x3FFF])
+        n >>= 14
+    return b"".join(pairs) + encode_uvarint(n)
 
 
 def encode_varint(n: int) -> bytes:
     """int64 varint: negatives encode as 10-byte two's complement."""
-    if n < 0:
-        n += 1 << 64
-    return encode_uvarint(n)
+    if n >= 0:
+        return encode_uvarint(n)
+    if n < -(1 << 63):  # no int64: what the sum makes of it, or the error
+        return encode_uvarint(n + (1 << 64))
+    # & on a negative int reads its two's complement, so no sum is needed
+    return b"".join((UV14C[n & 0x3FFF], UV14C[n >> 14 & 0x3FFF],
+                     UV14C[n >> 28 & 0x3FFF], UV14C[n >> 42 & 0x3FFF],
+                     _NEG_TAIL[n >> 56 & 0x7F]))
 
 
 def decode_uvarint(buf: bytes, pos: int = 0) -> tuple[int, int]:
@@ -163,6 +178,14 @@ class Writer:
 
     def out(self) -> bytes:
         return bytes(self.buf)
+
+
+def repeated_messages(tag_byte: bytes, bodies) -> bytes:
+    """A repeated embedded-message field in one pass: each body behind the
+    field's tag and its length, emitted also when empty (nullable=false).
+    For the containers that hold hundreds to thousands of elements, where a
+    Writer call per element is most of the encoding's cost."""
+    return b"".join([tag_byte + encode_uvarint(len(b)) + b for b in bodies])
 
 
 def delimited(msg: bytes) -> bytes:

@@ -272,9 +272,8 @@ class Client:
         tr = range_verify.tracer()
         store = self.trusted_store
 
-        def save(lb: LightBlock) -> None:
-            if lb is not new_lb:
-                store.save_light_block(lb)
+        def save(lb: LightBlock) -> int | None:
+            return None if lb is new_lb else store.save_light_block(lb)
 
         verified = trusted
         while verified.height < new_lb.height:

@@ -182,9 +182,11 @@ def verify_window(trusted: LightBlock, chain: list[LightBlock],
     """Verify `chain` (ascending, adjacent heights) against `trusted`.
 
     -> (n, refusal): chain[:n] verified and each went to ``save`` in height
-    order; ``refusal`` is None when n == len(chain), else the exception
-    `verify_adjacent` raises for chain[n] after chain[n - 1]. Anything this
-    function *raises* is a failure of the machinery, not a verdict."""
+    order (it returns the bytes it wrote, or None for a header it left out:
+    the ``light.store`` span's ``bytes`` and ``blocks``); ``refusal`` is
+    None when n == len(chain), else the exception `verify_adjacent` raises
+    for chain[n] after chain[n - 1]. Anything this function *raises* is a
+    failure of the machinery, not a verdict."""
     if not chain:
         return 0, None
     # Hash every header in the window as one batched merkle forest before
@@ -222,8 +224,10 @@ def verify_window(trusted: LightBlock, chain: list[LightBlock],
                 end = upto
             with span(tr, "light.store"):
                 if save is not None:
-                    for lb in chain[first:end]:
-                        save(lb)
+                    sizes = [save(lb) for lb in chain[first:end]]
+                    if tr is not None:
+                        sizes = [n for n in sizes if n is not None]
+                        tr.annotate(blocks=len(sizes), bytes=sum(sizes))
         if tr is not None:
             tr.annotate(sigs=sum(len(p) for _lb, p, _n in plan),
                         chunks=len(pending), verified=upto)

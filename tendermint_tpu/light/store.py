@@ -31,11 +31,14 @@ class DBStore:
 
     # --- Store interface (reference: light/store/store.go:12-44) -----------
 
-    def save_light_block(self, lb: LightBlock) -> None:
+    def save_light_block(self, lb: LightBlock) -> int:
+        """Returns the number of bytes handed to the db."""
         if lb.height <= 0:
             raise ValueError("lightBlock height must be > 0")
+        raw = lb.marshal()
         with self._mtx:
-            self._db.set(self._k(lb.height), lb.marshal())
+            self._db.set(self._k(lb.height), raw)
+        return len(raw)
 
     def delete_light_block(self, height: int) -> None:
         if height <= 0:

@@ -244,13 +244,17 @@ class CommitSig:
                 raise ValueError("signature is too big")
 
     def marshal(self) -> bytes:
+        # no Writer: a commit holds one of these per validator. proto3 omits
+        # the zero flag and the empty address and signature; the timestamp
+        # is nullable=false, emitted always.
+        uv = proto.encode_uvarint
+        flag, address = self.block_id_flag, self.validator_address
+        stamp, signature = self.timestamp.marshal(), self.signature
         return (
-            proto.Writer()
-            .varint(1, self.block_id_flag)
-            .bytes(2, self.validator_address)
-            .message(3, self.timestamp.marshal(), always=True)
-            .bytes(4, self.signature)
-            .out()
+            (b"\x08" + proto.encode_varint(flag) if flag else b"")
+            + (b"\x12" + uv(len(address)) + address if address else b"")
+            + b"\x1a" + uv(len(stamp)) + stamp
+            + (b"\x22" + uv(len(signature)) + signature if signature else b"")
         )
 
     @staticmethod
@@ -350,15 +354,14 @@ class Commit:
         return [not cs.absent() for cs in self.signatures]
 
     def marshal(self) -> bytes:
-        w = (
+        return (
             proto.Writer()
             .varint(1, self.height)
             .varint(2, self.round)
             .message(3, self.block_id.marshal(), always=True)
-        )
-        for cs in self.signatures:
-            w.message(4, cs.marshal(), always=True)
-        return w.out()
+            .out()
+        ) + proto.repeated_messages(  # field 4
+            b"\x22", [cs.marshal() for cs in self.signatures])
 
     @staticmethod
     def unmarshal(buf: bytes) -> "Commit":

@@ -57,7 +57,10 @@ class Time:
     # --- encoding ----------------------------------------------------------
     def marshal(self) -> bytes:
         """google.protobuf.Timestamp body (field 1 seconds, field 2 nanos)."""
-        return proto.Writer().varint(1, self.seconds).varint(2, self.nanos).out()
+        # no Writer: a commit holds one of these per signature
+        seconds, nanos = self.seconds, self.nanos
+        out = b"\x08" + proto.encode_varint(seconds) if seconds else b""
+        return out + b"\x10" + proto.encode_varint(nanos) if nanos else out
 
     @staticmethod
     def unmarshal(buf: bytes) -> "Time":

@@ -20,6 +20,10 @@ def clip_int64(v: int) -> int:
     return max(_INT64_MIN, min(_INT64_MAX, v))
 
 
+# the PublicKey oneof's tags: fields 1, 2, 3, wire type bytes
+_PUBKEY_TAG = {"ed25519": b"\x0a", "secp256k1": b"\x12", "sr25519": b"\x1a"}
+
+
 def pubkey_proto_bytes(pub: keys.PubKey) -> bytes:
     """tendermint.crypto.PublicKey oneof marshal (reference:
     crypto/encoding/codec.go PubKeyToProto; keys.proto fields: ed25519=1,
@@ -30,10 +34,11 @@ def pubkey_proto_bytes(pub: keys.PubKey) -> bytes:
     validators can't exist in a reference validator set at all; field 3 is
     the convention forks that do support it use. Wire compatibility for
     ed25519/secp256k1 chains is unaffected."""
-    field_num = {"ed25519": 1, "secp256k1": 2, "sr25519": 3}.get(pub.type)
-    if field_num is None:
+    tag = _PUBKEY_TAG.get(pub.type)
+    if tag is None:
         raise ValueError(f"key type {pub.type} not representable in PublicKey proto")
-    return proto.Writer().bytes(field_num, pub.bytes()).out()
+    raw = pub.bytes()
+    return tag + proto.encode_uvarint(len(raw)) + raw if raw else b""
 
 
 def pubkey_from_proto_bytes(buf: bytes) -> keys.PubKey:
@@ -102,13 +107,17 @@ class Validator:
 
     # full Validator proto (validator.proto)
     def marshal(self) -> bytes:
+        # no Writer: a set holds one of these per validator. proto3 omits
+        # the zero scalars; the key is nullable=false, emitted always.
+        uv = proto.encode_uvarint
+        address, power = self.address, self.voting_power
+        priority = self.proposer_priority
+        key = pubkey_proto_bytes(self.pub_key)
         return (
-            proto.Writer()
-            .bytes(1, self.address)
-            .message(2, pubkey_proto_bytes(self.pub_key), always=True)
-            .varint(3, self.voting_power)
-            .varint(4, self.proposer_priority)
-            .out()
+            (b"\x0a" + uv(len(address)) + address if address else b"")
+            + b"\x12" + uv(len(key)) + key
+            + (b"\x18" + proto.encode_varint(power) if power else b"")
+            + (b"\x20" + proto.encode_varint(priority) if priority else b"")
         )
 
     @staticmethod

@@ -560,13 +560,12 @@ class ValidatorSet:
     # --- wire --------------------------------------------------------------
 
     def marshal(self) -> bytes:
-        w = proto.Writer()
-        for v in self.validators:
-            w.message(1, v.marshal())
+        out = proto.repeated_messages(  # field 1
+            b"\x0a", [v.marshal() for v in self.validators])
         if self.proposer is not None:
-            w.message(2, self.proposer.marshal())
-        w.varint(3, self.total_voting_power())
-        return w.out()
+            out += proto.repeated_messages(b"\x12", (self.proposer.marshal(),))
+        total = self.total_voting_power()
+        return out + b"\x18" + proto.encode_varint(total) if total else out
 
     @staticmethod
     def unmarshal(buf: bytes) -> "ValidatorSet":

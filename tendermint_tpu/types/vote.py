@@ -125,12 +125,6 @@ def canonical_vote_bytes(chain_id: str, vtype: int, height: int, round_: int,
         prefix + proto.encode_uvarint(len(tsm)) + tsm + suf)
 
 
-# Stateless varint pieces for the nanos field of canonical_vote_bytes_many:
-# the two continuation bytes of a 14-bit group, and the one of a 7-bit group.
-_UV14C = tuple(bytes((k & 0x7F | 0x80, k >> 7 | 0x80)) for k in range(1 << 14))
-_UV7C = tuple(bytes((k | 0x80,)) for k in range(1 << 7))
-
-
 def canonical_vote_bytes_many(chain_id: str, vtype: int, height: int,
                               round_: int, block_id: BlockID,
                               timestamps) -> list[bytes] | None:
@@ -151,7 +145,9 @@ def canonical_vote_bytes_many(chain_id: str, vtype: int, height: int,
         return None
     prefix, suf = ends
     uv, join = proto.encode_uvarint, b"".join
-    pair, cont = _UV14C, _UV7C
+    # the nanos varint from the codec's tables: the two continuation bytes of
+    # a 14-bit group, the one of a 7-bit group
+    pair, cont = proto.UV14C, proto.UV7C
     # the last nanos byte (no continuation bit) with the suffix behind it
     tails = [uv(last) + suf for last in range(0x80)]
     # all but the timestamp body; its length (at most 22) takes one byte

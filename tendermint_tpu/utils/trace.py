@@ -153,7 +153,7 @@ CANONICAL_SPANS = {
     "light.replay": "the serial tally of each header of one dispatch over "
                     "its slice of the bitmap (span)",
     "light.store": "trusted-store writes of one dispatch's verified "
-                   "headers (span)",
+                   "headers; tags blocks, bytes (span)",
     # light-client serving gateway (light/gateway.py, docs/LIGHT.md)
     "light.gateway.serve": "one client query through the gateway: cache "
                            "lookup, coalesced verification, answer or "

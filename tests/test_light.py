@@ -4,6 +4,7 @@ and the batched header-range verify (BASELINE config 3)."""
 
 import pytest
 
+import wire_reference as ref
 from tendermint_tpu.crypto import ed25519
 from tendermint_tpu.light import verifier as lv
 from tendermint_tpu.light.client import Client, TrustOptions, SEQUENTIAL, SKIPPING
@@ -23,6 +24,7 @@ from tendermint_tpu.types.ttime import Time
 from tendermint_tpu.types.validator import Validator
 from tendermint_tpu.types.validator_set import ValidatorSet
 from tendermint_tpu.types.vote import BLOCK_ID_FLAG_COMMIT, PRECOMMIT_TYPE, Vote
+from tendermint_tpu.utils import trace
 
 CHAIN_ID = "light-test-chain"
 TRUST_PERIOD = 3 * 3600.0
@@ -193,6 +195,71 @@ def test_store_roundtrip_and_prune(chain):
     assert store.first_light_block_height() == 4
     got = store.light_block(5)
     assert got.signed_header.header.hash() == chain[4].hash()
+
+
+@pytest.fixture(scope="module")
+def rotating_chain():
+    """12 heights under 24 validators of unequal power whose proposer
+    priorities move on between heights, as on a live chain: each height
+    hands the store another ValidatorSet object that encodes to other bytes
+    (the priorities are in the encoding, field 4, and not in the hash)."""
+    privs, vs = _mk_keys(24, power=[10 + 7 * i for i in range(24)])
+    out, last_bid = [], BlockID()
+    for h in range(1, 13):
+        header = _mk_header(h, h * 10, vs, vs, last_bid)
+        commit = _sign_commit(header, vs, privs, skip=(h % 24,))
+        out.append(LightBlock(SignedHeader(header, commit), vs))
+        last_bid = commit.block_id
+        vs = vs.copy_increment_proposer_priority(1)
+    return out
+
+
+def test_store_writes_the_reference_encoding_of_a_rotating_set(rotating_chain):
+    db = MemDB()
+    store = DBStore(db)
+    for lb in rotating_chain:
+        want = ref.light_block(lb)
+        assert store.save_light_block(lb) == len(want)
+        assert db.get(b"lb/" + lb.height.to_bytes(8, "big")) == want
+        got = store.light_block(lb.height)
+        assert got.signed_header == lb.signed_header
+        assert got.validator_set.validators == lb.validator_set.validators
+        assert got.validator_set.proposer == lb.validator_set.proposer
+        assert got.marshal() == want
+    sets = [ref.validator_set(lb.validator_set) for lb in rotating_chain]
+    assert len(set(sets)) == len(sets)  # an identity or hash memo would be wrong
+    assert len({lb.validator_set.hash() for lb in rotating_chain}) == 1
+    assert any(v.proposer_priority < 0 for v in rotating_chain[3].validator_set.validators)
+
+
+@pytest.mark.parametrize("through", ["verify_header_range", "client"])
+def test_light_store_span_counts_blocks_and_bytes(rotating_chain, through):
+    """light.store's tags: headers written inside the span and the bytes
+    handed to the db for them, as the db holds them afterwards."""
+    c = rotating_chain
+    db = MemDB()
+    store = DBStore(db)
+    tracer = trace.Tracer("light-store", cap=256, enabled=True)
+    try:
+        with tracer.activate():
+            if through == "client":
+                # the trust root and the target are written outside the span
+                client, _ = _client(c, SEQUENTIAL, store=store)
+                client.verify_light_block_at_height(len(c), t(500))
+                inside = range(2, len(c))
+            else:
+                verify_header_range(c[0], c[1:], TRUST_PERIOD, t(500), DRIFT, store=store)
+                inside = range(2, len(c) + 1)
+        spans = [s for s in tracer.dump() if s.name == "light.store"]
+    finally:
+        tracer.disable()
+    assert spans and all({"blocks", "bytes"} <= set(s.tags) for s in spans)
+    assert sum(s.tags["blocks"] for s in spans) == len(inside)
+    assert sum(s.tags["bytes"] for s in spans) == sum(
+        len(db.get(b"lb/" + h.to_bytes(8, "big"))) for h in inside)
+    assert store.size() == len(c) - (through != "client")
+    for h in inside:
+        assert db.get(b"lb/" + h.to_bytes(8, "big")) == ref.light_block(c[h - 1])
 
 
 # --- client ----------------------------------------------------------------
