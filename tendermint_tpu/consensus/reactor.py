@@ -26,6 +26,7 @@ from tendermint_tpu.types.block_id import BlockID, PartSetHeader
 from tendermint_tpu.types.part_set import Part, PartSet
 from tendermint_tpu.types.proposal import Proposal
 from tendermint_tpu.types.vote import PRECOMMIT_TYPE, PREVOTE_TYPE, Vote
+from tendermint_tpu.utils import trace as _trace
 
 STATE_CHANNEL = 0x20
 DATA_CHANNEL = 0x21
@@ -200,6 +201,9 @@ class ConsensusReactor(Reactor):
         self.wait_sync = wait_sync  # True while fast sync is running
         self._peer_states: dict[str, PeerState] = {}
         self._mtx = threading.RLock()
+        # channel id -> [messages, seconds in receive, bytes], counted on
+        # the receiving threads while tracing is on (docs/OBSERVABILITY.md)
+        self.recv_stats: dict[int, list] = {}
         cs.on_new_round_step.append(self._broadcast_new_round_step)
         cs.on_vote.append(self._broadcast_has_vote)
         cs.on_valid_block.append(self._broadcast_new_valid_block)
@@ -263,6 +267,24 @@ class ConsensusReactor(Reactor):
     # --- receive -----------------------------------------------------------
 
     def receive(self, ch_id: int, peer: Peer, msg_bytes: bytes) -> None:
+        """Decode one wire message and hand it to the state machine. With
+        tracing on, ``recv_stats`` counts it: at a step's 15,000 votes a span
+        per message would turn the flight recorder's ring over."""
+        if not _trace.ENABLED:
+            self._receive(ch_id, peer, msg_bytes)
+            return
+        t0 = time.monotonic()
+        try:
+            self._receive(ch_id, peer, msg_bytes)
+        finally:
+            dt = time.monotonic() - t0
+            with self._mtx:
+                st = self.recv_stats.setdefault(ch_id, [0, 0.0, 0])
+                st[0] += 1
+                st[1] += dt
+                st[2] += len(msg_bytes)
+
+    def _receive(self, ch_id: int, peer: Peer, msg_bytes: bytes) -> None:
         ps: PeerState = peer.get("consensus_peer_state")
         if ps is None:
             return

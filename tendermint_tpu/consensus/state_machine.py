@@ -55,6 +55,15 @@ from tendermint_tpu.utils import peerscore
 from tendermint_tpu.utils import trace as _trace
 
 
+# Bound of the peer message queue (docs/OVERLOAD.md): the reference's
+# msgQueueSize, and on top of it what gossip can have in flight for a node
+# that keeps up with the chain -- per validator the prevote and the precommit
+# of the live height and the late precommit of the height before it (queued
+# as live, stale by the time it is drained), a copy from each of three peers.
+MSG_QUEUE_MIN = 1000
+MSG_QUEUE_PER_VALIDATOR = 9
+
+
 class ConsensusError(Exception):
     pass
 
@@ -196,8 +205,11 @@ class ConsensusState:
         # at capacity, stale-height gossip sheds first and live-height votes
         # survive, and gossip threads NEVER block on a saturated consensus
         # consumer. Internal messages (own votes/proposals) keep a plain
-        # bounded queue — they are never shed.
-        self._msg_queue = peerscore.ShedQueue(maxsize=1000,
+        # bounded queue — they are never shed. The bound follows the
+        # validator set (update_to_state): a queue smaller than one round's
+        # votes sheds votes of the live height whenever gossip outruns the
+        # drain, and a peer never re-sends what it has marked as delivered.
+        self._msg_queue = peerscore.ShedQueue(maxsize=MSG_QUEUE_MIN,
                                               on_shed=self._count_shed)
         self._internal_queue: queue.Queue = queue.Queue(maxsize=1000)
         self._ticker = TimeoutTicker(self._on_timeout_fired, clock=self.clock)
@@ -356,6 +368,16 @@ class ConsensusState:
         if board is not None:
             board.count_shed(channel)
 
+    def shed_counts(self) -> dict[str, dict[str, int]]:
+        """Messages the peer queue shed, by class (``live`` / ``stale`` /
+        ``future``, judged against the height at arrival) and channel."""
+        out: dict[str, dict[str, int]] = {"live": {}, "stale": {}, "future": {}}
+        names = {peerscore.PRIO_LIVE: "live", peerscore.PRIO_STALE: "stale",
+                 peerscore.PRIO_FUTURE: "future"}
+        for (prio, channel), n in self._msg_queue.shed_by_class.items():
+            out[names[prio]][channel] = n
+        return out
+
     def add_vote(self, vote: Vote, peer_id: str = "") -> None:
         if peer_id == "":
             self._internal_queue.put(MsgInfo(VoteMessage(vote), peer_id))
@@ -485,12 +507,14 @@ class ConsensusState:
                     and not self._msg_queue.empty()):
                 votes = self._drain_votes(mi)
                 if len(votes) > 1:
-                    if self.wal is not None and not self.replay_mode:
-                        for m in votes:
-                            blob = m.msg.wal_blob()
-                            blob.peer_id = m.peer_id
-                            self.wal.write(blob, _time.time_ns())
                     tr = self.tracer
+                    if self.wal is not None and not self.replay_mode:
+                        if tr.enabled:
+                            with tr.span("consensus.wal_write",
+                                         msgs=len(votes)):
+                                tr.annotate(bytes=self._wal_write_votes(votes))
+                        else:
+                            self._wal_write_votes(votes)
                     with self._mtx:
                         if tr.enabled:
                             # the drain span carries the height; verify
@@ -516,7 +540,24 @@ class ConsensusState:
                 else:
                     self.wal.write(blob, _time.time_ns())
             with self._mtx:
-                self._handle_msg(mi)
+                if (not internal and self.tracer.enabled
+                        and isinstance(mi.msg, VoteMessage)):
+                    with self.tracer.span("consensus.vote_serial",
+                                          why="single", votes=1):
+                        self._handle_msg(mi)
+                else:
+                    self._handle_msg(mi)
+
+    def _wal_write_votes(self, votes: list[MsgInfo]) -> int:
+        """A drain's votes into the WAL, buffered, every copy, before any is
+        verified -> payload bytes written."""
+        n_bytes = 0
+        for m in votes:
+            blob = m.msg.wal_blob()
+            blob.peer_id = m.peer_id
+            n_bytes += len(blob.payload)
+            self.wal.write(blob, _time.time_ns())
+        return n_bytes
 
     def _drain_votes(self, first: MsgInfo) -> list[MsgInfo]:
         """Pull immediately-available peer VoteMessages (bounded so internal
@@ -580,14 +621,21 @@ class ConsensusState:
             queued: list[int] = []
             sb_memo: dict[tuple, bytes] = {}
             chain_id = self.state.chain_id
+            # votes the batch leaves to the serial path: {msg index: why}
+            serial: dict[int, str] = {}
             for i, m in enumerate(msgs):
                 v = m.msg.vote
                 if val_set is None or v.height != height:
-                    continue  # serial path handles late/early votes
+                    # serial path handles late/early votes
+                    serial[i] = "late" if v.height < height else "early"
+                    continue
                 if not (0 <= v.validator_index < val_set.size()):
-                    continue  # precheck will raise the right error serially
+                    # precheck will raise the right error serially
+                    serial[i] = "precheck"
+                    continue
                 addr, val = val_set.get_by_index(v.validator_index)
                 if val is None or addr != v.validator_address:
+                    serial[i] = "precheck"
                     continue
                 sb_key = (v.height, v.round, v.type, v.block_id.key(),
                           v.timestamp)
@@ -601,16 +649,20 @@ class ConsensusState:
                     continue
                 verifier.add(val.pub_key, sb, v.signature)
                 queued.append(i)
+            if self.tracer.enabled:
+                self.tracer.annotate(
+                    queued=len(queued), cache_hits=len(dc.cached_ok),
+                    in_drain_copies=dc.copies_queued(), skipped=len(serial))
             if not queued:
                 # commit with an empty flush: applies the cache hits and
                 # flushes the batched hit/miss metrics deltas
-                self._apply_vote_results(msgs, dc.commit([], []))
+                self._apply_vote_results(msgs, dc.commit([], []), serial)
                 return
             pending = verifier.dispatch()
             if pending.has_device_output():
                 # stash; the drain loop applies it before the next state
                 # transition, overlapping the round trip with more draining
-                self._pending_flush = (msgs, queued, dc, pending)
+                self._pending_flush = (msgs, queued, dc, pending, serial)
                 return
             ok_by_i = self._resolve_vote_flush(queued, dc, pending)
         except Exception as e:  # noqa: BLE001
@@ -624,16 +676,18 @@ class ConsensusState:
             if self.logger is not None:
                 self.logger.error("batched vote verify failed; falling back "
                                   "to serial", err=e)
-        self._apply_vote_results(msgs, ok_by_i)
+        self._apply_vote_results(msgs, ok_by_i, serial)
 
-    @staticmethod
-    def _resolve_vote_flush(queued, dc, pending):
+    def _resolve_vote_flush(self, queued, dc, pending):
         """Resolve a dispatched vote flush into {msg index: verified}.
         Positively verified triples enter the signature cache in
         DrainCache.commit -- only from a resolved bitmap, so a resolve that
         raises (propagated to the caller's serial fallback) can never
         poison the cache."""
-        _, bitmap = pending.resolve()
+        tr = self.tracer
+        with (tr.span("consensus.flush_wait", sigs=len(queued))
+              if tr.enabled else _trace.NULL_SPAN):
+            _, bitmap = pending.resolve()
         return dc.commit(queued, bitmap)
 
     def _flush_pending_votes(self, _locked: bool = False) -> None:
@@ -643,7 +697,7 @@ class ConsensusState:
         if pf is None:
             return
         self._pending_flush = None
-        msgs, queued, dc, pending = pf
+        msgs, queued, dc, pending, serial = pf
         try:
             ok_by_i = self._resolve_vote_flush(queued, dc, pending)
         except Exception as e:  # noqa: BLE001 - same fallback as the sync path
@@ -652,35 +706,67 @@ class ConsensusState:
                 self.logger.error("batched vote verify failed; falling back "
                                   "to serial", err=e)
         if _locked:
-            self._apply_vote_results(msgs, ok_by_i)
+            self._apply_vote_results(msgs, ok_by_i, serial)
         else:
             with self._mtx:
-                self._apply_vote_results(msgs, ok_by_i)
+                self._apply_vote_results(msgs, ok_by_i, serial)
 
-    def _apply_vote_results(self, msgs: list[MsgInfo],
-                            ok_by_i: dict[int, bool]) -> None:
-        for i, m in enumerate(msgs):
-            ok = ok_by_i.get(i)
-            if ok is False:
-                # Same terminal state as the serial path's VoteError: vote
-                # dropped, error logged, consensus thread lives on — but
-                # the lane's FAILED bit is attributed to the delivering
-                # peer: MsgInfo.peer_id traveled the whole drain, so the
-                # batched bitmap sanctions exactly like serial verification
+    def _apply_vote_results(self, msgs: list[MsgInfo], ok_by_i: dict[int, bool],
+                            serial: dict[int, str] | None = None) -> None:
+        """Apply a drain's votes in arrival order. ``serial`` names the
+        votes the batch did not verify and why; with tracing on, the time
+        they spend in the serial path is recorded per reason."""
+        tr = self.tracer
+        if not tr.enabled:
+            for i, m in enumerate(msgs):
+                self._apply_vote_result(m, ok_by_i.get(i))
+            return
+        counts = {"added": 0, "not_added": 0, "invalid": 0, "errors": 0}
+        spent: dict[str, list] = {}       # why -> [votes, seconds]
+        with tr.span("consensus.vote_apply", votes=len(msgs)):
+            for i, m in enumerate(msgs):
+                why = serial.get(i) if serial else None
+                if why is None:
+                    counts[self._apply_vote_result(m, ok_by_i.get(i))] += 1
+                    continue
+                t0 = _time.monotonic()
+                counts[self._apply_vote_result(m, None)] += 1
+                acc = spent.setdefault(why, [0, 0.0])
+                acc[0] += 1
+                acc[1] += _time.monotonic() - t0
+            tr.annotate(added=counts["added"], invalid=counts["invalid"],
+                        duplicates=counts["not_added"],
+                        errors=counts["errors"])
+        for why, (n, seconds) in spent.items():
+            tr.record("consensus.vote_serial", seconds, why=why, votes=n)
+
+    def _apply_vote_result(self, m: MsgInfo, ok: bool | None) -> str:
+        """One vote of a drain through the normal addVote path -> ``added``,
+        ``not_added`` (a copy, or a vote the round state ignores),
+        ``invalid`` or ``errors``."""
+        if ok is False:
+            # Same terminal state as the serial path's VoteError: vote
+            # dropped, error logged, consensus thread lives on — but
+            # the lane's FAILED bit is attributed to the delivering
+            # peer: MsgInfo.peer_id traveled the whole drain, so the
+            # batched bitmap sanctions exactly like serial verification
+            self._punish_peer(m.peer_id)
+            if self.logger is not None:
+                self.logger.error(
+                    "failed to process message", err="invalid signature",
+                    peer=m.peer_id)
+            return "invalid"
+        try:
+            added = self._try_add_vote(m.msg.vote, m.peer_id, verified=bool(ok))
+        except Exception as e:  # noqa: BLE001 - mirror _handle_msg
+            invalid = isinstance(e, ErrVoteInvalidSignature)
+            if invalid:
                 self._punish_peer(m.peer_id)
-                if self.logger is not None:
-                    self.logger.error(
-                        "failed to process message", err="invalid signature",
-                        peer=m.peer_id)
-                continue
-            try:
-                self._try_add_vote(m.msg.vote, m.peer_id, verified=bool(ok))
-            except Exception as e:  # noqa: BLE001 - mirror _handle_msg
-                if isinstance(e, ErrVoteInvalidSignature):
-                    self._punish_peer(m.peer_id)
-                if self.logger is not None:
-                    self.logger.error("failed to process message", err=e,
-                                      peer=m.peer_id)
+            if self.logger is not None:
+                self.logger.error("failed to process message", err=e,
+                                  peer=m.peer_id)
+            return "invalid" if invalid else "errors"
+        return "added" if added else "not_added"
 
     def _punish_peer(self, peer_id: str,
                      offense: str = "invalid_signature") -> None:
@@ -782,6 +868,8 @@ class ConsensusState:
         base_ns = rs.commit_time.unix_ns() if not rs.commit_time.is_zero() else now_ns
         rs.start_time = Time.from_unix_ns(base_ns + int(self.config.commit_time_s() * 1e9))
         rs.validators = validators
+        self._msg_queue.maxsize = (
+            MSG_QUEUE_MIN + MSG_QUEUE_PER_VALIDATOR * validators.size())
         rs.proposal = None
         rs.proposal_block = None
         rs.proposal_block_parts = None
@@ -1176,39 +1264,41 @@ class ConsensusState:
             raise ConsensusError("expected ProposalBlockParts header to be commit header")
         if not block.hashes_to(block_id.hash):
             raise ConsensusError("cannot finalize commit; proposal block does not hash to commit hash")
-        # commit→apply overlap (docs/EXECUTION.md): dispatch the block's
-        # LastCommit verification on-device now so the round trip rides
-        # under the structural checks; the resolved handle then makes
-        # apply_block's re-validation free (resolve() is idempotent),
-        # collapsing the path's two synchronous verifies into one async one.
-        commit_pending = self.block_exec.dispatch_commit_verify(self.state, block)
-        self.block_exec.validate_block(self.state, block,
-                                       commit_pending=commit_pending)
+        # validate + save + apply: what a height costs after its +2/3
+        with self.tracer.span("consensus.finalize_commit", height=height):
+            # commit→apply overlap (docs/EXECUTION.md): dispatch the block's
+            # LastCommit verification on-device now so the round trip rides
+            # under the structural checks; the resolved handle then makes
+            # apply_block's re-validation free (resolve() is idempotent),
+            # collapsing the path's two synchronous verifies into one async one.
+            commit_pending = self.block_exec.dispatch_commit_verify(self.state, block)
+            self.block_exec.validate_block(self.state, block,
+                                           commit_pending=commit_pending)
 
-        from tendermint_tpu.utils import faults
+            from tendermint_tpu.utils import faults
 
-        # crash site 1 (reference: state.go:1605)
-        faults.fail_point("consensus.finalize.save_block")
-        if self.block_store.height < block.header.height:
-            seen_commit = rs.votes.precommits(rs.commit_round).make_commit()
-            with self.tracer.span("consensus.store_save", height=height):
-                self.block_store.save_block(block, block_parts, seen_commit)
+            # crash site 1 (reference: state.go:1605)
+            faults.fail_point("consensus.finalize.save_block")
+            if self.block_store.height < block.header.height:
+                seen_commit = rs.votes.precommits(rs.commit_round).make_commit()
+                with self.tracer.span("consensus.store_save", height=height):
+                    self.block_store.save_block(block, block_parts, seen_commit)
 
-        # crash site 2 (reference: state.go:1619)
-        faults.fail_point("consensus.finalize.end_height")
-        if self.wal is not None:
-            self.wal.write_sync(EndHeightMessage(height), self.clock.now_ns())
+            # crash site 2 (reference: state.go:1619)
+            faults.fail_point("consensus.finalize.end_height")
+            if self.wal is not None:
+                self.wal.write_sync(EndHeightMessage(height), self.clock.now_ns())
 
-        # crash site 3 (reference: state.go:1642)
-        faults.fail_point("consensus.finalize.apply_block")
-        state_copy = self.state.copy()
-        with self.tracer.span("consensus.abci_apply", height=height):
-            state_copy, retain_height = self.block_exec.apply_block(
-                state_copy,
-                BlockID(hash=block.hash(), part_set_header=block_parts.header()),
-                block,
-                commit_pending=commit_pending,
-            )
+            # crash site 3 (reference: state.go:1642)
+            faults.fail_point("consensus.finalize.apply_block")
+            state_copy = self.state.copy()
+            with self.tracer.span("consensus.abci_apply", height=height):
+                state_copy, retain_height = self.block_exec.apply_block(
+                    state_copy,
+                    BlockID(hash=block.hash(), part_set_header=block_parts.header()),
+                    block,
+                    commit_pending=commit_pending,
+                )
 
         # crash site 4 (reference: state.go:1667)
         faults.fail_point("consensus.finalize.prune")

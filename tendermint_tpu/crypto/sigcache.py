@@ -139,6 +139,12 @@ class DrainCache:
         self._ckeys.append(ck)
         return False
 
+    def copies_queued(self) -> int:
+        """Triples this flush queued more than once: copies that fell into
+        the drain that holds their original, which the cache cannot answer
+        because it learns a triple only when the flush resolves."""
+        return len(self._ckeys) - len(set(self._ckeys))
+
     def commit(self, queued: list, bitmap) -> dict:
         self._flush_metrics()
         if self._cache is not None:

@@ -83,7 +83,7 @@ class VoteSet:
         through a BatchVerifier flush (the deferred batched mode)."""
         if vote is None:
             raise VoteSetError("nil vote")
-        checked = self._precheck(vote)
+        checked = self._precheck(vote, verified)
         if checked is None:
             return False  # exact duplicate
         val = checked
@@ -183,7 +183,7 @@ class VoteSet:
                     results[i] = (False, e)
         return results
 
-    def _precheck(self, vote: Vote):
+    def _precheck(self, vote: Vote, verified: bool = False):
         """Everything add_vote does before the signature check. Returns the
         validator, or None for an exact duplicate."""
         val_index = vote.validator_index
@@ -215,6 +215,17 @@ class VoteSet:
         if existing is not None:
             if existing.signature == vote.signature:
                 return None  # duplicate
+            # A second signature over a vote already held. The reference
+            # names it non-deterministic without looking at it; a copy a
+            # relay corrupted is an invalid signature whether it arrives
+            # before the good copy or after, and the batched drain, which
+            # verifies before it looks at the set, says so: the serial path
+            # has to give the deliverer the same verdict.
+            if not verified and not val.pub_key.verify_signature(
+                    vote.sign_bytes(self.chain_id), vote.signature):
+                raise ErrVoteInvalidSignature(
+                    f"failed to verify vote with ChainID {self.chain_id} and "
+                    f"PubKey {val.pub_key.bytes().hex()}: invalid signature")
             raise VoteError(
                 f"existing vote: {existing}; new vote: {vote}: non-deterministic signature"
             )

@@ -415,8 +415,18 @@ class ShedQueue:
         # in the common full-of-equal-priority flood case; the O(n)
         # victim scan runs only when an eviction will actually succeed
         self._prio_counts: dict[int, int] = {}
-        self.shed_counts: dict[str, int] = {}
+        # sheds by (priority class of what was shed, channel)
+        self.shed_by_class: dict[tuple[int, str], int] = {}
         self._on_shed = on_shed  # callback(channel) after the lock drops
+
+    @property
+    def shed_counts(self) -> dict[str, int]:
+        """Sheds by channel, whatever the class."""
+        out: dict[str, int] = {}
+        with self._mtx:
+            for (_prio, channel), n in self.shed_by_class.items():
+                out[channel] = out.get(channel, 0) + n
+        return out
 
     def put(self, item, priority: int | None = None,
             channel: str = "ctrl", block: bool = True,
@@ -436,6 +446,7 @@ class ShedQueue:
                     # (O(1) — the common case when a flood has filled the
                     # queue with its own priority class)
                     shed_channel = channel
+                    shed_prio = priority
                     admitted = False
                 else:
                     # evict the oldest entry of the lowest class present
@@ -447,11 +458,12 @@ class ShedQueue:
                             victim_prio = p
                             if p == PRIO_STALE:
                                 break  # nothing sheds earlier than stale
-                    vp, shed_channel, _vi = self._dq[victim_i]
+                    shed_prio, shed_channel, _vi = self._dq[victim_i]
                     del self._dq[victim_i]
-                    self._prio_counts[vp] -= 1
-                self.shed_counts[shed_channel] = \
-                    self.shed_counts.get(shed_channel, 0) + 1
+                    self._prio_counts[shed_prio] -= 1
+                by_class = (shed_prio, shed_channel)
+                self.shed_by_class[by_class] = \
+                    self.shed_by_class.get(by_class, 0) + 1
             if admitted:
                 self._dq.append((priority, channel, item))
                 if priority is not None:

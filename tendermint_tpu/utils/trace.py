@@ -77,7 +77,22 @@ CANONICAL_SPANS = {
     "consensus.abci_apply": "ABCI BeginBlock..Commit of the decided block (span)",
     # consensus timing
     "consensus.step": "time spent in the round step just left",
-    "consensus.vote_drain": "batched peer-vote drain: build + dispatch",
+    "consensus.vote_drain": "batched peer-vote drain: the previous flush's "
+                            "wait and apply, then build + dispatch (tags "
+                            "votes, queued, cache_hits, in_drain_copies, "
+                            "skipped)",
+    "consensus.wal_write": "a drain's votes written to the WAL, buffered, "
+                           "before any is verified (tags msgs, bytes)",
+    "consensus.flush_wait": "the consensus thread blocked on a vote flush's "
+                            "bitmap (tag sigs)",
+    "consensus.vote_apply": "a drain's votes through addVote in arrival "
+                            "order (tags votes, added, duplicates, invalid, "
+                            "errors)",
+    "consensus.vote_serial": "votes the batch did not verify, in the serial "
+                             "path (tags why = single / late / early / "
+                             "precheck, votes; one record per drain and why)",
+    "consensus.finalize_commit": "validate + save + apply of a decided "
+                                 "block (parent of store_save, abci_apply)",
     # deferred verify pipeline phases (crypto/batch.py; the sync-floor
     # attribution ROADMAP item 1 needs)
     "verify.host_prep": "host prep + kernel dispatch (ops dispatch_batch)",

@@ -40,7 +40,7 @@ from tendermint_tpu.p2p.key import NodeKey
 from tendermint_tpu.privval.file_pv import MockPV
 from tendermint_tpu.types.genesis import GenesisDoc, GenesisValidator
 from tendermint_tpu.types.ttime import Time
-from tendermint_tpu.utils import faults, lockwitness, nemesis, peerscore
+from tendermint_tpu.utils import faults, lockwitness, nemesis, peerscore, trace
 
 SEED = 2027
 VOTE_CH = 0x22
@@ -421,6 +421,7 @@ def test_vote_drain_bitmap_attributes_invalid_lanes_to_peers():
 
     cs = ConsensusState.__new__(ConsensusState)
     cs.logger = None
+    cs.tracer = trace.Tracer()
     cs.scoreboard = peerscore.PeerScoreBoard()
     applied = []
     cs._try_add_vote = lambda vote, peer_id, verified=False: applied.append(

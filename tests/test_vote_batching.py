@@ -103,6 +103,54 @@ def test_add_votes_duplicate_within_batch(big_net):
     assert results[2][0] is False and results[2][1] is None
 
 
+@pytest.mark.parametrize("second, verified, want", [
+    ("corrupted", False, "ErrVoteInvalidSignature"),
+    ("another_valid", False, "VoteError"),
+    ("another_valid", True, "VoteError"),
+    ("same", False, None),
+], ids=["corrupted_copy_is_an_invalid_signature",
+        "a_second_valid_signature_is_non_deterministic",
+        "verified_by_the_batch_and_non_deterministic", "exact_copy_is_a_duplicate"])
+def test_a_second_signature_over_a_held_vote(big_net, second, verified, want):
+    """PR 30: the serial path gives a copy of a vote it already holds the
+    verdict the batched drain gives it. A copy whose signature a relay
+    corrupted is an invalid signature (the deliverer can be sanctioned), not
+    the reference's unexamined "non-deterministic signature"; only a second
+    signature that verifies is that."""
+    privs, vals = big_net
+    bid = BlockID(hash=b"\x55" * 32,
+                  part_set_header=PartSetHeader(total=1, hash=b"\x66" * 32))
+    first = _signed_vote(privs[0], vals, PREVOTE_TYPE, bid)
+    vs = VoteSet(CHAIN_ID, 1, 0, PREVOTE_TYPE, vals)
+    assert vs.add_vote(first) is True
+    again = first.copy()
+    if second == "corrupted":
+        again.signature = bytes([first.signature[0] ^ 1]) + first.signature[1:]
+    elif second == "another_valid":
+        # a signer that does not derive its nonce as RFC 8032 says can sign
+        # the same bytes twice: any (R, S) with S = r + H(R, A, M) a verifies
+        import hashlib
+
+        from benchmark.reference import ed25519_ref as ref
+
+        a = ref._clamp(hashlib.sha512(privs[0].bytes()[:32]).digest())
+        pub = privs[0].pub_key().bytes()
+        msg = first.sign_bytes(CHAIN_ID)
+        r = 12345
+        big_r = ref._compress(ref._scalarmult(r, ref.BASE))
+        k = int.from_bytes(hashlib.sha512(big_r + pub + msg).digest(),
+                           "little") % ref.L
+        again.signature = big_r + ((r + k * a) % ref.L).to_bytes(32, "little")
+        assert privs[0].pub_key().verify_signature(msg, again.signature)
+    if want is None:
+        assert vs.add_vote(again, verified=verified) is False
+        return
+    with pytest.raises(VoteError) as e:
+        vs.add_vote(again, verified=verified)
+    assert type(e.value).__name__ == want
+    assert ("non-deterministic" in str(e.value)) == (want == "VoteError")
+
+
 def test_consensus_drain_applies_batch(big_net):
     """The state machine's _handle_vote_batch: a pile of gossiped precommits
     is flushed through one batch verify and applied in order (with one bad
