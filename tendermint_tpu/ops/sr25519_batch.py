@@ -28,6 +28,12 @@ validity conditions (host-checked canonical field element + device-checked
 square/t-sign/y-zero), the same transcript bytes (differential test in
 tests/test_sr25519_batch.py).
 
+Two kernels evaluate this, chosen by what the process can observe: on one
+TPU chip the Pallas chunk ops/ed25519_pallas._sr_verify_chunk (the ed25519
+kernel's comb loop, this decode and comparison as its tail, 4,096 lanes a
+call); under shard_map on several devices, and where the backend is no TPU,
+the jnp _sr_verify_kernel below (256 lanes a call).
+
 Pubkey comb tables live in one device-resident table keyed per key, a row a
 key, exactly like ed25519's and through the same code (edb.build_keyset,
 edb.KeyTable): a batch is a list of row numbers, and only a key the table
@@ -322,12 +328,38 @@ def _dispatch_device(items, n: int, multichip: bool = False):
     r_ok = _lt_p(r32) & ((r32[:, 0] & 1) == 0)
     valid = sig_ok & marker_ok & s_ok & r_ok & pub_ok
 
+    pallas = not multichip and edb._use_pallas()
     with _scalars_span(n):
         k32 = challenges([it[1] for it in items], pubs_arr, r32)
-        k_win = sc.comb_windows(k32).astype(np.int32)
-        s_win = sc.comb_windows(s32).astype(np.int32)
-    r_limbs = _bytes_to_limbs(r32)
+        if not pallas:  # the Pallas chunk cuts its windows on the device
+            k_win = sc.comb_windows(k32).astype(np.int32)
+            s_win = sc.comb_windows(s32).astype(np.int32)
 
+    if pallas:
+        # One fixed CHUNK-lane executable, fed like the ed25519 twin's
+        # (ed25519_pallas.dispatch_items_pipelined): raw bytes up, the
+        # per-key niels rows gathered by row number, a packed bitmap back.
+        from tendermint_tpu.ops import ed25519_pallas as edp
+
+        outs = []
+        for off in range(0, n, edp.CHUNK):
+            sl = slice(off, min(off + edp.CHUNK, n))
+            with edb.launch_span("jit__sr_verify_chunk", "pallas",
+                                 sl.stop - off, edp.CHUNK):
+                outs.append(edp._sr_verify_chunk(
+                    ks.gathered_lane(edp.pad_rows(key_idx[sl])),
+                    jnp.asarray(edp.pad_cols(k32[sl])),
+                    jnp.asarray(edp.pad_cols(s32[sl])),
+                    jnp.asarray(edp.pad_cols(r32[sl])),
+                    jnp.asarray(edp.pad_cols(valid[sl])),
+                ))
+        dev = edp.pack_bitmap(
+            outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1))
+        edb._start_host_copy(dev)
+        return dev, _cbreaker.routed(
+            lambda v: edp.unpack_bitmap(np.asarray(v), n), "pallas")
+
+    r_limbs = _bytes_to_limbs(r32)
     if multichip:
         # Multi-chip: the signature axis shards over the ("dp",) mesh, as
         # the ed25519 twin's does (the key table replicates once per append).
@@ -336,7 +368,8 @@ def _dispatch_device(items, n: int, multichip: bool = False):
         edb._start_host_copy(dev)
         return dev, _cbreaker.routed(lambda v: np.asarray(v)[:n], "sharded")
 
-    # Fixed-tile chunking through the one JNP_TILE-shaped executable.
+    # No TPU backend: fixed-tile chunking through the one JNP_TILE-shaped
+    # executable of the jnp kernel.
     tile = edb.JNP_TILE
     nb = max(edb._round_up(n, tile), tile)
     idx = np.zeros((nb,), dtype=np.int32)

@@ -94,6 +94,26 @@ def _pin_kernel_path(request, monkeypatch):
         monkeypatch.setenv("TM_TPU_HOST_CROSSOVER", "0")
 
 
+@pytest.fixture(autouse=True)
+def _breaker_counts_stay_with_their_test():
+    """The device breakers count failures for the life of the process, and a
+    test that injects a device fault leaves its count behind: whatever reads
+    the count later (the benchmark's `correct`, chip_smoke's end state) then
+    fails or passes by which file ran before it in its worker. Put the
+    counts back after every test; a module nobody imported is not imported."""
+    import sys
+
+    def breakers():
+        return [m.BREAKER for m in (
+            sys.modules.get("tendermint_tpu.ops.ed25519_batch"),
+            sys.modules.get("tendermint_tpu.ops.sr25519_batch")) if m is not None]
+
+    before = {id(b): (b.failures, b.trips, b.last_error) for b in breakers()}
+    yield
+    for b in breakers():
+        b.failures, b.trips, b.last_error = before.get(id(b), (0, 0, None))
+
+
 def pytest_collection_modifyitems(config, items):
     for item in items:
         # A soak-marked test is always slow-tier, whatever its module says.

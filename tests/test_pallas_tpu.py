@@ -42,3 +42,44 @@ def test_pallas_differential_vs_scalar():
     sample = list(range(0, len(items), 131))
     scal = np.array([ref.verify(*items[i]) for i in sample])
     assert (out[sample] == scal).all()
+
+
+def test_sr_pallas_differential_vs_host_and_scalar():
+    """Two chunks of sr25519 through the Pallas route (_sr_verify_chunk):
+    the bitmap equals the C host verifier's on every lane and the scalar
+    reference's on every corrupted lane and a sample."""
+    from tendermint_tpu.crypto import sr25519 as sr
+    from tendermint_tpu.ops import chost
+    from tendermint_tpu.ops import ed25519_batch as edb
+    from tendermint_tpu.ops import sr25519_batch as srb
+
+    assert edb._use_pallas()
+    rng = np.random.default_rng(9)
+    privs = [sr.gen_priv_key(bytes([i + 1]) * 4) for i in range(8)]
+    base = []
+    for i in range(64):
+        p = privs[i % 8]
+        msg = b"v%d|" % i + rng.bytes(int(rng.integers(0, 100)))
+        base.append((p.pub_key().data, msg,
+                     sr.sign(p.data, msg, rng_seed=bytes([i + 1]) * 32)))
+    items = (base * 80)[:4500]
+    bad = list(range(0, len(items), 37))
+    for j in bad:
+        pub, msg, sig = items[j]
+        items[j] = [
+            (pub, msg + b"!", sig),                                    # message
+            (pub, msg, sig[:40] + bytes([sig[40] ^ 4]) + sig[41:]),    # s
+            (pub, msg, bytes([sig[0] ^ 2]) + sig[1:]),                 # R
+            (pub, msg, (sr.P - 1).to_bytes(32, "little") + sig[32:]),  # y = 0
+            (b"\x02" + bytes(31), msg, sig),                           # key
+            (pub, msg, sig[:63] + bytes([sig[63] & 0x7F])),            # marker
+        ][j // 37 % 6]
+    dev, finish = srb.dispatch_batch(items)
+    out = finish(jax.device_get(dev))
+    assert finish.route == "pallas" and srb.BREAKER.failures == 0
+    assert not out[bad].any() and out.sum() == len(items) - len(bad)
+    if chost.ensure_available():
+        _, host = srb._host_fallback(items, len(items))
+        assert (out == host(None)).all()
+    sample = sorted(set(bad[:40] + list(range(1, len(items), 301))))
+    assert (out[sample] == np.array([sr.verify(*items[i]) for i in sample])).all()

@@ -12,12 +12,14 @@ test_ed25519_batch / test_sr25519_batch / test_multichip run the real
 ones): routing, the service's choice and `finish.route` are host work."""
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from tendermint_tpu.crypto import batch as cbatch
 from tendermint_tpu.crypto import ed25519, sr25519, verify_service
 from tendermint_tpu.ops import chost
 from tendermint_tpu.ops import ed25519_batch as edb
+from tendermint_tpu.ops import ed25519_pallas as edp
 from tendermint_tpu.ops import sr25519_batch as srb
 from tendermint_tpu.parallel import batch_shard
 from tendermint_tpu.utils import faults
@@ -188,6 +190,41 @@ def test_the_host_route_is_the_scalar_loop_while_the_library_builds(
         v.add(pk, m, s)
     assert edb.route_batch(4, False, v._batch_min_default) == "scalar"
     assert v.dispatch().resolve() == (True, [True] * 4) and not stand_ins
+
+
+# --- what "device" and "sharded" launch for sr25519 --------------------------
+
+
+@pytest.mark.parametrize("backend, n, want, lanes", [
+    ("tpu", 40, "pallas", edp.CHUNK),   # one chip: the Pallas chunk
+    ("cpu", 40, "jnp", edb.JNP_TILE),   # no TPU backend: the jnp tile
+    ("cpu", 72, "sharded", None),       # several devices: shard_map, jnp
+    ("tpu", 72, "sharded", None),       # ... whatever the backend
+])
+def test_sr25519_kernel_follows_backend_and_device_count(
+        backend, n, want, lanes, stand_ins, monkeypatch):
+    """route_batch names the route; which program the sr25519 device route
+    launches follows from what the process can observe, the backend and the
+    device count, and nothing else."""
+    monkeypatch.setattr(edb, "_use_pallas", lambda: backend == "tpu")
+    launched = []
+
+    def chunk(tab, k32, s32, r32, valid):
+        launched.append(("pallas", valid.shape[1]))
+        return valid.astype(jnp.int32)
+
+    def tile(tab, *arrays):
+        launched.append(("jnp", arrays[-1].shape[0]))
+        return arrays[-1]
+
+    monkeypatch.setattr(edp, "_sr_verify_chunk", chunk)
+    monkeypatch.setattr(edb.KeySet, "gathered_lane", lambda self, idx: None)
+    monkeypatch.setattr(srb, "_kernel", tile)
+    items = _raw(_items("sr25519", n))
+    assert edb.route_batch(n) == ("sharded" if want == "sharded" else "device")
+    dev, finish = srb.dispatch_batch(items)
+    assert finish(cbatch._device_get(dev)).all() and finish.route == want
+    assert launched == ([] if lanes is None else [(want, lanes)])
 
 
 # --- the service's guess and the dispatch agree -----------------------------
