@@ -100,13 +100,27 @@ class VerifyAheadPipeline:
 
     def __init__(self) -> None:
         self._entries: deque[_Entry] = deque()
+        # speculative dispatches issued, and those of them thrown away
+        # unresolved: dispatched - discarded - len(self) decisions resolved
+        self.dispatched = 0
+        self.discarded = 0
 
     def __len__(self) -> int:
         return len(self._entries)
 
-    def discard(self) -> None:
-        """Drop all speculative in-flight work (failed resolve, stale
-        inputs). Already-issued device work is simply never fetched."""
+    def discard(self, reason: str = "pool") -> None:
+        """Drop all speculative in-flight work. Already-issued device work
+        is simply never fetched. `reason` names why, on the
+        fastsync.discard mark: "valset" (the validator set changed under
+        the entries), "pool" (the pool's blocks changed, or the sync was
+        re-armed), "error" (the head's commit was invalid)."""
+        if self._entries:
+            self.discarded += len(self._entries)
+            if _trace.ENABLED:
+                _trace.current().mark("fastsync.discard",
+                                      entries=len(self._entries),
+                                      reason=reason,
+                                      height=self._entries[0].height)
         self._entries.clear()
 
     # --- dispatch ----------------------------------------------------------
@@ -180,6 +194,7 @@ class VerifyAheadPipeline:
             if e is None:
                 return
             self._entries.append(e)
+            self.dispatched += 1
             want += 1
 
     # --- the one step both reactors call -----------------------------------
@@ -209,9 +224,11 @@ class VerifyAheadPipeline:
             # be re-dispatched, never resolved.
             first, second = pool.peek_two_blocks()
             if (head.height != pool.height
-                    or first is not head.first or second is not head.second
-                    or head.vals_hash != reactor.state.validators.hash()):
-                self.discard()
+                    or first is not head.first or second is not head.second):
+                self.discard("pool")
+                continue
+            if head.vals_hash != reactor.state.validators.hash():
+                self.discard("valset")
                 continue
             break
         else:
@@ -233,7 +250,7 @@ class VerifyAheadPipeline:
                          if e.pending.pending is not None])
                 head.pending.resolve()
         except Exception as e:  # noqa: BLE001 - the serial invalid-block path
-            self.discard()
+            self.discard("error")
             reactor._punish_invalid(head.height, e)
             return False
         pool.pop_request()

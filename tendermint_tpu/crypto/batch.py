@@ -609,6 +609,22 @@ def warmup(sizes: tuple[int, ...] = (64,), background: bool = True):
     return None
 
 
+def forget_keys() -> None:
+    """Forget every key the device holds tables for: both key types' per-key
+    table and their key-sequence memo, each emptied under its lock. The next
+    verify builds the tables of its keys again, as the first verify of a
+    process that has just started does; a dispatch in flight keeps the
+    KeySet it took (ops/ed25519_batch.KeyTable.clear). For callers that
+    start a session over in one process: a sync from genesis after a reset,
+    a benchmark pass, a test."""
+    from tendermint_tpu.ops import ed25519_batch, sr25519_batch
+
+    for mod in (ed25519_batch, sr25519_batch):
+        with mod._KS_LOCK:
+            mod._KS_CACHE.clear()
+            mod._KS_UNIQ_CACHE.clear()
+
+
 _BATCH_TYPES: dict[str, type] = {}
 
 

@@ -430,7 +430,8 @@ class KeyTable(dict):
         _trace.STARTUP.record("startup.key_decode", t1 - t0, start=t0,
                               keys=len(new), kind=kind)
         _trace.STARTUP.record("startup.table_build", t2 - t1, start=t1,
-                              keys=len(new), kind=kind)
+                              keys=len(new), kind=kind,
+                              rows=_round_up(len(new), KEY_TILE))
         return len(new)
 
 

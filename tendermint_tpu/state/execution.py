@@ -318,25 +318,38 @@ class BlockExecutor:
         from tendermint_tpu.utils import metrics as tmmetrics
 
         _started = _t.monotonic()
-        self.validate_block(state, block, commit_pending=commit_pending)
+        # the four phases of an apply, as spans of the active tracer
+        # (docs/OBSERVABILITY.md); one attribute load each while tracing is off
+        tr = _trace.current() if _trace.ENABLED else None
+        with tr.span("apply.validate") if tr else _trace.NULL_SPAN:
+            self.validate_block(state, block, commit_pending=commit_pending)
 
-        abci_responses = self._exec_block_on_app(state, block)
-        self.store.save_abci_responses(block.header.height, abci_responses)
+        with tr.span("apply.exec") if tr else _trace.NULL_SPAN:
+            abci_responses = self._exec_block_on_app(state, block)
+            self.store.save_abci_responses(block.header.height, abci_responses)
 
-        end = abci_responses.end_block
-        validate_validator_updates(end.validator_updates, state.consensus_params)
-        validator_updates = validator_updates_from_abci(end.validator_updates)
+        with tr.span("apply.update_state") if tr else _trace.NULL_SPAN:
+            end = abci_responses.end_block
+            validate_validator_updates(end.validator_updates, state.consensus_params)
+            validator_updates = validator_updates_from_abci(end.validator_updates)
+            new_state = update_state(state, block_id, block, abci_responses,
+                                     validator_updates)
+            if tr and validator_updates:
+                joined = sum(1 for v in validator_updates if v.voting_power
+                             and not state.next_validators.has_address(v.address))
+                tr.annotate(updates=len(validator_updates), joined=joined,
+                            left=sum(1 for v in validator_updates
+                                     if not v.voting_power))
 
-        new_state = update_state(state, block_id, block, abci_responses, validator_updates)
+        with tr.span("apply.save") if tr else _trace.NULL_SPAN:
+            # Lock mempool, commit app state, update mempool (reference:
+            # state/execution.go:211-257).
+            app_hash, retain_height = self._commit(new_state, block, abci_responses)
+            if self.evidence_pool is not None:
+                self.evidence_pool.update(new_state, block.evidence)
 
-        # Lock mempool, commit app state, update mempool (reference:
-        # state/execution.go:211-257).
-        app_hash, retain_height = self._commit(new_state, block, abci_responses)
-        if self.evidence_pool is not None:
-            self.evidence_pool.update(new_state, block.evidence)
-
-        new_state = replace(new_state, app_hash=app_hash)
-        self.store.save(new_state)
+            new_state = replace(new_state, app_hash=app_hash)
+            self.store.save(new_state)
 
         # Post-commit work is off the critical path: apply_block returns
         # as soon as state is durably saved; the single FIFO worker keeps

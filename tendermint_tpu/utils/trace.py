@@ -118,7 +118,8 @@ CANONICAL_SPANS = {
     # the start-up ring (STARTUP): cold paths, recorded with tracing off too
     "startup.key_decode": "Python decompression of keys the table did not hold",
     "startup.table_build": "device build of those keys' comb tables, tile by "
-                           "tile, until the last is ready",
+                           "tile, until the last is ready (tags keys, rows "
+                           "= the tile rows built for them)",
     "startup.jit_trace": "jax traced a function and lowered it to MLIR",
     "startup.jit_compile": "backend compile, or its load from the cache",
     "startup.cache_load": "persistent compile-cache retrieval (inside "
@@ -128,6 +129,9 @@ CANONICAL_SPANS = {
     "fastsync.dispatch": "speculative commit-verify dispatch for one height",
     "fastsync.head_wait": "the head block's wait: batched prefetch + resolve",
     "fastsync.apply": "block save + ABCI apply of a fast-synced height",
+    "fastsync.discard": "speculative dispatches thrown away unresolved "
+                        "(mark; tags entries, reason = valset / pool / "
+                        "error, height of the first)",
     # tx front door + gossip plane
     "mempool.check_tx": "ABCI CheckTx round trip of one tx",
     "mempool.ingest_batch": "one batched ABCI CheckTxBatch dispatch of the "
@@ -145,6 +149,16 @@ CANONICAL_SPANS = {
                           "(span; n= txs)",
     "apply.post_commit": "post-commit event publish of one height on the "
                          "async worker (span; height= tag)",
+    # the four phases of BlockExecutor.apply_block, in order
+    "apply.validate": "validate_block: header against state, LastCommit's "
+                      "full verify_commit (or its resolve), block time (span)",
+    "apply.exec": "BeginBlock, DeliverTx*, EndBlock on the app and the "
+                  "ABCI responses' save (span)",
+    "apply.update_state": "validator updates checked and decoded, "
+                          "update_state (span; tags updates, joined, left "
+                          "where EndBlock changed the set)",
+    "apply.save": "app Commit, mempool and evidence update, the state "
+                  "store's save (span)",
     # self-healing storage plane (store/scrub.py, store/repair.py)
     "store.scrub": "one integrity-scrub pass over a node's stores (span)",
     "store.repair": "peer re-fetch + batch-verified rewrite of one damaged "

@@ -245,16 +245,207 @@ def test_validator_set_change_discards_speculation(chain, monkeypatch):
 
     ctx.block_exec = _RotatingExec()
     pipe = bpipe.VerifyAheadPipeline()
-    discards = {"n": 0}
-    orig_discard = pipe.discard
-
-    def spy_discard():
-        discards["n"] += 1
-        orig_discard()
-
-    pipe.discard = spy_discard
     while pipe.process_next(ctx):
         pass
-    assert discards["n"] >= 1, "stale-valset speculation was never discarded"
+    assert pipe.discarded == 4, "stale-valset speculation was never discarded"
+    assert pipe.dispatched - pipe.discarded == N_BLOCKS - 1
     assert ctx.applied == list(range(1, N_BLOCKS)) and not ctx.punished
     assert ctx.app_hash == ref.app_hash
+
+
+# --- a validator set that changes through the chain's own transactions --------
+
+
+def _churn_chain(n_vals=24, n_blocks=14):
+    """A chain built by a source BlockExecutor over the kvstore whose blocks
+    carry real ``val:`` updates: block 3 a join and a leave, block 7 a
+    re-weighting of two sitting validators that reorders the top of the set.
+    Every height is signed by the set in force there (updates of H at H+2).
+    -> (genesis doc, blocks, {height: validators hash}, keys by address)."""
+    from tendermint_tpu.abci.kvstore import KVStoreApplication
+    from tendermint_tpu.state.execution import BlockExecutor
+    from tendermint_tpu.state.state import make_genesis_state
+    from tendermint_tpu.state.store import StateStore
+    from tendermint_tpu.store.db import MemDB
+    from tendermint_tpu.types.genesis import GenesisDoc, GenesisValidator
+
+    privs = [ed25519.gen_priv_key(bytes([40 + i]) * 32) for i in range(n_vals + 1)]
+    joiner = privs.pop()
+    gd = GenesisDoc(chain_id="pipe-churn", genesis_time=Time(1700000000, 0),
+                    validators=[GenesisValidator(b"", p.pub_key(), 100 - i)
+                                for i, p in enumerate(privs)])
+    by_addr = {p.pub_key().address(): p for p in privs + [joiner]}
+    state = make_genesis_state(gd)
+    top, second, last = (state.validators.validators[i].pub_key.bytes()
+                         for i in (0, 1, -1))
+    val_tx = KVStoreApplication.make_val_tx
+    txs_at = {3: [val_tx(joiner.pub_key().bytes(), 150), val_tx(last, 0)],
+              7: [val_tx(top, 20), val_tx(second, 200)]}
+    store = StateStore(MemDB())
+    store.save(state)
+    bx = BlockExecutor(store, KVStoreApplication())
+    last_commit = Commit(height=0, round=0, block_id=BlockID(), signatures=[])
+    blocks, hashes = [], {}
+    for h in range(1, n_blocks + 1):
+        block = state.make_block(h, txs_at.get(h, []), last_commit, [],
+                                 state.validators.get_proposer().address)
+        bid = BlockID(hash=block.hash(),
+                      part_set_header=PartSet.from_data(block.marshal()).header())
+        last_commit = _signed_commit(state.validators, by_addr, state.chain_id,
+                                     h, bid)
+        hashes[h] = state.validators.hash()
+        state, _ = bx.apply_block(state, bid, block)
+        blocks.append(block)
+    bx.stop()
+    return gd, blocks, hashes, by_addr
+
+
+def _signed_commit(vals, by_addr, chain_id, height, bid):
+    sigs = []
+    for i, val in enumerate(vals.validators):
+        v = Vote(type=PRECOMMIT_TYPE, height=height, round=0, block_id=bid,
+                 timestamp=Time(1700000000 + height, 1000 * i),
+                 validator_address=val.address, validator_index=i)
+        sigs.append(CommitSig(BLOCK_ID_FLAG_COMMIT, val.address, v.timestamp,
+                              by_addr[val.address].sign(v.sign_bytes(chain_id))))
+    return Commit(height=height, round=0, block_id=bid, signatures=sigs)
+
+
+def _sync_node(gd, blocks):
+    from tendermint_tpu.abci.kvstore import KVStoreApplication
+    from tendermint_tpu.blockchain.reactor import BlockchainReactor
+    from tendermint_tpu.state.execution import BlockExecutor
+    from tendermint_tpu.state.state import make_genesis_state
+    from tendermint_tpu.state.store import StateStore
+    from tendermint_tpu.store.block_store import BlockStore
+    from tendermint_tpu.store.db import MemDB
+
+    state = make_genesis_state(gd)
+    store = StateStore(MemDB())
+    store.save(state)
+    bs = BlockStore(MemDB())
+    reactor = BlockchainReactor(state, BlockExecutor(store, KVStoreApplication()),
+                                bs, fast_sync=True)
+    for i, b in enumerate(blocks):
+        reactor.pool.add_block("pA" if i % 2 == 0 else "pB",
+                               Block.unmarshal(b.marshal()))
+    return reactor
+
+
+@pytest.fixture(scope="module")
+def churn_chain():
+    return _churn_chain()
+
+
+def test_real_val_updates_through_the_reactor_depths_agree(churn_chain,
+                                                           monkeypatch):
+    """The real v0 reactor and BlockExecutor over a chain whose set changes
+    twice through ``val:`` transactions: depth 1 and depth 4 apply every
+    block and reach the same state, app hash and validator sets, every
+    stored header naming the set the chain's own updates define. Each change
+    (in force at H+2: heights 5 and 9) throws away what was in flight, one
+    dispatch at depth 1 (it tops its window up before the apply too) and
+    four at depth 4, and every one is dispatched again."""
+    gd, blocks, hashes, _ = churn_chain
+    assert hashes[4] != hashes[5] and hashes[8] != hashes[9]
+    assert len({hashes[h] for h in (1, 5, 9)}) == 3
+    seen = {}
+    for depth in (1, 4):
+        monkeypatch.setenv("TM_TPU_VERIFY_AHEAD", str(depth))
+        reactor = _sync_node(gd, blocks)
+        pipe = bpipe.VerifyAheadPipeline()
+        applied = 0
+        while pipe.process_next(reactor):
+            applied += 1
+        assert applied == len(blocks) - 1 == reactor.block_store.height
+        for h in range(1, applied + 1):
+            header = reactor.block_store.load_block_meta(h).header
+            assert header.validators_hash == hashes[h], (depth, h)
+        st = reactor.state
+        assert st.last_height_validators_changed == 9
+        assert pipe.dispatched - pipe.discarded == applied and len(pipe) == 0
+        assert pipe.discarded == {1: 2, 4: 8}[depth]
+        seen[depth] = (st.app_hash, st.validators.hash(),
+                       st.next_validators.hash(), st.last_validators.hash(),
+                       [(v.address, v.voting_power)
+                        for v in st.validators.validators])
+    assert seen[1] == seen[4]
+    assert seen[4][1] == hashes[len(blocks)]
+
+
+@pytest.mark.parametrize("depth", [1, 4])
+def test_a_commit_signed_by_the_previous_set_is_rejected_at_the_change(
+        churn_chain, depth, monkeypatch):
+    """Height 9 is the first of the re-weighted set (the top two places
+    swap). A commit for it signed by the set of height 8, slot for slot,
+    verified under the old set as the speculation dispatched before the
+    change did: the pipeline must discard that speculation and reject the
+    block at height 9, at the first slot whose key changed, punishing both
+    senders (guarantee: a verification made against a set that is no longer
+    the height's is never resolved)."""
+    from tendermint_tpu.types.validator_set import ErrWrongSignature
+
+    gd, blocks, _hashes, by_addr = churn_chain
+    monkeypatch.setenv("TM_TPU_VERIFY_AHEAD", str(depth))
+    # the set of height 8, from a node of its own synced that far
+    probe = _sync_node(gd, blocks[:9])
+    pipe = bpipe.VerifyAheadPipeline()
+    while pipe.process_next(probe):
+        pass
+    assert probe.state.last_block_height == 8
+    old, new = probe.state.last_validators, probe.state.validators
+    first_moved = next(i for i, (a, b) in enumerate(
+        zip(old.validators, new.validators)) if a.address != b.address)
+    carrier = Block.unmarshal(blocks[9].marshal())          # block 10
+    carrier.last_commit = _signed_commit(
+        old, by_addr, gd.chain_id, 9, carrier.last_commit.block_id)
+    old.verify_commit_light(gd.chain_id, carrier.last_commit.block_id, 9,
+                            carrier.last_commit)             # sound under old
+    reactor = _sync_node(gd, blocks[:9] + [carrier] + blocks[10:])
+    rejected = []
+    real = reactor._punish_invalid
+    reactor._punish_invalid = lambda h, e: (rejected.append((h, e)), real(h, e))
+    pipe = bpipe.VerifyAheadPipeline()
+    applied = 0
+    while pipe.process_next(reactor):
+        applied += 1
+    assert applied == 8 and reactor.state.last_block_height == 8
+    (height, err), = rejected
+    assert height == 9 and isinstance(err, ErrWrongSignature)
+    assert err.index == first_moved == 0
+    assert not reactor.pool.blocks          # both peers' blocks were dropped
+
+
+def test_a_change_of_the_set_is_marked_and_the_apply_is_timed_in_phases(
+        churn_chain, monkeypatch):
+    """Under a tracer the same sync writes one fastsync.discard mark per
+    change (entries, reason=valset, the height of the first entry thrown
+    away) and the four phases of every apply, apply.update_state tagged with
+    what EndBlock changed (docs/OBSERVABILITY.md)."""
+    from tendermint_tpu.utils import trace
+
+    gd, blocks, _hashes, _ = churn_chain
+    monkeypatch.setenv("TM_TPU_VERIFY_AHEAD", "4")
+    reactor = _sync_node(gd, blocks)
+    reactor.tracer = trace.Tracer("churn", cap=1024, enabled=True)
+    pipe = bpipe.VerifyAheadPipeline()
+    try:
+        while pipe.process_next(reactor):
+            pass
+    finally:
+        reactor.tracer.disable()
+    spans = reactor.tracer.dump()
+    marks = [s.tags for s in spans if s.name == "fastsync.discard"]
+    assert [(t["entries"], t["reason"], t["height"]) for t in marks] == [
+        (4, "valset", 5), (4, "valset", 9)]
+    assert sum(t["entries"] for t in marks) == pipe.discarded
+    applied = len(blocks) - 1
+    phases = ("apply.validate", "apply.exec", "apply.update_state", "apply.save")
+    for name in phases:
+        assert sum(1 for s in spans if s.name == name) == applied, name
+    whole = sum(s.duration_s for s in spans if s.name == "fastsync.apply")
+    assert sum(s.duration_s for s in spans if s.name in phases) <= whole
+    changed = {s.tags["height"]: s.tags for s in spans
+               if s.name == "apply.update_state" and "updates" in s.tags}
+    assert {h: (t["updates"], t["joined"], t["left"])
+            for h, t in changed.items()} == {3: (2, 1, 1), 7: (2, 0, 0)}

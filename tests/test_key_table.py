@@ -213,7 +213,34 @@ def test_one_new_key_among_resident_ones_builds_one_tile(kind, builds, tracer):
     assert kind.mod._KS_UNIQ_CACHE.keyset is ks
     assert kind.mod._KS_UNIQ_CACHE[items[7][0]] == 7
     ring = [s for s in trace.STARTUP.dump() if s.name == "startup.table_build"]
-    assert ring[-1].tags == {"keys": 1, "kind": kind.name}
+    assert ring[-1].tags == {"keys": 1, "kind": kind.name,
+                             "rows": edb.KEY_TILE}
+
+
+def test_forget_keys_makes_the_next_verify_build_its_keys_again(kind, builds,
+                                                                tracer):
+    """crypto.batch.forget_keys is the public way to start a session as a
+    process that has just started does: both key types' tables and
+    sequence memos are emptied under their locks, a dispatch that still
+    holds the old KeySet keeps it, and the next verify of the same keys
+    builds them again and answers as before."""
+    from tendermint_tpu.crypto import batch as crypto_batch
+
+    items = _items(kind, 5)
+    _check(kind, items)
+    table = kind.mod._KS_UNIQ_CACHE
+    ks = table.keyset
+    del builds[:]
+    _check(kind, items)
+    assert builds == [] and _lookups(tracer)[-1]["hit"] == "sequence"
+    crypto_batch.forget_keys()
+    for mod in (edb, srb):
+        assert len(mod._KS_UNIQ_CACHE) == 0 and not mod._KS_CACHE
+    assert table.keyset is not ks and ks.n_rows == 5
+    assert _check(kind, items + [_corrupt(items[0])]).sum() == 5
+    assert builds == [edb.KEY_TILE]
+    tags = _lookups(tracer)[-1]
+    assert (tags["hit"], tags["built"], tags["resident"]) == ("miss", 5, 5)
 
 
 # --- (d) a key that is no curve point ----------------------------------------------
