@@ -322,17 +322,22 @@ def phase_commit(seed: int, n_vals: int = N_VALIDATORS, on_chip: bool = True,
         sharded = batch_shard.should_shard(len(raw))
         dev, finish = edb.dispatch_batch(raw)
         check(dev is not None, "ops dispatch_batch answered from the host")
-        devs = dev.devices()
+        # the packed pieces of the bitmap: one on a one-chip host, a chunk's
+        # a piece, each on the chip that computed it, on the sharded route
+        devs = set().union(*(p.devices() for p in dev))
         check({d.platform for d in devs} == {"tpu"},
               f"device output lives on {devs}")
         got = finish(jax.device_get(dev))
         check(np.array_equal(got, np.array(bitmap)),
               "direct dispatch bitmap != registry bitmap")
-        out["route"] = ("shard_map over %d devices, jnp _verify_kernel"
+        out["route"] = ("pallas _verify_chunk, a chunk a device over %d"
                         % len(devs) if sharded else "pallas _verify_chunk")
         out["output_devices"] = len(devs)
-        check(len(devs) == (jax.device_count() if sharded else 1),
-              f"output on {len(devs)} devices, have {jax.device_count()}")
+        want_devs = (min(len(dev), jax.local_device_count()) if sharded else 1)
+        check(len(devs) == want_devs and finish.route == (
+            "sharded" if sharded else "pallas"),
+              f"route {finish.route}: {len(dev)} pieces on {len(devs)} "
+              f"devices, have {jax.device_count()}")
         check(edb._use_pallas(), "_use_pallas() is false on a TPU backend")
         out["pallas_lowering"] = _pallas_lowering()
         check(svc.launches > launches0, "verify service launched nothing")

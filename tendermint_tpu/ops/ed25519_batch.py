@@ -276,9 +276,10 @@ def _read_tile(tab, row0):
     return jax.lax.dynamic_slice_in_dim(tab, row0, KEY_TILE, axis=0)
 
 
-def _grown(tab, rows: int, row_shape: tuple):
-    """A zeroed (rows, *row_shape) table holding tab's rows (tab may be None)."""
-    new = jnp.zeros((rows,) + row_shape, jnp.int32)
+def _grown(tab, rows: int, row_shape: tuple, device=None):
+    """A zeroed (rows, *row_shape) table holding tab's rows (tab may be
+    None), on `device` (None: where unplaced arrays go)."""
+    new = jnp.zeros((rows,) + row_shape, jnp.int32, device=device)
     return new if tab is None else _write_rows(new, tab, 0)
 
 
@@ -293,12 +294,16 @@ class KeySet:
     The device arrays are (capacity, 16, 4, 20) extended points and, once the
     Pallas route has asked for them, (capacity, 960) niels rows; capacity is
     KEY_TILE times a power of two, so the gathers below compile for few
-    shapes. A built tile is written in place (the array is donated), which
-    deletes the array object that was current before: every read of the
-    arrays is therefore enqueued under `_lock`, the lock the writes take."""
+    shapes. Each further device that launches chunks (gathered_lane with a
+    device: the sharded row on a TPU host, parallel/batch_shard) holds a copy
+    of the niels rows, made on its first launch and written tile by tile
+    from then on. A built tile is written in place (the array is donated),
+    which deletes the array object that was current before: every read of
+    the arrays is therefore enqueued under `_lock`, the lock the writes
+    take."""
 
     __slots__ = ("n_rows", "valid", "_lock", "_tab_ext", "_niels",
-                 "_replicated")
+                 "_niels_on", "_replicated")
 
     def __init__(self):
         self.n_rows = 0
@@ -308,6 +313,7 @@ class KeySet:
         self._lock = threading.Lock()
         self._tab_ext = None
         self._niels = None
+        self._niels_on: dict = {}  # device -> that device's copy of _niels
         self._replicated = None
 
     def append(self, a_neg: np.ndarray, valid: np.ndarray) -> None:
@@ -325,8 +331,11 @@ class KeySet:
             with self._lock:
                 self._tab_ext = _write_rows(self._tab_ext, tile, self.n_rows)
                 if self._niels is not None:
-                    self._niels = _write_rows(self._niels, _to_niels(tile),
-                                              self.n_rows)
+                    rows = _to_niels(tile)
+                    self._niels = _write_rows(self._niels, rows, self.n_rows)
+                    for d, copy in list(self._niels_on.items()):
+                        self._niels_on[d] = _write_rows(
+                            copy, jax.device_put(rows, d), self.n_rows)
                 self.n_rows += kt
         self._tab_ext.block_until_ready()
 
@@ -344,16 +353,21 @@ class KeySet:
             self._tab_ext = _grown(self._tab_ext, new, (16, 4, 20))
             if self._niels is not None:
                 self._niels = _grown(self._niels, new, (960,))
+                for d, copy in list(self._niels_on.items()):
+                    self._niels_on[d] = _grown(copy, new, (960,), d)
 
     def take(self, idx: np.ndarray):
         """(nb,) row numbers -> (nb, 16, 4, 20) per-item extended tables."""
         with self._lock:
             return jnp.take(self._tab_ext, jnp.asarray(idx), axis=0)
 
-    def gathered_lane(self, idx: np.ndarray):
+    def gathered_lane(self, idx: np.ndarray, device=None):
         """(nb,) row numbers -> (960, nb) lane-major niels tables for the
-        Pallas kernel. The first call converts the rows built so far, tile
-        by tile; from then on append converts each tile it builds."""
+        Pallas kernel, on `device` (None: where unplaced arrays go, the one
+        device of a one-chip host). The first call converts the rows built
+        so far, tile by tile; from then on append converts each tile it
+        builds. A device's first call copies the niels rows to it once;
+        append keeps the copy current a tile at a time."""
         with self._lock:
             if self._niels is None:
                 niels = jnp.zeros((self.valid.shape[0], 960), jnp.int32)
@@ -361,7 +375,13 @@ class KeySet:
                     niels = _write_rows(
                         niels, _to_niels(_read_tile(self._tab_ext, o)), o)
                 self._niels = niels
-            return _gather_transpose(self._niels, jnp.asarray(idx))
+            if device is None:
+                return _gather_transpose(self._niels, jnp.asarray(idx))
+            tab = self._niels_on.get(device)
+            if tab is None:
+                tab = self._niels_on[device] = jax.device_put(
+                    self._niels, device)
+            return _gather_transpose(tab, jax.device_put(idx, device))
 
     def replicated(self, mesh_key: tuple, sharding):
         """The extended table on every device of a mesh, copied once per
@@ -680,7 +700,10 @@ def route_batch(n: int, force_device: bool = False, scalar_min: int = 0) -> str:
                  for a handful of signatures, and on a cold process it would
                  pay an XLA compile. scalar_min is the registry's per-kind
                  batch_min; direct callers of dispatch_batch pass 0.
-      "sharded"  batch_shard.should_shard(n): shard_map over the local mesh.
+      "sharded"  batch_shard.should_shard(n): every local device works. On a
+                 TPU backend, from more than one Pallas chunk upward, the
+                 chunks of the one-chip kernel placed one a device; on any
+                 other backend shard_map of the jnp kernel over the mesh.
       "host"     not forced, n < host_crossover(), C library loaded or
                  building: a kernel flush loses to the CPU there, the sync
                  floor alone exceeds the C verifier's whole runtime. While
@@ -804,18 +827,29 @@ def _host_fallback(items, n, route: str | None = None):
     return None, _cbreaker.routed(lambda _unused: bitmap, route)
 
 
-def launch_span(program: str, route: str, left: int, lanes: int):
+def launch_span(program: str, route: str, left: int, lanes: int,
+                device=None):
     """prep.launch around the host's enqueue of ONE device program: `left`
     real signatures were still to launch, `lanes` is what the call holds,
-    so sigs over lanes is the share of launched lanes that did work."""
-    return (_trace.current().span("prep.launch", program=program, route=route,
-                                  sigs=max(0, min(left, lanes)), lanes=lanes)
-            if _trace.ENABLED else _trace.NULL_SPAN)
+    so sigs over lanes is the share of launched lanes that did work.
+    `device` is where the program was placed: a local device, None for where
+    unplaced arrays go (the first), "mesh" for shard_map over all of them."""
+    if not _trace.ENABLED:
+        return _trace.NULL_SPAN
+    if device is None:
+        device = jax.local_devices()[0]
+    return _trace.current().span(
+        "prep.launch", program=program, route=route,
+        sigs=max(0, min(left, lanes)), lanes=lanes,
+        device=getattr(device, "id", device))
 
 
 def _dispatch_device(items, n: int, multichip: bool):
-    """The accelerator route proper: comb tables + Pallas / shard_map / jnp
-    kernel dispatch. Raises on device failure (injected or real); the
+    """The accelerator route proper: comb tables + the kernel launches. On a
+    TPU backend the Pallas chunks, on the one device or (`multichip`, the
+    "sharded" route) placed a chunk a local device; elsewhere the jnp
+    kernel, under shard_map or alone. Raises on device failure (injected or
+    real); the
     circuit breaker in dispatch_batch owns the fallback. The fault site
     fires in dispatch_batch, NOT here: the breaker probe also runs this
     function, and probe timing must never consume the deterministic
@@ -824,24 +858,24 @@ def _dispatch_device(items, n: int, multichip: bool):
     # Non-decompressable keys get an identity comb table; they must be
     # rejected here, exactly as the scalar path's _decompress(pub) is None.
     pub_ok = pub_ok & ks.valid[key_idx]
+    if _use_pallas():
+        # Prep is done chunk-by-chunk inside the pipelined path so device
+        # compute overlaps host prep of the next chunk; across chips the
+        # same loop puts chunk k on local device k mod ndev.
+        from tendermint_tpu.ops import ed25519_pallas
+
+        return ed25519_pallas.dispatch_chunks(
+            "ed25519", n,
+            functools.partial(ed25519_pallas.dispatch_items_pipelined,
+                              ks, key_idx, items, pub_ok),
+            multichip)
     if multichip:
-        # Multi-chip: shard the signature axis over the device mesh
-        # (BASELINE.json north_star: validator sets sharded across TPU
-        # cores, pass/fail bitmap all-reduced).
+        # No TPU backend: shard the signature axis over the device mesh
+        # (shard_map of the jnp kernel, parallel/batch_shard).
         dev = _batch_shard().dispatch_batch_sharded(ks, key_idx, items, pub_ok)
         _start_host_copy(dev)
         return dev, _cbreaker.routed(
             lambda v: np.asarray(v)[:n].astype(bool), "sharded")
-    if _use_pallas():
-        # Prep is done chunk-by-chunk inside the pipelined path so device
-        # compute overlaps host prep of the next chunk.
-        from tendermint_tpu.ops import ed25519_pallas
-
-        dev = ed25519_pallas.pack_bitmap(
-            ed25519_pallas.dispatch_items_pipelined(ks, key_idx, items, pub_ok))
-        _start_host_copy(dev)
-        return dev, _cbreaker.routed(
-            lambda v: ed25519_pallas.unpack_bitmap(np.asarray(v), n), "pallas")
     s = prepare_scalars(items, pub_ok, windows=True)
 
     # Fixed-tile chunking: every batch runs through the one JNP_TILE-shaped
@@ -922,11 +956,13 @@ def dispatch_batch(items: list[tuple[bytes, bytes, bytes]],
 def _start_host_copy(dev) -> None:
     """Begin the D2H transfer NOW, at dispatch, so the copy rides behind the
     kernel on the active stream and the later device_get finds the bytes
-    already on the host instead of starting a round trip of its own."""
-    try:
-        dev.copy_to_host_async()
-    except (AttributeError, RuntimeError):
-        pass
+    already on the host instead of starting a round trip of its own. `dev`
+    is an array or the pieces of one (a piece a device, each its own copy)."""
+    for piece in jax.tree_util.tree_leaves(dev):
+        try:
+            piece.copy_to_host_async()
+        except (AttributeError, RuntimeError):
+            pass
 
 
 def verify_batch(items: list[tuple[bytes, bytes, bytes]],

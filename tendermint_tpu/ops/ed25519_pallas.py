@@ -37,6 +37,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tendermint_tpu.crypto.sr25519 import SQRT_M1
+from tendermint_tpu.ops import breaker as _cbreaker
 from tendermint_tpu.ops import ed25519_batch as edb
 from tendermint_tpu.ops import edwards25519 as ed
 from tendermint_tpu.ops import field25519 as fe
@@ -650,27 +651,76 @@ def pad_cols(x: np.ndarray) -> np.ndarray:
     return out
 
 
-def dispatch_items_pipelined(ks, key_idx: np.ndarray, items, pub_ok):
-    """Chunk-pipelined dispatch: host prep of chunk i+1 overlaps device
-    compute of chunk i (dispatches are async). Returns the (1, Npad) int32
-    device array WITHOUT fetching -- callers batch the readback. On the
-    1-core host this hides min(prep, device) per chunk versus the
-    prep-everything-then-dispatch path."""
-    n = len(items)
+def launch_chunks(program: str, chunk_fn, ks, key_idx: np.ndarray, n: int,
+                  columns, devices=()):
+    """The chunk loop of both key types: CHUNK signatures at a time, the
+    chunk's four byte arrays (`columns(sl)`, host work that runs while the
+    device computes the chunk before) go up, its niels rows are gathered,
+    and the jitted `chunk_fn` (XLA module `program`) is enqueued. Nothing is
+    fetched: -> the packed pieces of the bitmap, a tuple that one
+    `device_get` brings back and :func:`unpack_pieces` joins in chunk order.
+
+    With `devices` (the local devices of the "sharded" route on a TPU host,
+    parallel/batch_shard) chunk k is placed on devices[k mod ndev], from
+    devices[0] in every call: its arrays are put there, the rows come from
+    that device's copy of the table, the same program runs there and packs
+    its own piece, so the chunks of a batch run on their chips at the same
+    time. devices[0] is where unplaced arrays go, so a chunk for it is
+    enqueued exactly as on a one-chip host. Without `devices` (or with one)
+    nothing is placed: one concatenate, one pack, as ever."""
+    place = len(devices) > 1
+    route = "sharded" if place else "pallas"
     outs = []
-    for off in range(0, n, CHUNK):
+    for k, off in enumerate(range(0, n, CHUNK)):
         sl = slice(off, min(off + CHUNK, n))
+        cols = columns(sl)
+        # None: where unplaced arrays go (devices[0]), no placement call
+        at = k % len(devices) if place else 0
+        device = devices[at] if at else None
+        put = (jnp.asarray if device is None
+               else functools.partial(jax.device_put, device=device))
+        with edb.launch_span(program, route, sl.stop - sl.start, CHUNK, device):
+            first = put(pad_cols(cols[0]))
+            tab = ks.gathered_lane(pad_rows(key_idx[sl]), device)
+            outs.append(chunk_fn(
+                tab, first, *(put(pad_cols(c)) for c in cols[1:])))
+    if place:
+        return tuple(pack_bitmap(o) for o in outs)
+    return (pack_bitmap(
+        outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)),)
+
+
+def unpack_pieces(fetched, n: int) -> np.ndarray:
+    """The fetched pieces of :func:`launch_chunks` -> (n,) bool."""
+    pieces = [np.asarray(p) for p in fetched]
+    return unpack_bitmap(
+        pieces[0] if len(pieces) == 1 else np.concatenate(pieces), n)
+
+
+def dispatch_chunks(kind: str, n: int, launch, multichip: bool):
+    """The (device_out, finish) of the dispatch contract for a key type's
+    chunk loop: `launch(devices=())` is :func:`launch_chunks` with all but
+    the devices bound. On the "sharded" route (`multichip`) it runs under
+    parallel/batch_shard.dispatch_placed, which hands it the local devices;
+    either way every piece's host copy starts now."""
+    dev = (edb._batch_shard().dispatch_placed(kind, n, launch)
+           if multichip else launch())
+    edb._start_host_copy(dev)
+    return dev, _cbreaker.routed(lambda v: unpack_pieces(v, n),
+                                 "sharded" if multichip else "pallas")
+
+
+def dispatch_items_pipelined(ks, key_idx: np.ndarray, items, pub_ok,
+                             devices=()):
+    """Chunk-pipelined ed25519 dispatch: host prep of chunk i+1 (the
+    hashing) overlaps device compute of chunk i (dispatches are async), on
+    one device or, with `devices`, a chunk a device. -> the packed pieces
+    of :func:`launch_chunks`, nothing fetched -- callers batch the readback."""
+
+    def columns(sl):
         s = edb.prepare_scalars(items[sl], pub_ok[sl], windows=False,
                                 reduce=False)
-        cn = sl.stop - sl.start
-        with edb.launch_span("jit__verify_chunk", "pallas", cn, CHUNK):
-            h64 = jnp.asarray(pad_cols(s["h64"]))
-            tab = ks.gathered_lane(pad_rows(key_idx[sl]))
-            outs.append(_verify_chunk(
-                tab,
-                h64,
-                jnp.asarray(pad_cols(s["s32"])),
-                jnp.asarray(pad_cols(s["r32"])),
-                jnp.asarray(pad_cols(s["valid"])),
-            ))
-    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+        return s["h64"], s["s32"], s["r32"], s["valid"]
+
+    return launch_chunks("jit__verify_chunk", _verify_chunk, ks, key_idx,
+                         len(items), columns, devices)

@@ -328,7 +328,7 @@ def _dispatch_device(items, n: int, multichip: bool = False):
     r_ok = _lt_p(r32) & ((r32[:, 0] & 1) == 0)
     valid = sig_ok & marker_ok & s_ok & r_ok & pub_ok
 
-    pallas = not multichip and edb._use_pallas()
+    pallas = edb._use_pallas()
     with _scalars_span(n):
         k32 = challenges([it[1] for it in items], pubs_arr, r32)
         if not pallas:  # the Pallas chunk cuts its windows on the device
@@ -336,33 +336,24 @@ def _dispatch_device(items, n: int, multichip: bool = False):
             s_win = sc.comb_windows(s32).astype(np.int32)
 
     if pallas:
-        # One fixed CHUNK-lane executable, fed like the ed25519 twin's
-        # (ed25519_pallas.dispatch_items_pipelined): raw bytes up, the
-        # per-key niels rows gathered by row number, a packed bitmap back.
+        # One fixed CHUNK-lane executable through the ed25519 twin's chunk
+        # loop (ed25519_pallas.launch_chunks): raw bytes up, the per-key
+        # niels rows gathered by row number, packed pieces of a bitmap back;
+        # on the "sharded" route a chunk a local device.
         from tendermint_tpu.ops import ed25519_pallas as edp
 
-        outs = []
-        for off in range(0, n, edp.CHUNK):
-            sl = slice(off, min(off + edp.CHUNK, n))
-            with edb.launch_span("jit__sr_verify_chunk", "pallas",
-                                 sl.stop - off, edp.CHUNK):
-                outs.append(edp._sr_verify_chunk(
-                    ks.gathered_lane(edp.pad_rows(key_idx[sl])),
-                    jnp.asarray(edp.pad_cols(k32[sl])),
-                    jnp.asarray(edp.pad_cols(s32[sl])),
-                    jnp.asarray(edp.pad_cols(r32[sl])),
-                    jnp.asarray(edp.pad_cols(valid[sl])),
-                ))
-        dev = edp.pack_bitmap(
-            outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1))
-        edb._start_host_copy(dev)
-        return dev, _cbreaker.routed(
-            lambda v: edp.unpack_bitmap(np.asarray(v), n), "pallas")
+        def launch(devices=()):
+            return edp.launch_chunks(
+                "jit__sr_verify_chunk", edp._sr_verify_chunk, ks, key_idx, n,
+                lambda sl: (k32[sl], s32[sl], r32[sl], valid[sl]), devices)
+
+        return edp.dispatch_chunks("sr25519", n, launch, multichip)
 
     r_limbs = _bytes_to_limbs(r32)
     if multichip:
-        # Multi-chip: the signature axis shards over the ("dp",) mesh, as
-        # the ed25519 twin's does (the key table replicates once per append).
+        # Several devices, no TPU backend: the signature axis shards over
+        # the ("dp",) mesh, as the ed25519 twin's does (the key table
+        # replicates once per append).
         dev = edb._batch_shard().dispatch_sharded(
             "sr25519", ks, key_idx, [k_win, s_win, r_limbs, valid], n)
         edb._start_host_copy(dev)

@@ -1,32 +1,50 @@
-"""Multi-chip sharding of the batch-verify + tally kernel.
+"""Multi-chip spreading of the batch-verify kernels.
 
 The reference's parallelism analogue (SURVEY.md section 2.3): inside one
 validator process, the signature batch for a commit is data-parallel over the
-validator axis. We shard that axis across TPU devices with shard_map over a
-1-D ("dp",) mesh; the per-device pass/fail bitmaps stay sharded and the
-voting-power tally is all-reduced over ICI with psum - the on-device analogue
-of the reference's libs/bits.BitArray + talliedVotingPower loop
-(types/validator_set.go:685-714).
+validator axis, and the lanes share nothing: the tally is the host's
+(types/validator_set.go:685-714's loop, our commit.tally). Two ways to put
+that axis on the local devices, chosen by what the process observes:
+
+  TPU backend    :func:`dispatch_placed`: the chunk loop of the one-chip path
+                 (ops/ed25519_pallas.launch_chunks) with the local devices to
+                 place on. Chunk k of a batch -- 4,096 lanes of the Pallas
+                 kernel -- runs on local device k mod ndev, which holds its
+                 own copy of the key table (KeySet.gathered_lane) and packs
+                 its own piece of the bitmap; the pieces come back in the
+                 caller's one device_get. The same jitted programs as on one
+                 chip, compiled once a device; no collective.
+  other backends :func:`dispatch_sharded`: shard_map of the jnp kernels over
+                 a 1-D ("dp",) mesh in n_devices * JNP_TILE chunks, the key
+                 table replicated. (The CPU mesh of the tests and of
+                 __graft_entry__.dryrun_multichip; :func:`sharded_verify_tally`
+                 is the same body with the voting-power tally all-reduced by
+                 psum, the on-device analogue of libs/bits.BitArray +
+                 talliedVotingPower.)
 
 Production routing (docs/PARALLEL.md): ops/ed25519_batch.route_batch, the one
 routing decision of the verify path, asks :func:`should_shard` for both key
 types, so every caller of the BatchVerifier registry -- verify_commit_async,
 the fast-sync verify-ahead pipeline, the consensus vote drain, light
-range_verify -- gets multi-device sharding transparently through the deferred
-dispatch()/PendingVerify contract. With the continuous-batching verify
+range_verify -- reaches the other devices transparently through the deferred
+dispatch()/PendingVerify contract, from the floor of :func:`shard_threshold`
+upward. With the continuous-batching verify
 service on (crypto/verify_service.py, the default), the size
 :func:`should_shard` sees is the COALESCED generation -- several callers'
 concurrent dispatches merged into one launch -- so multi-caller traffic
 crosses the sharding threshold sooner than any single caller would. Knobs:
 
   TM_TPU_SHARD=0       opt out of sharding entirely (single-device paths)
-  TM_TPU_SHARD_MIN=N   batch-size floor for the sharded route (default
-                       n_devices * MIN_BUCKET: below one kernel bucket per
-                       device the fan-out cannot pay for itself)
+  TM_TPU_SHARD_MIN=N   batch-size floor for the sharded route (default: on a
+                       TPU backend one Pallas chunk plus one, since a single
+                       chunk has nothing to spread; elsewhere n_devices *
+                       MIN_BUCKET, below one kernel bucket per device the
+                       fan-out cannot pay for itself)
 """
 
 from __future__ import annotations
 
+import contextlib
 import os
 
 import jax
@@ -47,13 +65,23 @@ def shard_enabled() -> bool:
 
 
 def shard_threshold(ndev: int) -> int:
-    """Batch-size floor for the sharded route. Default: one kernel MIN_BUCKET
-    per device -- smaller batches cannot fill the mesh, and the per-device
-    dispatch overhead would exceed the fan-out win."""
+    """Batch-size floor for the sharded route. On a TPU backend: more than
+    one Pallas chunk, the unit that is placed on a device -- a batch of one
+    chunk or less runs as on a one-chip host. Elsewhere one kernel
+    MIN_BUCKET per device -- smaller batches cannot fill the mesh, and the
+    per-device dispatch overhead would exceed the fan-out win."""
     v = os.environ.get("TM_TPU_SHARD_MIN")
     if v:
         return int(v)
+    if ed25519_batch._use_pallas():
+        return _pallas_chunk() + 1
     return ndev * ed25519_batch.MIN_BUCKET
+
+
+def _pallas_chunk() -> int:
+    from tendermint_tpu.ops import ed25519_pallas
+
+    return ed25519_pallas.CHUNK
 
 
 def should_shard(n: int) -> bool:
@@ -109,7 +137,7 @@ def shard_args(mesh: Mesh, args: dict, power, for_block):
 
 
 # ---------------------------------------------------------------------------
-# Production path: Ed25519BatchVerifier routes here when >1 device
+# Production path: the "sharded" route of both ops dispatch_batch
 # ---------------------------------------------------------------------------
 
 _mesh_cache: tuple[tuple, Mesh] | None = None
@@ -175,17 +203,37 @@ def replicated_tables(ks, mesh: Mesh):
                          NamedSharding(mesh, P()))
 
 
-def _count_sharded_dispatch(ndev: int) -> None:
+@contextlib.contextmanager
+def _shard_dispatch(kind: str, n: int, chunks: int, devices: int):
+    """A sharded dispatch: the verify.shard_dispatch span around it and one
+    count on verify_sharded_total after it, `chunks` launches over
+    `devices` devices."""
     from tendermint_tpu.utils import metrics as tmmetrics
+    from tendermint_tpu.utils import trace as _trace
 
+    with (_trace.current().span("verify.shard_dispatch", kind=kind, n=n,
+                                chunks=chunks, devices=devices)
+          if _trace.ENABLED else _trace.NULL_SPAN):
+        yield
     if tmmetrics.GLOBAL_NODE_METRICS is not None:
-        tmmetrics.GLOBAL_NODE_METRICS.verify_sharded.add(devices=ndev)
+        tmmetrics.GLOBAL_NODE_METRICS.verify_sharded.add(devices=devices)
+
+
+def dispatch_placed(kind: str, n: int, launch):
+    """The sharded route on a TPU backend: `launch(devices)` is a key type's
+    chunk loop (ops/ed25519_pallas.launch_chunks) taking the devices to
+    place on, all the local ones, chunk k on device k mod ndev. -> what it
+    returns, the packed pieces of the bitmap, nothing fetched; bit for bit
+    the one-chip path's."""
+    devices = tuple(jax.local_devices())
+    chunks = -(-n // _pallas_chunk())
+    with _shard_dispatch(kind, n, chunks, min(chunks, len(devices))):
+        return launch(devices)
 
 
 def dispatch_sharded(kind: str, ks, key_idx, arrays: list, n: int):
-    """Generic multi-device production dispatch: the signature axis shards
-    over the ("dp",) mesh (the north-star sentence: validator sets sharded
-    across TPU cores, pass/fail bitmap all-reduced). Dispatches in fixed
+    """The sharded route off a TPU backend: the signature axis shards over
+    the ("dp",) mesh under shard_map. Dispatches in fixed
     n_devices*JNP_TILE chunks so no batch size triggers a fresh compile;
     padding lanes carry valid=False (every kernel masks its result with
     `valid`, so they can never read as accepted) and key index 0.
@@ -195,24 +243,16 @@ def dispatch_sharded(kind: str, ks, key_idx, arrays: list, n: int):
     r_limbs, valid). Returns the (Npad,) bool device array without fetching
     (callers batch the readback); the bitmap is byte-identical to the
     single-device path."""
-    from tendermint_tpu.utils import trace as _trace
-
-    if _trace.ENABLED:
-        tr = _trace.current()
-        if tr.enabled:
-            with tr.span("verify.shard_dispatch", kind=kind, n=n):
-                return _dispatch_sharded(kind, ks, key_idx, arrays, n)
-    return _dispatch_sharded(kind, ks, key_idx, arrays, n)
-
-
-def _dispatch_sharded(kind: str, ks, key_idx, arrays: list, n: int):
-    import numpy as np
-
     mesh = _get_mesh()
     ndev = mesh.devices.size
-    tile = ed25519_batch.JNP_TILE
-    chunk = ndev * tile
+    chunk = ndev * ed25519_batch.JNP_TILE
     nb = -(-n // chunk) * chunk
+    with _shard_dispatch(kind, n, nb // chunk, ndev):
+        return _dispatch_sharded(mesh, kind, ks, key_idx, arrays, n, nb, chunk)
+
+
+def _dispatch_sharded(mesh, kind, ks, key_idx, arrays, n, nb, chunk):
+    import numpy as np
 
     def pad(v):
         out = np.zeros((nb,) + v.shape[1:], dtype=v.dtype)
@@ -230,13 +270,13 @@ def _dispatch_sharded(kind: str, ks, key_idx, arrays: list, n: int):
     outs = []
     for off in range(0, nb, chunk):
         sl = slice(off, off + chunk)
-        with ed25519_batch.launch_span(program, "sharded", n - off, chunk):
+        with ed25519_batch.launch_span(program, "sharded", n - off, chunk,
+                                       "mesh"):
             outs.append(fn(
                 tab_full,
                 jax.device_put(idx[sl], spec),
                 *(jax.device_put(v[sl], spec) for v in padded),
             ))
-    _count_sharded_dispatch(ndev)
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
 
 

@@ -212,9 +212,10 @@ class NodeMetrics:
             "Signatures verified through the batch verifier.")
         self.verify_sharded = r.counter(  # tmlint: disable=metrics-discipline
             "consensus", "verify_sharded_total",
-            "Batch-verify dispatches routed through the multi-device "
-            "shard_map mesh (parallel/batch_shard).", labels=("devices",))
-        # (devices label = mesh size at dispatch time; metrics.py cannot
+            "Batch-verify dispatches spread over the local devices "
+            "(parallel/batch_shard), by the devices used.",
+            labels=("devices",))
+        # (devices label = devices used by the dispatch; metrics.py cannot
         # know it without importing jax, and a devices="" dummy series
         # would poison the per-size sums test_multichip asserts on)
         self.sigcache_hits = r.counter(

@@ -99,7 +99,9 @@ CANONICAL_SPANS = {
     "verify.queue": "dispatch()->resolve() queue wait of a PendingVerify",
     "verify.readback": "blocking D2H fetch (crypto/batch._device_get)",
     "verify.replay": "bitmap fetch -> serial accept/reject replay",
-    "verify.shard_dispatch": "multi-device shard_map dispatch (parallel/batch_shard)",
+    "verify.shard_dispatch": "a batch spread over the local devices: placed "
+                             "Pallas chunks or shard_map (parallel/"
+                             "batch_shard; tags kind, n, chunks, devices)",
     "verify.wake": "executor's done.set() -> the waiting caller runs again",
     # one commit decision, entry point to tally (types/validator_set.py);
     # commit.assemble is the decision's root and its span id the decision id
@@ -113,7 +115,7 @@ CANONICAL_SPANS = {
                    "table, the build of keys it does not hold",
     "prep.scalars": "per-signature hash (SHA-512 / merlin in C), mod L, windows",
     "prep.launch": "host time to enqueue one device program (route, real "
-                   "signatures, launched lanes)",
+                   "signatures, launched lanes, the device it was placed on)",
     "prep.host_verify": "the C / scalar host verifier answered the batch",
     # the start-up ring (STARTUP): cold paths, recorded with tracing off too
     "startup.key_decode": "Python decompression of keys the table did not hold",

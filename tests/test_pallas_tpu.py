@@ -83,3 +83,59 @@ def test_sr_pallas_differential_vs_host_and_scalar():
         assert (out == host(None)).all()
     sample = sorted(set(bad[:40] + list(range(1, len(items), 301))))
     assert (out[sample] == np.array([sr.verify(*items[i]) for i in sample])).all()
+
+
+@pytest.mark.skipif(jax.default_backend() != "tpu"
+                    or jax.local_device_count() < 2,
+                    reason="placing chunks needs two TPU devices")
+def test_a_commit_of_9999_on_every_local_chip_vs_the_serial_reference():
+    """The "sharded" route on a TPU host (parallel/batch_shard.dispatch_placed):
+    a 9,999-signature commit is three Pallas chunks on three chips, then a
+    batch one signature past ndev - 1 chunks puts a chunk on every chip;
+    both bitmaps equal the serial reference's on every corrupted lane (one
+    sort a chunk) and a sample, piece for piece in chunk order."""
+    from tendermint_tpu.crypto import ed25519 as ref
+    from tendermint_tpu.ops import ed25519_batch as edb
+    from tendermint_tpu.ops import ed25519_pallas as edp
+
+    ndev = jax.local_device_count()
+    rng = np.random.default_rng(33)
+    privs = [ref.gen_priv_key(bytes([i % 250 + 1]) * 32) for i in range(200)]
+    items = []
+    for i in range(9999):
+        p = privs[i % 200]
+        msg = b"c%d" % i + rng.bytes(20)
+        items.append((p.pub_key().data, msg, ref.sign(p.data, msg)))
+    pub, msg, sig = items[0]
+    s = int.from_bytes(sig[32:], "little")
+    wrong = {
+        17: (pub, msg, sig[:9] + bytes([sig[9] ^ 4]) + sig[10:]),       # bit
+        edp.CHUNK + 5: (pub, msg, sig[:32]
+                        + (s + ref.L).to_bytes(32, "little")),          # S >= L
+        2 * edp.CHUNK + 7: (pub, msg, sig[:63]),                       # short
+        9998: (b"\x02" + b"\x00" * 31, msg, sig),                      # no point
+    }
+    for lane, item in wrong.items():
+        items[lane] = item
+
+    def check(batch, chunks):
+        assert edb.route_batch(len(batch)) == "sharded"
+        dev, finish = edb.dispatch_batch(batch)
+        assert len(dev) == chunks and finish.route == "sharded"
+        local = jax.local_devices()
+        assert [next(iter(p.devices())) for p in dev] == [
+            local[k % ndev] for k in range(chunks)]
+        out = finish(jax.device_get(dev))
+        bad = sorted(j for j in range(len(batch)) if j % 9999 in wrong)
+        assert sorted(np.flatnonzero(~out)) == bad
+        sample = sorted(set(bad + list(range(3, len(batch), 257))))
+        assert (out[sample]
+                == np.array([ref.verify(*batch[j]) for j in sample])).all()
+        return out
+
+    failures = edb.BREAKER.failures
+    out = check(items, 3)
+    one_chip = edb._dispatch_device(items, len(items), False)
+    assert (one_chip[1](jax.device_get(one_chip[0])) == out).all()
+    check((items * ndev)[: (ndev - 1) * edp.CHUNK + 1], ndev)
+    assert edb.BREAKER.failures == failures

@@ -1,7 +1,9 @@
 """The one routing decision of the verify path (ops/ed25519_batch.route_batch).
 
 Which route a batch of n signatures takes -- the registry's pure-Python
-loop, the C host verifier, the one-chip kernel, shard_map over the mesh --
+loop, the C host verifier, the one-chip kernel, every local device (here
+shard_map over the CPU mesh; tests/test_placed_chunks.py has a TPU host's
+floor and its placed chunks) --
 and whether the verify service owns the launch, all come from this one
 function. The table below is docs/PARALLEL.md's, case by case; the tests
 after it hold both dispatch_batch entry points, the registry and the
@@ -195,14 +197,14 @@ def test_the_host_route_is_the_scalar_loop_while_the_library_builds(
 # --- what "device" and "sharded" launch for sr25519 --------------------------
 
 
-@pytest.mark.parametrize("backend, n, want, lanes", [
-    ("tpu", 40, "pallas", edp.CHUNK),   # one chip: the Pallas chunk
-    ("cpu", 40, "jnp", edb.JNP_TILE),   # no TPU backend: the jnp tile
-    ("cpu", 72, "sharded", None),       # several devices: shard_map, jnp
-    ("tpu", 72, "sharded", None),       # ... whatever the backend
+@pytest.mark.parametrize("backend, n, want, launch", [
+    ("tpu", 40, "pallas", ("chunk", edp.CHUNK)),  # one chip: the Pallas chunk
+    ("cpu", 40, "jnp", ("tile", edb.JNP_TILE)),   # no TPU backend: the jnp tile
+    ("cpu", 72, "sharded", None),      # several devices: shard_map, jnp
+    ("tpu", 72, "sharded", ("chunk", edp.CHUNK)),  # ... on a TPU: the chunk
 ])
 def test_sr25519_kernel_follows_backend_and_device_count(
-        backend, n, want, lanes, stand_ins, monkeypatch):
+        backend, n, want, launch, stand_ins, monkeypatch):
     """route_batch names the route; which program the sr25519 device route
     launches follows from what the process can observe, the backend and the
     device count, and nothing else."""
@@ -210,21 +212,22 @@ def test_sr25519_kernel_follows_backend_and_device_count(
     launched = []
 
     def chunk(tab, k32, s32, r32, valid):
-        launched.append(("pallas", valid.shape[1]))
+        launched.append(("chunk", valid.shape[1]))
         return valid.astype(jnp.int32)
 
     def tile(tab, *arrays):
-        launched.append(("jnp", arrays[-1].shape[0]))
+        launched.append(("tile", arrays[-1].shape[0]))
         return arrays[-1]
 
     monkeypatch.setattr(edp, "_sr_verify_chunk", chunk)
-    monkeypatch.setattr(edb.KeySet, "gathered_lane", lambda self, idx: None)
+    monkeypatch.setattr(edb.KeySet, "gathered_lane",
+                        lambda self, idx, device=None: None)
     monkeypatch.setattr(srb, "_kernel", tile)
     items = _raw(_items("sr25519", n))
     assert edb.route_batch(n) == ("sharded" if want == "sharded" else "device")
     dev, finish = srb.dispatch_batch(items)
     assert finish(cbatch._device_get(dev)).all() and finish.route == want
-    assert launched == ([] if lanes is None else [(want, lanes)])
+    assert launched == ([] if launch is None else [launch])
 
 
 # --- the service's guess and the dispatch agree -----------------------------
