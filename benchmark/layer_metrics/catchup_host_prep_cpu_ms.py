@@ -1,0 +1,3 @@
+"""host_prep_cpu_ms's reader, where catchup_blocks_per_s is the metric."""
+
+from benchmark.layer_metrics.host_prep_cpu_ms import read  # noqa: F401

@@ -1,0 +1,8 @@
+"""The consensus.thread_cpu marks of the window: CPU seconds of the verify
+service's thread over the wall seconds of the heights, %."""
+
+from benchmark.harness import cpu
+
+
+def read(run):
+    return cpu.share(run, "verify")

@@ -204,6 +204,15 @@ class ConsensusReactor(Reactor):
         # channel id -> [messages, seconds in receive, bytes], counted on
         # the receiving threads while tracing is on (docs/OBSERVABILITY.md)
         self.recv_stats: dict[int, list] = {}
+        # beside it, for the once-a-height consensus.recv mark: the threads
+        # that called receive since the mark before, every thread's CPU
+        # clock and the totals (messages, seconds, bytes) at that mark, and
+        # the height it closed
+        self._recv_callers: set[threading.Thread] = set()
+        self._recv_cpu_at: dict | None = None
+        self._recv_marked = (0, 0.0, 0)
+        self._recv_height = None
+        cs.on_new_round_step.append(self._mark_recv)
         cs.on_new_round_step.append(self._broadcast_new_round_step)
         cs.on_vote.append(self._broadcast_has_vote)
         cs.on_valid_block.append(self._broadcast_new_valid_block)
@@ -254,7 +263,7 @@ class ConsensusReactor(Reactor):
         # same peer-gossip cadence, so they share one loop with the maj23
         # pass kept on its own slower clock.
         threading.Thread(target=self._gossip_routine, args=(peer, ps),
-                         daemon=True).start()
+                         name=f"cs-gossip-{str(peer.id)[:8]}", daemon=True).start()
         if not self.wait_sync:
             self._send_new_round_step(peer)
 
@@ -269,7 +278,10 @@ class ConsensusReactor(Reactor):
     def receive(self, ch_id: int, peer: Peer, msg_bytes: bytes) -> None:
         """Decode one wire message and hand it to the state machine. With
         tracing on, ``recv_stats`` counts it: at a step's 15,000 votes a span
-        per message would turn the flight recorder's ring over."""
+        per message would turn the flight recorder's ring over. Who called
+        is noted for the ``consensus.recv`` mark of the height; the CPU clock
+        is not read here (a system call of 10 us and more where the
+        benchmark runs)."""
         if not _trace.ENABLED:
             self._receive(ch_id, peer, msg_bytes)
             return
@@ -283,6 +295,32 @@ class ConsensusReactor(Reactor):
                 st[0] += 1
                 st[1] += dt
                 st[2] += len(msg_bytes)
+                self._recv_callers.add(threading.current_thread())
+
+    def _mark_recv(self, rs) -> None:
+        """Once a height, with tracing on: what ``receive`` took since the
+        mark before and the CPU seconds the threads that called it got since
+        then (their clocks read from outside: ``receive`` and whatever they
+        do between two calls), into the flight recorder (on the consensus
+        thread, at the step into a new height)."""
+        if not self.cs.tracer.enabled or rs.height == self._recv_height:
+            return
+        cpu_now = _trace.thread_cpu_times()
+        with self._mtx:
+            first, self._recv_height = self._recv_height is None, rs.height
+            stats = list(self.recv_stats.values())
+            now = tuple(sum(st[k] for st in stats) for k in range(3))
+            before, self._recv_marked = self._recv_marked, now
+            callers, self._recv_callers = self._recv_callers, set()
+            cpu_before, self._recv_cpu_at = self._recv_cpu_at, cpu_now
+        if first:
+            return
+        msgs, seconds, nbytes = (a - b for a, b in zip(now, before))
+        cpu_s = None if cpu_now is None or cpu_before is None else sum(
+            cpu_now[t] - cpu_before.get(t, 0.0) for t in callers if t in cpu_now)
+        self.cs.tracer.mark(
+            "consensus.recv", height=rs.height - 1, msgs=msgs, seconds=seconds,
+            cpu_s=cpu_s, bytes=nbytes, threads=sorted({t.name for t in callers}))
 
     def _receive(self, ch_id: int, peer: Peer, msg_bytes: bytes) -> None:
         ps: PeerState = peer.get("consensus_peer_state")

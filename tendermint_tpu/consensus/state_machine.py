@@ -14,6 +14,7 @@ Differences from the reference are TPU-era, not semantic:
 
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 import time as _time
@@ -197,6 +198,8 @@ class ConsensusState:
         # instance tracer so a 50-node in-process mesh never interleaves
         # spans; a standalone machine records into the process default.
         self.tracer = _trace.DEFAULT
+        # what every thread of the process got, height over height (traced)
+        self._census = _trace.ThreadCensus()
 
         self.rs = cstypes.RoundState()
         self.state = None  # sm.State; set by update_to_state
@@ -429,6 +432,8 @@ class ConsensusState:
             # crypto-layer verify phases dispatched from it — lands in THIS
             # node's tracer (thread-local activation, utils/trace.py)
             with self.tracer.activate():
+                if self.tracer.enabled:
+                    self._census.read()  # the first height's baseline
                 self._receive_loop()
         except Exception as e:  # noqa: BLE001 - fail-stop, never die silent
             if self.logger is not None:
@@ -509,23 +514,17 @@ class ConsensusState:
                 if len(votes) > 1:
                     tr = self.tracer
                     if self.wal is not None and not self.replay_mode:
-                        if tr.enabled:
-                            with tr.span("consensus.wal_write",
-                                         msgs=len(votes)):
-                                tr.annotate(bytes=self._wal_write_votes(votes))
-                        else:
-                            self._wal_write_votes(votes)
-                    with self._mtx:
-                        if tr.enabled:
-                            # the drain span carries the height; verify
-                            # phases dispatched inside inherit it
-                            with tr.span("consensus.vote_drain",
-                                         height=self.rs.height,
-                                         round=self.rs.round,
-                                         votes=len(votes)):
-                                self._handle_vote_batch(votes)
-                        else:
-                            self._handle_vote_batch(votes)
+                        with (tr.span("consensus.wal_write", msgs=len(votes))
+                              if tr.enabled else _trace.NULL_SPAN):
+                            tr.annotate(bytes=self._wal_write_votes(votes))
+                    # the drain span carries the height; verify phases
+                    # dispatched inside inherit it
+                    with self._mtx, (
+                            tr.span("consensus.vote_drain",
+                                    height=self.rs.height, round=self.rs.round,
+                                    votes=len(votes))
+                            if tr.enabled else _trace.NULL_SPAN):
+                        self._handle_vote_batch(votes)
                     continue
             # Any other message mutates state through _handle_msg: apply the
             # in-flight vote flush first so side effects stay arrival-order.
@@ -539,14 +538,13 @@ class ConsensusState:
                     self.wal.write_sync(blob, _time.time_ns())
                 else:
                     self.wal.write(blob, _time.time_ns())
-            with self._mtx:
-                if (not internal and self.tracer.enabled
-                        and isinstance(mi.msg, VoteMessage)):
-                    with self.tracer.span("consensus.vote_serial",
-                                          why="single", votes=1):
-                        self._handle_msg(mi)
-                else:
-                    self._handle_msg(mi)
+            with self._mtx, (
+                    self.tracer.span("consensus.vote_serial", why="single",
+                                     votes=1)
+                    if (self.tracer.enabled and not internal
+                        and isinstance(mi.msg, VoteMessage))
+                    else _trace.NULL_SPAN):
+                self._handle_msg(mi)
 
     def _wal_write_votes(self, votes: list[MsgInfo]) -> int:
         """A drain's votes into the WAL, buffered, every copy, before any is
@@ -714,31 +712,35 @@ class ConsensusState:
     def _apply_vote_results(self, msgs: list[MsgInfo], ok_by_i: dict[int, bool],
                             serial: dict[int, str] | None = None) -> None:
         """Apply a drain's votes in arrival order. ``serial`` names the
-        votes the batch did not verify and why; with tracing on, the time
-        they spend in the serial path is recorded per reason."""
+        votes the batch did not verify and why; with tracing on, the wall
+        and CPU time they spend in the serial path is recorded per reason."""
         tr = self.tracer
-        if not tr.enabled:
-            for i, m in enumerate(msgs):
-                self._apply_vote_result(m, ok_by_i.get(i))
-            return
+        timed = serial if tr.enabled and serial else {}
         counts = {"added": 0, "not_added": 0, "invalid": 0, "errors": 0}
-        spent: dict[str, list] = {}       # why -> [votes, seconds]
-        with tr.span("consensus.vote_apply", votes=len(msgs)):
-            for i, m in enumerate(msgs):
-                why = serial.get(i) if serial else None
+        spent: dict[str, list] = {}       # why -> [votes, seconds, cpu s]
+        with (tr.span("consensus.vote_apply", votes=len(msgs))
+              if tr.enabled else _trace.NULL_SPAN):
+            # the clocks are read once a run of serial votes with one reason,
+            # not once a vote: the CPU clock is a system call
+            for why, run in itertools.groupby(
+                    enumerate(msgs), key=lambda im: timed.get(im[0])):
                 if why is None:
-                    counts[self._apply_vote_result(m, ok_by_i.get(i))] += 1
+                    for i, m in run:
+                        counts[self._apply_vote_result(m, ok_by_i.get(i))] += 1
                     continue
-                t0 = _time.monotonic()
-                counts[self._apply_vote_result(m, None)] += 1
-                acc = spent.setdefault(why, [0, 0.0])
-                acc[0] += 1
+                acc = spent.setdefault(why, [0, 0.0, 0.0])
+                t0, c0 = _time.monotonic(), _time.thread_time()
+                for _i, m in run:
+                    counts[self._apply_vote_result(m, None)] += 1
+                    acc[0] += 1
+                acc[2] += _time.thread_time() - c0
                 acc[1] += _time.monotonic() - t0
             tr.annotate(added=counts["added"], invalid=counts["invalid"],
                         duplicates=counts["not_added"],
                         errors=counts["errors"])
-        for why, (n, seconds) in spent.items():
-            tr.record("consensus.vote_serial", seconds, why=why, votes=n)
+        for why, (n, seconds, cpu_s) in spent.items():
+            tr.record("consensus.vote_serial", seconds, cpu_s=cpu_s, why=why,
+                      votes=n)
 
     def _apply_vote_result(self, m: MsgInfo, ok: bool | None) -> str:
         """One vote of a drain through the normal addVote path -> ``added``,
@@ -1315,6 +1317,11 @@ class ConsensusState:
         if self.priv_validator is not None:
             self.priv_validator_pub_key = self.priv_validator.get_pub_key()
         self._schedule_round_0()
+        if self.tracer.enabled:
+            census = self._census.read()
+            if census is not None:
+                self.tracer.mark("consensus.thread_cpu", height=height,
+                                 **census)
 
     # --- proposal handling --------------------------------------------------
 

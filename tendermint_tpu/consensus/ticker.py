@@ -53,6 +53,7 @@ class TimeoutTicker:
                      else self._clock.timer_duration(ti.duration_s))
             self._timer = threading.Timer(delay, self._fire, args=(ti,))
             self._timer.daemon = True
+            self._timer.name = "cs-ticker"  # one name in the thread census
             self._timer.start()
 
     def _fire(self, ti: TimeoutInfo) -> None:

@@ -125,8 +125,10 @@ class MConnection:
 
     def start(self) -> None:
         self._running = True
-        self._send_thread = threading.Thread(target=self._send_routine, daemon=True)
-        self._recv_thread = threading.Thread(target=self._recv_routine, daemon=True)
+        self._send_thread = threading.Thread(
+            target=self._send_routine, name="mconn-send", daemon=True)
+        self._recv_thread = threading.Thread(
+            target=self._recv_routine, name="mconn-recv", daemon=True)
         self._send_thread.start()
         self._recv_thread.start()
 

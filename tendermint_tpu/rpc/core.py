@@ -974,7 +974,10 @@ def unsafe_trace(env, enable=None, clear=False, dump=False):
     With no params: the tracer's state + per-span-name aggregation, and
     the same aggregation of the process's start-up ring (``startup``: key
     decompression, table builds, jit tracing and compiling; recorded with
-    tracing off too). ``enable``: true/false flips this node's tracer live.
+    tracing off too), and ``threads``: the process's CPU seconds so far and
+    each live thread's by name, with tracing off too (null where the
+    platform has no per-thread clock). ``enable``: true/false flips this
+    node's tracer live.
     ``clear`` drops the ring. ``dump=true`` adds the raw span lists
     (ring-bounded): ``spans``, and ``startup_spans``."""
     from tendermint_tpu.utils import trace as tmtrace
@@ -995,6 +998,7 @@ def unsafe_trace(env, enable=None, clear=False, dump=False):
     out = dict(tracer.describe())
     out["summary"] = tracer.summarize()
     out["startup"] = tmtrace.STARTUP.summarize()
+    out["threads"] = tmtrace.thread_cpu_table()
     if dump in (True, "true", "1", 1):
         out["spans"] = [s.as_dict() for s in tracer.dump()]
         out["startup_spans"] = [s.as_dict() for s in tmtrace.STARTUP.dump()]

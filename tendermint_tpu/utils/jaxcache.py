@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 
 from tendermint_tpu.utils import trace
 
@@ -45,20 +46,28 @@ _STARTUP_SPANS = {
     "/jax/core/compile/backend_compile_duration": "startup.jit_compile",
     "/jax/compilation_cache/cache_retrieval_time_sec": "startup.cache_load",
 }
-_tracing = threading.local()  # .depth: traces open on this thread
+# .depth: traces open on this thread; .cpu0: event -> the thread's CPU clock
+# at the start of each open region of that event
+_tracing = threading.local()
 
 
 def _on_start(event: str, _start_time: float, **_kw) -> None:
     """jax announces the start of a timed region as a scalar event."""
     if event == _TRACE_EVENT:
-        _tracing.depth = getattr(_tracing, "depth", 0) + 1
+        _tracing.depth = depth = getattr(_tracing, "depth", 0) + 1
+        if depth > 1:
+            return
+    if event in _STARTUP_SPANS:
+        starts = _tracing.__dict__.setdefault("cpu0", {})
+        starts.setdefault(event, []).append(time.thread_time())
 
 
 def _on_duration(event: str, duration: float, **kw) -> None:
     """jax reports a duration when the work ends, so record()'s default
     start (now - duration) is the right one here. Every jnp op inside a
     kernel is traced as a function of its own, thousands in one kernel's
-    trace: only the outermost trace is recorded, which covers them."""
+    trace: only the outermost trace is recorded, which covers them. A
+    region whose start was announced carries the thread's CPU seconds."""
     if event == _TRACE_EVENT:
         _tracing.depth = depth = getattr(_tracing, "depth", 1) - 1
         if depth > 0:
@@ -66,7 +75,10 @@ def _on_duration(event: str, duration: float, **kw) -> None:
     name = _STARTUP_SPANS.get(event)
     if name is not None:
         tags = {"fun": kw["fun_name"]} if "fun_name" in kw else {}
-        trace.STARTUP.record(name, duration, **tags)
+        cpu0 = getattr(_tracing, "cpu0", {}).get(event)
+        trace.STARTUP.record(
+            name, duration,
+            cpu_s=time.thread_time() - cpu0.pop() if cpu0 else None, **tags)
 
 
 def enable() -> None:

@@ -23,7 +23,12 @@ Three layers:
    (``decision=``, ``parent=``), so one decision is one tree
    (docs/OBSERVABILITY.md). While jax is imported every span also enters
    a ``jax.profiler.TraceAnnotation`` of its name, so a profiler session
-   shows the program's spans on the profiler's own clock.
+   shows the program's spans on the profiler's own clock. Every span names
+   the thread that wrote it and a ``span()`` carries that thread's CPU
+   seconds beside its wall seconds: under one interpreter lock a span's
+   wall time holds every other thread's turn too, its CPU time only its
+   own. :class:`ThreadCensus` is the same reading taken from outside, of
+   every live thread at once.
  - the module-level functions: ``span()/mark()/record()`` delegate to the
    thread's ACTIVE tracer (``Tracer.activate()``), falling back to the
    process :data:`DEFAULT` tracer; ``dump()/summarize()/enable()`` always
@@ -93,6 +98,13 @@ CANONICAL_SPANS = {
                              "precheck, votes; one record per drain and why)",
     "consensus.finalize_commit": "validate + save + apply of a decided "
                                  "block (parent of store_save, abci_apply)",
+    "consensus.recv": "what ConsensusReactor.receive took since the mark "
+                      "before, once a height (mark; tags msgs, seconds, "
+                      "bytes, threads = who called it, cpu_s = the CPU "
+                      "seconds those threads got since)",
+    "consensus.thread_cpu": "CPU seconds of every live thread since the mark "
+                            "before, once a height (mark; tags wall_s, "
+                            "process_s, rest_s, lost, threads = name -> s)",
     # deferred verify pipeline phases (crypto/batch.py; the sync-floor
     # attribution ROADMAP item 1 needs)
     "verify.host_prep": "host prep + kernel dispatch (ops dispatch_batch)",
@@ -238,11 +250,16 @@ class Span:
     tags: dict
     span_id: int = 0
     parent_id: int = 0  # 0 = root (no enclosing span on that thread)
+    thread: str = ""    # name of the thread that wrote it
+    # time.thread_time() over the region: seconds the thread was on a core.
+    # None on a mark and on a record() that was given none.
+    cpu_s: float | None = None
 
     def as_dict(self) -> dict:
         return {"name": self.name, "start": self.start,
                 "duration_s": self.duration_s, "span_id": self.span_id,
-                "parent_id": self.parent_id, "tags": dict(self.tags)}
+                "parent_id": self.parent_id, "thread": self.thread,
+                "cpu_s": self.cpu_s, "tags": dict(self.tags)}
 
 
 # ANY tracer enabled — THE one-attribute-load guard hot call sites check
@@ -358,6 +375,7 @@ class Tracer:
             c.heights = []
             c.decisions = []
             c.open = []      # the tag dicts of the open spans (annotate)
+            c.thread = threading.current_thread().name
         return c
 
     def current_height(self):
@@ -390,7 +408,10 @@ class Tracer:
         region get this span as parent and inherit its height and decision
         tags. ``decision=True`` makes this span a decision's root: the tag
         becomes its own id. ``parent=`` names the causing span when it is
-        not the enclosing one (work done on another thread)."""
+        not the enclosing one (work done on another thread). The thread's
+        CPU clock is read inside the wall clock's two readings, so
+        ``cpu_s <= duration_s``: the difference is time the thread was off
+        the core (the interpreter lock, a mutex, a socket, the device)."""
         if not self.enabled:
             yield 0
             return
@@ -411,9 +432,11 @@ class Tracer:
             c.decisions.append(d)
         ann = None if self._cold else _bridge(name, d)
         t0 = time.monotonic()
+        c0 = time.thread_time()
         try:
             yield sid
         finally:
+            cpu = time.thread_time() - c0
             dur = time.monotonic() - t0
             if ann is not None:
                 ann.__exit__(None, None, None)
@@ -423,7 +446,8 @@ class Tracer:
                 c.heights.pop()
             if d is not None:
                 c.decisions.pop()
-            self._append(Span(name, t0, dur, tags, sid, parent))
+            self._append(Span(name, t0, dur, tags, sid, parent, c.thread,
+                              cpu))
 
     def annotate(self, **tags) -> None:
         """Add tags to the innermost open span of this thread: what is only
@@ -441,17 +465,19 @@ class Tracer:
         self._inherit(c, tags)
         parent = c.parents[-1] if c.parents else 0
         self._append(Span(name, time.monotonic(), 0.0, tags,
-                          next(self._seq), parent))
+                          next(self._seq), parent, c.thread))
 
     def record(self, name: str, duration_s: float, *,
                start: float | None = None, parent: int | None = None,
-               **tags) -> None:
+               cpu_s: float | None = None, **tags) -> None:
         """An externally-timed span (e.g. a queue wait measured between
         two events). ``start`` is the ``time.monotonic()`` reading taken
         when the work began; without it the start is back-dated from now,
         which is right only when the record is written the moment the work
         ends. ``parent`` names the causing span when the record is written
-        on another thread than the one that caused it."""
+        on another thread than the one that caused it. ``cpu_s`` is the
+        writer's own ``time.thread_time()`` over the work where it has one;
+        a wait between two events on two threads has none."""
         if not self.enabled:
             return
         c = self._stacks()
@@ -461,7 +487,7 @@ class Tracer:
         if start is None:
             start = time.monotonic() - duration_s
         self._append(Span(name, start, duration_s, tags, next(self._seq),
-                          parent))
+                          parent, c.thread, cpu_s))
 
     def _append(self, s: Span) -> None:
         with self._mtx:
@@ -502,13 +528,15 @@ class Tracer:
             return len(self._spans)
 
     def summarize(self) -> dict[str, dict]:
-        """name -> {count, total_s, max_s} aggregation."""
+        """name -> {count, total_s, cpu_s, max_s} aggregation (``cpu_s``
+        over the spans that carry one)."""
         agg: dict[str, dict] = {}
         for s in self.dump():
             a = agg.setdefault(s.name, {"count": 0, "total_s": 0.0,
-                                        "max_s": 0.0})
+                                        "cpu_s": 0.0, "max_s": 0.0})
             a["count"] += 1
             a["total_s"] += s.duration_s
+            a["cpu_s"] += s.cpu_s or 0.0
             a["max_s"] = max(a["max_s"], s.duration_s)
         return agg
 
@@ -535,9 +563,11 @@ class Tracer:
         for s in spans:
             counts[s.name] = counts.get(s.name, 0) + 1
             first_start.setdefault(s.name, s.start)
-            p = phases.setdefault(s.name, {"count": 0, "total_s": 0.0})
+            p = phases.setdefault(s.name, {"count": 0, "total_s": 0.0,
+                                           "cpu_s": 0.0})
             p["count"] += 1
             p["total_s"] += s.duration_s
+            p["cpu_s"] += s.cpu_s or 0.0
         present = [n for n in LIFECYCLE if n in counts]
         starts = [first_start[n] for n in present]
         causal_ok = all(a <= b for a, b in zip(starts, starts[1:]))
@@ -563,6 +593,100 @@ def handle_tags(height, decision: int) -> dict:
     if decision:
         tags["decision"] = decision
     return tags
+
+
+# --- the census of threads ---------------------------------------------------
+# A span says what its own thread got. Which thread has the interpreter the
+# rest of the time is read from outside: every live thread's CPU clock, and
+# the process's.
+
+_THREAD_CLOCKS: bool | None = None  # can this platform read them? (lazy)
+
+
+def _thread_clock_id(native_id: int) -> int:
+    """Linux's CPU-time clock of the thread with this kernel id: the id
+    ``pthread_getcpuclockid`` computes, made from ``Thread.native_id`` so that
+    no ``pthread_t`` of a thread that may just have exited is followed. A
+    thread that is gone makes ``clock_gettime`` raise OSError."""
+    return ((~native_id) << 3) | 6
+
+
+def _has_thread_clocks() -> bool:
+    """True where the clock id above is the platform's own for the calling
+    thread and reads like ``time.thread_time()``; checked once."""
+    global _THREAD_CLOCKS
+    if _THREAD_CLOCKS is None:
+        try:
+            mine = _thread_clock_id(threading.get_native_id())
+            _THREAD_CLOCKS = (
+                mine == time.pthread_getcpuclockid(threading.get_ident())
+                and abs(time.clock_gettime(mine) - time.thread_time()) < 0.05)
+        except (AttributeError, OSError):
+            _THREAD_CLOCKS = False
+    return _THREAD_CLOCKS
+
+
+def thread_cpu_times() -> dict | None:
+    """{Thread: CPU seconds it has had} over the live threads Python knows,
+    or None where the platform has no such clock."""
+    if not _has_thread_clocks():
+        return None
+    out = {}
+    for t in threading.enumerate():
+        if t.native_id is None:
+            continue
+        try:
+            out[t] = time.clock_gettime(_thread_clock_id(t.native_id))
+        except OSError:  # exited between the listing and the reading
+            continue
+    return out
+
+
+def _by_name(seconds: dict) -> dict[str, float]:
+    """{Thread: s} -> {name: s}, threads of one name summed."""
+    out: dict[str, float] = {}
+    for t, s in seconds.items():
+        out[t.name] = out.get(t.name, 0.0) + s
+    return out
+
+
+def thread_cpu_table() -> dict | None:
+    """The process's CPU seconds so far and each live thread's, by name (the
+    ``threads`` key of the unsafe_trace answer; needs no tracer)."""
+    now = thread_cpu_times()
+    if now is None:
+        return None
+    return {"process_s": time.process_time(), "threads": _by_name(now)}
+
+
+class ThreadCensus:
+    """What each thread got between two readings: the tags of a
+    ``consensus.thread_cpu`` mark."""
+
+    def __init__(self):
+        self._last = None  # (monotonic, process_time, {Thread: cpu seconds})
+
+    def read(self) -> dict | None:
+        """CPU seconds since the reading before -> {wall_s, process_s,
+        threads: name -> s, rest_s, lost}. A thread born since counts its
+        whole reading. One that died since is lost with what it got since
+        (``lost`` counts them); that, and the threads Python never sees (the
+        runtime's, the compiler's), is ``rest_s``: process_s less the threads'
+        sum. None on the first reading, which only sets the baseline, and
+        where the platform has no such clock."""
+        now = thread_cpu_times()
+        if now is None:
+            return None
+        at, process = time.monotonic(), time.process_time()
+        last, self._last = self._last, (at, process, now)
+        if last is None:
+            return None
+        at0, process0, before = last
+        got = _by_name({t: s - before.get(t, 0.0) for t, s in now.items()})
+        return {"wall_s": at - at0, "process_s": process - process0,
+                "threads": got,
+                "rest_s": process - process0 - sum(got.values()),
+                "lost": sum(1 for t in before if t not in now)}
 
 
 # The process-default tracer: the module-level API's fallback target, and

@@ -564,13 +564,366 @@ def test_three_node_mesh_timeline_smoke(tmp_path):
         tl = _rpc(base, "unsafe_timeline", {"height": found})
         assert tl["height"] == found and tl["lifecycle_complete"]
         assert tl["causal_ok"] and tl["spans"]
+        # ISSUE 35: every span names its thread; the height has its census
+        # of threads and its receive mark, once each
+        assert all(s["thread"] and "cpu_s" in s for s in tl["spans"])
+        assert tl["phases"]["consensus.thread_cpu"]["count"] == 1
+        assert tl["phases"]["consensus.recv"]["count"] == 1
+        census = next(s["tags"] for s in tl["spans"]
+                      if s["name"] == "consensus.thread_cpu")
+        assert census["threads"]["cs-receive"] > 0 and census["wall_s"] > 0
         # unsafe_trace: state + aggregation, and live disable/enable
         view = _rpc(base, "unsafe_trace", {})
         assert view["enabled"] and view["spans"] > 0
         assert "consensus.step" in view["summary"]
         view = _rpc(base, "unsafe_trace", {"enable": False})
         assert not view["enabled"]
+        # the table of threads is served with tracing off too
+        assert view["threads"]["threads"]["cs-receive"] > 0
+        assert view["threads"]["process_s"] > 0
         view = _rpc(base, "unsafe_trace", {"enable": True})
         assert view["enabled"]
     finally:
         cluster.stop()
+
+
+# ---------------------------------------------------------------------------
+# 5. ISSUE 35: which thread ran a span and how much CPU that thread got
+# ---------------------------------------------------------------------------
+
+
+def _spin(seconds: float) -> None:
+    t0 = time.monotonic()
+    while time.monotonic() - t0 < seconds:
+        pass
+
+
+def test_a_sleeping_span_has_no_cpu_and_a_spinning_one_has_its_duration(tracer):
+    with tracer.span("consensus.flush_wait"):
+        time.sleep(0.1)
+    with tracer.span("consensus.vote_apply"):
+        _spin(0.1)
+    slept, spun = tracer.dump()
+    assert slept.duration_s >= 0.1 and slept.cpu_s < 0.02
+    assert 0.05 < spun.cpu_s <= spun.duration_s
+    for s in (slept, spun):
+        assert s.as_dict()["cpu_s"] == s.cpu_s
+        assert s.as_dict()["thread"] == threading.current_thread().name
+
+
+def test_a_span_names_the_thread_that_closed_it(tracer):
+    def work():
+        with tracer.span("verify.host_prep"):
+            tracer.mark("consensus.commit")
+        tracer.record("verify.queue", 0.01)
+
+    threads = [threading.Thread(target=work, name=f"writer-{k}")
+               for k in range(2)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join(timeout=5)
+        assert not th.is_alive()
+    work()
+    by_thread: dict = {}
+    for s in tracer.dump():
+        by_thread.setdefault(s.thread, []).append(s.name)
+    assert by_thread == {
+        name: ["consensus.commit", "verify.host_prep", "verify.queue"]
+        for name in ("writer-0", "writer-1", threading.current_thread().name)}
+
+
+def test_record_stores_the_cpu_it_is_given_and_a_mark_has_none(tracer):
+    tracer.record("verify.queue", 0.25)
+    tracer.record("consensus.vote_serial", 0.25, cpu_s=0.125, why="late")
+    tracer.mark("consensus.commit")
+    waited, serial, mark = tracer.dump()
+    assert waited.cpu_s is None and waited.as_dict()["cpu_s"] is None
+    assert serial.cpu_s == 0.125
+    assert mark.cpu_s is None and mark.thread == threading.current_thread().name
+
+
+def test_summarize_and_timeline_sum_cpu_beside_wall(tracer):
+    tracer.record("consensus.vote_serial", 0.5, cpu_s=0.125, height=3)
+    tracer.record("consensus.vote_serial", 0.25, cpu_s=0.0625, height=3)
+    tracer.record("verify.queue", 1.0, height=3)       # a wait: no CPU
+    with tracer.span("consensus.vote_apply", height=3):
+        _spin(0.02)
+    agg = tracer.summarize()
+    assert agg["consensus.vote_serial"] == {
+        "count": 2, "total_s": 0.75, "cpu_s": 0.1875, "max_s": 0.5}
+    assert agg["verify.queue"]["cpu_s"] == 0.0
+    assert 0.0 < agg["consensus.vote_apply"]["cpu_s"] <= \
+        agg["consensus.vote_apply"]["total_s"]
+    phases = tracer.timeline(3)["phases"]
+    assert phases["consensus.vote_serial"] == {
+        "count": 2, "total_s": 0.75, "cpu_s": 0.1875}
+    assert phases["consensus.vote_apply"]["cpu_s"] == \
+        agg["consensus.vote_apply"]["cpu_s"]
+    assert all("thread" in s and "cpu_s" in s
+               for s in tracer.timeline(3)["spans"])
+
+
+def test_the_startup_ring_carries_both_fields():
+    ring = trace.Tracer(name="startup", cap=8, cold=True)
+    with ring.span("startup.table_build", keys=1):
+        _spin(0.02)
+    ring.record("startup.jit_trace", 0.5, cpu_s=0.4, fun="f")
+    build, traced = ring.dump()
+    assert 0.0 < build.cpu_s <= build.duration_s
+    assert traced.cpu_s == 0.4
+    assert {s.thread for s in (build, traced)} == {
+        threading.current_thread().name}
+    assert ring.summarize()["startup.jit_trace"]["cpu_s"] == 0.4
+
+
+needs_thread_clocks = pytest.mark.skipif(
+    not hasattr(time, "pthread_getcpuclockid"),
+    reason="no per-thread CPU clock on this platform")
+
+
+@needs_thread_clocks
+def test_the_census_counts_the_born_in_full_and_the_dead_as_lost():
+    stop = threading.Event()
+    old = threading.Thread(target=stop.wait, name="census-old")
+    old.start()
+    census = trace.ThreadCensus()
+    assert census.read() is None            # the first reading: a baseline
+    spun = threading.Event()
+
+    def burn(cpu_s):            # until this thread has had that much CPU
+        c0 = time.thread_time()
+        while time.thread_time() - c0 < cpu_s:
+            pass
+
+    born = threading.Thread(
+        target=lambda: (burn(0.05), spun.set(), stop.wait()),
+        name="census-born")
+    born.start()
+    burn(0.05)
+    assert spun.wait(timeout=60)
+    got = census.read()
+    try:
+        assert got["threads"]["census-born"] >= 0.05      # its whole reading
+        assert got["threads"]["census-old"] < 0.02
+        assert got["threads"][threading.current_thread().name] >= 0.05
+        # the threads Python sees and the rest are the process
+        assert sum(got["threads"].values()) + got["rest_s"] == \
+            pytest.approx(got["process_s"], abs=1e-9)
+        assert abs(got["rest_s"]) < 0.05 + 0.2 * got["process_s"]
+        assert got["wall_s"] >= 0.05
+    finally:
+        stop.set()
+        for th in (old, born):
+            th.join(timeout=5)
+            assert not th.is_alive()
+    got = census.read()
+    assert got["lost"] >= 2     # these two (and whatever else the process lost)
+    assert not {"census-old", "census-born"} & set(got["threads"])
+    # the table the unsafe_trace route serves: cumulative, by name
+    table = trace.thread_cpu_table()
+    assert table["threads"][threading.current_thread().name] >= 0.05
+    assert table["process_s"] >= table["threads"][
+        threading.current_thread().name]
+
+
+def test_without_thread_clocks_the_census_is_absent(monkeypatch):
+    monkeypatch.setattr(trace, "_THREAD_CLOCKS", False)
+    assert trace.thread_cpu_times() is None
+    assert trace.thread_cpu_table() is None
+    assert trace.ThreadCensus().read() is None
+
+
+def test_disabled_tracer_reads_no_cpu_clock_and_takes_no_census(monkeypatch):
+    """ISSUE 35: the off path stays what it was. No span, record, mark or
+    guarded site reads ``time.thread_time``; the drain and the reactor's
+    ``receive`` take no census and write no mark."""
+    from tendermint_tpu.consensus import reactor as cs_reactor
+
+    def boom(*_a, **_kw):
+        raise AssertionError("a disabled site read a CPU clock")
+
+    assert not trace.ENABLED
+    monkeypatch.setattr(time, "thread_time", boom)
+    for name in ("thread_cpu_times", "thread_cpu_table"):
+        monkeypatch.setattr(trace, name, boom)
+    monkeypatch.setattr(trace.ThreadCensus, "read", boom)
+    t = trace.Tracer("off")
+    with t.span("consensus.vote_drain", height=1) as sid:
+        assert sid == 0
+    t.record("consensus.vote_serial", 0.1, cpu_s=0.1)
+    t.mark("consensus.thread_cpu")
+    t.annotate(votes=1)
+    with (trace.current().span("prep.launch", sigs=1, lanes=1)
+          if trace.ENABLED else trace.NULL_SPAN) as sid:
+        assert sid == 0
+    assert t.dump() == []
+    # the live path: a drain and a single vote through the receive loop, and
+    # a message through the reactor, with every tracer off
+    cs, wal_records, counted = _drain_through_the_receive_loop(None)
+    assert len(counted) == 11 and wal_records
+    r = cs_reactor.ConsensusReactor(cs)
+    r._receive = lambda *a: None
+    r.receive(0x22, object(), b"\x00")
+    r._mark_recv(cs.rs)
+    assert r.recv_stats == {} and not r._recv_callers
+
+
+def _drain_through_the_receive_loop(tracer, tmp_path=None):
+    """24 validators, one height: a drain of 14 deliveries (ten good votes,
+    a copy, a flipped signature, a vote of the next height, an index out of
+    range), a non-vote that ends it, then one vote alone -> (machine, the
+    WAL's records, the votes it counted). ``tracer`` None leaves the
+    machine's default (disabled) tracer."""
+    import tempfile
+
+    from tendermint_tpu.config.config import test_config
+    from tendermint_tpu.consensus import cstypes
+    from tendermint_tpu.consensus.state_machine import (
+        ConsensusState,
+        MsgInfo,
+        VoteMessage,
+    )
+    from tendermint_tpu.consensus.wal import WAL
+    from tendermint_tpu.state.state import make_genesis_state
+    from tendermint_tpu.types.block_id import BlockID, PartSetHeader
+    from tendermint_tpu.types.genesis import GenesisDoc, GenesisValidator
+    from tendermint_tpu.types.ttime import Time
+    from tendermint_tpu.types.vote import PREVOTE_TYPE
+    from tests.test_vote_batching import CHAIN_ID, _net, _signed_vote
+
+    privs, _ = _net(24)
+    state = make_genesis_state(GenesisDoc(
+        chain_id=CHAIN_ID, genesis_time=Time(1700001000, 0),
+        validators=[GenesisValidator(b"", p.pub_key(), 10) for p in privs]))
+    wal_dir = tempfile.mkdtemp(prefix="trace-wal-", dir=tmp_path)
+    cs = ConsensusState(test_config().consensus, state, None, None,
+                        wal=WAL(wal_dir))
+    if tracer is not None:
+        cs.tracer = tracer
+    vals = cs.rs.votes.val_set
+    bid = BlockID(hash=b"\x77" * 32,
+                  part_set_header=PartSetHeader(total=1, hash=b"\x88" * 32))
+    votes = [_signed_vote(p, vals, PREVOTE_TYPE, bid) for p in privs[:11]]
+    flipped = _signed_vote(privs[11], vals, PREVOTE_TYPE, bid)
+    flipped.signature = bytes([flipped.signature[0] ^ 1]) + flipped.signature[1:]
+    early = _signed_vote(privs[12], vals, PREVOTE_TYPE, bid)
+    early.height = 2
+    stray = _signed_vote(privs[13], vals, PREVOTE_TYPE, bid)
+    stray.validator_index = 99
+    counted: list = []
+    cs.on_vote.append(lambda v: counted.append(
+        (v.type, v.height, v.validator_index)))
+    cs.rs.step = cstypes.STEP_PREVOTE
+    drained, ended = threading.Event(), threading.Event()
+    for v in votes[:10] + [votes[3], flipped, early, stray]:
+        cs._msg_queue.put(MsgInfo(VoteMessage(v), "peerX"))
+    cs._msg_queue.put(("__sync__", drained))    # ends the drain
+    cs._msg_queue.put(MsgInfo(VoteMessage(votes[10]), "peerY"))
+    cs._running = True
+    loop = threading.Thread(target=cs._receive_routine, name="cs-receive")
+    loop.start()
+    try:
+        assert drained.wait(timeout=30)
+        # the vote alone is taken once the queue behind it is empty
+        deadline = time.monotonic() + 30
+        while len(counted) < 11 and time.monotonic() < deadline:
+            time.sleep(0.01)
+        cs._msg_queue.put(("__sync__", ended))
+        assert ended.wait(timeout=30)
+    finally:
+        cs._running = False
+        cs._msg_queue.put(None)
+        loop.join(timeout=10)
+        assert not loop.is_alive()
+        cs.wal.close()
+    records = [(tm.msg.peer_id, bytes(tm.msg.payload))
+               for tm, _at in WAL(wal_dir).iter_messages()]
+    return cs, records, counted
+
+
+def test_the_merged_drain_paths_write_and_count_the_same_traced_or_not(
+        tmp_path):
+    """ISSUE 35 (D17): one path where PR 30 wrote two. With the tracer on
+    and off the receive loop writes the same WAL records in the same order
+    and counts the same votes; on, it names the phases with their CPU."""
+    cs_off, wal_off, counted_off = _drain_through_the_receive_loop(
+        trace.Tracer("off"), tmp_path)
+    t = trace.Tracer("on", enabled=True)
+    try:
+        cs_on, wal_on, counted_on = _drain_through_the_receive_loop(t, tmp_path)
+    finally:
+        t.disable()
+    assert wal_on == wal_off
+    assert [peer for peer, _ in wal_on if peer] == ["peerX"] * 14 + ["peerY"]
+    assert counted_on == counted_off and len(counted_on) == 11
+    for cs in (cs_on, cs_off):
+        assert sum(cs.rs.votes.prevotes(0).bit_array()) == 11
+    assert cs_off.tracer.dump() == []
+    spans = {s.name: s for s in t.dump()}
+    by_why = {s.tags["why"]: s for s in t.dump()
+              if s.name == "consensus.vote_serial"}
+    assert spans["consensus.wal_write"].tags["msgs"] == 14
+    assert spans["consensus.wal_write"].tags["bytes"] > 0
+    assert spans["consensus.vote_drain"].tags["votes"] == 14
+    apply_ = spans["consensus.vote_apply"]
+    assert (apply_.tags["votes"], apply_.tags["added"]) == (14, 10)
+    assert apply_.tags["duplicates"] >= 1 and apply_.tags["invalid"] == 1
+    assert set(by_why) == {"early", "precheck", "single"}
+    names = {s.name for s in t.dump()}
+    assert {"consensus.wal_write", "consensus.vote_drain", "consensus.vote_apply",
+            "consensus.vote_serial"} <= names
+    for s in t.dump():
+        assert s.thread == "cs-receive"
+        if s.name in ("consensus.step", "verify.queue", "verify.wake"):
+            assert s.cpu_s is None          # waits between two events
+        elif s.name.startswith("consensus.") and s.duration_s:
+            # span()s, and vote_serial's record() of accumulated thread_time()
+            assert 0.0 <= s.cpu_s <= s.duration_s + 1e-3, s
+
+
+def test_receive_reads_no_cpu_clock_and_the_mark_has_its_callers_cpu(tracer):
+    """The CPU clock is a system call (10 us and more on the benchmark's
+    host): ``receive`` notes who called and reads no CPU clock; once a height
+    the mark reads the callers' clocks from outside."""
+    import types
+
+    from tendermint_tpu.consensus import reactor as cs_reactor
+
+    cs, _wal, _counted = _drain_through_the_receive_loop(tracer)
+    r = cs_reactor.ConsensusReactor(cs)
+    r._receive = lambda *a: _spin(0.0005)
+    r._mark_recv(types.SimpleNamespace(height=7))      # the baseline
+    real = time.thread_time
+
+    def deliver():
+        for _ in range(40):
+            r.receive(0x22, object(), b"\x00" * 100)
+
+    try:
+        time.thread_time = lambda: (_ for _ in ()).throw(AssertionError(
+            "receive read the CPU clock"))
+        other = threading.Thread(target=deliver, name="deliverer-2")
+        other.start()
+        deliver()
+        other.join(timeout=30)
+        assert not other.is_alive()
+    finally:
+        time.thread_time = real
+    assert r.recv_stats[0x22][0] == 80 and r.recv_stats[0x22][2] == 8000
+    tracer.clear()
+    r._mark_recv(types.SimpleNamespace(height=8))
+    r._mark_recv(types.SimpleNamespace(height=8))       # once a height
+    (mark,) = [s for s in tracer.dump() if s.name == "consensus.recv"]
+    tags = mark.tags
+    assert (tags["height"], tags["msgs"], tags["bytes"]) == (7, 80, 8000)
+    # a thread that has exited by the time of the mark is named, its CPU lost
+    assert tags["threads"] == sorted(["deliverer-2",
+                                      threading.current_thread().name])
+    assert tags["seconds"] == pytest.approx(r.recv_stats[0x22][1])
+    if trace.thread_cpu_times() is not None:
+        # this thread's 40 messages of 0.5 ms of spinning, and a little more
+        assert 0.015 <= tags["cpu_s"] < 1.0
+    else:
+        assert tags["cpu_s"] is None
+    assert len(r.recv_stats[0x22]) == 3                 # the shape is fixed
