@@ -176,6 +176,16 @@ class PeerState:
             if bits is not None and 0 <= index < len(bits):
                 bits[index] = True
 
+    def apply_vote_set_bits(self, height, round_, type_, bits: BitArray,
+                            n_vals) -> None:
+        """The peer holds these votes: the same view a ``HasVote`` for each
+        would mark (the last commit's for the height before the peer's), and
+        no bit beyond the view's own length."""
+        with self.mtx:
+            view = self._votes_bits(height, round_, type_, n_vals)
+            if view is not None:
+                view.update(bits)
+
     def _votes_bits(self, height, round_, type_, n_vals) -> BitArray | None:
         prs = self.prs
         if prs.height == height:
@@ -212,9 +222,13 @@ class ConsensusReactor(Reactor):
         self._recv_cpu_at: dict | None = None
         self._recv_marked = (0, 0.0, 0)
         self._recv_height = None
+        # the votes the state machine added since the peers were last told,
+        # in the order it added them (written and read under its lock)
+        self._added: list[Vote] = []
         cs.on_new_round_step.append(self._mark_recv)
         cs.on_new_round_step.append(self._broadcast_new_round_step)
-        cs.on_vote.append(self._broadcast_has_vote)
+        cs.on_vote.append(self._note_vote)
+        cs.on_work_done.append(self._announce_votes)
         cs.on_valid_block.append(self._broadcast_new_valid_block)
         cs.broadcast = self._cs_broadcast
 
@@ -388,22 +402,15 @@ class ConsensusReactor(Reactor):
                                 vote.validator_index, n_vals)
                 self.cs.add_vote(vote, peer_id=peer.id)
         elif ch_id == VOTE_SET_BITS_CHANNEL:
-            if 9 in f:
+            if 9 in f:  # VoteSetBits: the votes the peer holds for a block id
                 m = proto.fields(f[9][-1])
-                # peer tells us which votes it has for a maj23
-                height = proto.as_sint64(m.get(1, [0])[-1])
-                round_ = proto.as_sint64(m.get(2, [0])[-1])
-                type_ = proto.as_sint64(m.get(3, [0])[-1])
-                bits = bits_unmarshal(m.get(5, [b""])[-1]) if 5 in m else []
-                with ps.mtx:
-                    table = ps.prs.prevotes if type_ == PREVOTE_TYPE else ps.prs.precommits
-                    if height == ps.prs.height:
-                        existing = table.get(round_)
-                        if existing is None:
-                            table[round_] = bits
-                        else:
-                            for i, b in enumerate(bits[: len(existing)]):
-                                existing[i] = existing[i] or b
+                ps.apply_vote_set_bits(
+                    proto.as_sint64(m.get(1, [0])[-1]),
+                    proto.as_sint64(m.get(2, [0])[-1]),
+                    proto.as_sint64(m.get(3, [0])[-1]),
+                    bits_unmarshal(m.get(5, [b""])[-1]),
+                    n_vals,
+                )
 
     def _handle_vote_set_maj23(self, peer, ps, height, round_, type_, bid) -> None:
         """reference: consensus/reactor.go:300-340."""
@@ -429,9 +436,11 @@ class ConsensusReactor(Reactor):
     def _cs_broadcast(self, msg) -> None:
         """Internally-generated proposal/parts/votes: peers get them via the
         gossip routines; nothing to do eagerly (reference relies on gossip).
-        Votes additionally trigger HasVote broadcasts via on_vote."""
+        Votes the state machine adds are announced by _announce_votes."""
 
     def _broadcast_new_round_step(self, rs) -> None:
+        # a peer hears of the votes of the step the node leaves first
+        self._announce_votes()
         if self.switch is None:
             return
         self.switch.broadcast(STATE_CHANNEL, self._new_round_step_msg(rs))
@@ -443,11 +452,68 @@ class ConsensusReactor(Reactor):
             rs.height, rs.round, rs.proposal_block_parts.header(),
             rs.proposal_block_parts.bit_array(), rs.step == cstypes.STEP_COMMIT))
 
-    def _broadcast_has_vote(self, vote: Vote) -> None:
+    def _note_vote(self, vote: Vote) -> None:
+        """``cs.on_vote``: nothing is sent per vote; see _announce_votes."""
+        self._added.append(vote)
+
+    def _announce_votes(self) -> None:
+        """Tell every peer which votes the state machine added since the last
+        call: at the end of a drain's apply, at the end of a single message,
+        and before a ``NewRoundStep``. The votes of one (height, round, type,
+        block id) go out as whichever is fewer bytes: their ``HasVote``s, or
+        one ``VoteSetBits`` with every vote the node holds for that block id
+        (a stock peer replaces its view for the block id with the array, so
+        only the whole array is right, and one dropped on a full send queue
+        is made good by the next). Messages leave in the order the votes
+        were added, an array where the first vote of its group stood."""
+        votes = self._added
+        if not votes:
+            return
+        self._added = []
         if self.switch is None:
             return
-        self.switch.broadcast(STATE_CHANNEL, msg_has_vote(
-            vote.height, vote.round, vote.type, vote.validator_index))
+        groups: dict[tuple, list] = {}
+        for at, v in enumerate(votes):
+            groups.setdefault((v.height, v.round, v.type, v.block_id.key()),
+                              []).append((at, v))
+        out: list[tuple[int, int, bytes]] = []   # (place, channel, message)
+        arrays = 0
+        for (height, round_, type_, _), group in groups.items():
+            first, block_id = group[0][0], group[0][1].block_id
+            held = self._held_bits(height, round_, type_, block_id)
+            whole = None if held is None else msg_vote_set_bits(
+                height, round_, type_, block_id, held)
+            has_votes, size = [], 0
+            for at, v in group:
+                msg = msg_has_vote(height, round_, type_, v.validator_index)
+                size += len(msg)
+                if whole is not None and size > len(whole):
+                    has_votes = [(first, VOTE_SET_BITS_CHANNEL, whole)]
+                    arrays += 1
+                    break
+                has_votes.append((at, STATE_CHANNEL, msg))
+            out += has_votes
+        out.sort()
+        for _, ch_id, msg in out:
+            self.switch.broadcast(ch_id, msg)
+        tr = self.cs.tracer
+        if tr.enabled:
+            tr.mark("consensus.announce", votes=len(votes),
+                    has_votes=len(out) - arrays, bit_arrays=arrays,
+                    bytes=sum(len(msg) for _, _, msg in out))
+
+    def _held_bits(self, height, round_, type_, block_id) -> BitArray | None:
+        """The votes the node holds for this block id, where it still holds
+        the set: the height's, or the last commit's for a late precommit."""
+        rs = self.cs.rs
+        vote_set = None
+        if height == rs.height and rs.votes is not None:
+            vote_set = (rs.votes.prevotes(round_) if type_ == PREVOTE_TYPE
+                        else rs.votes.precommits(round_))
+        elif (height + 1 == rs.height and type_ == PRECOMMIT_TYPE
+              and rs.last_commit is not None and rs.last_commit.round == round_):
+            vote_set = rs.last_commit
+        return None if vote_set is None else vote_set.bit_array_by_block_id(block_id)
 
     def _new_round_step_msg(self, rs) -> bytes:
         import time as _t

@@ -246,7 +246,10 @@ class ConsensusState:
         self.misbehaviors: dict = {}
         # decided-block callback fans (reactor hooks; reference evsw usage)
         self.on_new_round_step = []  # callbacks(rs)
-        self.on_vote = []  # callbacks(vote)
+        self.on_vote = []  # callbacks(vote): once per vote added, in order
+        # callbacks(): a unit of the receive loop's work has ended (one
+        # drain's apply, one message): what on_vote gathered can leave
+        self.on_work_done = []
         self.on_valid_block = []  # callbacks(rs)
         # called with each internally-generated message (own proposal, parts,
         # votes) for the reactor / test harness to gossip to peers
@@ -735,6 +738,8 @@ class ConsensusState:
                     acc[0] += 1
                 acc[2] += _time.thread_time() - c0
                 acc[1] += _time.monotonic() - t0
+            for cb in self.on_work_done:
+                cb()
             tr.annotate(added=counts["added"], invalid=counts["invalid"],
                         duplicates=counts["not_added"],
                         errors=counts["errors"])
@@ -800,6 +805,8 @@ class ConsensusState:
                 self._punish_peer(peer_id)  # serial twin of the drain bitmap
             if self.logger is not None:
                 self.logger.error("failed to process message", err=e, peer=peer_id)
+        for cb in self.on_work_done:
+            cb()
 
     def _do_handle_timeout(self, ti: TimeoutInfo) -> None:
         """reference: consensus/state.go:890-940 handleTimeout."""

@@ -324,6 +324,8 @@ class EventBus:
             return len({k[0] for k in self._subs})
 
     def publish(self, event_type: str, data, extra_events: dict[str, list[str]] | None = None) -> None:
+        if not self._subs:
+            return  # nobody listens: no dict, no message, no lock
         events = {EVENT_TYPE_KEY: [event_type]}
         if extra_events:
             for k, v in extra_events.items():

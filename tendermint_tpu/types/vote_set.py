@@ -36,16 +36,18 @@ class VoteSetError(Exception):
 class _BlockVotes:
     """Votes for one BlockID (reference: types/vote_set.go:560-590)."""
 
-    __slots__ = ("peer_maj23", "votes", "sum")
+    __slots__ = ("peer_maj23", "bit_array", "votes", "sum")
 
     def __init__(self, peer_maj23: bool, num_validators: int):
         self.peer_maj23 = peer_maj23
+        self.bit_array = BitArray(num_validators)
         self.votes: list[Vote | None] = [None] * num_validators
         self.sum = 0
 
     def add_verified_vote(self, vote: Vote, voting_power: int) -> None:
         idx = vote.validator_index
         if self.votes[idx] is None:
+            self.bit_array[idx] = True
             self.votes[idx] = vote
             self.sum += voting_power
 
@@ -325,7 +327,7 @@ class VoteSet:
         bv = self.votes_by_block.get(block_id.key())
         if bv is None:
             return None
-        return BitArray.from_bools([v is not None for v in bv.votes])
+        return bv.bit_array.copy()
 
     def has_two_thirds_majority(self) -> bool:
         return self.maj23 is not None

@@ -148,7 +148,9 @@ class BitArray:
         m = 0
         for i, elem in enumerate(elems):
             m |= elem << (64 * i)
-        ba._mask = m & ((1 << ba.bits) - 1) if ba.bits else 0
+        # a sender may claim any length: no mask of a length the elements
+        # do not reach is built
+        ba._mask = m & ((1 << ba.bits) - 1) if ba.bits < m.bit_length() else m
         return ba
 
     # --- display (reference String: "x" = set, "_" = unset) -----------------

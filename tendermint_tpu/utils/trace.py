@@ -91,13 +91,18 @@ CANONICAL_SPANS = {
     "consensus.flush_wait": "the consensus thread blocked on a vote flush's "
                             "bitmap (tag sigs)",
     "consensus.vote_apply": "a drain's votes through addVote in arrival "
-                            "order (tags votes, added, duplicates, invalid, "
-                            "errors)",
+                            "order, then the announcement of those added "
+                            "(tags votes, added, duplicates, invalid, errors)",
     "consensus.vote_serial": "votes the batch did not verify, in the serial "
                              "path (tags why = single / late / early / "
                              "precheck, votes; one record per drain and why)",
     "consensus.finalize_commit": "validate + save + apply of a decided "
                                  "block (parent of store_save, abci_apply)",
+    "consensus.announce": "the peers told which votes were added since the "
+                          "mark before (mark, one per flush that sent "
+                          "anything; tags votes, has_votes, bit_arrays = "
+                          "messages offered to each peer, bytes = their "
+                          "length)",
     "consensus.recv": "what ConsensusReactor.receive took since the mark "
                       "before, once a height (mark; tags msgs, seconds, "
                       "bytes, threads = who called it, cpu_s = the CPU "

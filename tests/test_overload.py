@@ -423,6 +423,7 @@ def test_vote_drain_bitmap_attributes_invalid_lanes_to_peers():
     cs.logger = None
     cs.tracer = trace.Tracer()
     cs.scoreboard = peerscore.PeerScoreBoard()
+    cs.on_work_done = []
     applied = []
     cs._try_add_vote = lambda vote, peer_id, verified=False: applied.append(
         (peer_id, verified)) or True
@@ -452,6 +453,7 @@ def test_serial_vote_path_scores_typed_invalid_signature():
     cs = ConsensusState.__new__(ConsensusState)
     cs.logger = None
     cs.scoreboard = peerscore.PeerScoreBoard()
+    cs.on_work_done = []
 
     def raise_invalid(vote, peer_id, verified=False):
         raise ErrVoteInvalidSignature("invalid signature")
