@@ -50,6 +50,7 @@ issuing its own fetch.
 from __future__ import annotations
 
 import os
+import threading
 from collections import deque
 from dataclasses import dataclass
 
@@ -60,6 +61,8 @@ from tendermint_tpu.types.validator_set import PendingCommitVerify
 from tendermint_tpu.utils import trace as _trace
 
 DEFAULT_DEPTH = 4
+# a traced sync marks what every thread got this often (fastsync.thread_cpu)
+CENSUS_EVERY = 10
 
 
 def verify_ahead_depth() -> int:
@@ -104,6 +107,11 @@ class VerifyAheadPipeline:
         # unresolved: dispatched - discarded - len(self) decisions resolved
         self.dispatched = 0
         self.discarded = 0
+        # heights applied, and the census of threads a traced sync reads
+        # every CENSUS_EVERY of them (None while tracing is off)
+        self.applied = 0
+        self._census = None
+        self._census_from = 0
 
     def __len__(self) -> int:
         return len(self._entries)
@@ -157,7 +165,14 @@ class VerifyAheadPipeline:
         if first is None or second is None:
             return None
         state = reactor.state
-        first_parts = PartSet.from_data(first.marshal())
+        if _trace.ENABLED:
+            tr = _trace.current()
+            with tr.span("fastsync.part_set", height=height):
+                first_parts = PartSet.from_data(first.marshal())
+                tr.annotate(bytes=first_parts.byte_size,
+                            parts=first_parts.count)
+        else:
+            first_parts = PartSet.from_data(first.marshal())
         first_id = BlockID(hash=first.hash(), part_set_header=first_parts.header())
         try:
             # same pre-checks, in the same order, as the serial loop
@@ -212,8 +227,24 @@ class VerifyAheadPipeline:
                 return self._process_next(reactor)
         return self._process_next(reactor)
 
+    def _mark_census(self, height: int) -> None:
+        """A traced sync's census of the process's threads: one
+        fastsync.thread_cpu mark every CENSUS_EVERY heights applied, counted
+        from the baseline the pipeline's first traced step read. Once in ten
+        heights and never per transaction: the thread clock is a system call
+        a thread."""
+        got = self._census.read()
+        if got is not None:
+            _trace.current().mark(
+                "fastsync.thread_cpu", height=height,
+                sync_thread=threading.current_thread().name, **got)
+
     def _process_next(self, reactor) -> bool:
         pool = reactor.pool
+        if _trace.ENABLED and self._census is None:
+            self._census = _trace.ThreadCensus()
+            self._census.read()          # the baseline: writes no mark
+            self._census_from = self.applied
         for _ in range(2):
             self._fill(reactor)
             if not self._entries:
@@ -277,4 +308,8 @@ class VerifyAheadPipeline:
             else:
                 reactor.state, _ = reactor.block_exec.apply_block(
                     reactor.state, head.first_id, head.first)
+        self.applied += 1
+        if (self._census is not None and _trace.ENABLED
+                and (self.applied - self._census_from) % CENSUS_EVERY == 0):
+            self._mark_census(head.height)
         return True

@@ -176,6 +176,9 @@ class BlockchainReactor(Reactor):
         # the node's StoreRepairer (store/repair.py): BlockResponses feed
         # its fetch waiters, corrupt serving-side loads route to it
         self.repairer = None
+        # what the invalid-block path last refused: (height, the exception,
+        # the peers whose blocks were dropped for it)
+        self.last_invalid: tuple | None = None
         self._running = False
         self._thread: threading.Thread | None = None
         self._synced = threading.Event()
@@ -330,6 +333,7 @@ class BlockchainReactor(Reactor):
         ban, not recycle free disconnects."""
         bad = self.pool.redo_request(height)
         bad2 = self.pool.redo_request(height + 1)
+        self.last_invalid = (height, e, sorted({bad, bad2} - {None}))
         if self.switch is not None:
             board = getattr(self.switch, "scoreboard", None)
             for pid in {bad, bad2} - {None}:

@@ -20,6 +20,7 @@ from tendermint_tpu.mempool.ingest import IngestCoalescer
 from tendermint_tpu.mempool import ingest as _ingest
 from tendermint_tpu.types.tx import tx_key
 from tendermint_tpu.utils import faults
+from tendermint_tpu.utils import trace as _trace
 
 
 class MempoolError(Exception):
@@ -530,6 +531,15 @@ class Mempool:
         mempool/v0/clist_mempool.go:577-639). Caller must hold the lock.
         pre_check/post_check, when given, replace the admission filters —
         they derive from the NEW state (state/tx_filter.py)."""
+        # in the ring of whoever applies the block, under its apply.save
+        with (_trace.current().span("mempool.update", height=height,
+                                    txs=len(txs))
+              if _trace.ENABLED else _trace.NULL_SPAN):
+            self._update(height, txs, deliver_tx_responses, pre_check,
+                         post_check)
+
+    def _update(self, height, txs, deliver_tx_responses, pre_check,
+                post_check) -> None:
         if pre_check is not None:
             self.pre_check = pre_check
         if post_check is not None:

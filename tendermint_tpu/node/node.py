@@ -266,6 +266,7 @@ class Node:
         self.block_indexer = None
         self.indexer_service = None
         self.event_sink = None
+        self._idx_db = None
         if config.tx_index.indexer == "kv":
             from tendermint_tpu.state.txindex import (
                 BlockIndexer,
@@ -283,6 +284,7 @@ class Node:
             self.store_repairer.block_indexer = self.block_indexer
             self.indexer_service = IndexerService(
                 self.tx_indexer, self.block_indexer, self.event_bus, logger)
+            self._idx_db = idx_db
         elif config.tx_index.indexer == "psql":
             # Write-only SQL sink (reference: node/node.go:282-299 "psql");
             # tx/block search RPCs report unsupported, as upstream.
@@ -299,6 +301,12 @@ class Node:
             self.block_indexer = sink.block_indexer()
             self.indexer_service = IndexerService(
                 self.tx_indexer, self.block_indexer, self.event_bus, logger)
+
+        if self.indexer_service is not None:
+            # the heights the indexer has not caught up with count into the
+            # backlog that holds apply_block back (docs/EXECUTION.md)
+            self.block_exec.follow_backlog(self.indexer_service.backlog_heights)
+            self.indexer_service.on_indexed = self.block_exec.backlog_changed
 
         # Prometheus metrics (reference: node/node.go:118-132 MetricsProvider)
         self.metrics = None
@@ -499,16 +507,20 @@ class Node:
             self.rpc_server.stop()
         if getattr(self, "grpc_server", None) is not None:
             self.grpc_server.stop()
-        if self.indexer_service is not None:
-            self.indexer_service.stop()
-        if self.event_sink is not None:
-            self.event_sink.stop()
         if self.metrics_server is not None:
             self.metrics_server.stop()
         self.consensus.stop()
         # drain queued post-commit event publishes (so indexers/subscribers
-        # see every committed height), then park the worker thread
+        # see every committed height), let the index catch up with what was
+        # published, then detach the indexer and park the worker thread
         self.block_exec.flush_post_commit(timeout_s=5.0)
+        if self.indexer_service is not None:
+            if self.indexer_service.backlog_heights():
+                self.indexer_service.wait_indexed(self.block_store.height,
+                                                  timeout_s=5.0)
+            self.indexer_service.stop()
+        if self.event_sink is not None:
+            self.event_sink.stop()
         self.block_exec.stop()
         self.switch.stop()
         if getattr(self, "signer_endpoint", None) is not None:
@@ -526,6 +538,15 @@ class Node:
 
         if not crypto_batch.WARMUP.join(timeout=600.0) and self.logger:
             self.logger.error("kernel warm-up still running after 600 s")
+
+    def close_stores(self) -> None:
+        """Close the block, state and index stores' connections (sqlite
+        folds its WAL back into the file). For a stopped node whose process
+        lives on: stop() leaves the stores readable, and a process that
+        exits needs neither."""
+        for db in (self.block_store._db, self.state_store._db, self._idx_db):
+            if db is not None:
+                db.close()
 
     def abort(self) -> None:
         """Power-loss teardown (docs/SOAK.md crash actions): release this

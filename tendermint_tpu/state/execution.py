@@ -126,20 +126,44 @@ def _observe_invalid_txs(n: int) -> None:
 # --- post-commit worker (docs/EXECUTION.md) ---------------------------------
 
 
+# How many heights may be behind apply_block -- post-commit tasks not yet
+# run, plus headers published and not yet indexed -- before the next apply
+# waits for them (docs/EXECUTION.md). The reference publishes inside
+# ApplyBlock and its indexer's subscription is unbuffered, so a slow indexer
+# holds the caller back there too; without a bound every queued height keeps
+# its block and its responses alive. A constant, not a knob.
+MAX_BACKLOG_HEIGHTS = 2
+# a waiter looks again this often whatever it was told: a follower that
+# died, or was stopped, then cannot hold an apply for good
+_BACKLOG_POLL_S = 0.05
+
+
 class PostCommitWorker:
     """Single FIFO daemon thread for post-commit work (event publish →
     tx index, RPC subscribers) so `apply_block` returns as soon as state
     is durably saved. One queue, one thread: work for height h runs
     before work for h+1, the ordering subscribers rely on. Crash-shielded:
-    a failing task is dropped and later heights still publish."""
+    a failing task is dropped and later heights still publish.
+
+    Counters: ``submitted`` and ``done`` tasks, and ``backlog_max``, the most
+    that were ever waiting or running at once. ``on_done`` is called after
+    every task (the executor's backlog gate listens there)."""
 
     _STOP = object()
 
-    def __init__(self, logger=None):
+    def __init__(self, logger=None, on_done=None):
         self._logger = logger
         self._q: queue.SimpleQueue = queue.SimpleQueue()
         self._thread: threading.Thread | None = None
         self._mtx = threading.Lock()
+        self._on_done = on_done
+        self.submitted = 0
+        self.done = 0
+        self.backlog_max = 0
+
+    def backlog(self) -> int:
+        """Tasks submitted and not yet finished."""
+        return self.submitted - self.done
 
     def submit(self, fn) -> None:
         with self._mtx:
@@ -149,7 +173,20 @@ class PostCommitWorker:
                                      daemon=True)
                 self._thread = t
                 t.start()
-        self._q.put(fn)
+            self.submitted += 1
+            self.backlog_max = max(self.backlog_max, self.backlog())
+        self._q.put(self._counted(fn))
+
+    def _counted(self, fn):
+        def task():
+            try:
+                fn()
+            finally:
+                with self._mtx:
+                    self.done += 1
+                if self._on_done is not None:
+                    self._on_done()
+        return task
 
     def flush(self, timeout_s: float = 10.0) -> bool:
         """Block until everything submitted so far has run (tests,
@@ -252,8 +289,14 @@ class BlockExecutor:
         self.block_store = block_store
         self.logger = logger
         self.metrics = metrics
+        # the backlog gate (MAX_BACKLOG_HEIGHTS): whoever shortens the
+        # backlog notifies this condition
+        self._backlog_cv = threading.Condition()
+        self._follower = None
+        self.backlog_waits = 0
         # lazy: no thread until the first post-commit submission
-        self._post_commit = PostCommitWorker(logger)
+        self._post_commit = PostCommitWorker(logger,
+                                             on_done=self.backlog_changed)
 
     # --- proposal creation (reference: state/execution.go:94-129) ----------
 
@@ -301,6 +344,43 @@ class BlockExecutor:
             last_block_id=state.last_block_id,
             vals_hash=state.last_validators.hash())
 
+    # --- the backlog behind apply_block (docs/EXECUTION.md) -----------------
+
+    @property
+    def post_commit(self) -> PostCommitWorker:
+        """The worker, for its counters (submitted, done, backlog_max)."""
+        return self._post_commit
+
+    def follow_backlog(self, heights_waiting) -> None:
+        """Count the consumer of the post-commit events into the backlog:
+        ``heights_waiting()`` says how many published heights it has not
+        finished (the node's IndexerService.backlog_heights). The consumer
+        calls ``backlog_changed`` whenever that number falls."""
+        self._follower = heights_waiting
+
+    def backlog_heights(self) -> int:
+        """Heights applied whose post-commit work is not finished: tasks
+        the worker still holds, and what the follower still holds."""
+        follower = self._follower
+        return self._post_commit.backlog() + (follower() if follower else 0)
+
+    def backlog_changed(self) -> None:
+        with self._backlog_cv:
+            self._backlog_cv.notify_all()
+
+    def _await_backlog(self, tr) -> None:
+        """Hold the next apply while MAX_BACKLOG_HEIGHTS heights or more are
+        still behind it."""
+        bound = MAX_BACKLOG_HEIGHTS
+        if self.backlog_heights() < bound:
+            return
+        self.backlog_waits += 1
+        with (tr.span("apply.backlog_wait", backlog=self.backlog_heights(),
+                      bound=bound) if tr else _trace.NULL_SPAN):
+            with self._backlog_cv:
+                while self.backlog_heights() >= bound:
+                    self._backlog_cv.wait(_BACKLOG_POLL_S)
+
     def flush_post_commit(self, timeout_s: float = 10.0) -> bool:
         """Wait for all queued post-commit work (event publish) to run."""
         return self._post_commit.flush(timeout_s)
@@ -321,6 +401,8 @@ class BlockExecutor:
         # the four phases of an apply, as spans of the active tracer
         # (docs/OBSERVABILITY.md); one attribute load each while tracing is off
         tr = _trace.current() if _trace.ENABLED else None
+        if self.event_bus is not None:
+            self._await_backlog(tr)
         with tr.span("apply.validate") if tr else _trace.NULL_SPAN:
             self.validate_block(state, block, commit_pending=commit_pending)
 
@@ -412,32 +494,41 @@ class BlockExecutor:
             return
         from tendermint_tpu.types import events
 
-        with _trace.current().span("apply.post_commit",
-                                   height=block.header.height):
-            self._publish_events(block, block_id, abci_responses,
-                                 validator_updates, events)
+        tr = _trace.current()
+        with tr.span("apply.post_commit", height=block.header.height,
+                     txs=len(block.data.txs),
+                     events=2 + len(block.evidence) + len(block.data.txs)
+                     + (1 if validator_updates else 0)):
+            with tr.span("events.publish_block"):
+                tr.annotate(events=self._publish_events(
+                    block, block_id, abci_responses, validator_updates,
+                    events))
 
     def _publish_events(self, block, block_id, abci_responses,
-                        validator_updates, events) -> None:
-        self.event_bus.publish_event_new_block(
+                        validator_updates, events) -> int:
+        """-> messages matched and queued to a subscription (an event bus
+        that does not count them reads as none)."""
+        bus = self.event_bus
+        queued = bus.publish_event_new_block(
             events.EventDataNewBlock(block=block, block_id=block_id,
                                      result_begin_block=abci_responses.begin_block,
-                                     result_end_block=abci_responses.end_block))
-        self.event_bus.publish_event_new_block_header(
+                                     result_end_block=abci_responses.end_block)) or 0
+        queued += bus.publish_event_new_block_header(
             events.EventDataNewBlockHeader(header=block.header,
                                            num_txs=len(block.data.txs),
                                            result_begin_block=abci_responses.begin_block,
-                                           result_end_block=abci_responses.end_block))
+                                           result_end_block=abci_responses.end_block)) or 0
         for ev in block.evidence:
-            self.event_bus.publish_event_new_evidence(
-                events.EventDataNewEvidence(evidence=ev, height=block.header.height))
+            queued += bus.publish_event_new_evidence(
+                events.EventDataNewEvidence(evidence=ev, height=block.header.height)) or 0
         for i, tx in enumerate(block.data.txs):
-            self.event_bus.publish_event_tx(events.EventDataTx(
+            queued += bus.publish_event_tx(events.EventDataTx(
                 height=block.header.height, tx=tx, index=i,
-                result=abci_responses.deliver_txs[i]))
+                result=abci_responses.deliver_txs[i])) or 0
         if validator_updates:
-            self.event_bus.publish_event_validator_set_updates(
-                events.EventDataValidatorSetUpdates(validator_updates=validator_updates))
+            queued += bus.publish_event_validator_set_updates(
+                events.EventDataValidatorSetUpdates(validator_updates=validator_updates)) or 0
+        return queued
 
 
 def update_state(state: State, block_id: BlockID, block: Block,

@@ -25,6 +25,7 @@ from tendermint_tpu.types.params import ConsensusParams
 from tendermint_tpu.types.ttime import Time
 from tendermint_tpu.types.validator_set import ValidatorSet
 from tendermint_tpu.utils import faults
+from tendermint_tpu.utils import trace as _trace
 
 _STATE_KEY = b"stateKey"
 VALSET_CHECK_INTERVAL = 100000  # reference: state/store.go valSetCheckpointInterval
@@ -253,7 +254,15 @@ class StateStore:
     # --- ABCI responses ----------------------------------------------------
 
     def save_abci_responses(self, height: int, responses: ABCIResponses) -> None:
-        self._set(_abci_key(height), responses.marshal())
+        if not _trace.ENABLED:
+            self._set(_abci_key(height), responses.marshal())
+            return
+        tr = _trace.current()
+        with tr.span("state.save_responses", height=height,
+                     txs=len(responses.deliver_txs)):
+            raw = responses.marshal()
+            self._set(_abci_key(height), raw)
+            tr.annotate(bytes=len(raw))
 
     def load_abci_responses(self, height: int) -> ABCIResponses:
         resp = self._load_checked(_abci_key(height), ABCIResponses.unmarshal)

@@ -25,6 +25,7 @@ import threading
 from tendermint_tpu.store import envelope
 from tendermint_tpu.store.db import DB, prefix_end
 from tendermint_tpu.utils import faults
+from tendermint_tpu.utils import trace as _trace
 from tendermint_tpu.types import events as tmevents
 from tendermint_tpu.types.tx import tx_hash
 
@@ -79,6 +80,8 @@ class TxIndexer:
         self._mtx = threading.Lock()
         self._staged: list | None = None
         self.on_corruption = None
+        # (rows, bytes) of the last height_txn's batch, for indexer.height
+        self.last_batch = (0, 0)
 
     @contextlib.contextmanager
     def height_txn(self):
@@ -101,6 +104,9 @@ class TxIndexer:
                 sets, self._staged = self._staged, None
                 if sets:
                     self._db.write_batch(sets)
+                if _trace.ENABLED:
+                    self.last_batch = (len(sets),
+                                       sum(len(v) for _k, v in sets))
 
     def index(self, height: int, idx: int, tx: bytes, result) -> None:
         h = tx_hash(tx)
@@ -296,6 +302,39 @@ class IndexerService:
         self.logger = logger
         self._running = False
         self._thread: threading.Thread | None = None
+        self._block_sub = None
+        # counters: heights and transactions indexed; the most events (tx
+        # and header messages) and the most heights (header messages, the
+        # one in hand included) that ever waited for this service
+        self.heights_indexed = 0
+        self.txs_indexed = 0
+        self.backlog_max = 0
+        self.backlog_heights_max = 0
+        self.last_indexed_height = 0
+        self._in_hand = 0
+        self._indexed_cv = threading.Condition()
+        # called after every height indexed (the node points it at its
+        # BlockExecutor.backlog_changed)
+        self.on_indexed = None
+
+    def backlog_heights(self) -> int:
+        """Heights published to this service and not yet indexed: headers
+        waiting, and the height in hand. 0 once the drain thread is gone,
+        so nobody waits on a service that will never catch up."""
+        sub, t = self._block_sub, self._thread
+        if sub is None or not self._running or t is None or not t.is_alive():
+            return 0
+        return len(sub.queue) + self._in_hand
+
+    def wait_indexed(self, height: int, timeout_s: float) -> bool:
+        """Block until every height up to ``height`` is in the index (its
+        header's and its transactions' rows written). False on timeout, or
+        when the service stops first."""
+        with self._indexed_cv:
+            return self._indexed_cv.wait_for(
+                lambda: self.last_indexed_height >= height
+                or not self._running, timeout_s
+            ) and self.last_indexed_height >= height
 
     def start(self) -> None:
         self._tx_sub = self.event_bus.subscribe(
@@ -312,6 +351,8 @@ class IndexerService:
 
     def stop(self) -> None:
         self._running = False
+        with self._indexed_cv:
+            self._indexed_cv.notify_all()
         try:
             self.event_bus.unsubscribe_all(self.SUBSCRIBER)
         except ValueError:
@@ -340,7 +381,27 @@ class IndexerService:
             bmsg = self._block_sub.next(timeout=0.1)
             if bmsg is None:
                 continue
-            d = bmsg.data
+            self._in_hand = 1
+            # what had piled up when the service turned to this height: the
+            # header in hand, those behind it, their transactions' messages
+            waiting = len(self._block_sub.queue) + 1
+            self.backlog_heights_max = max(self.backlog_heights_max, waiting)
+            self.backlog_max = max(self.backlog_max,
+                                   waiting + len(self._tx_sub.queue))
+            try:
+                if not self._index_height(bmsg.data):
+                    return
+            finally:
+                self._in_hand = 0
+            if self.on_indexed is not None:
+                self.on_indexed()
+
+    def _index_height(self, d) -> bool:
+        """One height: its header, then its num_txs transactions. False
+        when the service stopped before the height was whole."""
+        tr = _trace.current() if _trace.ENABLED else None
+        with (tr.span("indexer.height", height=d.header.height,
+                      txs=d.num_txs) if tr else _trace.NULL_SPAN):
             # Batch the height: every posting of this block (header + its
             # num_txs tx results) lands in ONE indexer transaction when the
             # backend offers a height_txn seam (kv batches the store write,
@@ -365,7 +426,7 @@ class IndexerService:
                     while self._running and msg is None:
                         msg = self._tx_sub.next(timeout=0.1)
                     if msg is None:
-                        return
+                        return False
                     t = msg.data
                     try:
                         self.tx_indexer.index(t.height, t.index, t.tx,
@@ -373,3 +434,13 @@ class IndexerService:
                     except Exception as e:  # noqa: BLE001
                         if self.logger:
                             self.logger.error("failed to index tx", err=e)
+            # the transaction is written: the height is in the index
+            if tr:
+                rows, nbytes = getattr(self.tx_indexer, "last_batch", (0, 0))
+                tr.annotate(rows=rows, bytes=nbytes)
+        with self._indexed_cv:
+            self.heights_indexed += 1
+            self.txs_indexed += d.num_txs
+            self.last_indexed_height = d.header.height
+            self._indexed_cv.notify_all()
+        return True

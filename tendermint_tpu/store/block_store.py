@@ -26,6 +26,7 @@ from tendermint_tpu.encoding import proto
 from tendermint_tpu.store import envelope
 from tendermint_tpu.store.db import DB, prefix_end
 from tendermint_tpu.utils import faults
+from tendermint_tpu.utils import trace as _trace
 from tendermint_tpu.types.block import Block, Commit, Header
 from tendermint_tpu.types.block_id import BlockID
 from tendermint_tpu.types.part_set import Part, PartSet
@@ -209,7 +210,9 @@ class BlockStore:
         if block is None:
             raise ValueError("BlockStore can only save a non-nil block")
         height = block.header.height
-        with self._mtx:
+        tr = _trace.current() if _trace.ENABLED else None
+        with (tr.span("store.save_block", height=height) if tr
+              else _trace.NULL_SPAN), self._mtx:
             want = self.height + 1
             if self.height > 0 and height != want:
                 raise ValueError(f"BlockStore can only save contiguous blocks. Wanted {want}, got {height}")
@@ -226,6 +229,9 @@ class BlockStore:
             sets.append((_STATE_KEY, envelope.wrap(self._state_bytes())))
             faults.fire("store.block.save")
             self._db.write_batch(sets)
+            if tr:
+                tr.annotate(bytes=sum(len(v) for _k, v in sets),
+                            parts=part_set.count, rows=len(sets))
 
     def save_seen_commit(self, height: int, seen_commit: Commit) -> None:
         """Standalone seen-commit write for the state-sync bootstrap

@@ -15,6 +15,7 @@ from tendermint_tpu.encoding import proto
 from tendermint_tpu.types import tx as tx_mod
 from tendermint_tpu.types.block_id import BlockID
 from tendermint_tpu.types.ttime import Time
+from tendermint_tpu.utils import trace as _trace
 from tendermint_tpu.types.vote import (
     BLOCK_ID_FLAG_ABSENT,
     BLOCK_ID_FLAG_COMMIT,
@@ -379,6 +380,9 @@ class Data:
     txs: list[bytes] = dc_field(default_factory=list)
 
     def hash(self) -> bytes:
+        if _trace.ENABLED and self.txs:
+            with _trace.current().span("block.data_hash", txs=len(self.txs)):
+                return tx_mod.txs_hash(self.txs)
         return tx_mod.txs_hash(self.txs)
 
     def marshal(self) -> bytes:

@@ -323,9 +323,10 @@ class EventBus:
         with self._mtx:
             return len({k[0] for k in self._subs})
 
-    def publish(self, event_type: str, data, extra_events: dict[str, list[str]] | None = None) -> None:
+    def publish(self, event_type: str, data, extra_events: dict[str, list[str]] | None = None) -> int:
+        """-> how many subscriptions the message matched and was queued to."""
         if not self._subs:
-            return  # nobody listens: no dict, no message, no lock
+            return 0  # nobody listens: no dict, no message, no lock
         events = {EVENT_TYPE_KEY: [event_type]}
         if extra_events:
             for k, v in extra_events.items():
@@ -333,24 +334,30 @@ class EventBus:
         msg = PubSubMessage(data=data, events=events)
         with self._mtx:
             subs = list(self._subs.values())
+        queued = 0
         for sub in subs:
             if sub.query.matches(events):
                 sub.publish(msg)
+                queued += 1
+        return queued
 
     # --- typed publishers (reference: types/event_bus.go:80-300) -----------
 
-    def publish_event_new_block(self, data: EventDataNewBlock) -> None:
+    # the publishers of a block's own events return publish()'s count, so
+    # the post-commit task can say how many messages a height queued
+
+    def publish_event_new_block(self, data: EventDataNewBlock) -> int:
         extra = _abci_events(data.result_begin_block, data.result_end_block)
-        self.publish(EVENT_NEW_BLOCK, data, extra)
+        return self.publish(EVENT_NEW_BLOCK, data, extra)
 
-    def publish_event_new_block_header(self, data: EventDataNewBlockHeader) -> None:
+    def publish_event_new_block_header(self, data: EventDataNewBlockHeader) -> int:
         extra = _abci_events(data.result_begin_block, data.result_end_block)
-        self.publish(EVENT_NEW_BLOCK_HEADER, data, extra)
+        return self.publish(EVENT_NEW_BLOCK_HEADER, data, extra)
 
-    def publish_event_new_evidence(self, data: EventDataNewEvidence) -> None:
-        self.publish(EVENT_NEW_EVIDENCE, data)
+    def publish_event_new_evidence(self, data: EventDataNewEvidence) -> int:
+        return self.publish(EVENT_NEW_EVIDENCE, data)
 
-    def publish_event_tx(self, data: EventDataTx) -> None:
+    def publish_event_tx(self, data: EventDataTx) -> int:
         from tendermint_tpu.types.tx import tx_hash
 
         extra: dict[str, list[str]] = {
@@ -363,7 +370,7 @@ class EventBus:
                     if attr.index:
                         key = f"{ev.type}.{attr.key.decode(errors='replace')}"
                         extra.setdefault(key, []).append(attr.value.decode(errors="replace"))
-        self.publish(EVENT_TX, data, extra)
+        return self.publish(EVENT_TX, data, extra)
 
     def publish_event_vote(self, data: EventDataVote) -> None:
         self.publish(EVENT_VOTE, data)
@@ -398,8 +405,8 @@ class EventBus:
     def publish_event_lock(self, data: EventDataRoundState) -> None:
         self.publish(EVENT_LOCK, data)
 
-    def publish_event_validator_set_updates(self, data: EventDataValidatorSetUpdates) -> None:
-        self.publish(EVENT_VALIDATOR_SET_UPDATES, data)
+    def publish_event_validator_set_updates(self, data: EventDataValidatorSetUpdates) -> int:
+        return self.publish(EVENT_VALIDATOR_SET_UPDATES, data)
 
 
 def _abci_events(begin_block, end_block) -> dict[str, list[str]]:

@@ -151,6 +151,13 @@ CANONICAL_SPANS = {
     "fastsync.discard": "speculative dispatches thrown away unresolved "
                         "(mark; tags entries, reason = valset / pool / "
                         "error, height of the first)",
+    "fastsync.part_set": "a pooled block marshalled and cut into parts "
+                         "before its dispatch (span; tags bytes, parts)",
+    "fastsync.thread_cpu": "CPU seconds of every live thread since the mark "
+                           "before, every 10 heights a pipeline applied "
+                           "(mark; tags wall_s, process_s, rest_s, lost, "
+                           "threads = name -> s, sync_thread = who called "
+                           "process_next)",
     # tx front door + gossip plane
     "mempool.check_tx": "ABCI CheckTx round trip of one tx",
     "mempool.ingest_batch": "one batched ABCI CheckTxBatch dispatch of the "
@@ -167,7 +174,24 @@ CANONICAL_SPANS = {
     "abci.deliver_batch": "one batched ABCI DeliverTxBatch chunk dispatch "
                           "(span; n= txs)",
     "apply.post_commit": "post-commit event publish of one height on the "
-                         "async worker (span; height= tag)",
+                         "async worker (span; tags height, txs, events = "
+                         "messages published)",
+    "apply.backlog_wait": "apply_block held until the heights still behind "
+                          "it (post-commit tasks, headers waiting for the "
+                          "indexer) fell under the bound (span; tags "
+                          "backlog, bound)",
+    "events.publish_block": "one height's messages through the event bus, "
+                            "on the publishing thread (span; tags height, "
+                            "events = messages matched and queued)",
+    "indexer.height": "one height through the indexer service: its header "
+                      "and its transactions in one indexer transaction "
+                      "(span; tags height, txs, rows, bytes)",
+    "mempool.update": "Mempool.update of one committed block (span; tags "
+                      "height, txs)",
+    "block.data_hash": "the Merkle root of a block's transactions "
+                       "(Data.hash; span; tag txs)",
+    "state.save_responses": "a height's ABCI responses marshalled and saved "
+                            "(span; tags height, txs, bytes)",
     # the four phases of BlockExecutor.apply_block, in order
     "apply.validate": "validate_block: header against state, LastCommit's "
                       "full verify_commit (or its resolve), block time (span)",
@@ -179,6 +203,9 @@ CANONICAL_SPANS = {
     "apply.save": "app Commit, mempool and evidence update, the state "
                   "store's save (span)",
     # self-healing storage plane (store/scrub.py, store/repair.py)
+    "store.save_block": "BlockStore.save_block: meta, parts, commits and "
+                        "the store's state in one batch (span; tags height, "
+                        "bytes, parts, rows)",
     "store.scrub": "one integrity-scrub pass over a node's stores (span)",
     "store.repair": "peer re-fetch + batch-verified rewrite of one damaged "
                     "height (span; height= tag)",
@@ -666,7 +693,7 @@ def thread_cpu_table() -> dict | None:
 
 class ThreadCensus:
     """What each thread got between two readings: the tags of a
-    ``consensus.thread_cpu`` mark."""
+    ``consensus.thread_cpu`` or ``fastsync.thread_cpu`` mark."""
 
     def __init__(self):
         self._last = None  # (monotonic, process_time, {Thread: cpu seconds})

@@ -544,3 +544,271 @@ def test_deliver_metrics_preseeded_and_counted():
         tmmetrics.GLOBAL_NODE_METRICS = prev
     assert "tendermint_abci_deliver_tx_invalid_total 2.0" in nm2
     assert "tendermint_abci_deliver_batch_size_count 0" not in nm2
+
+
+# ---------------------------------------------------------------------------
+# the backlog behind apply_block: counters, wait_indexed, the bound (PR 37)
+# ---------------------------------------------------------------------------
+
+
+def _indexed_executor():
+    """A BlockExecutor over the kvstore whose events an IndexerService over
+    MemDB indexes, wired as node/node.py wires them."""
+    from tendermint_tpu.state.txindex import (BlockIndexer, IndexerService,
+                                              TxIndexer)
+    from tendermint_tpu.types.events import EventBus
+
+    gd, privs = _genesis()
+    state = make_genesis_state(gd)
+    store = StateStore(MemDB())
+    store.save(state)
+    bus = EventBus()
+    bx = BlockExecutor(store, KVStoreApplication(), event_bus=bus)
+    idx_db = MemDB()
+    txi = TxIndexer(idx_db)
+    svc = IndexerService(txi, BlockIndexer(idx_db), bus)
+    bx.follow_backlog(svc.backlog_heights)
+    svc.on_indexed = bx.backlog_changed
+    return bx, svc, state, privs
+
+
+def _apply(bx, state, privs, heights, txs_per_block, last_commit=None,
+           before_each=None):
+    last_commit = last_commit or Commit(height=0, round=0, block_id=BlockID(),
+                                        signatures=[])
+    for h in heights:
+        txs = [b"h%d-k%d=v" % (h, i) for i in range(txs_per_block)]
+        block = state.make_block(h, txs, last_commit, [],
+                                 state.validators.get_proposer().address)
+        bid, last_commit = _commit_for(state, block, privs)
+        if before_each is not None:
+            before_each()
+        state, _ = bx.apply_block(state, bid, block)
+    return state, last_commit
+
+
+def test_the_event_bus_says_how_many_subscriptions_it_queued_to():
+    from tendermint_tpu.types import events
+
+    bus = events.EventBus()
+    assert bus.publish(events.EVENT_TX, object()) == 0
+    bus.subscribe("a", "tm.event=Tx")
+    bus.subscribe("b", "tm.event=Tx AND tx.height=3")
+    bus.subscribe("c", "tm.event=NewBlock")
+    data = events.EventDataTx(height=3, tx=b"k=v", index=0)
+    assert bus.publish_event_tx(data) == 2
+    assert bus.publish_event_tx(events.EventDataTx(height=4, tx=b"k=v")) == 1
+    assert bus.publish_event_new_block(events.EventDataNewBlock()) == 1
+    assert bus.publish_event_new_block_header(
+        events.EventDataNewBlockHeader()) == 0
+
+
+def test_the_post_commit_worker_counts_and_the_spans_say_what_was_published():
+    from tendermint_tpu.utils import trace
+
+    bx, svc, state, privs = _indexed_executor()
+    svc.start()
+    worker = bx.post_commit
+    assert (worker.submitted, worker.done, worker.backlog_max) == (0, 0, 0)
+    trace.dump(clear=True)
+    trace.enable()
+    try:
+        _apply(bx, state, privs, (1, 2, 3), 5)
+        assert bx.flush_post_commit(timeout_s=10.0)
+        assert svc.wait_indexed(3, timeout_s=10.0)
+    finally:
+        trace.disable()
+    spans = trace.dump(clear=True)
+    assert (worker.submitted, worker.done) == (3, 3)
+    assert 1 <= worker.backlog_max <= 2 and worker.backlog() == 0
+    posted = [s for s in spans if s.name == "apply.post_commit"]
+    assert [(s.tags["height"], s.tags["txs"], s.tags["events"])
+            for s in posted] == [(1, 5, 7), (2, 5, 7), (3, 5, 7)]
+    assert {s.thread for s in posted} == {"post-commit"}
+    # the indexer's two subscriptions take the header and the five
+    # transactions; nobody takes NewBlock
+    published = [s for s in spans if s.name == "events.publish_block"]
+    assert [s.tags["events"] for s in published] == [6, 6, 6]
+    assert [s.parent_id for s in published] == [s.span_id for s in posted]
+    indexed = [s for s in spans if s.name == "indexer.height"]
+    assert [(s.tags["height"], s.tags["txs"]) for s in indexed] == [
+        (1, 5), (2, 5), (3, 5)]
+    for s in indexed:
+        # a document and two postings a transaction (tx.height, app.creator)
+        assert s.tags["rows"] == 15 and s.tags["bytes"] > 0
+        assert s.thread == "indexer" and s.cpu_s <= s.duration_s + 1e-3
+    delivered = [s for s in spans if s.name == "abci.deliver_txs"]
+    assert [s.tags["n"] for s in delivered] == [5, 5, 5]
+    svc.stop()
+    bx.stop()
+
+
+def test_a_failing_post_commit_task_still_counts_as_done():
+    w = PostCommitWorker()
+    w.submit(lambda: 1 / 0)
+    w.submit(lambda: None)
+    assert w.flush(timeout_s=5.0)
+    assert (w.submitted, w.done, w.backlog()) == (2, 2, 0)
+    w.stop()
+
+
+def test_wait_indexed_and_the_indexers_counters():
+    bx, svc, state, privs = _indexed_executor()
+    # not started: nothing will ever be indexed, and nobody is kept waiting
+    assert svc.wait_indexed(1, timeout_s=5.0) is False
+    assert svc.backlog_heights() == 0
+    svc.start()
+    state, last_commit = _apply(bx, state, privs, (1, 2), 4)
+    assert svc.wait_indexed(2, timeout_s=10.0) is True
+    assert svc.wait_indexed(1, timeout_s=0.0) is True
+    assert svc.wait_indexed(3, timeout_s=0.05) is False
+    assert (svc.heights_indexed, svc.txs_indexed, svc.last_indexed_height) \
+        == (2, 8, 2)
+    assert 1 <= svc.backlog_max and svc.backlog_heights_max >= 1
+    assert len(svc.tx_indexer.search("tx.height=2")) == 4
+    assert svc.backlog_heights() == 0
+    svc.stop()
+    # stopped: a waiter is released at once
+    assert svc.wait_indexed(9, timeout_s=5.0) is False
+    bx.stop()
+
+
+def test_apply_waits_at_the_bound_and_nothing_is_lost_when_the_indexer_is_slow():
+    """The indexer is held at its first transaction. Two heights may pile up
+    behind apply_block; the third apply waits (counted, and traced as
+    apply.backlog_wait) until the indexer moves again, and afterwards every
+    transaction of every height is in the index."""
+    import threading
+
+    from tendermint_tpu.state import execution
+    from tendermint_tpu.utils import trace
+
+    bx, svc, state, privs = _indexed_executor()
+    gate = threading.Event()
+    real = svc.tx_indexer.index
+
+    def held(*a):
+        assert gate.wait(30.0)
+        real(*a)
+
+    svc.tx_indexer.index = held
+    svc.start()
+    trace.dump(clear=True)
+    trace.enable()
+    try:
+        state, last_commit = _apply(bx, state, privs, (1, 2), 6)
+        assert bx.flush_post_commit(timeout_s=10.0)
+        # height 1 in the indexer's hand, height 2's header behind it
+        assert bx.backlog_heights() == execution.MAX_BACKLOG_HEIGHTS == 2
+        assert bx.backlog_waits == 0
+        done = []
+        t = threading.Thread(target=lambda: done.append(_apply(
+            bx, state, privs, (3, 4, 5, 6), 6, last_commit)))
+        t.start()
+        t.join(0.3)
+        assert t.is_alive() and not done and bx.backlog_waits == 1
+        assert bx.store.load().last_block_height == 2     # height 3 not begun
+        gate.set()
+        t.join(30.0)
+        assert not t.is_alive() and done[0][0].last_block_height == 6
+        assert svc.wait_indexed(6, timeout_s=30.0)
+    finally:
+        gate.set()
+        trace.disable()
+    waits = [s for s in trace.dump(clear=True) if s.name == "apply.backlog_wait"]
+    assert bx.backlog_waits == len(waits) >= 1
+    assert waits[0].duration_s >= 0.25
+    for s in waits:
+        assert s.tags["bound"] == 2 and s.tags["backlog"] >= 2
+    assert bx.post_commit.backlog_max <= 2 and svc.backlog_heights_max <= 2
+    assert (svc.heights_indexed, svc.txs_indexed) == (6, 36)
+    for h in range(1, 7):
+        assert len(svc.tx_indexer.search(f"tx.height={h}")) == 6
+    assert bx.backlog_heights() == 0
+    svc.stop()
+    bx.stop()
+
+
+def test_a_follower_that_is_gone_does_not_hold_apply():
+    """An indexer stopped with heights still in its queues reports no
+    backlog (it will never catch up), and an executor nobody follows counts
+    its own tasks alone."""
+    import time as _t
+
+    bx, svc, state, privs = _indexed_executor()
+    real = svc.tx_indexer.index
+    svc.tx_indexer.index = lambda *a: (_t.sleep(0.05), real(*a))
+    svc.start()
+    state, last_commit = _apply(bx, state, privs, (1,), 3)
+    svc.stop()
+    assert svc.backlog_heights() == 0
+    state, last_commit = _apply(bx, state, privs, (2, 3, 4, 5), 3, last_commit)
+    assert bx.flush_post_commit(timeout_s=10.0)
+    assert state.last_block_height == 5 and bx.backlog_heights() == 0
+    bx.stop()
+
+
+def test_mempool_update_is_a_span_of_the_apply():
+    from tendermint_tpu.mempool.mempool import Mempool
+    from tendermint_tpu.utils import trace
+
+    gd, privs = _genesis()
+    state = make_genesis_state(gd)
+    store = StateStore(MemDB())
+    store.save(state)
+    app = KVStoreApplication()
+    bx = BlockExecutor(store, app, mempool=Mempool(app))
+    trace.dump(clear=True)
+    trace.enable()
+    try:
+        _apply(bx, state, privs, (1, 2), 3)
+    finally:
+        trace.disable()
+    spans = trace.dump(clear=True)
+    updates = [s for s in spans if s.name == "mempool.update"]
+    assert [(s.tags["height"], s.tags["txs"]) for s in updates] == [(1, 3), (2, 3)]
+    saves = {s.span_id for s in spans if s.name == "apply.save"}
+    assert {s.parent_id for s in updates} <= saves
+    bx.stop()
+
+
+def test_node_stop_lets_the_index_catch_up_and_close_stores_closes(tmp_path):
+    """Node.stop flushes the post-commit queue and then waits for the index
+    (IndexerService.wait_indexed) before it detaches the indexer: every
+    applied height is in the index afterwards. close_stores then closes the
+    sqlite connections, and the files answer to new ones."""
+    from tendermint_tpu.config.config import Config
+    from tendermint_tpu.node.node import Node, default_app
+    from tendermint_tpu.state.txindex import TxIndexer
+    from tendermint_tpu.store.block_store import BlockStore
+    from tendermint_tpu.store.db import new_db
+    from tendermint_tpu.utils.log import NopLogger
+
+    gd, privs = _genesis(n_vals=2, chain_id="stop-chain")
+    node = Node(Config().set_root(str(tmp_path)), default_app("kvstore"), gd,
+                logger=NopLogger())
+    node.indexer_service.start()
+    state = node.state_store.load()
+    last_commit = Commit(height=0, round=0, block_id=BlockID(), signatures=[])
+    for h in (1, 2, 3):
+        txs = [b"s%d-%d=v" % (h, i) for i in range(20)]
+        block = state.make_block(h, txs, last_commit, [],
+                                 state.validators.get_proposer().address)
+        bid, last_commit = _commit_for(state, block, privs)
+        node.block_store.save_block(
+            block, PartSet.from_data(block.marshal()), last_commit)
+        state, _ = node.block_exec.apply_block(state, bid, block)
+    node.stop()
+    assert node.indexer_service.last_indexed_height == 3
+    assert node.indexer_service.txs_indexed == 60
+    node.close_stores()
+    with pytest.raises(Exception):
+        node.block_store.load_block_meta(1)        # its connection is closed
+    blocks = new_db("sqlite", str(tmp_path / "data" / "blockstore.db"))
+    index = new_db("sqlite", str(tmp_path / "data" / "tx_index.db"))
+    try:
+        assert BlockStore(blocks).height == 3
+        assert len(TxIndexer(index).search("tx.height=3")) == 20
+    finally:
+        blocks.close()
+        index.close()

@@ -449,3 +449,93 @@ def test_a_change_of_the_set_is_marked_and_the_apply_is_timed_in_phases(
                if s.name == "apply.update_state" and "updates" in s.tags}
     assert {h: (t["updates"], t["joined"], t["left"])
             for h, t in changed.items()} == {3: (2, 1, 1), 7: (2, 0, 0)}
+
+
+# --- what a block's body causes, traced (PR 37) -----------------------------------
+
+
+def test_a_traced_sync_times_the_body_and_counts_its_threads(churn_chain,
+                                                            monkeypatch):
+    """Under a tracer a sync writes one fastsync.part_set a dispatch (bytes,
+    parts), one store.save_block and one state.save_responses a height
+    applied, a block.data_hash for every body that has transactions, and one
+    fastsync.thread_cpu mark every ten heights, counted from the baseline its
+    first step read (docs/OBSERVABILITY.md)."""
+    import threading
+
+    from tendermint_tpu.utils import trace
+
+    gd, blocks, _hashes, _ = churn_chain
+    monkeypatch.setenv("TM_TPU_VERIFY_AHEAD", "4")
+    reactor = _sync_node(gd, blocks)
+    reactor.tracer = trace.Tracer("body", cap=2048, enabled=True)
+    pipe = bpipe.VerifyAheadPipeline()
+    try:
+        while pipe.process_next(reactor):
+            pass
+    finally:
+        reactor.tracer.disable()
+    applied = len(blocks) - 1
+    assert pipe.applied == applied == 13
+    by_name = {}
+    for s in reactor.tracer.dump():
+        by_name.setdefault(s.name, []).append(s)
+    cut = by_name["fastsync.part_set"]
+    assert len(cut) == pipe.dispatched
+    sizes = {b.header.height: len(b.marshal()) for b in blocks}
+    for s in cut:
+        assert s.tags["bytes"] == sizes[s.tags["height"]]
+        assert s.tags["parts"] == 1 and s.cpu_s is not None
+    saved = by_name["store.save_block"]
+    assert [s.tags["height"] for s in saved] == list(range(1, applied + 1))
+    for s in saved:
+        # meta, hash index, one part, seen commit, store state; LastCommit
+        # from height 1 on (an empty one at height 1)
+        assert s.tags["rows"] == 6 and s.tags["parts"] == 1
+        assert s.tags["bytes"] > sizes[s.tags["height"]]
+    responses = by_name["state.save_responses"]
+    assert [(s.tags["height"], s.tags["txs"]) for s in responses] == [
+        (h, 2 if h in (3, 7) else 0) for h in range(1, applied + 1)]
+    # only a body with transactions is hashed under a span: once, inside
+    # apply.validate
+    assert [s.tags["txs"] for s in by_name["block.data_hash"]] == [2, 2]
+    (mark,) = by_name["fastsync.thread_cpu"]
+    me = threading.current_thread().name
+    assert mark.tags["height"] == 10
+    assert mark.tags["sync_thread"] == me and me in mark.tags["threads"]
+    assert 0 < mark.tags["wall_s"] and 0 < mark.tags["process_s"]
+    assert mark.duration_s == 0.0
+
+
+def test_an_untraced_sync_counts_heights_and_reads_no_clock(churn_chain,
+                                                           monkeypatch):
+    from tendermint_tpu.utils import trace
+
+    gd, blocks, _hashes, _ = churn_chain
+    reads = []
+    monkeypatch.setattr(trace.ThreadCensus, "read",
+                        lambda self: reads.append(1))
+    reactor = _sync_node(gd, blocks)
+    pipe = bpipe.VerifyAheadPipeline()
+    while pipe.process_next(reactor):
+        pass
+    assert pipe.applied == 13 and not reads and pipe._census is None
+
+
+def test_the_reactor_remembers_what_it_refused(churn_chain):
+    """BlockchainReactor.last_invalid: the height, the exception and the
+    peers whose blocks were dropped, for whoever asks afterwards."""
+    from tendermint_tpu.types.validator_set import ErrWrongSignature
+
+    gd, blocks, _hashes, _ = churn_chain
+    reactor = _sync_node(gd, blocks[:5] + [_tampered_copy(blocks[5])]
+                         + blocks[6:])
+    assert reactor.last_invalid is None
+    pipe = bpipe.VerifyAheadPipeline()
+    applied = 0
+    while pipe.process_next(reactor):
+        applied += 1
+    height, err, peers = reactor.last_invalid
+    assert applied == 4 and height == 5 and peers == ["pA", "pB"]
+    assert isinstance(err, ErrWrongSignature) and err.index == 0
+    assert reactor.block_store.height == 4
