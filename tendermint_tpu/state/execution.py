@@ -27,7 +27,7 @@ from tendermint_tpu.types.block_id import BlockID
 from tendermint_tpu.types.params import ConsensusParams
 from tendermint_tpu.types.ttime import Time
 from tendermint_tpu.types.validator import Validator
-from tendermint_tpu.types.validator_set import ValidatorSet
+from tendermint_tpu.types.validator_set import ValidatorSet, index_builds
 from tendermint_tpu.utils import faults
 from tendermint_tpu.utils import trace as _trace
 
@@ -317,9 +317,11 @@ class BlockExecutor:
                                 block_time)
 
     def validate_block(self, state: State, block: Block,
-                       commit_pending: SpeculativeCommitVerify | None = None) -> None:
+                       commit_pending: SpeculativeCommitVerify | None = None,
+                       tr=None) -> None:
         inner = commit_pending.fresh_for(state, block) if commit_pending else None
-        validate_block(state, block, self.block_store, commit_pending=inner)
+        validate_block(state, block, self.block_store, commit_pending=inner,
+                       tr=tr)
         if self.evidence_pool is not None:
             self.evidence_pool.check_evidence(state, block.evidence)
 
@@ -404,7 +406,11 @@ class BlockExecutor:
         if self.event_bus is not None:
             self._await_backlog(tr)
         with tr.span("apply.validate") if tr else _trace.NULL_SPAN:
-            self.validate_block(state, block, commit_pending=commit_pending)
+            builds = index_builds()
+            self.validate_block(state, block, commit_pending=commit_pending,
+                                tr=tr)
+            if tr:
+                tr.annotate(index_builds=index_builds() - builds)
 
         with tr.span("apply.exec") if tr else _trace.NULL_SPAN:
             abci_responses = self._exec_block_on_app(state, block)

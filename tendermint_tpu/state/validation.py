@@ -2,6 +2,8 @@
 
 from __future__ import annotations
 
+import time
+
 from tendermint_tpu.state.state import State
 from tendermint_tpu.types.block import Block
 from tendermint_tpu.types.ttime import Time
@@ -12,7 +14,7 @@ class BlockValidationError(Exception):
 
 
 def validate_block(state: State, block: Block, block_store=None,
-                   commit_pending=None) -> None:
+                   commit_pending=None, tr=None) -> None:
     """reference: state/validation.go:15. Includes the batched
     LastValidators.VerifyCommit at the same point the reference does (line 93),
     which on TPU is one kernel launch instead of N serial verifies.
@@ -22,7 +24,10 @@ def validate_block(state: State, block: Block, block_store=None,
     caller) replaces the synchronous verify with a resolve of the
     already-dispatched device work — the commit→apply overlap seam
     (docs/EXECUTION.md). Resolution replays the exact serial accept/reject
-    decision, so accept/reject and error attribution are unchanged."""
+    decision, so accept/reject and error attribution are unchanged.
+
+    Under a tracer (`tr`) the block time's weighted median is timed into
+    the caller's open span, tag `median_s`."""
     block.validate_basic()
 
     h = block.header
@@ -90,7 +95,10 @@ def validate_block(state: State, block: Block, block_store=None,
                 f"block time {block.header.time} not greater than last block time {state.last_block_time}"
             )
         if block.last_commit is not None and len(state.last_validators.validators) > 0:
+            t0 = time.perf_counter()
             median = median_time(block.last_commit, state.last_validators)
+            if tr is not None:
+                tr.annotate(median_s=time.perf_counter() - t0)
             if block.header.time != median:
                 raise BlockValidationError(
                     f"invalid block time. Expected {median}, got {block.header.time}"

@@ -121,6 +121,30 @@ class PendingCommitVerify:
             self._finalize(bitmap)
 
 
+class _AddressIndex:
+    """Where each address stands in a set's ``validators``. Derived data,
+    like the set's hash, and held the same way, except that a set and its
+    copies (the same validators in the same order) share one holder, so
+    whichever of them is asked first builds the dict for all of them:
+    ``State.next_validators`` is never asked for an address, and every
+    height's ``validators`` is a copy of it. The dict is assigned once,
+    complete, and never changed after: safe under threads that only read."""
+
+    __slots__ = ("positions",)
+
+    def __init__(self):
+        self.positions: dict[bytes, int] | None = None
+
+
+# address indexes built in this process since it started: apply.validate's
+# ``index_builds`` tag is the difference of two readings
+_index_builds = 0
+
+
+def index_builds() -> int:
+    return _index_builds
+
+
 class ValidatorSet:
     """Sorted by voting power desc, then address asc. Not thread-safe."""
 
@@ -147,13 +171,53 @@ class ValidatorSet:
         return len(self.validators) == 0
 
     def has_address(self, address: bytes) -> bool:
-        return any(v.address == address for v in self.validators)
+        return self._position(address) >= 0
 
     def get_by_address(self, address: bytes) -> tuple[int, Validator | None]:
-        for i, v in enumerate(self.validators):
-            if v.address == address:
-                return i, v.copy()
-        return -1, None
+        i = self._position(address)
+        return (i, self.validators[i].copy()) if i >= 0 else (-1, None)
+
+    def _address_index(self) -> _AddressIndex:
+        """This set's holder: made here when the set has none, or when
+        ``validators`` is another list than the one it was made for."""
+        held = getattr(self, "_addr_index", None)
+        if held is None or held[1] is not self.validators:
+            held = self._addr_index = (_AddressIndex(), self.validators)
+        return held[0]
+
+    def _positions(self) -> dict[bytes, int]:
+        index = self._address_index()
+        positions = index.positions
+        if positions is None:
+            global _index_builds
+            positions = {}
+            for i, v in enumerate(self.validators):
+                positions.setdefault(v.address, i)  # the first, as a scan finds
+            index.positions = positions
+            _index_builds += 1
+        return positions
+
+    def _position(self, address: bytes) -> int:
+        """Where the first validator with ``address`` stands, or -1: what a
+        scan of ``validators`` answers, in one step. A hit is checked against
+        the list, and a miss is believed only while the index is as long as
+        the list, so a list appended to, cut or assigned behind the set's
+        back gets a new index, of this set alone, and the scan's answer. (A
+        validator put in another's place, the length kept, can still be
+        missed: the convention of ``hash()``.)"""
+        vals = self.validators
+        positions = self._positions()
+        try:
+            i = positions.get(address, -1)
+        except TypeError:  # unhashable, a bytearray: compare, as the scan did
+            return next((i for i, v in enumerate(vals) if v.address == address), -1)
+        if i >= 0:
+            if i < len(vals) and vals[i].address == address:
+                return i
+        elif len(positions) == len(vals):
+            return -1
+        self._addr_index = None
+        return self._positions().get(address, -1)
 
     def get_by_index(self, index: int) -> tuple[bytes | None, Validator | None]:
         if index < 0 or index >= len(self.validators):
@@ -183,6 +247,8 @@ class ValidatorSet:
         new._total_voting_power = self._total_voting_power
         # the set hash covers (pubkey, power) only, both copied verbatim
         new._hash_cache = getattr(self, "_hash_cache", None)
+        # the same validators in the same order: one address index for both
+        new._addr_index = (self._address_index(), new.validators)
         return new
 
     def validate_basic(self) -> None:
@@ -347,6 +413,9 @@ class ValidatorSet:
         self.validators = sorted(
             merged.values(), key=lambda v: (-v.voting_power, v.address)
         )
+        # membership and order change here, and nowhere else (the removals
+        # above were still looked up in the old list's index)
+        self._addr_index = None
         self._total_voting_power = 0
         self._update_total_voting_power()
         if updates or removals:

@@ -194,7 +194,10 @@ CANONICAL_SPANS = {
                             "(span; tags height, txs, bytes)",
     # the four phases of BlockExecutor.apply_block, in order
     "apply.validate": "validate_block: header against state, LastCommit's "
-                      "full verify_commit (or its resolve), block time (span)",
+                      "full verify_commit (or its resolve), block time (span; "
+                      "tags median_s = the weighted median alone, "
+                      "index_builds = ValidatorSet address indexes built "
+                      "meanwhile)",
     "apply.exec": "BeginBlock, DeliverTx*, EndBlock on the app and the "
                   "ABCI responses' save (span)",
     "apply.update_state": "validator updates checked and decoded, "

@@ -51,15 +51,18 @@ def _genesis(n_vals=1, chain_id="exec-chain"):
     return gd, privs
 
 
-def _commit_for(state, block, privs, round_=0):
+def _commit_for(state, block, privs, round_=0, spread_ns=0):
+    """Every validator's precommit for `block`, 1 ms after its time, and
+    `spread_ns` later for each place down the set."""
     bid = BlockID(hash=block.hash(),
                   part_set_header=PartSet.from_data(block.marshal()).header())
     sigs = []
     by_addr = {p.pub_key().address(): p for p in privs}
-    for val in state.validators.validators:
+    for i, val in enumerate(state.validators.validators):
         priv = by_addr[val.address]
         v = Vote(type=PRECOMMIT_TYPE, height=block.header.height, round=round_,
-                 block_id=bid, timestamp=block.header.time.add_ns(1_000_000),
+                 block_id=bid,
+                 timestamp=block.header.time.add_ns(1_000_000 + i * spread_ns),
                  validator_address=val.address,
                  validator_index=state.validators.get_by_address(val.address)[0])
         v.signature = priv.sign(v.sign_bytes(state.chain_id))
@@ -182,6 +185,82 @@ def test_validator_power_change_propagates_and_batch_verifies():
     bad.signature = bytes(64)
     res_bad = vs.add_votes([bad])
     assert not res_bad[0][0] and res_bad[0][1] is not None
+
+
+def _chain_with_a_changing_set(heights=9):
+    """Four validators; a fifth joins with most of the power by a `val:`
+    transaction at height 2, and validator 0 leaves at height 5. Every
+    precommit has a time of its own, so a block's time says whose power the
+    median weighed. Returns what each height committed and its
+    apply.validate span's tags."""
+    from tendermint_tpu.utils import trace
+
+    gd, privs = _genesis(5)
+    gd.validators = gd.validators[:4]
+    state = make_genesis_state(gd)
+    app = KVStoreApplication()
+    store = StateStore(MemDB())
+    store.save(state)
+    bx = BlockExecutor(store, app)
+    txs = {2: [KVStoreApplication.make_val_tx(privs[4].pub_key().bytes(), 70)],
+           5: [KVStoreApplication.make_val_tx(privs[0].pub_key().bytes(), 0)]}
+    tracer = trace.Tracer("guard", cap=256, enabled=True)
+    committed = []
+    last_commit = Commit(height=0, round=0, block_id=BlockID(), signatures=[])
+    try:
+        with tracer.activate():
+            for h in range(1, heights + 1):
+                block = state.make_block(
+                    h, txs.get(h, []), last_commit, [],
+                    state.validators.get_proposer().address)
+                bid = BlockID(hash=block.hash(), part_set_header=PartSet.from_data(
+                    block.marshal()).header())
+                new_state, _ = bx.apply_block(state, bid, block)
+                # signed after the apply: _commit_for asks the set for addresses
+                _, last_commit = _commit_for(state, block, privs,
+                                             spread_ns=1_000_000)
+                committed.append((block.header.time, block.header.validators_hash,
+                                  new_state.app_hash, state.validators.size()))
+                state = new_state
+    finally:
+        tracer.disable()
+    tags = [s.tags for s in tracer.dump() if s.name == "apply.validate"]
+    return committed, tags
+
+
+def test_a_changing_set_commits_what_a_scan_for_addresses_commits(monkeypatch):
+    """PR 39: the address index changes no block time, no validators_hash and
+    no app hash of a chain whose set changes, and apply.validate says what the
+    block time cost and how many indexes were built under it."""
+    from tendermint_tpu.types.validator_set import ValidatorSet
+
+    committed, tags = _chain_with_a_changing_set()
+    assert [size for *_, size in committed] == [4, 4, 4, 5, 5, 5, 4, 4, 4]
+    assert len({t for t, *_ in committed}) == len(committed)
+    assert len({vh for _, vh, *_ in committed}) == 3
+    # the median is weighed: under equal powers it falls on the set's third
+    # place, and on the newcomer's, the first, once it holds 70 of 110
+    gaps = [(b[0].unix_ns() - a[0].unix_ns()) // 1_000_000
+            for a, b in zip(committed, committed[1:])]
+    assert gaps == [3, 3, 3, 1, 1, 1, 1, 1]
+    assert all(t["median_s"] >= 0 for t in tags[1:])
+    assert "median_s" not in tags[0]      # the first block has no LastCommit
+    # an index is built where a membership is first asked for an address:
+    # heights 1, 4 (the newcomer's set validates) and 7 (validator 0 is gone)
+    assert [t["index_builds"] for t in tags] == [1, 0, 0, 1, 0, 0, 1, 0, 0]
+
+    def scan(self, address):
+        for i, v in enumerate(self.validators):
+            if v.address == address:
+                return i, v.copy()
+        return -1, None
+
+    monkeypatch.setattr(ValidatorSet, "get_by_address", scan)
+    monkeypatch.setattr(ValidatorSet, "has_address",
+                        lambda self, address: scan(self, address)[0] >= 0)
+    scanned, scanned_tags = _chain_with_a_changing_set()
+    assert scanned == committed
+    assert [t["index_builds"] for t in scanned_tags] == [0] * len(committed)
 
 
 def test_block_store_roundtrip():
