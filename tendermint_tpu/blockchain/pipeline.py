@@ -227,23 +227,27 @@ class VerifyAheadPipeline:
                 return self._process_next(reactor)
         return self._process_next(reactor)
 
-    def _mark_census(self, height: int) -> None:
+    def _mark_census(self, reactor, height: int | None) -> None:
         """A traced sync's census of the process's threads: one
         fastsync.thread_cpu mark every CENSUS_EVERY heights applied, counted
-        from the baseline the pipeline's first traced step read. Once in ten
-        heights and never per transaction: the thread clock is a system call
-        a thread."""
+        from the baseline the pipeline's first traced step read (``height``
+        None). Once in ten heights and never per transaction: the thread
+        clock is a system call a thread. A reactor with connections writes
+        what they moved meanwhile beside it (p2p.wire)."""
         got = self._census.read()
-        if got is not None:
+        if got is not None and height is not None:
             _trace.current().mark(
                 "fastsync.thread_cpu", height=height,
                 sync_thread=threading.current_thread().name, **got)
+        mark_wire = getattr(reactor, "mark_wire", None)
+        if mark_wire is not None:
+            mark_wire(height)
 
     def _process_next(self, reactor) -> bool:
         pool = reactor.pool
         if _trace.ENABLED and self._census is None:
             self._census = _trace.ThreadCensus()
-            self._census.read()          # the baseline: writes no mark
+            self._mark_census(reactor, None)    # the baseline: writes no mark
             self._census_from = self.applied
         for _ in range(2):
             self._fill(reactor)
@@ -311,5 +315,5 @@ class VerifyAheadPipeline:
         self.applied += 1
         if (self._census is not None and _trace.ENABLED
                 and (self.applied - self._census_from) % CENSUS_EVERY == 0):
-            self._mark_census(head.height)
+            self._mark_census(reactor, head.height)
         return True

@@ -20,15 +20,20 @@ import time
 from tendermint_tpu.blockchain.pipeline import VerifyAheadPipeline
 from tendermint_tpu.encoding import proto
 from tendermint_tpu.p2p.connection import ChannelDescriptor
-from tendermint_tpu.p2p.switch import Peer, Reactor
+from tendermint_tpu.p2p.switch import Peer, Reactor, counters_since
 from tendermint_tpu.store.envelope import CorruptedStoreError
 from tendermint_tpu.types.block import Block
+from tendermint_tpu.utils import trace as _trace
 
 BLOCKCHAIN_CHANNEL = 0x40
 TRY_SYNC_INTERVAL_S = 0.01
 STATUS_UPDATE_INTERVAL_S = 10.0
 SWITCH_TO_CONSENSUS_INTERVAL_S = 1.0
 REQUEST_WINDOW = 16
+# reference: blockchain/v0/pool.go peerTimeout. A peer that leaves a request
+# unanswered this long is dropped from the pool and stopped, and what it was
+# asked for is asked of another peer.
+REQUEST_TIMEOUT_S = 15.0
 
 
 def msg_block_request(height: int) -> bytes:
@@ -62,6 +67,12 @@ class BlockPool:
         self.peers: dict[str, tuple[int, int]] = {}  # id -> (base, height)
         self.blocks: dict[int, tuple[Block, str]] = {}  # height -> (block, peer)
         self.requested: dict[int, str] = {}
+        self._asked_at: dict[int, float] = {}   # height -> when it was asked
+        # height -> the peers whose block for it the sync refused: that
+        # height is asked of another peer while there is one
+        self.refused: dict[int, set[str]] = {}
+        self.received = 0    # blocks taken into the pool
+        self.timed_out = 0   # requests given up after REQUEST_TIMEOUT_S
         self._mtx = threading.RLock()
 
     def set_peer_range(self, peer_id: str, base: int, height: int) -> None:
@@ -80,14 +91,39 @@ class BlockPool:
             self.peers = {}
             self.blocks = {}
             self.requested = {}
+            self._asked_at = {}
+            self.refused = {}
 
     def remove_peer(self, peer_id: str) -> None:
         with self._mtx:
             self.peers.pop(peer_id, None)
             for h in [h for h, p in self.requested.items() if p == peer_id]:
                 del self.requested[h]
+                self._asked_at.pop(h, None)
             for h in [h for h, (_, p) in self.blocks.items() if p == peer_id]:
                 del self.blocks[h]
+
+    def expire_requests(self, now: float | None = None) -> list[str]:
+        """The peers that left a request unanswered for REQUEST_TIMEOUT_S
+        (reference: pool.go peerTimeout, "peer did not send us anything").
+        Each is forgotten as remove_peer forgets it, so its heights are open
+        again for wanted_requests; a block it sends later is taken like any
+        other, or ignored where another peer's came first."""
+        with self._mtx:
+            now = time.monotonic() if now is None else now
+            late = sorted({p for h, p in self.requested.items()
+                           if now - self._asked_at.get(h, now)
+                           > REQUEST_TIMEOUT_S})
+            for pid in late:
+                self.timed_out += sum(1 for p in self.requested.values()
+                                      if p == pid)
+                self.remove_peer(pid)
+            return late
+
+    def sizes(self) -> tuple[int, int]:
+        """(requests open, blocks pooled)."""
+        with self._mtx:
+            return len(self.requested), len(self.blocks)
 
     def max_peer_height(self) -> int:
         with self._mtx:
@@ -103,9 +139,13 @@ class BlockPool:
         with self._mtx:
             h = block.header.height
             if h < self.height or h in self.blocks:
+                # a second or a late answer (the height was asked again of
+                # another peer after a timeout): nothing to punish
                 return
             self.blocks[h] = (block, peer_id)
             self.requested.pop(h, None)
+            self._asked_at.pop(h, None)
+            self.received += 1
 
     def peek_two_blocks(self) -> tuple[Block | None, Block | None]:
         with self._mtx:
@@ -122,14 +162,18 @@ class BlockPool:
     def pop_request(self) -> None:
         with self._mtx:
             self.blocks.pop(self.height, None)
+            self.refused.pop(self.height, None)
             self.height += 1
 
     def redo_request(self, height: int) -> str | None:
-        """Invalid block: drop it + the peer that sent it."""
+        """Invalid block: drop it + the peer that sent it, and remember
+        not to ask that peer for this height again (of an invalid pair one
+        sender may be honest: it comes back and serves the other height)."""
         with self._mtx:
             bad_peer = None
             if height in self.blocks:
                 bad_peer = self.blocks[height][1]
+                self.refused.setdefault(height, set()).add(bad_peer)
             for h in [h for h, (_, p) in self.blocks.items() if p == bad_peer]:
                 del self.blocks[h]
             return bad_peer
@@ -144,18 +188,27 @@ class BlockPool:
             return self.requested.get(height) == peer_id
 
     def wanted_requests(self) -> list[tuple[int, str]]:
-        """Pick heights to request and a peer for each."""
+        """Pick heights to request and a peer for each: of the peers whose
+        range holds the height, in the order of their ids, the one the
+        height falls on; one whose block for it was refused only when no
+        other holds it."""
         with self._mtx:
             out = []
+            now = time.monotonic()
+            ordered = sorted(self.peers.items())
             for h in range(self.height, self.height + REQUEST_WINDOW):
                 if h in self.blocks or h in self.requested:
                     continue
-                candidates = [pid for pid, (b, ph) in self.peers.items()
-                              if b <= h <= ph]
+                candidates = [pid for pid, (b, ph) in ordered if b <= h <= ph]
+                refused = self.refused.get(h)
+                if refused:
+                    candidates = [p for p in candidates
+                                  if p not in refused] or candidates
                 if not candidates:
                     continue
                 pid = candidates[h % len(candidates)]
                 self.requested[h] = pid
+                self._asked_at[h] = now
                 out.append((h, pid))
             return out
 
@@ -179,6 +232,12 @@ class BlockchainReactor(Reactor):
         # what the invalid-block path last refused: (height, the exception,
         # the peers whose blocks were dropped for it)
         self.last_invalid: tuple | None = None
+        # peers this reactor had the switch stop: the senders of an invalid
+        # pair, and those that left a request unanswered
+        self.peers_stopped = 0
+        self._sync_started: float | None = None   # start_sync, until the
+        #                                           first block is pooled
+        self._wire_last: dict | None = None       # mark_wire's last reading
         self._running = False
         self._thread: threading.Thread | None = None
         self._synced = threading.Event()
@@ -217,12 +276,20 @@ class BlockchainReactor(Reactor):
             else:
                 peer.try_send(BLOCKCHAIN_CHANNEL, msg_no_block_response(height))
         elif 3 in f:  # BlockResponse
-            m = proto.fields(f[3][-1])
-            block = Block.unmarshal(m.get(1, [b""])[-1])
-            rep = self.repairer
-            if rep is not None:
-                rep.offer_block(peer.id, block)
-            self.pool.add_block(peer.id, block)
+            tracer = self._tracer() if _trace.ENABLED else None
+            with (tracer.span("blockchain.recv_block", bytes=len(msg_bytes),
+                              peer=peer.id[:12])
+                  if tracer is not None else _trace.NULL_SPAN):
+                m = proto.fields(f[3][-1])
+                block = Block.unmarshal(m.get(1, [b""])[-1])
+                rep = self.repairer
+                if rep is not None:
+                    rep.offer_block(peer.id, block)
+                self.pool.add_block(peer.id, block)
+                if tracer is not None:
+                    tracer.annotate(height=block.header.height)
+            if tracer is not None:
+                self._mark_first_block(block)
         elif 4 in f:  # StatusRequest
             peer.try_send(BLOCKCHAIN_CHANNEL,
                           msg_status_response(self.block_store.height, self.block_store.base))
@@ -232,11 +299,46 @@ class BlockchainReactor(Reactor):
             base = proto.as_sint64(m.get(2, [0])[-1])
             self.pool.set_peer_range(peer.id, base, height)
 
+    def _tracer(self):
+        """The node's recorder while it is on, else the thread's."""
+        tracer = getattr(self, "tracer", None)
+        if tracer is not None and tracer.enabled:
+            return tracer
+        return _trace.current()
+
+    def _mark_first_block(self, block: Block) -> None:
+        """fastsync.first_block: from start_sync to the first block in the
+        pool (listen, dial, handshake, status exchange, the first request and
+        a block's way over the wire). Once a sync, and only a traced one."""
+        started, self._sync_started = self._sync_started, None
+        if started is not None:
+            self._tracer().mark("fastsync.first_block",
+                                height=block.header.height,
+                                seconds=time.monotonic() - started)
+
+    def mark_wire(self, height: int | None) -> None:
+        """p2p.wire: what the switch's connections moved since the mark
+        before, summed over its peers (Switch.wire_totals), and the pool's two
+        sizes. The pipeline calls it beside fastsync.thread_cpu; ``height``
+        None only takes the baseline."""
+        if self.switch is None:
+            return
+        now = self.switch.wire_totals()
+        last, self._wire_last = self._wire_last, now
+        if height is None or last is None:
+            return
+        requested, pooled = self.pool.sizes()
+        self._tracer().mark("p2p.wire", height=height, requested=requested,
+                            pooled=pooled, peers=len(self.switch.peers),
+                            **counters_since(now, last))
+
     # --- sync loop (reference: blockchain/v0/reactor.go:309-419) -----------
 
     def start_sync(self) -> None:
         self._running = True
-        self._thread = threading.Thread(target=self._pool_routine, daemon=True)
+        self._sync_started = time.monotonic()
+        self._thread = threading.Thread(target=self._pool_routine,
+                                        name="fastsync-pool", daemon=True)
         self._thread.start()
 
     def switch_to_fast_sync(self, state) -> None:
@@ -280,6 +382,9 @@ class BlockchainReactor(Reactor):
                 if self.switch is not None:
                     self.switch.broadcast(BLOCKCHAIN_CHANNEL, msg_status_request())
                 last_status = now
+            # a request nobody answered is asked of another peer
+            for pid in self.pool.expire_requests(now):
+                self._stop_peer(pid, "fast-sync request timed out")
             # issue requests
             if self.switch is not None:
                 with self.switch._peers_mtx:
@@ -312,9 +417,17 @@ class BlockchainReactor(Reactor):
             # Drain: process every contiguously-available block before
             # sleeping. The old one-block-per-tick pacing capped sync at
             # 1/TRY_SYNC_INTERVAL_S blocks/s however fast verification ran.
+            applied = False
             while self._running and self._try_sync():
-                pass
-            time.sleep(TRY_SYNC_INTERVAL_S)
+                applied = True
+            # the next pair is not in the pool: the wire or a peer sets the
+            # pace, not the apply
+            waiting = (_trace.ENABLED and not applied
+                       and not self.pool.is_caught_up())
+            with (self._tracer().span("fastsync.pool_wait",
+                                      height=self.pool.height)
+                  if waiting else _trace.NULL_SPAN):
+                time.sleep(TRY_SYNC_INTERVAL_S)
 
     def _try_sync(self) -> bool:
         """Verify + apply the next block through the depth-K verify-ahead
@@ -336,9 +449,13 @@ class BlockchainReactor(Reactor):
         self.last_invalid = (height, e, sorted({bad, bad2} - {None}))
         if self.switch is not None:
             board = getattr(self.switch, "scoreboard", None)
-            for pid in {bad, bad2} - {None}:
+            for pid in sorted({bad, bad2} - {None}):
                 if board is not None:
                     board.record(pid, "bad_message")
-                if pid in self.switch.peers:
-                    self.switch.stop_peer_for_error(
-                        self.switch.peers[pid], f"invalid block: {e}")
+                self._stop_peer(pid, f"invalid block: {e}")
+
+    def _stop_peer(self, peer_id: str, reason: str) -> None:
+        peer = self.switch.peers.get(peer_id) if self.switch is not None else None
+        if peer is not None:
+            self.peers_stopped += 1
+            self.switch.stop_peer_for_error(peer, reason)

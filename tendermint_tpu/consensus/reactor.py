@@ -123,6 +123,7 @@ class PeerState:
         self.prs = PeerRoundState()
         self.mtx = threading.RLock()
         self.running = True
+        self.thread: threading.Thread | None = None   # its gossip routine
 
     def apply_new_round_step(self, height, round_, step, last_commit_round, n_vals) -> None:
         with self.mtx:
@@ -210,6 +211,7 @@ class ConsensusReactor(Reactor):
         self.cs = cs
         self.wait_sync = wait_sync  # True while fast sync is running
         self._peer_states: dict[str, PeerState] = {}
+        self._ending: list[threading.Thread] = []  # removed peers' routines
         self._mtx = threading.RLock()
         # channel id -> [messages, seconds in receive, bytes], counted on
         # the receiving threads while tracing is on (docs/OBSERVABILITY.md)
@@ -276,16 +278,32 @@ class ConsensusReactor(Reactor):
         # PER_PEER_THREADS per link side); the three loops all poll on the
         # same peer-gossip cadence, so they share one loop with the maj23
         # pass kept on its own slower clock.
-        threading.Thread(target=self._gossip_routine, args=(peer, ps),
-                         name=f"cs-gossip-{str(peer.id)[:8]}", daemon=True).start()
+        ps.thread = threading.Thread(
+            target=self._gossip_routine, args=(peer, ps),
+            name=f"cs-gossip-{str(peer.id)[:8]}", daemon=True)
+        ps.thread.start()
         if not self.wait_sync:
             self._send_new_round_step(peer)
 
     def remove_peer(self, peer: Peer, reason) -> None:
         with self._mtx:
             ps = self._peer_states.pop(peer.id, None)
-        if ps is not None:
-            ps.running = False
+            if ps is not None:
+                ps.running = False
+                self._ending = [t for t in self._ending if t.is_alive()]
+                if ps.thread is not None:
+                    self._ending.append(ps.thread)
+
+    def wait_gossip_ended(self, timeout_s: float) -> bool:
+        """Join the gossip routines of the peers that were removed (they end
+        within one gossip sleep): a stopped node's threads must be gone
+        before its stores are closed under them."""
+        deadline = time.monotonic() + timeout_s
+        with self._mtx:
+            ending, self._ending = self._ending, []
+        for t in ending:
+            t.join(max(0.0, deadline - time.monotonic()))
+        return not any(t.is_alive() for t in ending)
 
     # --- receive -----------------------------------------------------------
 

@@ -408,6 +408,11 @@ class Node:
                 host, port = hp.rsplit(":", 1)
                 self.addr_book.add_our_address(
                     NetAddress(self.node_key.id(), host, int(port)))
+        # before the switch starts: its redial loop sleeps a second when it
+        # finds no persistent peer on its first pass
+        if self.config.p2p.persistent_peers:
+            self.switch.add_persistent_peers(
+                self.config.p2p.persistent_peers.split(","))
         self.switch.start()
         # boot-time integrity scrub (TMTPU_SCRUB_ON_START=0 opts out,
         # docs/DURABILITY.md), on a background thread: the full walk is
@@ -437,9 +442,6 @@ class Node:
 
             threading.Thread(target=_boot_scrub, name="boot-scrub",
                              daemon=True).start()
-        if self.config.p2p.persistent_peers:
-            self.switch.add_persistent_peers(
-                self.config.p2p.persistent_peers.split(","))
         if self._statesync_active:
             import threading
 
@@ -523,6 +525,9 @@ class Node:
             self.event_sink.stop()
         self.block_exec.stop()
         self.switch.stop()
+        # the removed peers' gossip routines read the block store: gone
+        # before a caller may close the stores (close_stores)
+        self.consensus_reactor.wait_gossip_ended(timeout_s=2.0)
         if getattr(self, "signer_endpoint", None) is not None:
             self.signer_endpoint.close()
         # release the ingest coalescer's executor thread (it holds strong

@@ -32,6 +32,11 @@ DEFAULT_SEND_RATE = 5_120_000
 DEFAULT_RECV_RATE = 5_120_000
 
 
+# a channel's row of MConnection.wire_counters()
+WIRE_KEYS = ("packets_sent", "msgs_sent", "bytes_sent",
+             "packets_recv", "msgs_recv", "bytes_recv")
+
+
 class MConnectionError(Exception):
     pass
 
@@ -60,6 +65,10 @@ class _Channel:
         self.sent_pos = 0
         self.recently_sent = 0
         self.recving = bytearray()
+        # what crossed the wire on this channel: [packets, messages, payload
+        # bytes], each written by one routine only (plain integers, no clock)
+        self.sent = [0, 0, 0]
+        self.received = [0, 0, 0]
 
     def is_send_pending(self) -> bool:
         return self.sending is not None or not self.send_queue.empty()
@@ -75,6 +84,10 @@ class _Channel:
             self.sending = None
             self.sent_pos = 0
         self.recently_sent += len(chunk)
+        sent = self.sent
+        sent[0] += 1
+        sent[1] += eof
+        sent[2] += len(chunk)
         return chunk, eof
 
 
@@ -191,6 +204,29 @@ class MConnection:
     def try_send(self, ch_id: int, msg: bytes) -> bool:
         return self.send(ch_id, msg, block=False)
 
+    def wire_counters(self) -> dict:
+        """What this connection moved so far, per direction: packets,
+        messages and payload bytes (all channels, and each channel under
+        ``channels``), the sealed frames of the connection under it, and the
+        seconds the flow-rate limiter slept. Counted with integer adds on
+        the routines' own threads; read from outside (the ``p2p.wire``
+        mark), so a reading may be a packet behind."""
+        out = {"packets_sent": 0, "msgs_sent": 0, "bytes_sent": 0,
+               "packets_recv": 0, "msgs_recv": 0, "bytes_recv": 0,
+               "frames_sent": getattr(self._conn, "frames_sent", 0),
+               "frames_recv": getattr(self._conn, "frames_recv", 0),
+               "sealed_bytes_sent": getattr(self._conn, "sealed_bytes_sent", 0),
+               "sealed_bytes_recv": getattr(self._conn, "sealed_bytes_recv", 0),
+               "send_blocked_s": self.send_monitor.blocked_s,
+               "recv_blocked_s": self.recv_monitor.blocked_s,
+               "channels": {}}
+        for ch_id, ch in self._channels.items():
+            row = dict(zip(WIRE_KEYS, (*ch.sent, *ch.received)))
+            out["channels"][f"{ch_id:#x}"] = row
+            for key, n in row.items():
+                out[key] += n
+        return out
+
     def _pick_channel(self) -> _Channel | None:
         """Least ratio of recentlySent/priority (reference:
         connection.go:380-420 sendPacketMsg)."""
@@ -296,6 +332,10 @@ class MConnection:
                     if ch is None:
                         raise MConnectionProtocolError(f"unknown channel {ch_id:#x}")
                     ch.recving += data
+                    got = ch.received
+                    got[0] += 1
+                    got[1] += eof
+                    got[2] += len(data)
                     if len(ch.recving) > ch.desc.recv_message_capacity:
                         raise MConnectionProtocolError("received message exceeds capacity")
                     if eof:

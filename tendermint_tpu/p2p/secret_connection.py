@@ -84,6 +84,10 @@ class SecretConnection:
         self._recv_buf = b""
         self._send_nonce = 0
         self._recv_nonce = 0
+        # sealed frames and their bytes, per direction (the handshake's
+        # included): plain integers, each written under its own lock
+        self.frames_sent = self.frames_recv = 0
+        self.sealed_bytes_sent = self.sealed_bytes_recv = 0
 
         # 1. ephemeral key exchange
         eph_priv = X25519PrivateKey.generate()
@@ -149,9 +153,13 @@ class SecretConnection:
                 # _send_lock exists to serialize exactly this write (nonce
                 # order must match wire order); it guards nothing else
                 self._sock.sendall(sealed)  # tmlint: disable=lock-held-call
+                self.frames_sent += 1
+                self.sealed_bytes_sent += len(sealed)
 
     def _read_frame(self) -> bytes:
         sealed = _read_exact(self._sock, SEALED_FRAME_SIZE)
+        self.frames_recv += 1
+        self.sealed_bytes_recv += len(sealed)
         nonce = b"\x00" * 4 + struct.pack("<Q", self._recv_nonce)
         self._recv_nonce += 1
         try:
@@ -178,6 +186,14 @@ class SecretConnection:
             return self._read_frame()
 
     def close(self) -> None:
+        # shutdown first: close() alone neither wakes a thread blocked in
+        # recv() on this socket nor sends the FIN while that thread holds
+        # it, so the reader would outlive the connection and the far end
+        # would keep a dead peer until its pong timeout
+        try:
+            self._sock.shutdown(socket.SHUT_RDWR)
+        except OSError:
+            pass
         try:
             self._sock.close()
         except OSError:

@@ -192,6 +192,12 @@ class Transport:
 
     def close(self) -> None:
         if self._listener is not None:
+            # shutdown wakes a thread blocked in accept(); close() alone
+            # leaves it there, and the port open, until one more peer dials
+            try:
+                self._listener.shutdown(socket.SHUT_RDWR)
+            except OSError:
+                pass
             try:
                 self._listener.close()
             except OSError:
@@ -245,6 +251,7 @@ class Switch:
         self.peers: dict[str, Peer] = {}
         self._peers_mtx = threading.RLock()
         self._running = False
+        self._stopped = False   # stop() was called: no peer is added after it
         self.logger = logger
         self.max_inbound = max_inbound
         self.max_outbound = max_outbound
@@ -260,6 +267,9 @@ class Switch:
         # accumulated while the partition blocked every dial).
         self._reconnect_attempts: dict[str, int] = {}
         self._reconnect_next_try: dict[str, float] = {}
+        # MConnection.wire_counters() of the peers that are gone, summed:
+        # wire_totals() never runs backwards when a peer is stopped
+        self._wire_gone: dict = {}
 
     # --- registry ----------------------------------------------------------
 
@@ -303,6 +313,7 @@ class Switch:
         for r in self.reactors.values():
             r.on_stop()
         with self._peers_mtx:
+            self._stopped = True
             peers = list(self.peers.values())
         for p in peers:
             self.stop_peer_for_error(p, "switch stopping")
@@ -418,6 +429,10 @@ class Switch:
             conn.close()
             raise P2PError(f"peer {peer_info.node_id[:12]} is banned")
         with self._peers_mtx:
+            if self._stopped:
+                # a dial or an accept that was in flight when stop() ran
+                conn.close()
+                raise P2PError("switch stopped")
             if peer_info.node_id in self.peers:
                 conn.close()
                 raise P2PError("duplicate peer")
@@ -506,6 +521,7 @@ class Switch:
             if self.peers.get(peer.id) is not peer:
                 return
             del self.peers[peer.id]
+            _sum_counters(self._wire_gone, peer.mconn.wire_counters())
         peer.stop()
         for r in self.reactors.values():
             try:
@@ -521,10 +537,35 @@ class Switch:
         for p in peers:
             p.try_send(ch_id, msg)
 
+    def wire_totals(self) -> dict:
+        """MConnection.wire_counters() summed over every peer this switch
+        has had, live or gone (the ``p2p.wire`` mark reads its deltas)."""
+        with self._peers_mtx:
+            out: dict = {}
+            _sum_counters(out, self._wire_gone)
+            for p in self.peers.values():
+                _sum_counters(out, p.mconn.wire_counters())
+        return out
+
     def num_peers(self) -> tuple[int, int]:
         with self._peers_mtx:
             out = sum(1 for p in self.peers.values() if p.outbound)
             return out, len(self.peers) - out
+
+
+def _sum_counters(total: dict, one: dict) -> None:
+    """total += one, key by key, a nested table likewise."""
+    for key, n in one.items():
+        if isinstance(n, dict):
+            _sum_counters(total.setdefault(key, {}), n)
+        else:
+            total[key] = total.get(key, 0) + n
+
+
+def counters_since(now: dict, last: dict) -> dict:
+    """now - last, key by key, a nested table likewise."""
+    return {k: (counters_since(v, last.get(k, {})) if isinstance(v, dict)
+                else v - last.get(k, 0)) for k, v in now.items()}
 
 
 def _split_addr(addr: str) -> tuple[str, int]:

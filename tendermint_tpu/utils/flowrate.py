@@ -42,6 +42,9 @@ class Monitor:
         # update() then debits it (see limit() docstring)
         self._budget: float | None = None
         self._budget_t = self._start
+        # seconds limit() slept because nothing was allowed: whole sample
+        # periods counted, no clock read for it
+        self.blocked_s = 0.0
 
     def update(self, n: int) -> int:
         """Record n transferred bytes (reference Update)."""
@@ -110,3 +113,4 @@ class Monitor:
                 return max(0, min(want, allowed))
             # sleep just long enough for one sample period of budget
             time.sleep(self._period)
+            self.blocked_s += self._period

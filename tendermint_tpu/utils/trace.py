@@ -166,6 +166,21 @@ CANONICAL_SPANS = {
                                "(requests= txs per batch)",
     "mempool.ingest_wait": "submit->resolve wait of one tx through the "
                            "ingest coalescer",
+    "blockchain.recv_block": "a BlockResponse through BlockchainReactor."
+                             "receive: envelope parse, Block.unmarshal, the "
+                             "pool's add_block (span; tags bytes, height, "
+                             "peer)",
+    "fastsync.pool_wait": "the sync loop's sleep when the next pair of "
+                          "blocks was not in the pool and the pool is not "
+                          "caught up: the wire or a peer sets the pace "
+                          "(span; tag height)",
+    "fastsync.first_block": "start_sync to the first block in the pool "
+                            "(mark; tags height, seconds)",
+    "p2p.wire": "what the switch's connections moved since the mark "
+                "before, summed over its peers, and the pool's two sizes "
+                "(mark beside fastsync.thread_cpu; tags packets_*, msgs_*, "
+                "bytes_*, frames_*, sealed_bytes_*, *_blocked_s, channels, "
+                "requested, pooled, peers)",
     "p2p.send": "message queued to a peer channel (mark)",
     "p2p.recv": "message delivered to a reactor (span over on_receive)",
     # batched execution plane (state/execution.py, docs/EXECUTION.md)
