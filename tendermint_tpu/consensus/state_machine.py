@@ -519,7 +519,8 @@ class ConsensusState:
                     if self.wal is not None and not self.replay_mode:
                         with (tr.span("consensus.wal_write", msgs=len(votes))
                               if tr.enabled else _trace.NULL_SPAN):
-                            tr.annotate(bytes=self._wal_write_votes(votes))
+                            n_bytes, writes = self._wal_write_votes(votes)
+                            tr.annotate(bytes=n_bytes, writes=writes)
                     # the drain span carries the height; verify phases
                     # dispatched inside inherit it
                     with self._mtx, (
@@ -549,16 +550,15 @@ class ConsensusState:
                     else _trace.NULL_SPAN):
                 self._handle_msg(mi)
 
-    def _wal_write_votes(self, votes: list[MsgInfo]) -> int:
-        """A drain's votes into the WAL, buffered, every copy, before any is
-        verified -> payload bytes written."""
-        n_bytes = 0
-        for m in votes:
-            blob = m.msg.wal_blob()
-            blob.peer_id = m.peer_id
-            n_bytes += len(blob.payload)
-            self.wal.write(blob, _time.time_ns())
-        return n_bytes
+    def _wal_write_votes(self, votes: list[MsgInfo]) -> tuple[int, int]:
+        """A drain's votes into the WAL, buffered, every copy a message of
+        its own, in arrival order, before any is verified -> (payload bytes
+        written, write calls issued on the file)."""
+        payloads = Vote.marshal_many([m.msg.vote for m in votes])
+        writes = self.wal.write_blobs(
+            [("vote", p, m.peer_id) for p, m in zip(payloads, votes)],
+            _time.time_ns())
+        return sum(map(len, payloads)), writes
 
     def _drain_votes(self, first: MsgInfo) -> list[MsgInfo]:
         """Pull immediately-available peer VoteMessages (bounded so internal

@@ -1,9 +1,13 @@
 """Consensus write-ahead log (reference: consensus/wal.go:57,75,91,201,231,300).
 
-Frame format mirrors the reference's WALEncoder: crc32c | length | protobuf
-TimedWALMessage. Messages are replayed on restart to recover in-flight
-consensus state; EndHeightMessage marks a completed height (fsync'd, the
-crash-recovery anchor).
+Frame format mirrors the reference's WALEncoder: crc32 | length | protobuf
+TimedWALMessage. The checksum is ``zlib.crc32`` (IEEE), where the reference's
+is crc32c (Castagnoli): a log is read back only by the program that wrote it,
+and the checksum is NOT changed by the one-pass encoder below (ISSUE 41): the
+files it writes are byte for byte the files the per-message writer wrote.
+Messages are replayed on restart to recover in-flight consensus state;
+EndHeightMessage marks a completed height (fsync'd, the crash-recovery
+anchor).
 
 File rotation follows libs/autofile/group.go semantics (size-limited chunks
 Head, Head.000, ...), simplified to a single directory of numbered chunks.
@@ -52,22 +56,57 @@ class WALMessageBlob:
     peer_id: str = ""
 
 
-def _encode_msg(m) -> bytes:
-    w = proto.Writer()
+_HEADER = struct.Struct(">II")
+
+
+def _frame(time_field: bytes, msg: bytes) -> bytes:
+    """crc32 | length | TimedWALMessage{time = 1, msg = 2}: THE definition
+    of a frame, for one message or a drain's thousand (its reader is
+    ``_valid_frames``). ``msg`` is the encoded WALMessage."""
+    body = b"".join((time_field, b"\x12", proto.encode_uvarint(len(msg)), msg))
+    if len(body) > MAX_MSG_SIZE_BYTES:
+        raise WALError(f"msg is too big: {len(body)} bytes, max: {MAX_MSG_SIZE_BYTES} bytes")
+    return _HEADER.pack(zlib.crc32(body), len(body)) + body
+
+
+def _time_field(time_ns: int) -> bytes:
+    return b"\x08" + proto.encode_varint(time_ns) if time_ns else b""
+
+
+def blob_frames(blobs, time_ns: int) -> list[bytes]:
+    """One frame a ``(kind, payload, peer_id)``, in order, all under one
+    clock reading: WALMessage{blob = 2 {kind = 1, payload = 2, peer_id = 3}},
+    empty fields omitted as ``proto.Writer`` omits them. No Writer: a drain
+    holds a thousand of these, and all but a handful share kind and peer."""
+    uvarint = proto.encode_uvarint
+    time_field = _time_field(time_ns)
+    kinds: dict[str, bytes] = {}  # kind -> field 1, encoded
+    peers: dict[str, bytes] = {}  # peer id -> field 3, encoded
+    frames = []
+    for kind, payload, peer_id in blobs:
+        kind_field = kinds.get(kind)
+        if kind_field is None:
+            kind_field = kinds[kind] = proto.Writer().string(1, kind).out()
+        peer_field = peers.get(peer_id)
+        if peer_field is None:
+            peer_field = peers[peer_id] = proto.Writer().string(3, peer_id).out()
+        n = len(payload)
+        inner = (b"".join((kind_field, b"\x12", uvarint(n), payload, peer_field))
+                 if n else kind_field + peer_field)
+        frames.append(_frame(time_field,
+                             b"".join((b"\x12", uvarint(len(inner)), inner))))
+    return frames
+
+
+def _msg_frames(m, time_ns: int) -> list[bytes]:
+    """The frame of one message, as the n = 1 case of the drain's encoder."""
+    if isinstance(m, WALMessageBlob):
+        return blob_frames(((m.kind, m.payload, m.peer_id),), time_ns)
     if isinstance(m, EndHeightMessage):
-        w.message(1, proto.Writer().varint(1, m.height).out(), always=True)
-    elif isinstance(m, WALMessageBlob):
-        inner = (
-            proto.Writer()
-            .string(1, m.kind)
-            .bytes(2, m.payload)
-            .string(3, m.peer_id)
-            .out()
-        )
-        w.message(2, inner, always=True)
-    else:
-        raise WALError(f"unknown WAL message type {type(m)}")
-    return w.out()
+        end = proto.Writer().varint(1, m.height).out()
+        return [_frame(_time_field(time_ns),
+                       proto.Writer().message(1, end, always=True).out())]
+    raise WALError(f"unknown WAL message type {type(m)}")
 
 
 def _decode_msg(buf: bytes):
@@ -213,27 +252,46 @@ class WAL:
     def write(self, msg, time_ns: int = 0) -> None:
         """Buffered write (fsync only on write_sync; reference:
         consensus/wal.go:166-199)."""
+        frames = _msg_frames(msg, time_ns)
         with self._mtx:
-            self._write_locked(msg, time_ns)
+            self._write_locked(frames)
+
+    def write_blobs(self, blobs, time_ns: int = 0) -> int:
+        """A drain's ``(kind, payload, peer_id)`` messages, buffered, a frame
+        each and in order, the bytes ``write`` would have written one by one
+        at ``time_ns`` -> the ``write`` calls issued on the file."""
+        frames = blob_frames(blobs, time_ns)
+        with self._mtx:
+            return self._write_locked(frames)
 
     def write_sync(self, msg, time_ns: int = 0) -> None:
+        frames = _msg_frames(msg, time_ns)
         with self._mtx:
-            self._write_locked(msg, time_ns)
+            self._write_locked(frames)
             faults.fire("wal.fsync")  # crash here loses the buffered frames
             self._head.flush()
             os.fsync(self._head.fileno())
 
-    def _write_locked(self, msg, time_ns: int) -> None:
-        body = proto.Writer().varint(1, time_ns).message(2, _encode_msg(msg), always=True).out()
-        if len(body) > MAX_MSG_SIZE_BYTES:
-            raise WALError(f"msg is too big: {len(body)} bytes, max: {MAX_MSG_SIZE_BYTES} bytes")
-        crc = zlib.crc32(body) & 0xFFFFFFFF
-        frame = struct.pack(">II", crc, len(body)) + body
-        # torn/partial rules write a cut prefix of this frame and crash,
-        # leaving on disk exactly what a power cut mid-append leaves.
-        faults.torn_write("wal.write", self._head, frame)
-        self._head.write(frame)
+    def _write_locked(self, frames: list[bytes]) -> int:
+        """Hand the frames to the file in one write, and check the head's
+        size once, after them: a chunk passes ``head_size_limit`` by one
+        drain at most (the reference checks on a ticker, not a write:
+        libs/autofile/group.go processTicks). While a fault rule is armed
+        the frames go one by one, so that a schedule's hit index counts
+        frames. -> write calls issued."""
+        if faults.REGISTRY.active:
+            for frame in frames:
+                # torn/partial rules write a cut prefix of this frame and
+                # crash, leaving on disk exactly what a power cut mid-append
+                # leaves (the frames before it are already with the file).
+                faults.torn_write("wal.write", self._head, frame)
+                self._head.write(frame)
+            writes = len(frames)
+        else:
+            self._head.write(b"".join(frames))
+            writes = 1
         self._maybe_rotate()
+        return writes
 
     def flush_and_sync(self) -> None:
         with self._mtx:

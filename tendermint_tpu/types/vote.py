@@ -266,6 +266,43 @@ class Vote:
         )
 
     @staticmethod
+    def marshal_many(votes) -> list[bytes]:
+        """``[v.marshal() for v in votes]`` in one pass, for the hundreds of
+        votes of a drain that share all but five fields: the bytes of fields
+        1-4 are built once a ``(type, height, round, block_id)`` by the same
+        Writer calls as ``marshal``, which stays the definition
+        (tests/test_wal.py pins this to it); the tail follows the Writer's
+        proto3 omissions, every length a varint."""
+        uvarint, varint = proto.encode_uvarint, proto.encode_varint
+        heads: dict = {}
+        out = []
+        for v in votes:
+            bid = v.block_id
+            psh = bid.part_set_header
+            key = (v.type, v.height, v.round, bid.hash, psh.total, psh.hash)
+            head = heads.get(key)
+            if head is None:
+                head = heads[key] = (
+                    proto.Writer()
+                    .varint(1, v.type)
+                    .varint(2, v.height)
+                    .varint(3, v.round)
+                    .message(4, bid.marshal(), always=True)
+                    .out()
+                )
+            ts = v.timestamp.marshal()
+            parts = [head, b"\x2a", uvarint(len(ts)), ts]
+            address, index, sig = v.validator_address, v.validator_index, v.signature
+            if address:
+                parts += (b"\x32", uvarint(len(address)), address)
+            if index:
+                parts += (b"\x38", varint(index))
+            if sig:
+                parts += (b"\x42", uvarint(len(sig)), sig)
+            out.append(b"".join(parts))
+        return out
+
+    @staticmethod
     def unmarshal(buf: bytes) -> "Vote":
         f = proto.fields(buf)
         return Vote(

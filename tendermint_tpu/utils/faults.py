@@ -79,7 +79,8 @@ class FaultDisconnect(FaultInjected):
 # names, but everything the framework instruments is declared here so
 # docs/FAULTS.md, the crash matrix, and sites() can never drift apart.
 CANONICAL_SITES: dict[str, str] = {
-    "wal.write": "WAL frame append (consensus/wal.py _write_locked); "
+    "wal.write": "WAL frame append (consensus/wal.py _write_locked), one "
+                 "hit a frame, a drain's frames too; "
                  "torn/partial leave a cut frame on disk then crash",
     "wal.fsync": "before the fsync of WAL write_sync/flush_and_sync; "
                  "crash here loses buffered frames",

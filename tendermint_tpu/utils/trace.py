@@ -87,7 +87,9 @@ CANONICAL_SPANS = {
                             "votes, queued, cache_hits, in_drain_copies, "
                             "skipped)",
     "consensus.wal_write": "a drain's votes written to the WAL, buffered, "
-                           "before any is verified (tags msgs, bytes)",
+                           "before any is verified (tags msgs, bytes, "
+                           "writes: write calls on the file, 1 a drain, "
+                           "msgs while a fault rule is armed)",
     "consensus.flush_wait": "the consensus thread blocked on a vote flush's "
                             "bitmap (tag sigs)",
     "consensus.vote_apply": "a drain's votes through addVote in arrival "

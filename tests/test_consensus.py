@@ -163,3 +163,93 @@ def test_wal_written_and_replayable():
         heights = [tm.msg.height for tm, _ in wal.iter_messages()
                    if isinstance(tm.msg, EndHeightMessage)]
         assert 1 in heights and 2 in heights
+
+
+def test_drained_votes_are_in_the_wal_as_delivered_and_replay_to_the_same_state():
+    """ISSUE 41: a drain's votes are written in one pass, a message each. One
+    real validator (power 100) that needs two of twelve simulated ones (5
+    each) to commit; at its own prevote they deliver their prevotes and
+    precommits, nine for the block and three for nil, three copies from three
+    peers, so every height commits through a drain. The WAL then holds every
+    delivery, copies included, in order, and a fresh machine fed the WAL alone
+    in replay mode reaches the same height and app hash."""
+    from tendermint_tpu.consensus.state_machine import MsgInfo, wal_blob_to_msg
+    from tendermint_tpu.consensus.ticker import TimeoutInfo
+    from tendermint_tpu.consensus.wal import WALMessageBlob
+    from tendermint_tpu.types.block_id import BlockID
+    from tendermint_tpu.types.vote import PRECOMMIT_TYPE, PREVOTE_TYPE, Vote
+    from tendermint_tpu.utils import trace
+
+    heights, peers = 2, ("peerA", "peerB", "peerC")
+    priv = ed25519.gen_priv_key(b"\x41" * 32)
+    ghosts = [ed25519.gen_priv_key(bytes([0x60 + i]) * 32) for i in range(12)]
+    genesis = GenesisDoc(
+        chain_id="harness-chain", genesis_time=Time(1700001000, 0),
+        validators=[GenesisValidator(b"", priv.pub_key(), 100)]
+        + [GenesisValidator(b"", g.pub_key(), 5) for g in ghosts])
+    delivered: list = []
+
+    def ghost_votes(cs, own: Vote):
+        vals = cs.rs.validators
+        for vtype in (PREVOTE_TYPE, PRECOMMIT_TYPE):
+            for k, g in enumerate(ghosts):
+                address = g.pub_key().address()
+                v = Vote(type=vtype, height=own.height, round=own.round,
+                         block_id=own.block_id if k < 9 else BlockID(),
+                         timestamp=Time(1700001000 + own.height, 1000 * k),
+                         validator_address=address,
+                         validator_index=vals.get_by_address(address)[0])
+                v.signature = g.sign(v.sign_bytes("harness-chain"))
+                yield v
+
+    with tempfile.TemporaryDirectory() as d:
+        node = Node(genesis, MockPV(priv), make_test_config(),
+                    wal_dir=os.path.join(d, "wal"))
+        cs = node.cs
+        cs.tracer = trace.Tracer("drain-replay", enabled=True)
+
+        def on_own_message(msg):
+            # on the consensus thread, so the whole burst is queued before
+            # the receive loop takes its first vote: one drain a height
+            if (isinstance(msg, VoteMessage) and msg.vote.type == PREVOTE_TYPE
+                    and msg.vote.round == 0 and msg.vote.height <= heights):
+                votes = list(ghost_votes(cs, msg.vote))
+                for copy, peer in enumerate(peers):
+                    for v in votes[copy:] + votes[:copy]:
+                        delivered.append((peer, v))
+                        cs.add_vote(v.copy(), peer_id=peer)
+
+        cs.broadcast = on_own_message
+        node.mempool.check_tx(b"drained=1")
+        cs.start()
+        try:
+            assert wait_height([node], heights, timeout=30)
+        finally:
+            cs.stop()
+            cs.tracer.disable()
+        assert len(delivered) == heights * 2 * len(ghosts) * len(peers)
+        writes = [s.tags for s in cs.tracer.dump()
+                  if s.name == "consensus.wal_write"]
+        assert [t["writes"] for t in writes] == [1] * heights
+        assert sum(t["msgs"] for t in writes) == len(delivered)
+
+        records = [tm.msg for tm, _ in WAL(os.path.join(d, "wal")).iter_messages()]
+        from_peers = [(m.peer_id, wal_blob_to_msg(m).vote) for m in records
+                      if isinstance(m, WALMessageBlob) and m.peer_id]
+        assert from_peers == delivered
+
+        fresh_node = Node(genesis, None, make_test_config())
+        fresh = fresh_node.cs
+        fresh._ticker.stop()        # the WAL holds the timeouts that fired
+        fresh.replay_mode = True
+        for m in records:
+            msg = wal_blob_to_msg(m) if isinstance(m, WALMessageBlob) else None
+            if isinstance(msg, TimeoutInfo):
+                fresh._do_handle_timeout(msg)
+            elif msg is not None:
+                with fresh._mtx:
+                    fresh._handle_msg(MsgInfo(msg, m.peer_id))
+        ran, replayed = node.state_store.load(), fresh_node.state_store.load()
+        assert ran.last_block_height >= heights
+        assert ((replayed.last_block_height, replayed.app_hash)
+                == (ran.last_block_height, ran.app_hash))

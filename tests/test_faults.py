@@ -234,6 +234,44 @@ def test_wal_torn_write_crash_and_repair(tmp_path, action, expect_ok):
     w2.close()
 
 
+@pytest.mark.parametrize("action", ["torn", "partial"])
+def test_wal_torn_write_tears_the_nth_frame_of_a_drain(tmp_path, action):
+    """ISSUE 41: a drain's frames are handed to the file in one write, but
+    ``wal.write:torn@12`` still tears the twelfth FRAME: while a rule is
+    armed the drain goes frame by frame, the eleven before it already with
+    the file; with nothing armed it is one write."""
+    from tendermint_tpu.consensus.wal import WAL, WALMessageBlob, _valid_frames
+
+    d = str(tmp_path / action)
+    blobs = [("vote", b"vote-%02d" % i * 9, "peer%d" % (i % 3))
+             for i in range(20)]
+    faults.REGISTRY.crash_fn = _raise_sim
+    faults.configure([f"wal.write:{action}@12"], seed=7)
+    w = WAL(d)
+    with pytest.raises(SimulatedCrash):
+        w.write_blobs(blobs, time_ns=5)
+    w._head.close()  # process death
+    assert faults.snapshot()[0]["wal.write"] == 12
+    chunk = os.path.join(d, "wal.000000")
+    with open(chunk, "rb") as f:
+        data = f.read()
+    ends = [end for _pos, end, _t, _m in _valid_frames(data)]
+    assert len(ends) == 11
+    torn = len(data) - ends[-1]      # a cut twelfth frame, nothing after it
+    frame = ends[-1] - ends[-2]      # (the frames differ only in a digit)
+    assert (8 <= torn < frame) if action == "torn" else (1 <= torn < 8)
+
+    faults.configure(["wal.write:torn@1000"])   # armed, not reached
+    w2 = WAL(d)                      # the repair keeps the eleven
+    assert os.path.getsize(chunk) == ends[-1]
+    assert w2.write_blobs(blobs[11:], time_ns=6) == 9    # a frame a write
+    faults.clear()
+    assert w2.write_blobs(blobs, time_ns=7) == 1         # one write a drain
+    w2.close()
+    got = [tm.msg for tm, _ in WAL(d).iter_messages()]
+    assert got == [WALMessageBlob(*b) for b in blobs + blobs]
+
+
 def test_wal_torn_cut_point_replays_from_seed(tmp_path):
     faults.REGISTRY.crash_fn = _raise_sim
     cuts = []

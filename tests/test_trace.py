@@ -865,6 +865,7 @@ def test_the_merged_drain_paths_write_and_count_the_same_traced_or_not(
               if s.name == "consensus.vote_serial"}
     assert spans["consensus.wal_write"].tags["msgs"] == 14
     assert spans["consensus.wal_write"].tags["bytes"] > 0
+    assert spans["consensus.wal_write"].tags["writes"] == 1   # one pass
     assert spans["consensus.vote_drain"].tags["votes"] == 14
     apply_ = spans["consensus.vote_apply"]
     assert (apply_.tags["votes"], apply_.tags["added"]) == (14, 10)
@@ -880,6 +881,28 @@ def test_the_merged_drain_paths_write_and_count_the_same_traced_or_not(
         elif s.name.startswith("consensus.") and s.duration_s:
             # span()s, and vote_serial's record() of accumulated thread_time()
             assert 0.0 <= s.cpu_s <= s.duration_s + 1e-3, s
+
+
+def test_a_drain_goes_frame_by_frame_while_a_fault_rule_is_armed(tracer,
+                                                                 tmp_path):
+    """ISSUE 41: ``wal.write``'s hit index counts frames, so with any rule
+    armed a drain hands the file a frame at a time (``writes`` = ``msgs``)
+    and writes the records the one-pass path writes."""
+    from tendermint_tpu.utils import faults
+
+    _cs, wal_one_pass, _ = _drain_through_the_receive_loop(
+        trace.Tracer("off"), tmp_path)
+    faults.configure(["wal.write:torn@1000"])   # armed, never reached
+    try:
+        _cs, wal_armed, counted = _drain_through_the_receive_loop(
+            tracer, tmp_path)
+        hits, fired = faults.snapshot()
+    finally:
+        faults.clear()
+    write = next(s for s in tracer.dump() if s.name == "consensus.wal_write")
+    assert write.tags["msgs"] == write.tags["writes"] == 14
+    assert hits["wal.write"] >= 15 and not fired    # the drain's 14, then one
+    assert wal_armed == wal_one_pass and len(counted) == 11
 
 
 def test_receive_reads_no_cpu_clock_and_the_mark_has_its_callers_cpu(tracer):

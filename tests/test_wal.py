@@ -8,12 +8,21 @@ messages that were written, in order."""
 import os
 import random
 import struct
+import zlib
+
+import pytest
 
 from tendermint_tpu.consensus.wal import (
+    MAX_MSG_SIZE_BYTES,
     WAL,
     EndHeightMessage,
+    WALError,
     WALMessageBlob,
 )
+from tendermint_tpu.encoding import proto
+from tendermint_tpu.types.block_id import BlockID, PartSetHeader
+from tendermint_tpu.types.ttime import Time
+from tendermint_tpu.types.vote import PRECOMMIT_TYPE, PREVOTE_TYPE, Vote
 
 
 def _write_wal(path, n=20):
@@ -201,3 +210,188 @@ def test_tear_in_rotated_chunk_repairs_and_retires_later_chunks(tmp_path):
     assert got and got[-1] == extra
     assert _is_prefix(got[:-1], msgs)
     assert any(".corrupted." in n for n in os.listdir(d))
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 41: a drain's frames built in one pass and written once are the
+# frames the per-message writer wrote, byte for byte
+# ---------------------------------------------------------------------------
+
+N_VALIDATORS = 5000
+T_NS = 1_767_225_600_123_456_789
+
+
+def _parent_frame(m, time_ns: int) -> bytes:
+    """The frame as the per-message writer built it until PR 41, with
+    ``proto.Writer``s: the reference the one-pass encoder is held to."""
+    if isinstance(m, EndHeightMessage):
+        msg = proto.Writer().message(
+            1, proto.Writer().varint(1, m.height).out(), always=True).out()
+    else:
+        inner = (proto.Writer().string(1, m.kind).bytes(2, m.payload)
+                 .string(3, m.peer_id).out())
+        msg = proto.Writer().message(2, inner, always=True).out()
+    body = proto.Writer().varint(1, time_ns).message(2, msg, always=True).out()
+    return struct.pack(">II", zlib.crc32(body) & 0xFFFFFFFF, len(body)) + body
+
+
+# one field of a plain precommit replaced: what the corrupted pass, the fuzz
+# surfaces and a chain's first heights deliver
+_BLOCK = BlockID(b"\xb1" * 32, PartSetHeader(8, b"\xb2" * 32))
+EDGE_VOTES = {
+    "plain": {},
+    "prevote": {"type": PREVOTE_TYPE},
+    "unknown_type": {"type": 0},
+    "nil_block": {"block_id": BlockID()},
+    "hash_without_parts": {"block_id": BlockID(b"\xb1" * 32)},
+    "round_3": {"round": 3},
+    "height_0": {"height": 0},
+    "index_0": {"validator_index": 0},
+    "last_index": {"validator_index": N_VALIDATORS - 1},
+    "negative_index": {"validator_index": -1},
+    "zero_time": {"timestamp": Time(0, 0)},
+    "negative_seconds": {"timestamp": Time(-62135596800, 5)},
+    "zero_nanos": {"timestamp": Time(1_767_225_600, 0)},
+    "zero_seconds": {"timestamp": Time(0, 999_999_999)},
+    "empty_address": {"validator_address": b""},
+    "short_address": {"validator_address": b"\x01\x02\x03"},
+    "long_address": {"validator_address": b"\xaa" * 300},
+    "empty_signature": {"signature": b""},
+    "short_signature": {"signature": b"\x07"},
+    "long_signature": {"signature": b"\x55" * 20_000},
+}
+
+
+def _vote(**over) -> Vote:
+    fields = dict(type=PRECOMMIT_TYPE, height=41, round=0, block_id=_BLOCK,
+                  timestamp=Time(1_767_225_600, 987_654_321),
+                  validator_address=b"\xad" * 20, validator_index=77,
+                  signature=b"\x51" * 64)
+    fields.update(over)
+    return Vote(**fields)
+
+
+def _random_drain(n: int, seed: int) -> list[tuple[Vote, str]]:
+    """(vote, peer id) a delivery: mostly one height's votes for one block
+    from three peers, interleaved, as a drain holds them, with every edge
+    above and random departures mixed in."""
+    rng = random.Random(f"drain:{n}:{seed}")
+    peers = ["", "%040x" % rng.getrandbits(160), "%040x" % rng.getrandbits(160),
+             "peerZ"]
+    edges = list(EDGE_VOTES.values())
+    out = []
+    for i in range(n):
+        over = {"type": rng.choice((PREVOTE_TYPE, PRECOMMIT_TYPE)),
+                "timestamp": Time(1_767_225_600 + rng.randrange(3),
+                                  rng.randrange(10**9)),
+                "validator_address": rng.randbytes(20),
+                "validator_index": rng.randrange(N_VALIDATORS),
+                "signature": rng.randbytes(64)}
+        if rng.random() < 0.1:
+            over["block_id"] = BlockID()          # a vote for nil
+        if rng.random() < 0.05:
+            over["round"] = rng.randrange(1, 4)
+        if rng.random() < 0.05:
+            over["height"] = 40                   # a late precommit
+        if rng.random() < 0.2:
+            over.update(rng.choice(edges))
+        out.append((_vote(**over), rng.choice(peers)))
+    return out
+
+
+def _head_bytes(path) -> bytes:
+    with open(_head_file(path), "rb") as f:
+        return f.read()
+
+
+def _assert_one_pass_equals_per_message(tmp_path, drain, time_ns=T_NS):
+    votes = [v for v, _ in drain]
+    payloads = Vote.marshal_many(votes)
+    assert payloads == [v.marshal() for v in votes]
+    blobs = [WALMessageBlob("vote", v.marshal(), peer) for v, peer in drain]
+
+    one = WAL(str(tmp_path / "one"))
+    assert one.write_blobs([("vote", p, peer)
+                            for p, (_, peer) in zip(payloads, drain)],
+                           time_ns) == 1
+    one.close()
+    each = WAL(str(tmp_path / "each"))
+    for blob in blobs:
+        each.write(blob, time_ns)
+    each.close()
+
+    written = _head_bytes(str(tmp_path / "one"))
+    assert written == _head_bytes(str(tmp_path / "each"))
+    assert written == b"".join(_parent_frame(b, time_ns) for b in blobs)
+    assert _replayed(str(tmp_path / "one")) == blobs
+    assert [Vote.unmarshal(m.payload) for m in _replayed(str(tmp_path / "one"))] \
+        == votes
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("n", [1, 2, 656, 1024])
+def test_a_drain_written_in_one_pass_is_the_file_written_a_message_at_a_time(
+        tmp_path, n, seed):
+    _assert_one_pass_equals_per_message(tmp_path, _random_drain(n, seed))
+
+
+@pytest.mark.parametrize("peer", ["", "%040x" % 0xFEED])
+@pytest.mark.parametrize("edge", sorted(EDGE_VOTES))
+def test_an_odd_vote_alone_or_among_plain_ones_is_written_as_marshal_writes_it(
+        tmp_path, edge, peer):
+    odd = _vote(**EDGE_VOTES[edge])
+    assert Vote.marshal_many([odd]) == [odd.marshal()]
+    _assert_one_pass_equals_per_message(
+        tmp_path, [(_vote(), "p"), (odd, peer), (_vote(validator_index=3), "p"),
+                   (odd, "")])
+
+
+@pytest.mark.parametrize("time_ns", [0, 1, T_NS])
+def test_the_single_writer_is_the_one_pass_encoder_at_n_1(tmp_path, time_ns):
+    """Every kind of message through ``write`` / ``write_sync``: the parent's
+    bytes, so a log begun before PR 41 is appended to in its own format."""
+    msgs = [WALMessageBlob("proposal", b"\x0a\x03abc"),
+            WALMessageBlob("timeout", b"", ""),
+            WALMessageBlob("", b"x" * 300, "peer"),
+            EndHeightMessage(0), EndHeightMessage(41)]
+    wal = WAL(str(tmp_path / "wal"))
+    for i, m in enumerate(msgs):
+        (wal.write_sync if i % 2 else wal.write)(m, time_ns)
+    wal.close()
+    want = b"".join(_parent_frame(m, time_ns) for m in msgs)
+    assert _head_bytes(str(tmp_path / "wal")) == want
+    assert _replayed(str(tmp_path / "wal")) == msgs
+
+
+@pytest.mark.parametrize("path", ["drain", "single"])
+def test_a_frame_over_the_size_limit_is_refused_on_both_paths(tmp_path, path):
+    wal = WAL(str(tmp_path / "wal"))
+    big = b"\x00" * (MAX_MSG_SIZE_BYTES + 1)
+    with pytest.raises(WALError, match="too big"):
+        if path == "drain":
+            wal.write_blobs([("vote", b"ok", "p"), ("vote", big, "p")], T_NS)
+        else:
+            wal.write(WALMessageBlob("vote", big, "p"), T_NS)
+    with pytest.raises(WALError, match="unknown WAL message"):
+        wal.write(object(), T_NS)
+    wal.close()
+    assert _replayed(str(tmp_path / "wal")) == []
+
+
+def test_a_drain_that_crosses_the_size_limit_rotates_once_at_its_end(tmp_path):
+    d = str(tmp_path / "wal")
+    wal = WAL(d, head_size_limit=4096)
+    first = [("vote", b"a%03d" % i * 8, "p%d" % (i % 3)) for i in range(200)]
+    second = [("vote", b"b%03d" % i * 8, "") for i in range(10)]
+    wal.write_blobs(first, 1)             # ~12 KB: three limits in one drain
+    assert sorted(os.listdir(d)) == ["wal.000000", "wal.000001"]
+    assert os.path.getsize(os.path.join(d, "wal.000001")) == 0
+    wal.write_blobs(second, 2)            # under the limit: no rotation
+    wal.write_sync(EndHeightMessage(1), 3)
+    wal.close()
+    assert sorted(os.listdir(d)) == ["wal.000000", "wal.000001"]
+    got = list(WAL(d, head_size_limit=4096).iter_messages())
+    assert [tm.msg for tm, _ in got] == (
+        [WALMessageBlob(*b) for b in first + second] + [EndHeightMessage(1)])
+    assert [at[0] for _, at in got] == [0] * 200 + [1] * 11
+    assert [tm.time_ns for tm, _ in got] == [1] * 200 + [2] * 10 + [3]
