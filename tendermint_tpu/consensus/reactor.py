@@ -202,6 +202,80 @@ class PeerState:
         return None
 
 
+# --- a vote's copies, decoded once --------------------------------------------
+
+
+class VoteMemo:
+    """A vote message's wire bytes -> the ``Vote`` decoded from them. Gossip
+    delivers a vote once from every peer that has not seen the node's
+    ``HasVote`` yet; equal bytes decode to equal votes, so the copies are
+    handed the object the first delivery built. Only ``Vote.unmarshal``
+    builds an entry, and nothing between ``receive`` and the vote sets, the
+    WAL and the evidence pool writes to a received vote (the signers write
+    to the node's own votes, which never come through ``receive``), so
+    several ``MsgInfo``s may carry one.
+
+    Kept: votes of the node's height and the one before it (late
+    precommits' copies come after the commit; any other height the state
+    machine drops), until the node steps two heights past them. At most
+    ``bound(n_vals)`` entries; at the bound nothing is inserted and a miss
+    costs what a delivery cost without the memo. Every connection's receive
+    thread calls ``get`` and ``put`` at once: two that miss on the same
+    bytes both decode, and ``put`` keeps the first object for both."""
+
+    def __init__(self):
+        self._mtx = threading.Lock()
+        self._votes: dict[bytes, Vote] = {}
+        self._floor = 0
+        # lookups answered, lookups not answered, and of those the votes
+        # not stored because the memo was full
+        self.hits = self.misses = self.full = 0
+
+    @staticmethod
+    def bound(n_vals: int) -> int:
+        """A prevote and a precommit from every validator, for two heights:
+        every honest vote of a height decided in its first round and of the
+        one before it. Later rounds' votes and whatever else a peer invents
+        share that room, and once it is taken they are decoded each time."""
+        return 4 * n_vals
+
+    def get(self, msg_bytes: bytes) -> Vote | None:
+        with self._mtx:
+            vote = self._votes.get(msg_bytes)
+            if vote is None:
+                self.misses += 1
+            else:
+                self.hits += 1
+            return vote
+
+    def put(self, msg_bytes: bytes, vote: Vote, node_height: int,
+            n_vals: int) -> Vote:
+        """-> the vote every delivery of these bytes is to carry."""
+        if not node_height - 1 <= vote.height <= node_height:
+            return vote
+        with self._mtx:
+            held = self._votes.get(msg_bytes)
+            if held is not None:
+                return held
+            if len(self._votes) >= self.bound(n_vals):
+                self.full += 1
+            else:
+                self._votes[msg_bytes] = vote
+            return vote
+
+    def forget_below(self, height: int) -> None:
+        with self._mtx:
+            if height != self._floor:
+                self._floor = height
+                self._votes = {k: v for k, v in self._votes.items()
+                               if v.height >= height}
+
+    def counts(self) -> tuple[int, int, int, int]:
+        """-> (hits, misses, full, entries held)."""
+        with self._mtx:
+            return self.hits, self.misses, self.full, len(self._votes)
+
+
 # --- the reactor -------------------------------------------------------------
 
 
@@ -224,10 +298,15 @@ class ConsensusReactor(Reactor):
         self._recv_cpu_at: dict | None = None
         self._recv_marked = (0, 0.0, 0)
         self._recv_height = None
+        self.vote_memo = VoteMemo()
+        self._memo_marked = (0, 0, 0)     # its hits, misses, full at that mark
         # the votes the state machine added since the peers were last told,
         # in the order it added them (written and read under its lock)
         self._added: list[Vote] = []
         cs.on_new_round_step.append(self._mark_recv)
+        # after the mark, which reads what the memo held at its fullest
+        cs.on_new_round_step.append(
+            lambda rs: self.vote_memo.forget_below(rs.height - 1))
         cs.on_new_round_step.append(self._broadcast_new_round_step)
         cs.on_vote.append(self._note_vote)
         cs.on_work_done.append(self._announce_votes)
@@ -338,6 +417,7 @@ class ConsensusReactor(Reactor):
         if not self.cs.tracer.enabled or rs.height == self._recv_height:
             return
         cpu_now = _trace.thread_cpu_times()
+        *memo_now, memo_size = self.vote_memo.counts()
         with self._mtx:
             first, self._recv_height = self._recv_height is None, rs.height
             stats = list(self.recv_stats.values())
@@ -345,21 +425,30 @@ class ConsensusReactor(Reactor):
             before, self._recv_marked = self._recv_marked, now
             callers, self._recv_callers = self._recv_callers, set()
             cpu_before, self._recv_cpu_at = self._recv_cpu_at, cpu_now
+            memo_before, self._memo_marked = self._memo_marked, memo_now
         if first:
             return
         msgs, seconds, nbytes = (a - b for a, b in zip(now, before))
+        hits, misses, full = (a - b for a, b in zip(memo_now, memo_before))
         cpu_s = None if cpu_now is None or cpu_before is None else sum(
             cpu_now[t] - cpu_before.get(t, 0.0) for t in callers if t in cpu_now)
         self.cs.tracer.mark(
             "consensus.recv", height=rs.height - 1, msgs=msgs, seconds=seconds,
-            cpu_s=cpu_s, bytes=nbytes, threads=sorted({t.name for t in callers}))
+            cpu_s=cpu_s, bytes=nbytes, threads=sorted({t.name for t in callers}),
+            vote_memo_hits=hits, vote_memo_misses=misses, vote_memo_full=full,
+            vote_memo_size=memo_size)
 
     def _receive(self, ch_id: int, peer: Peer, msg_bytes: bytes) -> None:
         ps: PeerState = peer.get("consensus_peer_state")
         if ps is None:
             return
-        f = proto.fields(msg_bytes)
         n_vals = self.cs.rs.validators.size() if self.cs.rs.validators else 0
+        if ch_id == VOTE_CHANNEL and not self.wait_sync:
+            # before any parsing: two deliveries in three are copies
+            self._receive_vote(peer, ps, msg_bytes, n_vals)
+            return
+        # (a vote that arrives during fast sync is parsed here and dropped)
+        f = proto.fields(msg_bytes)
         if ch_id == STATE_CHANNEL:
             if 1 in f:  # NewRoundStep
                 m = proto.fields(f[1][-1])
@@ -410,15 +499,6 @@ class ConsensusReactor(Reactor):
                 part = Part.unmarshal(m.get(3, [b""])[-1])
                 ps.set_has_block_part(height, round_, part.index)
                 self.cs.add_proposal_block_part(height, round_, part, peer_id=peer.id)
-        elif ch_id == VOTE_CHANNEL:
-            if self.wait_sync:
-                return
-            if 6 in f:
-                m = proto.fields(f[6][-1])
-                vote = Vote.unmarshal(m.get(1, [b""])[-1])
-                ps.set_has_vote(vote.height, vote.round, vote.type,
-                                vote.validator_index, n_vals)
-                self.cs.add_vote(vote, peer_id=peer.id)
         elif ch_id == VOTE_SET_BITS_CHANNEL:
             if 9 in f:  # VoteSetBits: the votes the peer holds for a block id
                 m = proto.fields(f[9][-1])
@@ -429,6 +509,26 @@ class ConsensusReactor(Reactor):
                     bits_unmarshal(m.get(5, [b""])[-1]),
                     n_vals,
                 )
+
+    def _receive_vote(self, peer: Peer, ps: PeerState, msg_bytes: bytes,
+                      n_vals: int) -> None:
+        """A message on the vote channel: the ``Vote`` an earlier delivery
+        of the same bytes built, or the one decoded from them now; then,
+        per delivery and per peer, the peer's bit and the state machine's
+        queue. A message with no vote in it, or one whose decode raises, is
+        never kept."""
+        vote = self.vote_memo.get(msg_bytes)
+        if vote is None:
+            f = proto.fields(msg_bytes)
+            if 6 not in f:
+                return
+            m = proto.fields(f[6][-1])
+            vote = self.vote_memo.put(
+                msg_bytes, Vote.unmarshal(m.get(1, [b""])[-1]),
+                self.cs.rs.height, n_vals)
+        ps.set_has_vote(vote.height, vote.round, vote.type,
+                        vote.validator_index, n_vals)
+        self.cs.add_vote(vote, peer_id=peer.id)
 
     def _handle_vote_set_maj23(self, peer, ps, height, round_, type_, bid) -> None:
         """reference: consensus/reactor.go:300-340."""

@@ -108,7 +108,10 @@ CANONICAL_SPANS = {
     "consensus.recv": "what ConsensusReactor.receive took since the mark "
                       "before, once a height (mark; tags msgs, seconds, "
                       "bytes, threads = who called it, cpu_s = the CPU "
-                      "seconds those threads got since)",
+                      "seconds those threads got since, vote_memo_hits / "
+                      "_misses / _full / _size = vote messages answered by "
+                      "the reactor's memo, decoded, not kept at its bound, "
+                      "and entries held)",
     "consensus.thread_cpu": "CPU seconds of every live thread since the mark "
                             "before, once a height (mark; tags wall_s, "
                             "process_s, rest_s, lost, threads = name -> s)",
