@@ -425,73 +425,6 @@ def config_sr25519(rr):
                 gen_s=round(gen_s, 1), **detail)
 
 
-def config_sharded(rr, items):
-    """The multi-device story (ISSUE 4 tentpole): the production
-    BatchVerifier registry at the headline 20,480-sig shape, sharded over
-    the ("dp",) mesh vs pinned single-device (TM_TPU_SHARD=0), reporting
-    MARGINAL us/sig for both (p50(N) - p50(N/4) over the extra sigs, the
-    same fixed-floor removal the headline uses). On one device the sharded
-    route never engages and this config just records that fact."""
-    import jax
-
-    from tendermint_tpu.crypto import batch as cbatch
-    from tendermint_tpu.parallel import batch_shard
-
-    ndev = len(jax.devices())
-    if ndev < 2 or not batch_shard.shard_enabled():
-        return dict(metric="sharded_marginal_us_per_sig", value=None,
-                    unit="us/sig", devices=ndev,
-                    skipped="single device: sharded route never engages")
-
-    from tendermint_tpu.crypto import ed25519 as ed
-
-    pubs = {}
-
-    def registry_verify(subset):
-        verifier = cbatch.create_batch_verifier("ed25519")
-        for pub, msg, sig in subset:
-            pk = pubs.get(pub)
-            if pk is None:
-                pk = pubs[pub] = ed.PubKey(pub)
-            verifier.add(pk, msg, sig)
-        ok_all, bitmap = verifier.dispatch().resolve()
-        assert ok_all
-        return bitmap
-
-    quarter = items[: len(items) // 4]
-    extra = len(items) - len(quarter)
-
-    def marginal(env):
-        prev = os.environ.get("TM_TPU_SHARD")
-        if env is None:
-            os.environ.pop("TM_TPU_SHARD", None)
-        else:
-            os.environ["TM_TPU_SHARD"] = env
-        try:
-            registry_verify(items)  # warm this route's executables/keysets
-            full, detail = rr.run(lambda: registry_verify(items),
-                                  iters=2, rounds=2, report="min")
-            quart, _ = rr.run(lambda: registry_verify(quarter),
-                              iters=2, rounds=2, report="min")
-            return max(full - quart, 0.001) * 1e3 / extra, full, detail
-        finally:
-            if prev is None:
-                os.environ.pop("TM_TPU_SHARD", None)
-            else:
-                os.environ["TM_TPU_SHARD"] = prev
-
-    sharded_us, sharded_ms, detail = marginal(None)
-    single_us, single_ms, _ = marginal("0")
-    return dict(metric="sharded_marginal_us_per_sig",
-                value=round(sharded_us, 2), unit="us/sig",
-                vs_baseline=round(BASELINE_US_PER_SIG / sharded_us, 2),
-                single_device_marginal_us=round(single_us, 2),
-                speedup_vs_single=round(single_us / sharded_us, 2),
-                sharded_p50_ms=round(sharded_ms, 1),
-                single_p50_ms=round(single_ms, 1),
-                devices=ndev, **detail)
-
-
 def config_addvote(rr):
     """BASELINE config 5: the addVote hot loop — gossiped votes at a
     1024-validator height drained through VoteSet.add_votes (one batched
@@ -1171,7 +1104,6 @@ def main() -> None:
         ("light_serve", config_light_serve, (rr,)),
         ("mempool_ingest", config_mempool_ingest, (rr,)),
         ("chain_throughput", config_chain_throughput, (rr,)),
-        ("sharded", config_sharded, (rr, items)),
     ):
         try:
             configs[name] = fn(*args)

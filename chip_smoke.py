@@ -32,7 +32,7 @@ recorded a failure, is a non-zero exit):
 It refuses to run unless ``jax.default_backend() == "tpu"``. On success the
 last line of stdout is ``{"ok": true, "device": {...}}``; on any failure no
 such line is printed. The phase functions take their sizes as arguments so
-tests/test_chip_smoke.py can run them tiny on the CPU mesh
+tests/test_chip_smoke.py can run them tiny on the CPU
 (``on_chip=False`` drops only the assertions that need a TPU).
 """
 
@@ -186,7 +186,6 @@ def phase_commit(seed: int, n_vals: int = N_VALIDATORS, on_chip: bool = True,
     from tendermint_tpu.crypto import ed25519 as ref
     from tendermint_tpu.crypto import verify_service
     from tendermint_tpu.ops import ed25519_batch as edb
-    from tendermint_tpu.parallel import batch_shard
     from tendermint_tpu.types.block import Commit, CommitSig
     from tendermint_tpu.types.block_id import BlockID, PartSetHeader
     from tendermint_tpu.types.ttime import Time
@@ -319,7 +318,7 @@ def phase_commit(seed: int, n_vals: int = N_VALIDATORS, on_chip: bool = True,
         import jax
 
         raw = [(pk.bytes(), m, s) for pk, m, s in items]
-        sharded = batch_shard.should_shard(len(raw))
+        sharded = edb.should_shard(len(raw))
         dev, finish = edb.dispatch_batch(raw)
         check(dev is not None, "ops dispatch_batch answered from the host")
         # the packed pieces of the bitmap: one on a one-chip host, a chunk's
@@ -495,8 +494,10 @@ def phase_fastsync(seed: int, n_ed: int = FASTSYNC_ED, n_sr: int = FASTSYNC_SR,
         sr_items[0] = (sr_items[0][0], sr_items[0][1] + b"x", sr_items[0][2])
         dev, finish = sr25519_batch.dispatch_batch(sr_items, force_device=True)
         check(dev is not None, "sr25519 dispatch answered from the host")
-        check({d.platform for d in dev.devices()} == {"tpu"},
-              f"sr25519 output lives on {dev.devices()}")
+        # the packed pieces of the bitmap, as in phase_commit
+        devs = set().union(*(p.devices() for p in dev))
+        check({d.platform for d in devs} == {"tpu"},
+              f"sr25519 output lives on {devs}")
         got = finish(jax.device_get(dev))
         check(not got[0] and got[1:].all(), "sr25519 device bitmap wrong")
         out["sr25519_device_sigs"] = len(sr_items)

@@ -577,29 +577,24 @@ def warmup(sizes: tuple[int, ...] = (64,), background: bool = True):
                        exc_info=e)
 
     def _warm_mesh(pub, sig):
-        """Warm what the "sharded" route runs on a host with several devices,
-        so that no commit of the node's life compiles. On a TPU backend that
-        is one Pallas chunk of each key type on EVERY local device (with its
-        table gather and its pack): a batch of ndev chunks puts chunk k on
-        device k, and placement starts from device 0 in every launch, so
-        these are the devices any later batch uses. Elsewhere the route only
-        ever runs one shard_map shape, n_devices * JNP_TILE items."""
+        """Warm what the "sharded" route runs on a TPU host with several
+        chips, so that no commit of the node's life compiles: one Pallas
+        chunk of each key type on EVERY local device (with its table gather
+        and its pack). A batch of ndev chunks puts chunk k on device k, and
+        placement starts from device 0 in every launch, so these are the
+        devices any later batch uses."""
         import jax
 
+        from tendermint_tpu.ops import ed25519_batch
+
+        if not ed25519_batch._use_pallas():
+            return  # before the Pallas module is imported for nothing
         from tendermint_tpu.crypto import sr25519
-        from tendermint_tpu.ops import ed25519_batch, sr25519_batch
-        from tendermint_tpu.parallel import batch_shard
+        from tendermint_tpu.ops import ed25519_pallas, sr25519_batch
 
-        ndev = jax.local_device_count()
-        if ndev < 2 or not batch_shard.shard_enabled():
+        n = (jax.local_device_count() - 1) * ed25519_pallas.CHUNK + 1
+        if not ed25519_batch.should_shard(n):
             return
-        if ed25519_batch._use_pallas():
-            from tendermint_tpu.ops import ed25519_pallas
-
-            n = (ndev - 1) * ed25519_pallas.CHUNK + 1
-        else:
-            n = ndev * ed25519_batch.JNP_TILE
-        n = max(n, batch_shard.shard_threshold(ndev))
         ed25519_batch.verify_batch([(pub, b"warmup", sig)] * n,
                                    force_device=True)
         spriv = sr25519.gen_priv_key(b"\x43" * 32)

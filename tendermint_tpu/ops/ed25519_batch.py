@@ -105,8 +105,8 @@ def _gather_point(table, idx):
     return got.reshape(n, 4, 20)
 
 
-def _verify_kernel(tab, h_win, s_win, r_y, r_sign, valid, axis_name=None):
-    """The jitted batch verify (pure-jnp path; CPU fallback + shard_map body).
+def _verify_kernel(tab, h_win, s_win, r_y, r_sign, valid):
+    """The jitted batch verify (pure-jnp path: what runs off a TPU backend).
 
     tab:    (N, 16, 4, 20) int32  comb table of -A per signature (cached)
     h_win:  (N, 64)    int32   comb windows of h, processing order
@@ -114,8 +114,6 @@ def _verify_kernel(tab, h_win, s_win, r_y, r_sign, valid, axis_name=None):
     r_y:    (N, 20)    int32   raw y limbs of sig[:32] (bit 255 stripped)
     r_sign: (N,)       int32   bit 255 of sig[:32]
     valid:  (N,)       bool    host-side precheck results
-    axis_name: mesh axis when running inside shard_map (marks the loop carry
-               as device-varying; see jax shard-map scan-vma docs)
     ->      (N,)       bool
     """
     n = tab.shape[0]
@@ -130,9 +128,6 @@ def _verify_kernel(tab, h_win, s_win, r_y, r_sign, valid, axis_name=None):
         return acc
 
     acc0 = ed.identity((n,))
-    if axis_name is not None:
-        # mark the loop carry device-varying under shard_map
-        acc0 = jax.lax.pcast(acc0, axis_name, to="varying")
     acc = jax.lax.fori_loop(0, 64, body, acc0)
 
     y, sign = ed.compress_canonical(acc)
@@ -295,7 +290,7 @@ class KeySet:
     Pallas route has asked for them, (capacity, 960) niels rows; capacity is
     KEY_TILE times a power of two, so the gathers below compile for few
     shapes. Each further device that launches chunks (gathered_lane with a
-    device: the sharded row on a TPU host, parallel/batch_shard) holds a copy
+    device: the "sharded" route, ed25519_pallas.dispatch_chunks) holds a copy
     of the niels rows, made on its first launch and written tile by tile
     from then on. A built tile is written in place (the array is donated),
     which deletes the array object that was current before: every read of
@@ -303,7 +298,7 @@ class KeySet:
     take."""
 
     __slots__ = ("n_rows", "valid", "_lock", "_tab_ext", "_niels",
-                 "_niels_on", "_replicated")
+                 "_niels_on")
 
     def __init__(self):
         self.n_rows = 0
@@ -314,7 +309,6 @@ class KeySet:
         self._tab_ext = None
         self._niels = None
         self._niels_on: dict = {}  # device -> that device's copy of _niels
-        self._replicated = None
 
     def append(self, a_neg: np.ndarray, valid: np.ndarray) -> None:
         """Build the tables of K new keys, a KEY_TILE at a time through the
@@ -382,16 +376,6 @@ class KeySet:
                 tab = self._niels_on[device] = jax.device_put(
                     self._niels, device)
             return _gather_transpose(tab, jax.device_put(idx, device))
-
-    def replicated(self, mesh_key: tuple, sharding):
-        """The extended table on every device of a mesh, copied once per
-        mesh and per append (parallel/batch_shard.replicated_tables)."""
-        with self._lock:
-            key = (mesh_key, self.n_rows)
-            if self._replicated is None or self._replicated[0] != key:
-                self._replicated = (
-                    key, jax.device_put(self._tab_ext, sharding))
-            return self._replicated[1]
 
 
 class KeyTable(dict):
@@ -632,9 +616,9 @@ def _jnp_args(s: dict, n: int, nb: int) -> dict:
 
 
 def prepare(items):
-    """Padded full-batch prep for the jnp kernel (compat path used by the
-    multi-chip shard harness): returns (dict incl. gathered per-item comb
-    tables, n)."""
+    """Padded full-batch prep for the jnp kernel (the example batch of
+    __graft_entry__.entry's compile check): returns (dict incl. gathered
+    per-item comb tables, n)."""
     n = len(items)
     nb = next_bucket(n)
     ks, key_idx, pub_ok = get_keyset([it[0] for it in items])
@@ -679,13 +663,24 @@ def host_crossover() -> int:
     return c if c is not None else HOST_CROSSOVER_DEFAULT
 
 
-def _batch_shard():
-    """parallel/batch_shard, imported late because it imports this module:
-    the one place where ops reaches up into parallel (the routing policy
-    and the sharded launch, for both key types)."""
-    from tendermint_tpu.parallel import batch_shard
+def shard_enabled() -> bool:
+    """False when the operator opted out (TM_TPU_SHARD=0)."""
+    return os.environ.get("TM_TPU_SHARD") != "0"
 
-    return batch_shard
+
+def should_shard(n: int) -> bool:
+    """Whether a batch of n signatures is spread over the local devices: a
+    TPU backend, more than one local device, sharding not opted out, and
+    more than one Pallas chunk, the unit that is placed on a device (a
+    batch of one chunk or less runs as on a one-chip host). Off a TPU this
+    is False whatever the device count: one device's jnp kernel, as a CPU
+    runs it."""
+    if not (_use_pallas() and jax.local_device_count() > 1
+            and shard_enabled()):
+        return False
+    from tendermint_tpu.ops import ed25519_pallas  # a second to import
+
+    return n > ed25519_pallas.CHUNK
 
 
 def route_batch(n: int, force_device: bool = False, scalar_min: int = 0) -> str:
@@ -700,10 +695,10 @@ def route_batch(n: int, force_device: bool = False, scalar_min: int = 0) -> str:
                  for a handful of signatures, and on a cold process it would
                  pay an XLA compile. scalar_min is the registry's per-kind
                  batch_min; direct callers of dispatch_batch pass 0.
-      "sharded"  batch_shard.should_shard(n): every local device works. On a
-                 TPU backend, from more than one Pallas chunk upward, the
-                 chunks of the one-chip kernel placed one a device; on any
-                 other backend shard_map of the jnp kernel over the mesh.
+      "sharded"  should_shard(n): a TPU host with several chips, from more
+                 than one Pallas chunk upward. The chunks of the one-chip
+                 kernel placed one a local device
+                 (ed25519_pallas.dispatch_chunks).
       "host"     not forced, n < host_crossover(), C library loaded or
                  building: a kernel flush loses to the CPU there, the sync
                  floor alone exceeds the C verifier's whole runtime. While
@@ -723,7 +718,7 @@ def route_batch(n: int, force_device: bool = False, scalar_min: int = 0) -> str:
     loaded = chost.available()
     if not force_device and n < scalar_min and not loaded:
         return "scalar"
-    if _batch_shard().should_shard(n):
+    if should_shard(n):
         return "sharded"
     if (not force_device and n < host_crossover()
             and (loaded or chost.building())):
@@ -833,7 +828,7 @@ def launch_span(program: str, route: str, left: int, lanes: int,
     real signatures were still to launch, `lanes` is what the call holds,
     so sigs over lanes is the share of launched lanes that did work.
     `device` is where the program was placed: a local device, None for where
-    unplaced arrays go (the first), "mesh" for shard_map over all of them."""
+    unplaced arrays go (the first)."""
     if not _trace.ENABLED:
         return _trace.NULL_SPAN
     if device is None:
@@ -841,19 +836,18 @@ def launch_span(program: str, route: str, left: int, lanes: int,
     return _trace.current().span(
         "prep.launch", program=program, route=route,
         sigs=max(0, min(left, lanes)), lanes=lanes,
-        device=getattr(device, "id", device))
+        device=device.id)
 
 
 def _dispatch_device(items, n: int, multichip: bool):
     """The accelerator route proper: comb tables + the kernel launches. On a
     TPU backend the Pallas chunks, on the one device or (`multichip`, the
     "sharded" route) placed a chunk a local device; elsewhere the jnp
-    kernel, under shard_map or alone. Raises on device failure (injected or
-    real); the
-    circuit breaker in dispatch_batch owns the fallback. The fault site
-    fires in dispatch_batch, NOT here: the breaker probe also runs this
-    function, and probe timing must never consume the deterministic
-    consensus-path hit indices of ops.ed25519.device."""
+    kernel. Raises on device failure (injected or real); the circuit
+    breaker in dispatch_batch owns the fallback. The fault site fires in
+    dispatch_batch, NOT here: the breaker probe also runs this function,
+    and probe timing must never consume the deterministic consensus-path
+    hit indices of ops.ed25519.device."""
     ks, key_idx, pub_ok = get_keyset([it[0] for it in items])
     # Non-decompressable keys get an identity comb table; they must be
     # rejected here, exactly as the scalar path's _decompress(pub) is None.
@@ -869,13 +863,6 @@ def _dispatch_device(items, n: int, multichip: bool):
             functools.partial(ed25519_pallas.dispatch_items_pipelined,
                               ks, key_idx, items, pub_ok),
             multichip)
-    if multichip:
-        # No TPU backend: shard the signature axis over the device mesh
-        # (shard_map of the jnp kernel, parallel/batch_shard).
-        dev = _batch_shard().dispatch_batch_sharded(ks, key_idx, items, pub_ok)
-        _start_host_copy(dev)
-        return dev, _cbreaker.routed(
-            lambda v: np.asarray(v)[:n].astype(bool), "sharded")
     s = prepare_scalars(items, pub_ok, windows=True)
 
     # Fixed-tile chunking: every batch runs through the one JNP_TILE-shaped
@@ -924,10 +911,10 @@ def dispatch_batch(items: list[tuple[bytes, bytes, bytes]],
     round trips, one batched fetch costs one.
 
     :func:`route_batch` names the route: the C host verifier below the
-    measured crossover (ops/chost), the shard_map multi-device path when a
-    mesh is present, else the fused Pallas kernel on TPU
-    (ops/ed25519_pallas) or the pure-jnp CPU fallback. force_device=True
-    skips the host route (kernel warmup, kernel tests).
+    measured crossover (ops/chost), else the fused Pallas kernel on TPU
+    (ops/ed25519_pallas), its chunks placed over the local chips when there
+    are several, or the pure-jnp CPU fallback. force_device=True skips the
+    host route (kernel warmup, kernel tests).
 
     The device route sits behind a circuit breaker (ops/breaker): a device
     dispatch failure is re-verified on the host within the same call, the

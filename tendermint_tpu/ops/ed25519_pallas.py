@@ -42,6 +42,8 @@ from tendermint_tpu.ops import ed25519_batch as edb
 from tendermint_tpu.ops import edwards25519 as ed
 from tendermint_tpu.ops import field25519 as fe
 from tendermint_tpu.ops import scalar25519 as sc_mod
+from tendermint_tpu.utils import metrics as tmmetrics
+from tendermint_tpu.utils import trace as _trace
 
 MASK = fe.MASK
 FOLD = fe.FOLD
@@ -660,8 +662,8 @@ def launch_chunks(program: str, chunk_fn, ks, key_idx: np.ndarray, n: int,
     fetched: -> the packed pieces of the bitmap, a tuple that one
     `device_get` brings back and :func:`unpack_pieces` joins in chunk order.
 
-    With `devices` (the local devices of the "sharded" route on a TPU host,
-    parallel/batch_shard) chunk k is placed on devices[k mod ndev], from
+    With `devices` (the local devices of the "sharded" route,
+    :func:`dispatch_chunks`) chunk k is placed on devices[k mod ndev], from
     devices[0] in every call: its arrays are put there, the rows come from
     that device's copy of the table, the same program runs there and packs
     its own piece, so the chunks of a batch run on their chips at the same
@@ -700,11 +702,24 @@ def unpack_pieces(fetched, n: int) -> np.ndarray:
 def dispatch_chunks(kind: str, n: int, launch, multichip: bool):
     """The (device_out, finish) of the dispatch contract for a key type's
     chunk loop: `launch(devices=())` is :func:`launch_chunks` with all but
-    the devices bound. On the "sharded" route (`multichip`) it runs under
-    parallel/batch_shard.dispatch_placed, which hands it the local devices;
-    either way every piece's host copy starts now."""
-    dev = (edb._batch_shard().dispatch_placed(kind, n, launch)
-           if multichip else launch())
+    the devices bound. The "sharded" route (`multichip`) hands it all the
+    local devices, chunk k on device k mod ndev, inside the
+    verify.shard_dispatch span, and counts the dispatch on
+    verify_sharded_total by the devices used: the same jitted programs as
+    on one chip, bit for bit the same pieces, no collective. Either way
+    every piece's host copy starts now."""
+    if multichip:
+        devices = tuple(jax.local_devices())
+        chunks = -(-n // CHUNK)
+        used = min(chunks, len(devices))
+        with (_trace.current().span("verify.shard_dispatch", kind=kind, n=n,
+                                    chunks=chunks, devices=used)
+              if _trace.ENABLED else _trace.NULL_SPAN):
+            dev = launch(devices)
+        if tmmetrics.GLOBAL_NODE_METRICS is not None:
+            tmmetrics.GLOBAL_NODE_METRICS.verify_sharded.add(devices=used)
+    else:
+        dev = launch()
     edb._start_host_copy(dev)
     return dev, _cbreaker.routed(lambda v: unpack_pieces(v, n),
                                  "sharded" if multichip else "pallas")

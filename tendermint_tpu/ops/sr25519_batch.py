@@ -28,11 +28,11 @@ validity conditions (host-checked canonical field element + device-checked
 square/t-sign/y-zero), the same transcript bytes (differential test in
 tests/test_sr25519_batch.py).
 
-Two kernels evaluate this, chosen by what the process can observe: on one
-TPU chip the Pallas chunk ops/ed25519_pallas._sr_verify_chunk (the ed25519
-kernel's comb loop, this decode and comparison as its tail, 4,096 lanes a
-call); under shard_map on several devices, and where the backend is no TPU,
-the jnp _sr_verify_kernel below (256 lanes a call).
+Two kernels evaluate this, chosen by what the process can observe: on a
+TPU backend the Pallas chunk ops/ed25519_pallas._sr_verify_chunk (the
+ed25519 kernel's comb loop, this decode and comparison as its tail, 4,096
+lanes a call, a chunk a chip where there are several); where the backend is
+no TPU, the jnp _sr_verify_kernel below (256 lanes a call).
 
 Pubkey comb tables live in one device-resident table keyed per key, a row a
 key, exactly like ed25519's and through the same code (edb.build_keyset,
@@ -166,7 +166,7 @@ def _ristretto_decode_dev(s_limbs):
     return x, y, ok
 
 
-def _sr_verify_kernel(tab, k_win, s_win, r_limbs, valid, axis_name=None):
+def _sr_verify_kernel(tab, k_win, s_win, r_limbs, valid):
     """The jitted batch verify.
 
     tab:     (N, 16, 4, 20) int32  comb table of -A per signature (cached)
@@ -174,8 +174,6 @@ def _sr_verify_kernel(tab, k_win, s_win, r_limbs, valid, axis_name=None):
     s_win:   (N, 64) int32   comb windows of s
     r_limbs: (N, 20) int32   field limbs of the sig's 32-byte R encoding
     valid:   (N,)    bool    host-side precheck results
-    axis_name: mesh axis when running inside shard_map (marks the loop carry
-               as device-varying; same plumbing as the ed25519 twin)
     ->       (N,)    bool
     """
     n = tab.shape[0]
@@ -190,9 +188,6 @@ def _sr_verify_kernel(tab, k_win, s_win, r_limbs, valid, axis_name=None):
         return acc
 
     acc0 = ed.identity((n,))
-    if axis_name is not None:
-        # mark the loop carry device-varying under shard_map
-        acc0 = jax.lax.pcast(acc0, axis_name, to="varying")
     acc = jax.lax.fori_loop(0, 64, body, acc0)
 
     x_r, y_r, ok_r = _ristretto_decode_dev(r_limbs)
@@ -350,15 +345,6 @@ def _dispatch_device(items, n: int, multichip: bool = False):
         return edp.dispatch_chunks("sr25519", n, launch, multichip)
 
     r_limbs = _bytes_to_limbs(r32)
-    if multichip:
-        # Several devices, no TPU backend: the signature axis shards over
-        # the ("dp",) mesh, as the ed25519 twin's does (the key table
-        # replicates once per append).
-        dev = edb._batch_shard().dispatch_sharded(
-            "sr25519", ks, key_idx, [k_win, s_win, r_limbs, valid], n)
-        edb._start_host_copy(dev)
-        return dev, _cbreaker.routed(lambda v: np.asarray(v)[:n], "sharded")
-
     # No TPU backend: fixed-tile chunking through the one JNP_TILE-shaped
     # executable of the jnp kernel.
     tile = edb.JNP_TILE

@@ -213,11 +213,11 @@ class NodeMetrics:
         self.verify_sharded = r.counter(  # tmlint: disable=metrics-discipline
             "consensus", "verify_sharded_total",
             "Batch-verify dispatches spread over the local devices "
-            "(parallel/batch_shard), by the devices used.",
+            "(ops/ed25519_pallas.dispatch_chunks), by the devices used.",
             labels=("devices",))
         # (devices label = devices used by the dispatch; metrics.py cannot
         # know it without importing jax, and a devices="" dummy series
-        # would poison the per-size sums test_multichip asserts on)
+        # would poison a sum over the label)
         self.sigcache_hits = r.counter(
             "crypto", "sigcache_hits_total",
             "Vote-drain signature verifications skipped via the verified-"

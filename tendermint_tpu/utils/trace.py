@@ -121,9 +121,9 @@ CANONICAL_SPANS = {
     "verify.queue": "dispatch()->resolve() queue wait of a PendingVerify",
     "verify.readback": "blocking D2H fetch (crypto/batch._device_get)",
     "verify.replay": "bitmap fetch -> serial accept/reject replay",
-    "verify.shard_dispatch": "a batch spread over the local devices: placed "
-                             "Pallas chunks or shard_map (parallel/"
-                             "batch_shard; tags kind, n, chunks, devices)",
+    "verify.shard_dispatch": "a batch spread over the local devices: Pallas "
+                             "chunks placed one a chip (ops/ed25519_pallas."
+                             "dispatch_chunks; tags kind, n, chunks, devices)",
     "verify.wake": "executor's done.set() -> the waiting caller runs again",
     # one commit decision, entry point to tally (types/validator_set.py);
     # commit.assemble is the decision's root and its span id the decision id
@@ -132,7 +132,7 @@ CANONICAL_SPANS = {
     "commit.wait": "PendingCommitVerify.resolve waiting for the bitmap",
     "commit.tally": "serial accept/reject replay over the bitmap",
     # below ops dispatch_batch (ops/ed25519_batch, sr25519_batch,
-    # ed25519_pallas, parallel/batch_shard)
+    # ed25519_pallas)
     "prep.keyset": "pubkey join, keys mapped to rows of the per-key device "
                    "table, the build of keys it does not hold",
     "prep.scalars": "per-signature hash (SHA-512 / merlin in C), mod L, windows",
@@ -412,7 +412,7 @@ class Tracer:
     @contextlib.contextmanager
     def activate(self):
         """Make this tracer the thread's `current()` target, so library
-        layers (crypto/batch, parallel/batch_shard) record into the node
+        layers (crypto/batch, ops/ed25519_pallas) record into the node
         whose work they are doing without constructor plumbing."""
         prev = getattr(_tl, "tracer", None)
         _tl.tracer = self

@@ -1,16 +1,16 @@
-"""Test configuration: force an 8-device virtual CPU mesh so multi-chip
-sharding paths (jax.sharding.Mesh + shard_map) are exercised without TPU
-hardware. The environment is set before jax is imported; nothing here may
-run a JAX op before the config updates below."""
+"""Test configuration: force 8 virtual CPU devices, so that the chunk loop's
+placement over several devices (tests/test_placed_chunks.py) is exercised
+without TPU hardware. The environment is set before jax is imported; nothing
+here may run a JAX op before the config updates below."""
 
 import os
 
 # TM_TPU_TEST_BACKEND=tpu keeps the session on the real chip (for the
-# on-chip tests like test_pallas_tpu.py); default is the CPU mesh.
+# on-chip tests like test_pallas_tpu.py); default is the CPU's devices.
 _KEEP_TPU = os.environ.get("TM_TPU_TEST_BACKEND") == "tpu"
 
 # The env vars also reach the child processes tests spawn (e2e runner, node
-# subprocesses), which therefore inherit the same CPU-mesh setup.
+# subprocesses), which therefore inherit the same CPU setup.
 if not _KEEP_TPU:
     # Short-lived test processes must not race a background XLA warmup
     # compile at interpreter exit (C++ teardown abort); see crypto/batch.py.
@@ -43,9 +43,8 @@ _SLOW_MODULES = {
     "test_e2e_runner", "test_fastsync_recovery", "test_statesync",
     "test_observability", "test_p2p_node", "test_consensus",
     "test_remote_signer", "test_pallas_tpu", "test_adversarial",
-    # kernel-bound: wide batches / fresh XLA shapes on the 1-core CPU mesh
-    "test_multichip", "test_perf_gate", "test_sr25519_batch",
-    "test_ed25519_batch",
+    # kernel-bound: wide batches / fresh XLA shapes on the CPU
+    "test_perf_gate", "test_sr25519_batch", "test_ed25519_batch",
     # exhaustive state-space exploration (spec/model.py)
     "test_spec_model",
     # subprocess crash-recovery matrix + real-kernel breaker re-probe
@@ -82,8 +81,8 @@ def pytest_sessionfinish(session, exitstatus):
 # batches they mean to run through the kernel. Everything else keeps the
 # production adaptive routing.
 _KERNEL_PATH_MODULES = {
-    "test_ed25519_batch", "test_sr25519_batch", "test_multichip",
-    "test_pallas_tpu", "test_perf_gate",
+    "test_ed25519_batch", "test_sr25519_batch", "test_pallas_tpu",
+    "test_perf_gate",
 }
 
 
@@ -92,6 +91,29 @@ def _pin_kernel_path(request, monkeypatch):
     mod = request.module.__name__.rsplit(".", 1)[-1]
     if mod in _KERNEL_PATH_MODULES:
         monkeypatch.setenv("TM_TPU_HOST_CROSSOVER", "0")
+
+
+@pytest.fixture
+def fake_tpu_host(monkeypatch):
+    """-> fake(ndev, chunk): from then on the host code sees a TPU backend
+    whose chips are the first `ndev` of the CPU's forced devices and whose
+    Pallas chunk is `chunk` lanes -- the module attributes the routing
+    reads, as tests replace `host_crossover`. The chunk programs are the
+    caller's to stand in for. -> the devices."""
+    def fake(ndev: int, chunk: int):
+        from tendermint_tpu.ops import ed25519_batch as edb
+        from tendermint_tpu.ops import ed25519_pallas as edp
+
+        devices = jax.local_devices()[:ndev]
+        assert len(devices) == ndev
+        monkeypatch.setattr(edb, "_use_pallas", lambda: True)
+        monkeypatch.setattr(edp, "CHUNK", chunk)
+        monkeypatch.setattr(jax, "local_devices", lambda *a, **k: list(devices))
+        monkeypatch.setattr(jax, "local_device_count", lambda *a, **k: ndev)
+        monkeypatch.delenv("TM_TPU_SHARD", raising=False)
+        return devices
+
+    return fake
 
 
 @pytest.fixture(autouse=True)
@@ -131,7 +153,7 @@ def pytest_collection_modifyitems(config, items):
                         else pytest.mark.quick)
 
 if not _KEEP_TPU:
-    # an exported JAX_PLATFORMS=tpu must not win over the CPU-mesh default
+    # an exported JAX_PLATFORMS=tpu must not win over the CPU default
     jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_num_cpu_devices", 8)
     assert jax.default_backend() == "cpu" and len(jax.devices()) == 8

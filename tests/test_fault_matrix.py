@@ -162,7 +162,7 @@ def test_torn_write_plus_dead_device_acceptance(tmp_path):
 
 def test_device_breaker_recloses_with_real_kernel(monkeypatch):
     """Slow-tier twin of the quick breaker smoke: the background probe runs
-    the REAL device route (jnp kernel on the CPU mesh) and re-closes the
+    the REAL device route (jnp kernel on the CPU) and re-closes the
     circuit; the next batch verifies on the device again."""
     from tendermint_tpu.crypto import ed25519 as ref
     from tendermint_tpu.ops import ed25519_batch as edb

@@ -385,7 +385,6 @@ def test_failed_warmup_is_recorded_and_logged(monkeypatch, caplog):
     from tendermint_tpu.ops import ed25519_batch as edb
 
     monkeypatch.delenv("TM_TPU_SKIP_WARMUP", raising=False)
-    monkeypatch.setenv("TM_TPU_SHARD", "0")  # no mesh compile in tier-1
     caplog.set_level(logging.ERROR, logger="tendermint_tpu.crypto.batch")
 
     def boom():

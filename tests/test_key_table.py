@@ -350,24 +350,3 @@ def test_threads_looking_up_overlapping_sets_get_the_same_rows(kind):
     want = np.asarray(edb._build_comb_tables_tiled(
         np.stack([kind.decode(p) for p in table])))[:len(table)]
     assert (np.asarray(table.keyset.take(np.arange(len(table)))) == want).all()
-
-
-# --- the mesh's copy ---------------------------------------------------------------------
-
-
-def test_the_table_is_replicated_once_per_mesh_and_append(kind):
-    from tendermint_tpu.parallel import batch_shard
-
-    mesh = batch_shard.make_mesh(jax.devices()[:2])
-    table = kind.mod._KS_UNIQ_CACHE
-    pubs = _pubs(kind, 5)
-    ks, _idx, _ok = kind.mod.get_keyset(pubs[:3])
-    tab = batch_shard.replicated_tables(ks, mesh)
-    assert batch_shard.replicated_tables(ks, mesh) is tab
-    ks2, _idx, _ok = kind.mod.get_keyset(pubs[2::-1])    # resident: no copy
-    assert ks2 is ks and batch_shard.replicated_tables(ks, mesh) is tab
-    kind.mod.get_keyset(pubs)                             # two keys appended
-    new = batch_shard.replicated_tables(ks, mesh)
-    assert new is not tab and len(new.sharding.device_set) == 2
-    assert (np.asarray(new)[:5] == np.asarray(ks.take(np.arange(5)))).all()
-    assert table.keyset is ks

@@ -89,7 +89,7 @@ def test_sr_pallas_differential_vs_host_and_scalar():
                     or jax.local_device_count() < 2,
                     reason="placing chunks needs two TPU devices")
 def test_a_commit_of_9999_on_every_local_chip_vs_the_serial_reference():
-    """The "sharded" route on a TPU host (parallel/batch_shard.dispatch_placed):
+    """The "sharded" route (ops/ed25519_pallas.dispatch_chunks, multichip):
     a 9,999-signature commit is three Pallas chunks on three chips, then a
     batch one signature past ndev - 1 chunks puts a chunk on every chip;
     both bitmaps equal the serial reference's on every corrupted lane (one

@@ -154,28 +154,29 @@ def test_verify_ahead_batches_blocking_fetches(monkeypatch):
 
 
 @pytest.mark.quick
-def test_sharded_registry_bitmap_matches_single_device(monkeypatch):
-    """ISSUE 4 acceptance gate, quick tier: on the multi-device CPU mesh the
-    REGISTRY-level dispatch (crypto/batch.create_batch_verifier -- the exact
-    object verify_commit_async, fast-sync, the vote drain, and range_verify
-    construct) must shard and return a bitmap identical to TM_TPU_SHARD=0
-    single-device for the same batch, valid + tampered lanes, for ed25519,
-    sr25519, and the mixed router.
+def test_registry_bitmap_off_a_tpu_is_one_devices_whatever_the_count(
+        monkeypatch):
+    """Quick tier: off a TPU the REGISTRY-level dispatch
+    (crypto/batch.create_batch_verifier -- the exact object
+    verify_commit_async, fast-sync, the vote drain, and range_verify
+    construct) spreads nothing over the CPU's several devices: every batch
+    takes the `device` route and the jnp kernels answer with the scalar
+    ground truth, valid + tampered lanes, for ed25519, sr25519, and the
+    mixed router.
 
     Small tiles keep the one-time XLA compiles bounded on the CI host: the
-    sharded path dispatches in fixed ndev*JNP_TILE chunks, so shrinking
-    JNP_TILE shrinks the compiled chunk without changing the routing."""
+    jnp route dispatches in fixed JNP_TILE chunks, so shrinking JNP_TILE
+    shrinks the compiled chunk without changing the routing."""
     import jax
 
     if jax.local_device_count() < 2:
-        pytest.skip("needs the multi-device CPU mesh")
+        pytest.skip("needs the CPU's forced devices")
     from tendermint_tpu.crypto import sr25519
     from tendermint_tpu.ops import ed25519_batch as edb
-    from tendermint_tpu.parallel import batch_shard
 
     monkeypatch.setattr(edb, "JNP_TILE", 16)
-    monkeypatch.setenv("TM_TPU_SHARD_MIN", "16")
     monkeypatch.setenv("TM_TPU_BATCH_MIN", "1")
+    monkeypatch.delenv("TM_TPU_SHARD", raising=False)
 
     def ed_item(i, tamper=False):
         p = ed25519.gen_priv_key(bytes([i % 61 + 1]) * 32)
@@ -215,15 +216,10 @@ def test_sharded_registry_bitmap_matches_single_device(monkeypatch):
              ("sr25519", sr_items, [i != 5 for i in range(20)]),
              (None, mixed, want_mixed)]
     for key_type, items, want in cases:
-        monkeypatch.delenv("TM_TPU_SHARD", raising=False)
-        assert batch_shard.should_shard(len(items))
-        all_ok_sh, sharded = registry(key_type, items)
-        monkeypatch.setenv("TM_TPU_SHARD", "0")
-        all_ok_si, single = registry(key_type, items)
-        monkeypatch.delenv("TM_TPU_SHARD", raising=False)
-        assert sharded == single, f"{key_type}: sharded != single-device"
-        assert sharded == want, f"{key_type}: bitmap != scalar ground truth"
-        assert all_ok_sh == all_ok_si == all(want)
+        assert edb.route_batch(len(items)) == "device"
+        all_ok, bitmap = registry(key_type, items)
+        assert bitmap == want, f"{key_type}: bitmap != scalar ground truth"
+        assert all_ok == all(want)
 
 
 def test_range_verify_one_flush_and_no_scalar_header_hashing(monkeypatch):

@@ -1,6 +1,6 @@
-"""The "sharded" route as a TPU host runs it: Pallas chunks placed one a
-local device (ops/ed25519_pallas.launch_chunks under
-parallel/batch_shard.dispatch_placed), on the CPU's forced devices.
+"""The "sharded" route: Pallas chunks placed one a local device
+(ops/ed25519_pallas.launch_chunks under dispatch_chunks), a TPU host's chips
+played by the CPU's forced devices.
 
 The chunk programs are stood in for by the jnp kernels over the niels rows
 the chunk was GIVEN (so a device's copy of the key table decides the answer),
@@ -25,7 +25,6 @@ from tendermint_tpu.ops import ed25519_batch as edb
 from tendermint_tpu.ops import ed25519_pallas as edp
 from tendermint_tpu.ops import field25519 as fe
 from tendermint_tpu.ops import sr25519_batch as srb
-from tendermint_tpu.parallel import batch_shard
 
 LANES = edp.TILE
 NDEV = 3          # of the eight: four chunks wrap around to the first
@@ -95,12 +94,11 @@ def kind(request):
 
 
 @pytest.fixture
-def placed(monkeypatch):
+def placed(monkeypatch, fake_tpu_host):
     """A TPU host of NDEV chips, as far as the host code can tell: the
     backend test says Pallas, the chunk is a tile, both chunk programs are
     the stand-ins. -> the device of every chunk launched, in order."""
-    devices = jax.local_devices()[:NDEV]
-    assert len(devices) == NDEV
+    devices = fake_tpu_host(NDEV, LANES)
     seen = []
 
     def stand_in(body):
@@ -113,12 +111,6 @@ def placed(monkeypatch):
             return jax.device_put(ok[None, :].astype(jnp.int32), at)
         return chunk
 
-    monkeypatch.setattr(edb, "_use_pallas", lambda: True)
-    monkeypatch.setattr(edp, "CHUNK", LANES)
-    monkeypatch.setattr(jax, "local_devices", lambda *a, **k: list(devices))
-    monkeypatch.setattr(jax, "local_device_count", lambda *a, **k: NDEV)
-    monkeypatch.delenv("TM_TPU_SHARD", raising=False)
-    monkeypatch.delenv("TM_TPU_SHARD_MIN", raising=False)
     for k in KINDS:
         monkeypatch.setattr(edp, k.attr, stand_in(k.body))
     cbatch.forget_keys()
@@ -267,33 +259,47 @@ def test_one_device_makes_no_placement_call(devices, monkeypatch):
 @pytest.mark.parametrize("n, want", [
     (100, "host"), (edb.JNP_TILE * 4, "device"), (edp.CHUNK, "device"),
     (edp.CHUNK + 1, "sharded"), (9999, "sharded")])
-def test_route_table_on_a_tpu_host_with_four_chips(n, want, monkeypatch):
+def test_route_table_on_a_tpu_host_with_four_chips(n, want, monkeypatch,
+                                                   fake_tpu_host):
     """One chunk or less takes the rows a one-chip host takes; from one
     signature more the batch is spread."""
     from tendermint_tpu.ops import chost
 
     monkeypatch.setattr(chost, "available", lambda: True)
-    monkeypatch.setattr(edb, "_use_pallas", lambda: True)
     monkeypatch.setattr(edb, "host_crossover", lambda: 256)
-    monkeypatch.setattr(jax, "local_device_count", lambda: 4)
-    monkeypatch.delenv("TM_TPU_SHARD", raising=False)
-    monkeypatch.delenv("TM_TPU_SHARD_MIN", raising=False)
-    assert batch_shard.shard_threshold(4) == edp.CHUNK + 1
+    fake_tpu_host(4, edp.CHUNK)
+    assert edb.should_shard(n) == (n > edp.CHUNK)
     assert edb.route_batch(n) == want
     monkeypatch.setattr(jax, "local_device_count", lambda: 1)
     assert edb.route_batch(n) == ("device" if want == "sharded" else want)
-    # the operator's floor still overrides, and TM_TPU_SHARD=0 is one device
+    # TM_TPU_SHARD=0 is one device
     monkeypatch.setattr(jax, "local_device_count", lambda: 4)
-    monkeypatch.setenv("TM_TPU_SHARD_MIN", "64")
-    assert edb.route_batch(n) == "sharded"
     monkeypatch.setenv("TM_TPU_SHARD", "0")
     assert edb.route_batch(n) == ("device" if want == "sharded" else want)
 
 
-def test_off_a_tpu_the_floor_is_a_bucket_a_device(monkeypatch):
-    monkeypatch.delenv("TM_TPU_SHARD_MIN", raising=False)
-    assert not edb._use_pallas()
-    assert batch_shard.shard_threshold(8) == 8 * edb.MIN_BUCKET
+@pytest.mark.parametrize("n", [64, 2048, 20480])
+def test_off_a_tpu_nothing_is_sharded(kind, n, monkeypatch):
+    """A CPU-only node, or these tests' eight forced devices: whatever the
+    device count and the size, a batch takes the `device` route and the jnp
+    tile loop on one device (kernels stood in for by `valid`)."""
+    monkeypatch.delenv("TM_TPU_SHARD", raising=False)
+    assert not edb._use_pallas() and jax.local_device_count() > 1
+    tiles = []
+
+    def tile(tab, *arrays, **kw):
+        valid = kw["valid"] if kw else arrays[-1]
+        tiles.append((valid.shape, valid.devices()))
+        return valid
+
+    monkeypatch.setattr(edb, "_jnp_kernel", tile)
+    monkeypatch.setattr(srb, "_kernel", tile)
+    assert not edb.should_shard(n)
+    assert edb.route_batch(n, force_device=True) == "device"
+    dev, finish = kind.mod.dispatch_batch(_items(kind, n), force_device=True)
+    assert finish(cbatch._device_get(dev)).all() and finish.route == "jnp"
+    assert tiles == [((edb.JNP_TILE,), {jax.local_devices()[0]})] * (
+        -(-n // edb.JNP_TILE))
 
 
 # --- the warm-up ---------------------------------------------------------------

@@ -1,17 +1,20 @@
 """The one routing decision of the verify path (ops/ed25519_batch.route_batch).
 
 Which route a batch of n signatures takes -- the registry's pure-Python
-loop, the C host verifier, the one-chip kernel, every local device (here
-shard_map over the CPU mesh; tests/test_placed_chunks.py has a TPU host's
-floor and its placed chunks) --
+loop, the C host verifier, the one-chip kernel, every local chip (a TPU
+host of four chips, faked as far as the host code can tell;
+tests/test_placed_chunks.py has the real floor and the placement) --
 and whether the verify service owns the launch, all come from this one
 function. The table below is docs/PARALLEL.md's, case by case; the tests
 after it hold both dispatch_batch entry points, the registry and the
 service to the answer.
 
 Kernels are stood in for by their `valid` argument (the slow tier's
-test_ed25519_batch / test_sr25519_batch / test_multichip run the real
-ones): routing, the service's choice and `finish.route` are host work."""
+test_ed25519_batch / test_sr25519_batch run the real ones): routing, the
+service's choice and `finish.route` are host work."""
+
+import ast
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +26,6 @@ from tendermint_tpu.ops import chost
 from tendermint_tpu.ops import ed25519_batch as edb
 from tendermint_tpu.ops import ed25519_pallas as edp
 from tendermint_tpu.ops import sr25519_batch as srb
-from tendermint_tpu.parallel import batch_shard
 from tendermint_tpu.utils import faults
 
 SCALAR_MIN, CROSSOVER, SHARD_MIN = 32, 512, 64
@@ -34,24 +36,39 @@ SIZES = (8, 100, 2000)
 # (C library, force_device, mesh) -> the routes of SIZES, in order
 TABLE = {
     ("loaded", False, "1dev"): ("host", "host", "device"),
-    ("loaded", False, "8dev"): ("host", "sharded", "sharded"),
-    ("loaded", False, "8dev_shard_off"): ("host", "host", "device"),
+    ("loaded", False, "4chip"): ("host", "sharded", "sharded"),
+    ("loaded", False, "4chip_shard_off"): ("host", "host", "device"),
     ("loaded", True, "1dev"): ("device", "device", "device"),
-    ("loaded", True, "8dev"): ("device", "sharded", "sharded"),
-    ("loaded", True, "8dev_shard_off"): ("device", "device", "device"),
+    ("loaded", True, "4chip"): ("device", "sharded", "sharded"),
+    ("loaded", True, "4chip_shard_off"): ("device", "device", "device"),
     ("building", False, "1dev"): ("scalar", "host", "device"),
-    ("building", False, "8dev"): ("scalar", "sharded", "sharded"),
-    ("building", False, "8dev_shard_off"): ("scalar", "host", "device"),
+    ("building", False, "4chip"): ("scalar", "sharded", "sharded"),
+    ("building", False, "4chip_shard_off"): ("scalar", "host", "device"),
     ("building", True, "1dev"): ("device", "device", "device"),
-    ("building", True, "8dev"): ("device", "sharded", "sharded"),
-    ("building", True, "8dev_shard_off"): ("device", "device", "device"),
+    ("building", True, "4chip"): ("device", "sharded", "sharded"),
+    ("building", True, "4chip_shard_off"): ("device", "device", "device"),
     ("absent", False, "1dev"): ("scalar", "device", "device"),
-    ("absent", False, "8dev"): ("scalar", "sharded", "sharded"),
-    ("absent", False, "8dev_shard_off"): ("scalar", "device", "device"),
+    ("absent", False, "4chip"): ("scalar", "sharded", "sharded"),
+    ("absent", False, "4chip_shard_off"): ("scalar", "device", "device"),
     ("absent", True, "1dev"): ("device", "device", "device"),
-    ("absent", True, "8dev"): ("device", "sharded", "sharded"),
-    ("absent", True, "8dev_shard_off"): ("device", "device", "device"),
+    ("absent", True, "4chip"): ("device", "sharded", "sharded"),
+    ("absent", True, "4chip_shard_off"): ("device", "device", "device"),
 }
+
+
+def test_no_ops_module_imports_the_parallel_package():
+    """The routing decision and everything it asks live in ops: no module
+    there reaches up into a `parallel` package for half of its answer."""
+    for path in sorted(pathlib.Path(edb.__file__).parent.rglob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom):
+                names = [f"{node.module}.{a.name}" for a in node.names]
+            else:
+                continue
+            assert not any("parallel" in n.split(".") for n in names), (
+                path.name, node.lineno, names)
 
 
 def _set_chost(monkeypatch, state: str) -> None:
@@ -59,14 +76,15 @@ def _set_chost(monkeypatch, state: str) -> None:
     monkeypatch.setattr(chost, "building", lambda: state == "building")
 
 
-def _set_mesh(monkeypatch, mesh: str) -> None:
-    monkeypatch.setattr(jax, "local_device_count",
-                        lambda: 1 if mesh == "1dev" else 8)
-    monkeypatch.setenv("TM_TPU_SHARD_MIN", str(SHARD_MIN))
-    if mesh == "8dev_shard_off":
+def _set_mesh(monkeypatch, fake_tpu_host, mesh: str) -> None:
+    """One device, or a TPU host of four chips whose chunk puts the shard
+    floor at SHARD_MIN signatures."""
+    if mesh == "1dev":
+        monkeypatch.setattr(jax, "local_device_count", lambda: 1)
+        return
+    fake_tpu_host(4, SHARD_MIN - 1)
+    if mesh == "4chip_shard_off":
         monkeypatch.setenv("TM_TPU_SHARD", "0")
-    else:
-        monkeypatch.delenv("TM_TPU_SHARD", raising=False)
 
 
 @pytest.mark.parametrize(
@@ -75,9 +93,9 @@ def _set_mesh(monkeypatch, mesh: str) -> None:
      for (lib, force, mesh), wants in TABLE.items()
      for n, want in zip(SIZES, wants)],
     ids=str)
-def test_route_table(lib, force, mesh, n, want, monkeypatch):
+def test_route_table(lib, force, mesh, n, want, monkeypatch, fake_tpu_host):
     _set_chost(monkeypatch, lib)
-    _set_mesh(monkeypatch, mesh)
+    _set_mesh(monkeypatch, fake_tpu_host, mesh)
     # as tests/benchmark does: the module ATTRIBUTE, not the environment
     monkeypatch.setattr(edb, "host_crossover", lambda: CROSSOVER)
     assert edb.route_batch(n, force, SCALAR_MIN) == want
@@ -89,7 +107,7 @@ def test_no_scalar_route_for_direct_callers(lib, want, monkeypatch):
     """scalar_min defaults to 0: the service and other direct callers of
     dispatch_batch never get "scalar", whatever the size."""
     _set_chost(monkeypatch, lib)
-    _set_mesh(monkeypatch, "1dev")
+    monkeypatch.setattr(jax, "local_device_count", lambda: 1)
     monkeypatch.setattr(edb, "host_crossover", lambda: CROSSOVER)
     assert edb.route_batch(1) == want
 
@@ -97,7 +115,7 @@ def test_no_scalar_route_for_direct_callers(lib, want, monkeypatch):
 # --- the entry points take the route the function names ---------------------
 
 _KINDS = {"ed25519": (ed25519, edb), "sr25519": (sr25519, srb)}
-_FINISH_ROUTE = {"host": "host_c", "device": "jnp", "sharded": "sharded"}
+_FINISH_ROUTE = {"host": "host_c", "device": "pallas", "sharded": "sharded"}
 
 
 def _items(kind: str, n: int):
@@ -120,21 +138,24 @@ def _raw(items):
 
 
 @pytest.fixture
-def stand_ins(monkeypatch):
-    """Crossover 32, shard floor 64 on the 8 virtual devices, kernels
-    answering `valid`; -> the (n, finish) of every ops dispatch_batch."""
+def stand_ins(monkeypatch, fake_tpu_host):
+    """Crossover 32 and a TPU host of four chips with a 64-lane chunk, as
+    far as the host code can tell; kernels and chunk programs answering
+    `valid`; -> the (n, finish) of every ops dispatch_batch."""
     # build inline: the non-blocking available() answers False until a
     # background build lands, and the host route here is the C verifier's
     if not chost.ensure_available():
         pytest.skip("C host verifier unavailable (no gcc?)")
     monkeypatch.setattr(edb, "host_crossover", lambda: 32)
-    monkeypatch.setenv("TM_TPU_SHARD_MIN", "64")
-    monkeypatch.delenv("TM_TPU_SHARD", raising=False)
+    fake_tpu_host(4, 64)
     monkeypatch.delenv("TMTPU_VERIFY_SERVICE", raising=False)
     monkeypatch.setattr(edb, "_jnp_kernel", lambda tab, **kw: kw["valid"])
     monkeypatch.setattr(srb, "_kernel", lambda tab, *arrays: arrays[-1])
-    monkeypatch.setattr(batch_shard, "_sharded_verify_fn",
-                        lambda mesh, kind: lambda tab, idx, *arrays: arrays[-1])
+    for program in ("_verify_chunk", "_sr_verify_chunk"):
+        monkeypatch.setattr(edp, program,
+                            lambda tab, *cols: cols[-1].astype(jnp.int32))
+    monkeypatch.setattr(edb.KeySet, "gathered_lane",
+                        lambda self, idx, device=None: None)
     seen = []
     for mod in (edb, srb):
         def spy(items, force_device=False, _real=mod.dispatch_batch):
@@ -152,10 +173,10 @@ def stand_ins(monkeypatch):
                                      (72, "sharded")])
 def test_entry_points_and_registry_take_the_named_route(kind, n, want,
                                                         stand_ins):
-    """One batch on each side of the crossover and of the shard floor,
-    through ops.dispatch_batch and through the registry: the route on the
-    finish is the one route_batch names, and the service owns the launch
-    exactly when that route pays the sync floor."""
+    """One batch on each side of the crossover and of the shard floor (one
+    chunk), through ops.dispatch_batch and through the registry: the route
+    on the finish is the one route_batch names, and the service owns the
+    launch exactly when that route pays the sync floor."""
     items = _items(kind, n)
     assert edb.route_batch(n) == want
     assert edb.route_batch(n, False, SCALAR_MIN) == want  # C library loaded
@@ -198,10 +219,10 @@ def test_the_host_route_is_the_scalar_loop_while_the_library_builds(
 
 
 @pytest.mark.parametrize("backend, n, want, launch", [
-    ("tpu", 40, "pallas", ("chunk", edp.CHUNK)),  # one chip: the Pallas chunk
+    ("tpu", 40, "pallas", ("chunk", 64)),         # one chunk: the Pallas chunk
     ("cpu", 40, "jnp", ("tile", edb.JNP_TILE)),   # no TPU backend: the jnp tile
-    ("cpu", 72, "sharded", None),      # several devices: shard_map, jnp
-    ("tpu", 72, "sharded", ("chunk", edp.CHUNK)),  # ... on a TPU: the chunk
+    ("cpu", 72, "jnp", ("tile", edb.JNP_TILE)),   # ... whatever the devices
+    ("tpu", 72, "sharded", ("chunk", 64)),  # several chips: a chunk a chip
 ])
 def test_sr25519_kernel_follows_backend_and_device_count(
         backend, n, want, launch, stand_ins, monkeypatch):
@@ -220,14 +241,12 @@ def test_sr25519_kernel_follows_backend_and_device_count(
         return arrays[-1]
 
     monkeypatch.setattr(edp, "_sr_verify_chunk", chunk)
-    monkeypatch.setattr(edb.KeySet, "gathered_lane",
-                        lambda self, idx, device=None: None)
     monkeypatch.setattr(srb, "_kernel", tile)
     items = _raw(_items("sr25519", n))
     assert edb.route_batch(n) == ("sharded" if want == "sharded" else "device")
     dev, finish = srb.dispatch_batch(items)
     assert finish(cbatch._device_get(dev)).all() and finish.route == want
-    assert launched == ([] if launch is None else [launch])
+    assert launched == [launch] * (2 if want == "sharded" else 1)
 
 
 # --- the service's guess and the dispatch agree -----------------------------
@@ -235,7 +254,7 @@ def test_sr25519_kernel_follows_backend_and_device_count(
 
 @pytest.mark.parametrize("lib, n, force", [
     ("loaded", 40, False),    # at or above the crossover
-    ("loaded", 72, False),    # above the shard floor
+    ("loaded", 72, False),    # above the shard floor, one chunk
     ("loaded", 9, True),      # below the crossover, pinned to the device
     ("absent", 33, False),    # no C verifier: the device at any size
 ], ids=["above_crossover", "above_shard_floor", "forced", "no_c_library"])
@@ -252,7 +271,7 @@ def test_a_service_owned_request_is_not_answered_by_the_host(
     assert isinstance(p, cbatch.ServicePending)
     assert p.resolve() == (True, [True] * n)
     assert [f.route for _n, f in stand_ins] == [
-        "sharded" if n >= 64 else "jnp"]
+        "sharded" if n > 64 else "pallas"]
 
 
 def test_only_an_open_breaker_sends_a_service_owned_request_to_the_host(

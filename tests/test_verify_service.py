@@ -453,24 +453,28 @@ def _launches(tracer):
 
 
 def test_route_tag_names_the_route_taken_and_lanes_the_padded_size(
-        tracer, monkeypatch):
-    """A host-routed, a forced-device and a sharded batch on the CPU's
-    virtual devices: `route` is the route dispatch_batch took, the finish
-    carries it for batch_verify_seconds, and sum(lanes) is the padded size
-    actually launched."""
+        tracer, monkeypatch, fake_tpu_host):
+    """A host-routed and a forced-device batch, then a sharded one on a TPU
+    host of four chips (faked on the CPU's virtual devices): `route` is the
+    route dispatch_batch took, the finish carries it for
+    batch_verify_seconds, and sum(lanes) is the padded size actually
+    launched."""
+    import jax.numpy as jnp
+
     from tendermint_tpu.ops import ed25519_batch as edb
-    from tendermint_tpu.parallel import batch_shard
+    from tendermint_tpu.ops import ed25519_pallas as edp
     from tendermint_tpu.utils import metrics as tmmetrics
 
     raw = [(pk.bytes(), m, s) for pk, m, s in _ed_items(40, seed=61)]
     monkeypatch.setattr(tmmetrics, "GLOBAL_NODE_METRICS",
                         tmmetrics.NodeMetrics())
-    # routing, tags and lane counts are the host's work: the kernels (slow
-    # tier: test_ed25519_batch, test_multichip) are stood in for by their
-    # `valid` argument, which keeps this test off ten tiles of XLA:CPU
+    # routing, tags and lane counts are the host's work: the kernel (slow
+    # tier: test_ed25519_batch) and the chunk program (test_placed_chunks)
+    # are stood in for by their `valid` argument, which keeps this test off
+    # XLA:CPU's compiles of them
     monkeypatch.setattr(edb, "_jnp_kernel", lambda tab, **kw: kw["valid"])
-    monkeypatch.setattr(batch_shard, "_sharded_verify_fn",
-                        lambda mesh, kind: lambda tab, idx, *arrays: arrays[-1])
+    monkeypatch.setattr(edp, "_verify_chunk",
+                        lambda tab, *cols: cols[-1].astype(jnp.int32))
     with tracer.activate():
         # host: below the crossover the C verifier answers, nothing launches
         dev, finish = edb.dispatch_batch(raw)
@@ -480,8 +484,7 @@ def test_route_tag_names_the_route_taken_and_lanes_the_padded_size(
         assert host[0].tags["sigs"] == 40 and not _launches(tracer)
         tracer.clear()
 
-        # forced device, one chip's worth: the jnp kernel in 256-lane tiles
-        monkeypatch.setenv("TM_TPU_SHARD", "0")
+        # forced device off a TPU: the jnp kernel in 256-lane tiles
         big = raw * 7                                     # 280 signatures
         dev, finish = edb.dispatch_batch(big, force_device=True)
         assert finish(cbatch._device_get(dev)).all() and finish.route == "jnp"
@@ -494,24 +497,33 @@ def test_route_tag_names_the_route_taken_and_lanes_the_padded_size(
         assert keyset[0].tags["hit"] == "miss" and keyset[0].tags["keys"] == 280
         tracer.clear()
 
-        # sharded: the same batch over the 8 virtual devices
-        monkeypatch.delenv("TM_TPU_SHARD")
-        monkeypatch.setenv("TM_TPU_SHARD_MIN", "64")
-        assert batch_shard.should_shard(len(big))
+        # sharded: the same batch on four chips, five 64-lane chunks, the
+        # fifth back on the first chip
+        devices = fake_tpu_host(4, 64)
+        monkeypatch.setattr(edb.KeySet, "gathered_lane",
+                            lambda self, idx, device=None: None)
+        assert edb.should_shard(len(big))
         p = _dispatch("ed25519", [(pk, m, s) for pk, m, s in
                                   _ed_items(40, seed=61)] * 7)
         assert p.resolve()[0]
         got = _launches(tracer)
-        chunk = 8 * edb.JNP_TILE
-        assert [s.tags["route"] for s in got] == ["sharded"]
-        assert got[0].tags["program"] == "jit__local_verify"
-        assert got[0].tags["sigs"] == 280 and got[0].tags["lanes"] == chunk
-        shard = [s for s in tracer.dump() if s.name == "verify.shard_dispatch"]
-        assert got[0].parent_id == shard[0].span_id
+        assert [s.tags["route"] for s in got] == ["sharded"] * 5
+        assert {s.tags["program"] for s in got} == {"jit__verify_chunk"}
+        assert [s.tags["sigs"] for s in got] == [64, 64, 64, 64, 24]
+        assert [s.tags["lanes"] for s in got] == [64] * 5
+        assert [s.tags["device"] for s in got] == [
+            d.id for d in devices + devices[:1]]
+        shard, = [s for s in tracer.dump()
+                  if s.name == "verify.shard_dispatch"]
+        assert {s.parent_id for s in got} == {shard.span_id}
+        assert (shard.tags["kind"], shard.tags["n"], shard.tags["chunks"],
+                shard.tags["devices"]) == ("ed25519", 280, 5, 4)
         assert [s.tags["hit"] for s in tracer.dump()
                 if s.name == "prep.keyset"] == ["sequence"]
-    # the service observed the sharded launch under its route's label
+    # the dispatch was counted by its devices, and the service observed the
+    # sharded launch under its route's label
     text = tmmetrics.GLOBAL_NODE_METRICS.registry.expose()
+    assert 'tendermint_consensus_verify_sharded_total{devices="4"} 1' in text
     assert ('tendermint_consensus_batch_verify_seconds_count'
             '{route="sharded"} 1') in text
     for route in ("pallas", "jnp", "host_c", "host_scalar",

@@ -563,14 +563,14 @@ def _tarjan(graph: dict) -> list[set]:
 # ---------------------------------------------------------------------------
 
 # Where host<->device syncs are ALLOWED: the kernel modules (finishers,
-# probes, warmup), the shard driver, and the two audited choke FUNCTIONS —
+# probes, warmup) and the two audited choke FUNCTIONS —
 # crypto/batch._device_get (every PendingVerify/prefetch readback) and
 # crypto/verify_service._readback (the continuous-batching service's
 # single blocking fetch, itself routed through _device_get). Everything
 # else must go through PendingVerify/resolve_all or the service; a stray
 # device_get/block_until_ready anywhere else re-introduces an unshared
 # host<->device round trip.
-_DEVICE_ALLOW_DIRS = ("tendermint_tpu/ops/", "tendermint_tpu/parallel/")
+_DEVICE_ALLOW_DIRS = ("tendermint_tpu/ops/",)
 _DEVICE_CHOKE_FUNCS = (
     ("tendermint_tpu/crypto/batch.py", "_device_get"),
     ("tendermint_tpu/crypto/verify_service.py", "_readback"),
