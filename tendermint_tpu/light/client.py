@@ -44,6 +44,11 @@ SKIPPING = "skipping"
 DEFAULT_PRUNING_SIZE = 1000
 DEFAULT_MAX_CLOCK_DRIFT_S = 10.0
 DEFAULT_MAX_RETRY_ATTEMPTS = 10
+# A pivot lies 9/16 of the way from the verified block to the one refused:
+# the cached blocks of the batch before always lie past 1/2, so 9/16 finds
+# something in between (reference: light/client.go verifySkippingNumerator).
+VERIFY_SKIPPING_NUMERATOR = 9
+VERIFY_SKIPPING_DENOMINATOR = 16
 
 
 @dataclass
@@ -75,6 +80,15 @@ def _count(metric: str, n: int = 1, **labels) -> None:
     m = tmmetrics.GLOBAL_NODE_METRICS
     if m is not None:
         getattr(m, metric).add(n, **labels)
+
+
+def _gauge_max(metric: str, value: int) -> None:
+    """Raise a NodeMetrics gauge to `value` where a node exposes metrics."""
+    from tendermint_tpu.utils import metrics as tmmetrics
+
+    m = tmmetrics.GLOBAL_NODE_METRICS
+    if m is not None:
+        getattr(m, metric).raise_to(value)
 
 
 class Client:
@@ -123,6 +137,8 @@ class Client:
         # windows of a sequential sync that had to be re-run header by
         # header for another reason than a refused header (0 when healthy)
         self.range_fallbacks = 0
+        # the attempts of the last bisection, in order: (from, to, accepted)
+        self.last_bisection: list[tuple[int, int, bool]] = []
         self.latest_trusted: LightBlock | None = trusted_store.latest_light_block()
         if self.latest_trusted is None:
             self._initialize(trust_options)
@@ -369,13 +385,23 @@ class Client:
     ) -> list[LightBlock]:
         """Bisection (reference: light/client.go:706 verifySkipping).
 
-        Maintains a stack of pending blocks; on ErrNewValSetCantBeTrusted,
-        fetch the midpoint and retry against it. With save=False nothing is
-        written to the trusted store (the detector substantiates a witness's
-        divergent header without polluting trust).
+        A cache of the blocks fetched so far, the target first and each
+        pivot after the block it halves the way to. An attempt that the
+        trusted set cannot vouch for (ErrNewValSetCantBeTrusted) moves one
+        place down the cache, and only at its end is a new pivot fetched,
+        9/16 of the way from the verified block to the last one refused; an
+        attempt that verifies makes its block the verified one, drops it and
+        everything below it from the cache, and starts again from the target.
+        With save=False nothing is written to the trusted store (the detector
+        substantiates a witness's divergent header without polluting trust).
+
+        Every attempt is a ``light.skip.hop`` span and an entry of
+        ``last_bisection``; a pivot's fetch is a ``light.skip.fetch`` span.
         """
+        tr = range_verify.tracer()
         block_cache = [new_lb]
         verified_blocks = []
+        attempts = self.last_bisection = []
         depth = 0
         verified = trusted
         # Captured once: self.primary may be reassigned mid-bisection by a
@@ -383,46 +409,63 @@ class Client:
         use_primary = source is self.primary
         while True:
             candidate = block_cache[depth]
+            accepted = False
             try:
-                lv.verify(
-                    verified.signed_header,
-                    verified.validator_set,
-                    candidate.signed_header,
-                    candidate.validator_set,
-                    self.trusting_period_s,
-                    now,
-                    self.max_clock_drift_s,
-                    self.trust_level,
-                )
-            except lv.ErrNewValSetCantBeTrusted:
-                # Can't skip that far: bisect (reference client.go:755-776).
-                pivot = (verified.height + candidate.height) // 2
-                if pivot == verified.height:
-                    raise LightClientError(
-                        "bisection failed to converge "
-                        f"({verified.height} -> {candidate.height})"
-                    )
-                inter = (
-                    self._light_block_from_primary(pivot)
-                    if use_primary
-                    else source.light_block(pivot)
-                )
-                inter.validate_basic(self.chain_id)
-                block_cache.insert(depth + 1, inter)
+                with range_verify.span(
+                        tr, "light.skip.hop", to=candidate.height, depth=depth,
+                        accepted=0, **{"from": verified.height}):
+                    try:
+                        lv.verify(
+                            verified.signed_header,
+                            verified.validator_set,
+                            candidate.signed_header,
+                            candidate.validator_set,
+                            self.trusting_period_s,
+                            now,
+                            self.max_clock_drift_s,
+                            self.trust_level,
+                        )
+                        accepted = True
+                    except lv.ErrNewValSetCantBeTrusted:
+                        pass
+                    if accepted and tr is not None:
+                        tr.annotate(accepted=1)
+            finally:
+                attempts.append((verified.height, candidate.height, accepted))
+            if not accepted:
+                # Can't skip that far (reference client.go:755-776): the
+                # next cached block, or at the cache's end a new pivot.
+                _count("light_skip_refused")
+                if depth == len(block_cache) - 1:
+                    pivot = verified.height + (
+                        (candidate.height - verified.height)
+                        * VERIFY_SKIPPING_NUMERATOR // VERIFY_SKIPPING_DENOMINATOR)
+                    if pivot == verified.height:
+                        raise LightClientError(
+                            "bisection failed to converge "
+                            f"({verified.height} -> {candidate.height})"
+                        )
+                    with range_verify.span(tr, "light.skip.fetch", height=pivot):
+                        if use_primary:  # validate_basic included
+                            inter = self._light_block_from_primary(pivot)
+                        else:
+                            inter = source.light_block(pivot)
+                            inter.validate_basic(self.chain_id)
+                    block_cache.append(inter)
                 depth += 1
+                _gauge_max("light_skip_depth_max", depth)
                 continue
             # Verified one step.
             _count("light_headers_verified", mode=SKIPPING)
-            if candidate.height == new_lb.height:
+            _count("light_skip_hops")
+            if depth == 0:
                 return verified_blocks
             verified = candidate
             verified_blocks.append(candidate)
-            if save and candidate.height != new_lb.height:
+            if save:
                 self.trusted_store.save_light_block(candidate)
+            block_cache = block_cache[:depth]
             depth = 0
-            block_cache = [b for b in block_cache if b.height > candidate.height]
-            if not block_cache:
-                block_cache = [new_lb]
 
     def _backwards(self, trusted: LightBlock, new_lb: LightBlock) -> None:
         """Hash-linked walk below the first trusted header (reference:

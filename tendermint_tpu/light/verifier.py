@@ -24,6 +24,7 @@ from tendermint_tpu.types.validator_set import (
     ErrNotEnoughVotingPowerSigned,
     ValidatorSet,
 )
+from tendermint_tpu.utils import trace as _trace
 
 # New header can be trusted if at least one correct validator signed it
 # (reference: light/verifier.go:16 DefaultTrustLevel).
@@ -163,10 +164,15 @@ def verify_non_adjacent(trusted_header: SignedHeader, trusted_vals: ValidatorSet
         raise ErrNewValSetCantBeTrusted(e) from e
     # 2/3 of the new validators must have signed. Kept last: untrustedVals
     # can be made large to DOS the light client (reference comment :69-72).
+    # The two checks are two dependent decisions, one after the other
+    # (light.skip.trusting, then light.skip.light).
     try:
-        untrusted_vals.verify_commit_light(
-            trusted_header.header.chain_id, untrusted_header.commit.block_id,
-            untrusted_header.height, untrusted_header.commit)
+        with (_trace.current().span("light.skip.light")
+              if _trace.ENABLED else _trace.NULL_SPAN):
+            untrusted_vals.verify_commit_light(
+                trusted_header.header.chain_id,
+                untrusted_header.commit.block_id,
+                untrusted_header.height, untrusted_header.commit)
     except Exception as e:  # noqa: BLE001
         raise ErrInvalidHeader(e) from e
 

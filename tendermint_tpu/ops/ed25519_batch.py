@@ -384,7 +384,9 @@ class KeyTable(dict):
     build_keyset is given. `clear()` forgets every row (the next lookup
     builds its keys again), as does a lookup that would pass MAX_ROWS: that
     bounds HBM (8,960 bytes a row) against a peer that feeds a light client
-    key sets without end."""
+    key sets without end. `overflow_clears` counts those for the table's
+    lifetime (prep.keyset's `cleared` tag; on /metrics keytable_clears_total,
+    beside keytable_keys_built_total)."""
 
     MAX_ROWS = 1 << 16
 
@@ -392,6 +394,7 @@ class KeyTable(dict):
         super().__init__()
         self.keyset = KeySet()
         self.generation = 0
+        self.overflow_clears = 0
 
     def clear(self) -> None:
         super().clear()
@@ -417,6 +420,8 @@ class KeyTable(dict):
                 and self.keyset.n_rows + _round_up(len(new), KEY_TILE)
                 > self.MAX_ROWS):
             self.clear()
+            self.overflow_clears += 1
+            _count_metric("keytable_clears")
             new = list(dict.fromkeys(keys))
         t0 = _time.monotonic()
         a_neg = np.broadcast_to(ed.IDENTITY_LIMBS, (len(new), 4, 20)).copy()
@@ -436,7 +441,17 @@ class KeyTable(dict):
         _trace.STARTUP.record("startup.table_build", t2 - t1, start=t1,
                               keys=len(new), kind=kind,
                               rows=_round_up(len(new), KEY_TILE))
+        _count_metric("keytable_keys_built", len(new))
         return len(new)
+
+
+def _count_metric(metric: str, n: int = 1) -> None:
+    """Add to a NodeMetrics counter where a node exposes metrics."""
+    from tendermint_tpu.utils import metrics as tmmetrics
+
+    m = tmmetrics.GLOBAL_NODE_METRICS
+    if m is not None:
+        getattr(m, metric).add(n)
 
 
 _KS_LOCK = threading.Lock()
@@ -490,25 +505,29 @@ def build_keyset(pubs: list[bytes], cache: OrderedDict, lock: threading.Lock,
     if _trace.ENABLED:
         tr = _trace.current()
         with tr.span("prep.keyset", keys=len(pubs), kind=kind):
-            ks, key_idx, pub_ok, hit, built = _build_keyset(
+            ks, key_idx, pub_ok, hit, built, cleared = _build_keyset(
                 pubs, cache, lock, decode_neg, table, kind)
-            tr.annotate(hit=hit, built=built, resident=ks.n_rows)
+            tr.annotate(hit=hit, built=built, resident=ks.n_rows,
+                        cleared=cleared)
         return ks, key_idx, pub_ok
     return _build_keyset(pubs, cache, lock, decode_neg, table, kind)[:3]
 
 
 def _build_keyset(pubs, cache, lock, decode_neg, table, kind):
-    """-> (KeySet, key_idx, pub_ok, hit, built): hit is "sequence" (the memo
-    answered), "set" (every key was resident; rows mapped anew) or "miss"
-    (`built` keys, at least one, were decoded and had their tables built)."""
+    """-> (KeySet, key_idx, pub_ok, hit, built, cleared): hit is "sequence"
+    (the memo answered), "set" (every key was resident; rows mapped anew) or
+    "miss" (`built` keys, at least one, were decoded and had their tables
+    built); cleared is 1 where that build first emptied a table that would
+    have passed MAX_ROWS."""
     joined, pub_ok = _normalize_pubs(pubs)
     with lock:
         hit = cache.get(joined)
         # a memo entry of a KeySet the table has left behind is stale
         if hit is not None and hit[0] is table.keyset:
             cache.move_to_end(joined)
-            return hit[0], hit[1], pub_ok, "sequence", 0
+            return hit[0], hit[1], pub_ok, "sequence", 0, 0
         built = 0
+        clears = table.overflow_clears
         key_idx = table.rows_of(pubs) if pub_ok.all() else None
         if key_idx is None:
             keys = [joined[i : i + 32] for i in range(0, len(joined), 32)]
@@ -519,7 +538,8 @@ def _build_keyset(pubs, cache, lock, decode_neg, table, kind):
         cache.move_to_end(joined)
         while len(cache) > _KS_MAX:
             cache.popitem(last=False)
-    return ks, key_idx, pub_ok, "miss" if built else "set", built
+    return (ks, key_idx, pub_ok, "miss" if built else "set", built,
+            table.overflow_clears - clears)
 
 
 def get_keyset(pubs: list[bytes]) -> tuple[KeySet, np.ndarray, np.ndarray]:

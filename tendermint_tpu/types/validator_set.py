@@ -63,6 +63,15 @@ class ErrWrongSignature(ValidatorSetError):
         self.index = idx
 
 
+class ErrDoubleVote(ValidatorSetError):
+    """A commit lists one validator of the trusted set twice (reference:
+    types/validator_set.go:806, VerifyCommitLightTrusting's seenVals)."""
+
+    def __init__(self, val, first: int, idx: int):
+        self.first, self.index = first, idx
+        super().__init__(f"double vote from {val} ({first} and {idx})")
+
+
 class PendingCommitVerify:
     """A dispatched-but-undecided commit verification (the cross-decision
     pipeline handle of verify_commit_async / verify_commit_light_async).
@@ -88,14 +97,19 @@ class PendingCommitVerify:
     and its decision id (the ``commit.assemble`` root span's id), so the
     wait and the tally land in the same tree whoever resolves it, later."""
 
-    __slots__ = ("pending", "_finalize", "_error", "_tracer", "_decision")
+    __slots__ = ("pending", "_finalize", "_error", "_tracer", "_decision",
+                 "sigs")
 
-    def __init__(self, pending=None, finalize=None, error: Exception | None = None):
+    def __init__(self, pending=None, finalize=None, error: Exception | None = None,
+                 sigs: int = 0):
         self.pending = pending
         self._finalize = finalize
         self._error = error
         self._tracer = None
         self._decision = 0
+        # signatures handed to the verifier, where the entry point says so
+        # (the trusting check: its span's `n`)
+        self.sigs = sigs
 
     def resolve(self) -> None:
         """Raises exactly what the synchronous verify would; returns None on
@@ -583,48 +597,100 @@ class ValidatorSet:
 
     def verify_commit_light_trusting(self, chain_id: str, commit, trust_level) -> None:
         """trust_level of THIS set must have signed (reference:
-        types/validator_set.go:772-830). trust_level: (numerator, denominator)."""
+        types/validator_set.go:772-830). trust_level: (numerator, denominator).
+        Under the flight recorder the whole check (scan, dispatch, wait,
+        tally) is one ``light.skip.trusting`` span that names the decision
+        below it and the signatures it handed to the verifier."""
+        if _trace.ENABLED:
+            tr = _trace.current()
+            if tr.enabled:
+                with tr.span("light.skip.trusting"):
+                    pcv = self.verify_commit_light_trusting_async(
+                        chain_id, commit, trust_level)
+                    tr.annotate(n=pcv.sigs, decision=pcv._decision)
+                    try:
+                        pcv.resolve()
+                    except ValidatorSetError as e:
+                        tr.annotate(refused=type(e).__name__)
+                        raise
+                return
+        self.verify_commit_light_trusting_async(chain_id, commit,
+                                                trust_level).resolve()
+
+    def verify_commit_light_trusting_async(self, chain_id: str, commit,
+                                           trust_level) -> PendingCommitVerify:
+        """Deferred verify_commit_light_trusting: scan and dispatch now, the
+        serial decision replay (identical errors) on resolve(), as the other
+        two commit checks."""
+        if _trace.ENABLED:
+            tr = _trace.current()
+            if tr.enabled:
+                return self._assemble_traced(
+                    tr, "trusting", self._verify_commit_light_trusting_assemble,
+                    chain_id, commit, trust_level)
+        return self._verify_commit_light_trusting_assemble(
+            chain_id, commit, trust_level)
+
+    def _verify_commit_light_trusting_assemble(self, chain_id: str, commit,
+                                               trust_level,
+                                               tr=None) -> PendingCommitVerify:
         num, den = trust_level
         if den == 0:
-            raise ValidatorSetError("trustLevel has zero Denominator")
+            return PendingCommitVerify(error=ValidatorSetError(
+                "trustLevel has zero Denominator"))
         total_mul = self.total_voting_power() * num
         if total_mul > 2**63 - 1:
-            raise ValidatorSetError("int64 overflow while calculating voting power needed")
+            return PendingCommitVerify(error=ValidatorSetError(
+                "int64 overflow while calculating voting power needed"))
         needed = total_mul // den
 
+        # The serial loop looks a signer up by address in THIS set (another
+        # height's), refuses one it has seen, verifies, tallies and stops
+        # above `needed`. The scan finds the slots that loop would reach:
+        # it ends at the threshold or at a double vote, which the serial
+        # code reports only after every signature before it has verified.
+        position, validators = self._position, self.validators
         seen: dict[int, int] = {}
-        prefix: list[tuple[int, int]] = []  # (commit idx, val idx)
+        idxs: list[int] = []      # commit slots, in order
+        val_idxs: list[int] = []  # the signer's place in this set
+        double_vote: ErrDoubleVote | None = None
         tallied_scan = 0
         for idx, cs in enumerate(commit.signatures):
             if not cs.for_block():
                 continue
-            val_idx, val = self.get_by_address(cs.validator_address)
-            if val is None:
+            val_idx = position(cs.validator_address)
+            if val_idx < 0:
                 continue
             if val_idx in seen:
-                raise ValidatorSetError(
-                    f"double vote from {val} ({seen[val_idx]} and {idx})"
-                )
+                double_vote = ErrDoubleVote(validators[val_idx],
+                                            seen[val_idx], idx)
+                break
             seen[val_idx] = idx
-            prefix.append((idx, val_idx))
-            tallied_scan += val.voting_power
+            idxs.append(idx)
+            val_idxs.append(val_idx)
+            tallied_scan += validators[val_idx].voting_power
             if tallied_scan > needed:
                 break
 
         verifier = crypto_batch.create_batch_verifier()
-        self.add_commit_sigs(verifier, chain_id, commit,
-                             [idx for idx, _ in prefix],
-                             [val_idx for _, val_idx in prefix])
-        _, bitmap = verifier.verify()
+        self.add_commit_sigs(verifier, chain_id, commit, idxs, val_idxs, tr)
+        pending = verifier.dispatch()
+        powers = [validators[val_idx].voting_power for val_idx in val_idxs]
+        signatures = list(commit.signatures)
 
-        tallied = 0
-        for (idx, val_idx), ok in zip(prefix, bitmap):
-            if not ok:
-                raise ErrWrongSignature(idx, commit.signatures[idx].signature)
-            tallied += self.validators[val_idx].voting_power
-            if tallied > needed:
-                return
-        raise ErrNotEnoughVotingPowerSigned(tallied, needed)
+        def finalize(bitmap: list[bool]) -> None:
+            tallied = 0
+            for idx, power, ok in zip(idxs, powers, bitmap):
+                if not ok:
+                    raise ErrWrongSignature(idx, signatures[idx].signature)
+                tallied += power
+                if tallied > needed:
+                    return
+            if double_vote is not None:
+                raise double_vote
+            raise ErrNotEnoughVotingPowerSigned(tallied, needed)
+
+        return PendingCommitVerify(pending, finalize, sigs=len(idxs))
 
     # --- wire --------------------------------------------------------------
 

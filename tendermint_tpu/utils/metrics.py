@@ -68,6 +68,12 @@ class Gauge(_Metric):
         with self._mtx:
             self._values[k] = self._values.get(k, 0.0) + delta
 
+    def raise_to(self, value: float, **labels) -> None:
+        """A high-water mark: set, unless the series already reads higher."""
+        k = self._key(labels)
+        with self._mtx:
+            self._values[k] = max(self._values.get(k, 0.0), float(value))
+
     def expose(self) -> list[str]:
         with self._mtx:
             return [f"{self.name}{self._fmt_labels(k)} {v}"
@@ -235,6 +241,29 @@ class NodeMetrics:
             "Windows of a sequential sync re-run header by header because "
             "the range path itself failed (not because a header was "
             "refused).")
+        self.light_skip_hops = r.counter(
+            "light", "skip_hops_total",
+            "Attempts of a skipping (bisection) sync that verified: the "
+            "blocks a sync trusted on its way to its target, the target "
+            "included.")
+        self.light_skip_refused = r.counter(
+            "light", "skip_refused_total",
+            "Attempts of a skipping sync that the trusted set could not "
+            "vouch for (ErrNewValSetCantBeTrusted): each moved the bisection "
+            "one block down its cache or fetched a pivot.")
+        self.light_skip_depth_max = r.gauge(
+            "light", "skip_depth_max",
+            "Deepest place in the bisection's block cache a skipping sync "
+            "of this process has reached (high-water mark).")
+        # device key tables (ops/ed25519_batch.KeyTable)
+        self.keytable_keys_built = r.counter(
+            "crypto", "keytable_keys_built_total",
+            "Keys whose device comb tables were built because the table "
+            "did not hold them.")
+        self.keytable_clears = r.counter(
+            "crypto", "keytable_clears_total",
+            "Times a key table forgot every row because a build would have "
+            "passed KeyTable.MAX_ROWS.")
         # state
         self.block_processing_time = r.histogram(
             "state", "block_processing_time",
@@ -364,6 +393,11 @@ class NodeMetrics:
         for mode in ("sequential", "skipping"):
             self.light_headers_verified.add(0.0, mode=mode)
         self.light_range_fallbacks.add(0.0)
+        self.light_skip_hops.add(0.0)
+        self.light_skip_refused.add(0.0)
+        self.light_skip_depth_max.set(0.0)
+        self.keytable_keys_built.add(0.0)
+        self.keytable_clears.add(0.0)
         # ingest front door: the result label universe is closed by
         # construction (docs/INGEST.md), seed it fully; the batch-size
         # histogram scrapes explicit zeros like the phase histogram

@@ -252,6 +252,19 @@ CANONICAL_SPANS = {
                     "its slice of the bitmap (span)",
     "light.store": "trusted-store writes of one dispatch's verified "
                    "headers; tags blocks, bytes (span)",
+    # skipping mode (light/client.py _verify_skipping, light/verifier.py)
+    "light.skip.hop": "one attempt of a bisection, lv.verify of a cached "
+                      "block from the verified one (span; from=, to=, "
+                      "depth=, accepted= tags)",
+    "light.skip.trusting": "a whole verify_commit_light_trusting: scan by "
+                           "address in the trusted set, dispatch, wait, "
+                           "tally (span; n= signatures handed to the "
+                           "verifier, decision= its commit.assemble, "
+                           "refused= on a refusal)",
+    "light.skip.light": "the hop's verify_commit_light on the new set, "
+                        "after the trusting check passed (span)",
+    "light.skip.fetch": "a pivot from the source, validate_basic included "
+                        "(span; height= tag)",
     # light-client serving gateway (light/gateway.py, docs/LIGHT.md)
     "light.gateway.serve": "one client query through the gateway: cache "
                            "lookup, coalesced verification, answer or "
