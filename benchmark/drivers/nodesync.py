@@ -16,7 +16,8 @@ benchmark whatever way it exits.
 
 A **pass**: ``crypto.batch.forget_keys()``; a default ``Node(Config()
 .set_root(new home), default_app("kvstore"), genesis)`` whose config differs
-from ``Config()`` only in the listen addresses (127.0.0.1, port 0), the two
+from ``Config()`` only in the listen addresses (the run's own loopback
+address, ``nodesync_peer.loopback_of``, port 0), the two
 ``persistent_peers`` and the ``testnet`` command's two local-network flags;
 **the clock starts at the call of ``Node.start()``**: listen, switch, dial,
 ``SecretConnection`` handshake, ``NodeInfo``, status exchange,
@@ -50,12 +51,44 @@ warm-up pass and every pass of the window to the configuration's guarantees:
      with one flipped transaction byte at a middle height: refused at the
      reference's height and kind, the senders stopped and scored, and the
      pass ends at the last height with the reference's app hash through the
-     honest peer;
- (h) one pass in which a peer is ``SIGSTOP``ped once the pool has taken a
-     seeded number of blocks: ``BlockPool.timed_out`` > 0 and the pass ends
-     at the last height inside the bound the configuration states;
+     honest peer. The corrupted peer's process is ended with this leg;
+ (h) one clean pass from one peer alone (the other stopped for its length),
+     then one pass in which that other peer is ``SIGSTOP``ped once the pool
+     knows its range and has taken a seeded number of blocks. The driver's
+     watcher reads the pool's open requests (``BlockPool.requested``) and
+     ``BlockPool.timed_out`` a millisecond apart: what the silent peer was
+     asked and left unanswered was given up no sooner than the
+     configuration's ``peer_timeout_s`` and no later than a second past it,
+     ``timed_out`` counts exactly those requests, the silent peer is the only
+     peer the reactor stopped, and the pass ends at the last height, with
+     the reference's app hash, within ``peer_timeout_s``, that second and
+     twice the one-peer pass of the oldest request the silent peer left
+     open;
  (i) ``correct.check_decisions`` on the pooled commits, as every cell
      (breakers, fall-backs, compiles and variables are ``run.py``'s).
+
+**What a verdict may depend on**: the program's answers and the
+configuration's stated bounds, never on which of two processes spoke first.
+Legs (h) then (g) run in that order and each pass knows only the processes it
+names: until PR 48 the corrupted peer of (g) stayed alive through (h), the
+honest peer's PEX reactor had learnt its address, the silent pass's node
+dialled it a second after it started, and once the silent peer's requests
+were given up half of them went to the corrupted peer: the node refused its
+block, stopped **both** senders, and got the honest one back only when that
+peer dialled in (the node's one redial thread sat in a handshake with the
+stopped process): 3 to 13 s, which the old bound (twice a two-peer pass)
+held or did not (PERF.md section 6, PR 48). Every duration a leg compares is
+taken inside the pass it judges or in a pass made beside it by the same
+``check``, on the watcher's clock; everything else is a count.
+**The one retry left** is (g)'s: the corrupted height is one the pool asks of
+the corrupted peer when it knows both peers or that one alone (it is dialled
+first), but a status message is answered by another process, and where the
+honest peer's arrives first the whole first window is asked of it and
+nothing is refused. That pass shows nothing about the program, so it is
+made once more; a program that accepts the flipped byte accepts it twice.
+
+Each failure carries its guarantee's letter (``run.fail("h", ...)``), so the
+result line's ``failures.by_check`` says which leg it was.
 
 A peer at height 21 holds the commit for 21, so a node that has applied 20
 and switched to consensus is fed block 21 by its peers' consensus reactors,
@@ -121,6 +154,7 @@ if not (hasattr(bc.BlockPool, "expire_requests")
 
 HOMES_DIR = os.path.join(spec.BENCH_DIR, ".homes")
 PEER_SCRIPT = nodesync_peer.__file__
+HOST = nodesync_peer.loopback_of(os.getpid())   # where this run's nodes listen
 # hub-150-full's chain cut to its first 21 blocks: a cache file of its own
 CHAIN_CACHE = "hub-150-full-p2p"
 CHANNEL = f"{bc.BLOCKCHAIN_CHANNEL:#x}"
@@ -129,14 +163,31 @@ SAMPLE_TXS = 64
 REFERENCE_SAMPLE = 4
 STEP_TIMEOUT_S = 120.0          # one height, or a silent peer's timeout, at most
 PEER_READY_S = 300.0
-LOOP_GRANULARITY_S = 1.0
+LOOP_GRANULARITY_S = 1.0        # a request is given up this long after its
+#                                 time-out at the latest (guarantee (h))
+WATCH_LAG_S = 0.25              # what the watcher may see an open request
+#                                 late by, on a busy host: it reads the pool
+#                                 a millisecond apart, from a thread of the
+#                                 measured process
 
 # What a pass left behind, read when its clock stopped (a plain namespace:
 # spec.py loads this file outside sys.modules, where a dataclass cannot be
 # made): home, applied, t = (t0, t1), state, app_sample, pipeline, counters,
 # pool, wire (Switch.wire_totals), invalid, scored, last_apply_end,
-# peers_cpu_s; status_msgs once _wire_differs has explained the wire
+# peers_cpu_s; status_msgs once _wire_differs has explained the wire; silent
+# (what _silence saw, seconds from t[0]) where a peer was silenced
 PassRecord = SimpleNamespace
+
+
+class _Why(str):
+    """What of a pass differs from the reference, in words, and under
+    ``check`` the letter of the guarantee it falls under."""
+
+    def __new__(cls, check: str, words: str):
+        why = super().__new__(cls, words)
+        why.check = check
+        return why
+
 
 _LIVE = []       # peer processes to kill when the interpreter exits
 
@@ -158,7 +209,7 @@ class PeerProcess:
         env = {**os.environ, "JAX_PLATFORMS": "cpu",
                "TM_TPU_SKIP_WARMUP": "1"}
         self.proc = subprocess.Popen(
-            [sys.executable, PEER_SCRIPT, home, str(os.getpid())],
+            [sys.executable, PEER_SCRIPT, home, str(os.getpid()), HOST],
             stdin=subprocess.PIPE, stdout=subprocess.PIPE, stderr=self.log,
             env=env, cwd=spec.ROOT, start_new_session=True)
         _LIVE.append(self)
@@ -354,7 +405,8 @@ class Driver:
         home = os.path.join(self._home_prefix(), f"pass-{self._homes}")
         self._homes += 1
         os.makedirs(home)
-        cfg = nodesync_peer.local_config(home, ",".join(p.addr for p in peers))
+        cfg = nodesync_peer.local_config(
+            home, ",".join(p.addr for p in peers), host=HOST)
         p2p = cfg.p2p
         stated = {k: self.p2p[k] for k in (
             "send_rate", "recv_rate", "max_packet_msg_payload_size", "pex",
@@ -364,10 +416,13 @@ class Driver:
                                  f"node would run with other values")
         return Node(cfg, default_app("kvstore"), self.chain.genesis), home
 
-    def _pass(self, peers, decide, sample=(), silence=None) -> PassRecord:
+    def _pass(self, peers, decide, sample=(), silence=None,
+              frozen=()) -> PassRecord:
         """One pass from ``peers``. ``silence``: (peer, k) stops that peer's
         process once it has reported its range and the pool has taken k
-        blocks, and lets it go on when the pass is over."""
+        blocks, and lets it go on when the pass is over. ``frozen``: peer
+        processes stopped for the whole pass (a dial to one is accepted by
+        the kernel and its handshake never answered, so it joins nothing)."""
         from tendermint_tpu.abci.types import RequestQuery
 
         node, home = self._node(peers)
@@ -375,10 +430,12 @@ class Driver:
         forget_keys()
         cpu0 = [p.cpu_s() for p in peers]
         spans0 = len(self.run.spans)
-        watcher, pass_over = None, threading.Event()
+        watcher, pass_over, seen = None, threading.Event(), {}
+        for peer in frozen:
+            peer.signal(signal.SIGSTOP)
         if silence is not None:
             watcher = threading.Thread(
-                target=self._silence, args=(reactor, *silence, pass_over),
+                target=self._silence, args=(reactor, *silence, pass_over, seen),
                 name="bench-silencer", daemon=True)
             watcher.start()
 
@@ -432,9 +489,15 @@ class Driver:
                 pass_over.set()
                 watcher.join()
                 silence[0].signal(signal.SIGCONT)
+            for peer in frozen:
+                peer.signal(signal.SIGCONT)
             node.stop()
             node.close_stores()
         record.peers_cpu_s = [p.cpu_s() - c for p, c in zip(peers, cpu0)]
+        if silence is not None:
+            record.silent = {
+                k: v - t0 if k.endswith("_at") and v is not None else v
+                for k, v in seen.items()}
         return record
 
     def _last_apply_end(self, spans0: int) -> float | None:
@@ -446,18 +509,37 @@ class Driver:
         return ends[-1] if ends else None
 
     def _silence(self, reactor, peer: PeerProcess, k: int,
-                 pass_over: threading.Event) -> None:
+                 pass_over: threading.Event, seen: dict) -> None:
         """Stop ``peer``'s process once the node's pool knows its range and
-        has taken ``k`` blocks: from then on it answers nothing."""
+        has taken ``k`` blocks: from then on it answers nothing. Then watch,
+        a millisecond apart, what the pool holds open at that peer, until it
+        gives those requests up (``BlockPool.timed_out`` moves). Into
+        ``seen``, on this thread's clock: ``stopped_at``, ``asked_at`` (when
+        the oldest request still open at the end was first seen open),
+        ``expired_at``, and ``open_at_expiry`` (how many it left open)."""
         pool = reactor.pool
+        first_seen = {}      # height -> when it was first seen open at peer
         while not pass_over.wait(0.001):
-            if peer.id in pool.peers and pool.received >= k:
+            now = time.monotonic()
+            # the requests first, the counter second: a counter still at
+            # nought says the snapshot is from before the requests were
+            # given up, whatever happened since
+            open_now = [h for h, p in dict(pool.requested).items()
+                        if p == peer.id]
+            if pool.timed_out:
+                seen.update(expired_at=now, open_at_expiry=len(first_seen),
+                            asked_at=min(first_seen.values(), default=None))
+                return
+            # from the pass's start, not from the stop: a request the peer
+            # was asked before it fell silent keeps the time it was asked
+            first_seen = {h: first_seen.get(h, now) for h in open_now}
+            if ("stopped_at" not in seen and peer.id in pool.peers
+                    and pool.received >= k):
                 peer.signal(signal.SIGSTOP)
+                seen.update(stopped_at=now, after_blocks=pool.received)
                 self.run.notes.setdefault("silenced", []).append(
                     {"after_blocks": pool.received,
-                     "open_requests": sum(1 for p in pool.requested.values()
-                                          if p == peer.id)})
-                return
+                     "open_requests": len(open_now)})
 
     def _key_sample(self, ref) -> list:
         keys = sorted(ref["store"])
@@ -497,9 +579,9 @@ class Driver:
             record = self._pass(self.peers, lambda fn, _sigs: fn(),
                                 sample=self.sample)
             if record.applied != self.heights:
-                run.failures.append(
-                    f"warm-up pass applied {record.applied} of "
-                    f"{self.heights} heights, rejected {record.invalid}")
+                run.fail("b", f"warm-up pass applied {record.applied} of "
+                              f"{self.heights} heights, rejected "
+                              f"{record.invalid}")
             self.records.append(record)
 
     def measure(self) -> None:
@@ -508,9 +590,9 @@ class Driver:
         while run.elapsed() < run.seconds:
             record = self._pass(self.peers, run.decide, sample=self.sample)
             if record.applied != self.heights:
-                run.failures.append(
-                    f"pass applied {record.applied} of {self.heights} "
-                    f"heights, rejected {record.invalid}, pool {record.pool}")
+                run.fail("b", f"pass applied {record.applied} of "
+                              f"{self.heights} heights, rejected "
+                              f"{record.invalid}, pool {record.pool}")
                 break
             run.passes.append((record.t[0], record.t[1], self.heights))
             self.records.append(record)
@@ -531,36 +613,39 @@ class Driver:
 
     def check(self) -> None:
         run, ref = self.run, self.ref
-        fail = run.failures.append
-        extra = []
         try:
             if (ref["refused"]
                     or ref["applied"] != list(range(1, self.heights + 1))):
-                fail(f"the reference refuses the clean chain: "
-                     f"{ref['refused']}, {len(ref['applied'])} heights applied")
+                run.failures.append(
+                    f"the reference refuses the clean chain: "
+                    f"{ref['refused']}, {len(ref['applied'])} heights applied")
                 return
             if [len(ref["prefixes"][h]) for h in ref["applied"]] != self.sigs:
-                fail("the light prefixes of the program's set differ in "
-                     "length from those of the reference's")
+                run.failures.append(
+                    "the light prefixes of the program's set differ in "
+                    "length from those of the reference's")
             window = self.records[len(self.records) - len(run.passes):]
             self._note_window(window)
             # (a)-(f): every whole clean pass, its files opened again
             for k, record in enumerate(self.records):
-                why = (self._differs(record, ref)
-                       or self._wire_differs(record, ref))
-                if why:
-                    fail(f"pass {k} (0 is the warm-up): {why}")
-            longest = max((r.t[1] - r.t[0] for r in self.records), default=0.0)
-            self._check_corrupted(ref, extra)
-            self._check_silent(ref, longest)
+                self._hold_clean(f"pass {k} (0 is the warm-up)", record, ref)
+            self._check_silent(ref)
+            self._check_corrupted(ref)
             # (i) the pooled commits, as every cell
             correct.check_decisions(run, self.ds,
                                     [self.ds.vals.verify_commit_light,
                                      self.ds.vals.verify_commit])
         finally:
-            for peer in self.peers + extra:
+            for peer in self.peers:
                 peer.kill()
             shutil.rmtree(self._home_prefix(), ignore_errors=True)
+
+    def _hold_clean(self, name: str, record: PassRecord, ref) -> None:
+        """Guarantees (a)-(f) on one whole clean pass: the first that does
+        not hold, under its letter."""
+        why = self._differs(record, ref) or self._wire_differs(record, ref)
+        if why:
+            self.run.fail(why.check, f"{name}: {why}")
 
     def _note_window(self, window) -> None:
         """What the readers of ``layer_metrics/full_*`` and ``wire_*`` take
@@ -589,21 +674,22 @@ class Driver:
             "pool": [r.pool for r in window],
             "totals_last_pass": window[-1].wire}
 
-    def _differs(self, record: PassRecord, ref) -> str | None:
-        """A clean pass against the reference's replay of the same bytes: the
-        node as it was when the clock stopped, then its files through new
-        connections."""
+    def _differs(self, record: PassRecord, ref) -> _Why | None:
+        """A pass against the reference's replay of the same bytes: the node
+        as it was when the clock stopped, then its files through new
+        connections. The first thing that differs, in words, with its
+        guarantee's letter (``_Why``)."""
         last = self.heights
         if record.applied != last or record.state["height"] != last:
-            return (f"applied {record.applied}, the reactor's state at height "
-                    f"{record.state['height']}, wanted {last}")
+            return _Why("b", (f"applied {record.applied}, the reactor's state at "
+                         f"height {record.state['height']}, wanted {last}"))
         if record.state["app_hash"] != ref["app_hash"]:
-            return "the app hash differs from the reference's"
+            return _Why("b", "the app hash differs from the reference's")
         if record.state["last_results_hash"] != ref["last_results_hash"]:
-            return "last_results_hash differs from the reference's"
+            return _Why("b", "last_results_hash differs from the reference's")
         for key, value in record.app_sample.items():
             if ref["store"].get(key) != value:
-                return f"the app answers another value for key {key!r}"
+                return _Why("b", f"the app answers another value for key {key!r}")
         c = record.counters
         txs = sum(ref["txs"][h] for h in range(1, last + 1))
         # a node that went on to consensus may have taken the tip's block up
@@ -611,21 +697,21 @@ class Driver:
         if not (last <= c["post_commit_done"] <= c["post_commit_submitted"]
                 <= last + 1 and last <= c["heights_indexed"] <= last + 1
                 and c["txs_indexed"] in (txs, txs + tip)):
-            return f"counters {c} for {last} heights"
+            return _Why("d", f"counters {c} for {last} heights")
         if (self.max_backlog is not None
                 and max(c["post_commit_backlog_max"],
                         c["indexer_backlog_heights_max"]) > self.max_backlog):
-            return (f"the backlog behind apply_block reached "
-                    f"{c['post_commit_backlog_max']} tasks and "
-                    f"{c['indexer_backlog_heights_max']} headers; the "
-                    f"configuration states {self.max_backlog}")
+            return _Why("e", (f"the backlog behind apply_block reached "
+                         f"{c['post_commit_backlog_max']} tasks and "
+                         f"{c['indexer_backlog_heights_max']} headers; the "
+                         f"configuration states {self.max_backlog}"))
         p = record.pipeline
         if record.invalid is None and record.pool["timed_out"] == 0 and (
                 p["dispatched"] - p["discarded"] != last or p["in_flight"]):
-            return f"pipeline {p} for {last} decisions"
+            return _Why("a", f"pipeline {p} for {last} decisions")
         return self._files_differ(record.home, ref)
 
-    def _files_differ(self, home: str, ref) -> str | None:
+    def _files_differ(self, home: str, ref) -> _Why | None:
         """Guarantees (c), (d) and (f): the files of a stopped node, through
         new connections. Every stored height's parts, not a sample."""
         from tendermint_tpu.state.store import StateStore
@@ -642,8 +728,9 @@ class Driver:
             # the tip's block too where consensus committed it before the stop
             top = blocks.height
             if top not in (last, last + 1) or blocks.base != 1:
-                return (f"the reopened block store holds {blocks.base}.."
-                        f"{top}, wanted 1..{last} (or the tip's block too)")
+                return _Why("c", (f"the reopened block store holds {blocks.base}.."
+                             f"{top}, wanted 1..{last} (or the tip's block "
+                             f"too)"))
             state = state_store.load()
             tip_txs = len(block_replay.parse_body(ref["raws"][last])["txs"])
             if state.last_block_height == last:
@@ -653,37 +740,38 @@ class Driver:
                         block_replay.results_hash([(0, b"", 0, 0)] * tip_txs))
             if (state.last_block_height not in (last, top)
                     or (state.app_hash, state.last_results_hash) != want):
-                return "the reopened state store's last save is not the " \
-                       "reference's state at that height"
+                return _Why("c", ("the reopened state store's last save is not "
+                             "the reference's state at that height"))
             for h in range(1, last + 1):
                 meta = blocks.load_block_meta(h)
                 header = meta.header
                 if (header.data_hash, header.last_results_hash,
                         header.app_hash) != ref["headers"][h]:
-                    return f"stored header {h} names other hashes"
+                    return _Why("c", f"stored header {h} names other hashes")
                 psh = meta.block_id.part_set_header
                 if (psh.total, psh.hash) != ref["part_set_headers"][h]:
-                    return f"stored block {h} names another part set"
+                    return _Why("c", f"stored block {h} names another part set")
                 if meta.num_txs != ref["txs"][h]:
-                    return f"stored block {h} counts {meta.num_txs} txs"
+                    return _Why("c", f"stored block {h} counts {meta.num_txs} txs")
             for h in range(1, top + 1):
                 for i, chunk in enumerate(block_replay.parts(ref["raws"][h - 1])):
                     part = blocks.load_block_part(h, i)
                     if part is None or part.bytes_ != chunk:
-                        return f"stored part {i} of block {h} differs"
+                        return _Why("f", f"stored part {i} of block {h} differs")
             if len(state_store.load_abci_responses(last).deliver_txs) \
                     != ref["txs"][last]:
-                return "the reopened state store lacks the last height's " \
-                       "ABCI responses"
+                return _Why("c", ("the reopened state store lacks the last "
+                             "height's ABCI responses"))
             sampled = sorted({1 + datagen.pick(seed, last, "store-height", j)
                               for j in range(SAMPLE_HEIGHTS)} | {last})
             for h in sampled:
                 found = index.search(f"tx.height={h}")
                 if len(found) != ref["txs"][h]:
-                    return (f"search(tx.height={h}) returns {len(found)} of "
-                            f"{ref['txs'][h]} transactions")
+                    return _Why("d", (f"search(tx.height={h}) returns {len(found)} "
+                                 f"of {ref['txs'][h]} transactions"))
             if index.search(f"tx.height={top + 1}"):
-                return f"the index holds transactions of height {top + 1}"
+                return _Why("d", (f"the index holds transactions of height "
+                             f"{top + 1}"))
             for j in range(SAMPLE_TXS):
                 h = 1 + datagen.pick(seed, last, "tx-height", j)
                 txs = block_replay.parse_body(ref["raws"][h - 1])["txs"]
@@ -693,24 +781,25 @@ class Driver:
                         or doc["index"] != i
                         or base64.b64decode(doc["tx"]) != txs[i]
                         or doc["tx_result"]["code"] != 0):
-                    return f"get(hash) of transaction {i} of block {h}: {doc}"
+                    return _Why("d", (f"get(hash) of transaction {i} of block {h}: "
+                                 f"{doc}"))
         finally:
             for db in dbs:
                 db.close()
         return None
 
-    def _wire_differs(self, record: PassRecord, ref) -> str | None:
+    def _wire_differs(self, record: PassRecord, ref) -> _Why | None:
         """Guarantee (f), the wire's half: a clean pass took every block
         once, and what the node counted on 0x40 is that plus whole status
         messages; every sealed frame is 1,044 bytes."""
         wire, want = record.wire, ref["wire"]
         got = wire.get("channels", {}).get(CHANNEL)
         if got is None:
-            return f"the switch counted nothing on channel {CHANNEL}"
+            return _Why("f", f"the switch counted nothing on channel {CHANNEL}")
         if record.pool != {"received": want["msgs"], "timed_out": 0,
                            "peers_stopped": 0}:
-            return (f"the pool took {record.pool}; a clean pass takes "
-                    f"{want['msgs']} blocks, each once")
+            return _Why("f", (f"the pool took {record.pool}; a clean pass takes "
+                         f"{want['msgs']} blocks, each once"))
         told = wire_sync.account(
             ref["raws"], {"msgs": got["msgs_recv"],
                           "packets": got["packets_recv"],
@@ -718,18 +807,19 @@ class Driver:
             [tuple(p.range) for p in self.peers],
             self.p2p["max_packet_msg_payload_size"])
         if told is None:
-            return (f"channel {CHANNEL} received {got}; the reference counts "
-                    f"{want['msgs']} blocks in {want['packets']} packets and "
-                    f"{want['bytes']} bytes, and no number of status messages "
-                    f"explains the rest")
+            return _Why("f", (
+                f"channel {CHANNEL} received {got}; the reference counts "
+                f"{want['msgs']} blocks in {want['packets']} packets and "
+                f"{want['bytes']} bytes, and no number of status messages "
+                f"explains the rest"))
         record.status_msgs = told
         frame = self.p2p["sealed_frame_bytes"]
         for way in ("sent", "recv"):
             if wire[f"sealed_bytes_{way}"] != frame * wire[f"frames_{way}"]:
-                return f"a sealed frame {way} is not {frame} bytes"
+                return _Why("f", f"a sealed frame {way} is not {frame} bytes")
         if wire["frames_recv"] < want["frames_least"]:
-            return (f"{wire['frames_recv']} frames received; the blocks alone "
-                    f"take {want['frames_least']}")
+            return _Why("f", (f"{wire['frames_recv']} frames received; the blocks "
+                         f"alone take {want['frames_least']}"))
         return None
 
     # --- (g): a peer that serves a corrupted block -------------------------------
@@ -758,8 +848,8 @@ class Driver:
         raws[h - 1] = bytes(raw)
         return raws, h
 
-    def _check_corrupted(self, ref, extra: list) -> None:
-        run, fail = self.run, self.run.failures.append
+    def _check_corrupted(self, ref) -> None:
+        run = self.run
         honest = self.peers[0]
         home = os.path.join(self._home_prefix(), "peer-corrupted")
         made = {}
@@ -775,65 +865,123 @@ class Driver:
             self.ds.chain_id, self._genesis_keys(), self.chain.raws, raws,
             [bid.hash for bid in self.chain.block_ids])
         bad = self._start_peers([home])[0]
-        extra.append(bad)
-        if bad.id != bad_id:
-            fail(f"the corrupted peer came up as {bad.id}, not {bad_id}")
-            return
-        for _attempt in range(2):
-            # the corrupted peer first: it is dialled first, so the pool
-            # never knows the honest one alone
-            record = self._pass([bad, honest], lambda fn, _sigs: fn(),
-                                sample=self.sample)
-            if record.invalid is not None:
-                break
-        got = record.invalid
+        try:
+            if bad.id != bad_id:
+                run.fail("g", f"the corrupted peer came up as {bad.id}, not "
+                              f"{bad_id}")
+                return
+            for _attempt in range(2):
+                # the corrupted peer first: it is dialled first. The second
+                # attempt is for a pass whose pool heard the honest peer's
+                # status first and asked it for the whole first window (the
+                # module's text): nothing was refused, nothing was shown
+                record = self._pass([bad, honest], lambda fn, _sigs: fn(),
+                                    sample=self.sample)
+                if record.invalid is not None:
+                    break
+        finally:
+            # no later pass may meet this process: the peers' PEX reactors
+            # have its address by now and hand it to every node they meet
+            bad.kill()
         run.notes.setdefault("rejected", {})["flipped byte in a transaction"] = {
-            "reference": want["refused"], "program": got,
+            "reference": want["refused"], "program": record.invalid,
             "applied": record.applied, "scored": record.scored,
             "pool": record.pool, "seconds": record.t[1] - record.t[0]}
+        self._hold_corrupted(record, want, at, bad.id, ref)
+
+    def _hold_corrupted(self, record: PassRecord, want: dict, at: int,
+                        bad_id: str, ref) -> None:
+        """Guarantee (g) on what the pass beside the corrupted peer left
+        behind (``want``: how the plain reference reads the corrupted copy)."""
+        run, got = self.run, record.invalid
+        run.compare("g_refused_height", None if got is None else got[0], at)
         if (want["refused"] is None
                 or want["refused"][:2] != (at, "commit_block_id")
                 or want["heights"] != [at] or not want["completes"]
                 or want["data_hash_differs"] is not True):
-            fail(f"the reference reads the corrupted copy as {want}")
+            run.fail("g", f"the reference reads the corrupted copy as {want}")
             return
         if (got is None or got[0] != at or got[1] != "ValueError"
-                or "different block" not in got[4] or bad.id not in got[3]
+                or "different block" not in got[4] or bad_id not in got[3]
                 or not set(got[3]) <= set(record.scored)
-                or bad.id not in record.scored
+                or bad_id not in record.scored
                 or record.pool["peers_stopped"] < len(got[3])):
-            fail(f"a corrupted block at height {at}: the reference refuses "
-                 f"{want['refused']}; the program rejected {got}, scored "
-                 f"{record.scored}, pool {record.pool}")
+            run.fail("g", f"a corrupted block at height {at}: the reference "
+                          f"refuses {want['refused']}; the program rejected "
+                          f"{got}, scored {record.scored}, pool {record.pool}")
         why = self._differs(record, ref)
         if why:
-            fail(f"the pass beside a corrupted peer did not end as the "
-                 f"reference's: {why}")
+            run.fail("g", f"the pass beside a corrupted peer did not end as "
+                          f"the reference's: ({why.check}) {why}")
 
     # --- (h): a peer that stops answering ------------------------------------------
 
-    def _check_silent(self, ref, longest: float) -> None:
-        run, fail = self.run, self.run.failures.append
+    def _check_silent(self, ref) -> None:
+        run = self.run
         silent, other = self.peers[1], self.peers[0]
+        # the denominator of "the time the remaining blocks take": a clean
+        # pass from the peer that will remain, the other stopped throughout
+        alone = self._pass([other], lambda fn, _sigs: fn(),
+                           sample=self.sample, frozen=[silent])
+        self._hold_clean("the pass from one peer alone", alone, ref)
+        one_peer_s = alone.t[1] - alone.t[0]
         k = datagen.pick(run.seed, max(1, self.heights // 5), "silent-after")
-        for _attempt in range(2):
-            # the silent peer first: it is dialled and reports first
-            record = self._pass([silent, other], lambda fn, _sigs: fn(),
-                                sample=self.sample, silence=(silent, k))
-            if record.pool["timed_out"]:
-                break
-        took = record.t[1] - record.t[0]
-        bound = bc.REQUEST_TIMEOUT_S + LOOP_GRANULARITY_S + 2 * longest
+        # the silent peer first: it is dialled and reports first
+        record = self._pass([silent, other], lambda fn, _sigs: fn(),
+                            sample=self.sample, silence=(silent, k))
         run.notes["silent_peer"] = {
-            "stopped_after_blocks": k, "seconds": took, "bound_s": bound,
-            "pool": record.pool, "applied": record.applied}
-        if record.pool["timed_out"] <= 0:
-            fail(f"a peer stopped after {k} blocks: no request timed out "
-                 f"({record.pool})")
-        if took > bound:
-            fail(f"a peer stopped after {k} blocks: the pass took {took:.1f} s;"
-                 f" the bound is {bound:.1f} s")
+            "stopped_after_blocks": k, "seconds": record.t[1] - record.t[0],
+            "one_peer_s": one_peer_s, "one_peer_pool": alone.pool,
+            "watched": record.silent, "pool": record.pool,
+            "applied": record.applied}
+        self._hold_silent(record, one_peer_s, ref)
+
+    def _hold_silent(self, record: PassRecord, one_peer_s: float, ref) -> None:
+        """Guarantee (h) on what the pass beside the silent peer left
+        behind."""
+        for why in self._silent_verdict(record, one_peer_s):
+            self.run.fail("h", f"a peer stopped after "
+                               f"{record.silent.get('after_blocks')} blocks: "
+                               f"{why}")
         why = self._differs(record, ref)
         if why:
-            fail(f"the pass beside a silent peer did not end as the "
-                 f"reference's: {why}")
+            self.run.fail("h", f"the pass beside a silent peer did not end "
+                               f"as the reference's: ({why.check}) {why}")
+
+    def _silent_verdict(self, record: PassRecord, one_peer_s: float) -> list:
+        """What of (h) does not hold, in words, read from ``record.pool``,
+        ``record.t`` and ``record.silent`` (the watcher's account, seconds
+        from the pass's start). Counts where a count says it; the two
+        durations run from the oldest request the silent peer left open, on
+        one clock, to the moment the pool gave it up and to the pass's end."""
+        timeout, compare = self.p2p["peer_timeout_s"], self.run.compare
+        pool, seen = record.pool, record.silent
+        compare("h_timed_out", pool["timed_out"],
+                seen.get("open_at_expiry", ">0"))
+        if pool["timed_out"] <= 0 or seen.get("asked_at") is None:
+            return [f"no request of the silent peer's timed out ({pool}); "
+                    f"the watcher saw {seen}"]
+        out = []
+        if pool["timed_out"] != seen["open_at_expiry"]:
+            out.append(f"BlockPool.timed_out counts {pool['timed_out']}; the "
+                       f"peer left {seen['open_at_expiry']} requests open")
+        compare("h_peers_stopped", pool["peers_stopped"], 1)
+        if pool["peers_stopped"] != 1:
+            out.append(f"{pool['peers_stopped']} peers were stopped; one went "
+                       f"silent, and the pass knew no third ({pool})")
+        waited = seen["expired_at"] - seen["asked_at"]
+        limits = (timeout - WATCH_LAG_S, timeout + LOOP_GRANULARITY_S)
+        compare("h_given_up_after_s", waited, list(limits))
+        if not limits[0] <= waited <= limits[1]:
+            out.append(f"its oldest open request was given up after "
+                       f"{waited:.2f} s; the configuration's time-out is "
+                       f"{timeout} s (held to {limits[0]}..{limits[1]})")
+        took = record.t[1] - record.t[0] - seen["asked_at"]
+        bound = timeout + LOOP_GRANULARITY_S + 2 * one_peer_s
+        compare("h_done_after_s", took, bound)
+        if took > bound:
+            out.append(f"the pass ended {took:.1f} s after that request; the "
+                       f"bound is {bound:.1f} s ({timeout} + "
+                       f"{LOOP_GRANULARITY_S} + twice the one-peer pass's "
+                       f"{one_peer_s:.2f})")
+        return out

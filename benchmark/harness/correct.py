@@ -198,8 +198,8 @@ def check_breakers(run) -> None:
     for mod in (ed25519_batch, sr25519_batch):
         b = mod.BREAKER
         if b.failures:
-            run.failures.append(f"breaker {b.name}: {b.failures} failure(s), "
-                                f"last {b.last_error!r}")
+            run.fail("breakers", f"breaker {b.name}: {b.failures} failure(s), "
+                                 f"last {b.last_error!r}")
     if verify_service.get().fallbacks:
-        run.failures.append(
-            f"verify service fell back {verify_service.get().fallbacks}x")
+        run.fail("fallbacks",
+                 f"verify service fell back {verify_service.get().fallbacks}x")

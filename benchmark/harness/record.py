@@ -16,11 +16,13 @@ from __future__ import annotations
 
 import shutil
 import time
+from collections import Counter
 from dataclasses import dataclass, field
 
 from benchmark.harness import stats, xplane
 
 ANNOTATION_PREFIX = "bench."
+FAILURE_CHARS = 240      # of one message in the result line's `failures`
 VERIFY_SPANS = ("verify.host_prep", "verify.queue", "verify.readback",
                 "verify.replay")
 
@@ -50,6 +52,8 @@ class Run:
     trace: xplane.Trace | None = None
     trace_dir: str | None = None
     failures: list = field(default_factory=list)  # what made `correct` false
+    checks: list = field(default_factory=list)    # fail()'s names, in order
+    compared: dict = field(default_factory=dict)  # name -> (number, its limit)
     window: tuple | None = None                  # (t0, t1) of the window
 
     # profiled slice (traced runs): decisions [skip, skip + count)
@@ -103,6 +107,34 @@ class Run:
         if len(self.failures) < 8:
             self.failures.append(f"decision {len(self.decisions)}: "
                                  f"{type(e).__name__}: {e}")
+
+    # --- what made `correct` false ----------------------------------------------
+
+    def fail(self, check: str, message: str) -> None:
+        """A check that did not hold, under its short name: a guarantee's
+        letter where the configuration letters them (``"h"``), else a plain
+        word (``"breakers"``). The message goes to ``failures`` as an append
+        does; a failure appended without a name counts under ``other``."""
+        self.failures.append(message)
+        self.checks.append(check)
+
+    def compare(self, name: str, value, limit) -> None:
+        """Note a number that a check holds to a limit (a count and the count
+        it must equal, seconds and the bound they must stay under): printed
+        with every result, correct or not. The check itself calls ``fail``."""
+        self.compared[name] = (value, limit)
+
+    def failure_summary(self) -> dict:
+        """The ``failures`` key of the result line: how many, how many under
+        each check's name, the first three messages and every number
+        compared beside its limit, so that a refusal's record says which
+        check it was (the driver keeps the end of the last line and nothing
+        of the earlier ones)."""
+        by_check = Counter(self.checks)
+        by_check["other"] += len(self.failures) - len(self.checks)
+        return {"n": len(self.failures), "by_check": dict(+by_check),
+                "first": [str(m)[:FAILURE_CHARS] for m in self.failures[:3]],
+                "compared": {k: list(v) for k, v in self.compared.items()}}
 
     # --- traced runs --------------------------------------------------------
 

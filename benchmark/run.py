@@ -104,7 +104,8 @@ def run_cell(args) -> int:
         calibration=dict(ed25519_batch._HOST_CAL),
         host_crossover=ed25519_batch.host_crossover())
     if crypto_batch.WARMUP.state == "failed":
-        run.failures.append(f"node warm-up failed: {crypto_batch.WARMUP.error!r}")
+        run.fail("warmup",
+                 f"node warm-up failed: {crypto_batch.WARMUP.error!r}")
     driver = cell.driver.Driver(run, ds, cell.traffic)
     driver.warm_up()
     misses_before_window = cache.misses
@@ -119,15 +120,17 @@ def run_cell(args) -> int:
     run.counters = {k: (before[k], after[k]) for k in before}
     compiled_in_window = cache.misses - misses_before_window
     run.setup["memory_peak_bytes"] = device.memory_peak_bytes()
+    run.compare("compiled_in_window", compiled_in_window, 0)
     if compiled_in_window:
-        run.failures.append(f"{compiled_in_window} program(s) compiled inside "
-                            f"the measured window")
+        run.fail("compiled_in_window", f"{compiled_in_window} program(s) "
+                 f"compiled inside the measured window")
 
     # --- correctness, outside the window -------------------------------------
     driver.check()
     run.setup["compile_cache_misses"] = cache.misses
     attempted = len(run.decisions)
     failed = sum(1 for d in run.decisions if not d.ok)
+    run.compare("decisions_failed", failed, 0)
     if attempted == 0:
         run.failures.append("no decision was attempted in the window")
     say(window_s=run.window[1] - run.window[0] - run.profiler_s,
@@ -172,7 +175,14 @@ def run_cell(args) -> int:
         metrics[entry["name"]] = {"value": float(value), "unit": entry["unit"]}
     result["metrics"] = metrics
     result["device"] = device_out
+    # last in the line, and as the last lines of standard error: which checks
+    # failed and every number that was held to a limit, beside that limit
+    result["failures"] = run.failure_summary()
     print(json.dumps(result), flush=True)
+    for name, (value, limit) in run.compared.items():
+        print(f"compared {name}: {value} (limit {limit})", file=sys.stderr)
+    print(f"failures {json.dumps(result['failures'])}", file=sys.stderr,
+          flush=True)
     return 0
 
 

@@ -199,12 +199,18 @@ def test_a_twin_is_the_reader_of_the_metric_it_is_named_after(twin):
 
 @pytest.mark.parametrize("name", sorted(LISTS))
 def test_the_benchmark_lists_the_metric_as_issue_35_says(name):
+    """What the entry protects, never where it stands: the metric is listed
+    once, for the cells ISSUE 35 named (a later PR may add cells after
+    them), with its ``moves`` and its unit."""
     with open(os.path.join(spec.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    names = [m["name"] for m in bench["per_layer"]]
-    assert set(names[-len(LISTS):]) == set(LISTS)         # appended, at the end
-    entry = bench["per_layer"][names.index(name)]
-    assert entry.get("workloads") == LISTS[name]
+    entries = [m for m in bench["per_layer"] if m["name"] == name]
+    assert len(entries) == 1
+    entry = entries[0]
+    if LISTS[name] is None:
+        assert "workloads" not in entry               # read in every cell
+    else:
+        assert entry["workloads"][:len(LISTS[name])] == LISTS[name]
     assert entry["moves"] == MOVES.get(name, "commit_p50_ms")
     assert entry["unit"] == UNITS[name.rsplit("_", 1)[-1]]
     # the two cells whose lists tests/benchmark pins get none of them

@@ -13,6 +13,8 @@ import pytest
 from benchmark.harness.spec import ROOT
 
 CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+# PR 48: which checks failed and what was compared, last in the line
+LINE_KEYS = ["correct", "attempted", "failed", "metrics", "device", "failures"]
 
 
 def _run(args, root=ROOT, env_extra=None, unset=()):
@@ -49,7 +51,14 @@ def test_rehearsal_prints_the_contracts_last_line(workload, metric, live):
         assert notes["corrupted_nil_votes"] >= 1
         assert notes["signer_sets"]["distinct"] > 1
         assert notes["light_prefix_sigs"][0] >= 9
-    assert set(line) == CONTRACT_KEYS
+    assert list(line) == LINE_KEYS
+    assert line["failures"]["n"] == 0 and line["failures"]["by_check"] == {}
+    assert line["failures"]["first"] == []
+    assert line["failures"]["compared"]["decisions_failed"] == [0, 0]
+    # standard error ends with the same numbers, one a line
+    assert out.stderr.strip().splitlines()[-1] == \
+        "failures " + json.dumps(line["failures"])
+    assert "compared decisions_failed: 0 (limit 0)" in out.stderr
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0
     assert line["device"]["platform"] == "cpu"

@@ -225,17 +225,21 @@ def test_the_cell_lists_what_issue_30_says():
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "localnet-5k", "vote-drain", 1)
-    assert bench["workloads"][-1] is cell and bench["configs"][-1]["name"] == "localnet-5k"
+    # by membership, never by position (D14): later PRs appended cells,
+    # configurations and metrics after these
+    assert [w["name"] for w in bench["workloads"]].count(CELL) == 1
+    assert [c["name"] for c in bench["configs"]].count("localnet-5k") == 1
     listed = {m["name"] for m in bench["per_layer"] + bench["end_to_end"]
               if CELL in m.get("workloads", [])}
-    assert listed == set(NEW) | {
+    # what ISSUE 30 listed; PRs 35 and 36 listed more for the cell since
+    assert listed >= set(NEW) | {
         "commit_p50_ms", "host_prep_ms", "prep_hash_ms", "prep_launch_ms",
         "prep_keyset_ms", "wake_ms", "kernel_us_per_sig",
         "verify_kernel_roofline", "lane_fill", "device_launches_per_decision",
         "keyset_miss_share", "device_idle_share"}
     for m in bench["per_layer"]:
         if m["name"] in NEW:
-            assert m["workloads"] == [CELL] and m["moves"] == "commit_p50_ms"
+            assert m["workloads"][0] == CELL and m["moves"] == "commit_p50_ms"
             assert m["layer"] == "consensus"
     config = spec.Cell(CELL).config
     assert config["dataset"]["validators"] == {"ed25519": 5000, "sr25519": 0}
