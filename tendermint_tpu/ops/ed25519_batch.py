@@ -179,9 +179,19 @@ _build_comb_tables = jax.jit(_build_comb_tables_impl)
 # cold compile of these limb-heavy graphs takes from seconds to minutes
 # (chip_smoke.py prints what it measured). Chunking every batch through ONE
 # (tile-sized) executable makes compilation a one-time cost per process
-# regardless of batch size.
+# regardless of batch size: a KEY_TILE of keys a build, on either backend
+# (the jnp program below; on a TPU ed25519_pallas._build_kernel at the same
+# shape), a JNP_TILE of signatures a jnp verify.
 KEY_TILE = 256
 JNP_TILE = 256
+
+
+def pad_identity(a_neg: np.ndarray, rows: int) -> np.ndarray:
+    """(K, 4, 20) limbs, K <= rows -> (rows, 4, 20), the rows past K holding
+    the identity: what a build's padding lanes compute on."""
+    padded = np.broadcast_to(ed.IDENTITY_LIMBS, (rows, 4, 20)).copy()
+    padded[: a_neg.shape[0]] = a_neg
+    return padded
 
 
 def _build_comb_tables_tiled(a_neg: np.ndarray):
@@ -189,8 +199,7 @@ def _build_comb_tables_tiled(a_neg: np.ndarray):
     fixed-shape chunks so _build_comb_tables compiles exactly once."""
     k = a_neg.shape[0]
     kp = max(_round_up(k, KEY_TILE), KEY_TILE)
-    padded = np.broadcast_to(ed.IDENTITY_LIMBS, (kp, 4, 20)).copy()
-    padded[:k] = a_neg
+    padded = pad_identity(a_neg, kp)
     chunks = [
         _build_comb_tables(jnp.asarray(padded[o : o + KEY_TILE]))
         for o in range(0, kp, KEY_TILE)
@@ -310,16 +319,23 @@ class KeySet:
         self._niels = None
         self._niels_on: dict = {}  # device -> that device's copy of _niels
 
-    def append(self, a_neg: np.ndarray, valid: np.ndarray) -> None:
+    def append(self, a_neg: np.ndarray, valid: np.ndarray) -> str:
         """Build the tables of K new keys, a KEY_TILE at a time through the
         one tile-shaped executable, into rows n_rows.. (the KeyTable's lock
-        is held: one appender at a time). Waits for the last tile: a build
-        is timed to its result, and the first kernel over new keys waits
-        for it anyway."""
+        is held: one appender at a time). -> the program that built them:
+        "pallas" on a TPU backend (ed25519_pallas.build_comb_tile, a key a
+        lane), "jnp" elsewhere. Waits for the last tile: a build is timed to
+        its result, and the first kernel over new keys waits for it anyway."""
         k = a_neg.shape[0]
         self._reserve(self.n_rows + _round_up(k, KEY_TILE))
+        if _use_pallas():
+            from tendermint_tpu.ops import ed25519_pallas
+
+            program, build = "pallas", ed25519_pallas.build_comb_tile
+        else:
+            program, build = "jnp", _build_comb_tables_tiled
         for o in range(0, k, KEY_TILE):
-            tile = _build_comb_tables_tiled(a_neg[o : o + KEY_TILE])
+            tile = build(a_neg[o : o + KEY_TILE])
             kt = min(KEY_TILE, k - o)
             self.valid[self.n_rows : self.n_rows + kt] = valid[o : o + kt]
             with self._lock:
@@ -332,6 +348,7 @@ class KeySet:
                             copy, jax.device_put(rows, d), self.n_rows)
                 self.n_rows += kt
         self._tab_ext.block_until_ready()
+        return program
 
     def _reserve(self, rows: int) -> None:
         cap = self.valid.shape[0]
@@ -386,7 +403,7 @@ class KeyTable(dict):
     bounds HBM (8,960 bytes a row) against a peer that feeds a light client
     key sets without end. `overflow_clears` counts those for the table's
     lifetime (prep.keyset's `cleared` tag; on /metrics keytable_clears_total,
-    beside keytable_keys_built_total)."""
+    beside keytable_keys_built_total and keytable_build_launches_total)."""
 
     MAX_ROWS = 1 << 16
 
@@ -433,15 +450,17 @@ class KeyTable(dict):
                 valid[j] = True
         t1 = _time.monotonic()
         row0 = self.keyset.n_rows
-        self.keyset.append(a_neg, valid)
+        program = self.keyset.append(a_neg, valid)
         t2 = _time.monotonic()
+        rows = _round_up(len(new), KEY_TILE)
         self.update(zip(new, range(row0, row0 + len(new))))
         _trace.STARTUP.record("startup.key_decode", t1 - t0, start=t0,
                               keys=len(new), kind=kind)
         _trace.STARTUP.record("startup.table_build", t2 - t1, start=t1,
-                              keys=len(new), kind=kind,
-                              rows=_round_up(len(new), KEY_TILE))
+                              keys=len(new), kind=kind, rows=rows,
+                              launches=rows // KEY_TILE, program=program)
         _count_metric("keytable_keys_built", len(new))
+        _count_metric("keytable_build_launches", rows // KEY_TILE)
         return len(new)
 
 

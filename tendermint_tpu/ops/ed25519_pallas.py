@@ -21,6 +21,11 @@ choices:
    is rebuilt per call, and each table addition is a 7-mul mixed add. The
    fixed-base comb table for B is baked in as niels constants the same way.
 
+The per-key tables themselves are built by a third kernel over the same
+field and point helpers (_build_kernel: 192 doublings and 15 additions a
+lane, a key a lane), one TILE of keys a launch; edb.KeySet.append is its
+caller on a TPU backend.
+
 Bound discipline matches ops/field25519: all stored limbs < 9500, products
 and 20-term accumulations stay below 2^31 in int32 (squaring's doubled
 cross-products included: 10 * 9500 * 19000 + 9500^2 + fold < 2^31).
@@ -423,10 +428,13 @@ def _sr_kernel(consts_ref, tab_ref, k_win_ref, s_win_ref, r_ref, valid_ref, ok_r
     ok_ref[:, :] = ok.astype(jnp.int32)
 
 
-def _pallas_call(kernel, consts: np.ndarray, rows: tuple, args, interpret):
+def _pallas_call(kernel, consts: np.ndarray, rows: tuple, args, interpret,
+                 out_rows: int = 1, scratch_rows: tuple = ()):
     """One launch of `kernel` over TILE-lane grid steps: `consts` whole at
     every step, each of `args` ((rows[i], N), N a multiple of TILE) a tile
-    at a time -> ok (1, N) int32."""
+    at a time -> (out_rows, N) int32 (the verify kernels' ok lanes: one
+    row). `scratch_rows`: a (rows, TILE) VMEM buffer each, handed to the
+    kernel after its output."""
     n = args[0].shape[1]
     grid = (n // TILE,)
 
@@ -438,10 +446,12 @@ def _pallas_call(kernel, consts: np.ndarray, rows: tuple, args, interpret):
     )
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((out_rows, n), jnp.int32),
         grid=grid,
         in_specs=[consts_spec] + [spec(r) for r in rows],
-        out_specs=spec(1),
+        out_specs=spec(out_rows),
+        scratch_shapes=[pltpu.VMEM((r, TILE), jnp.int32)
+                        for r in scratch_rows],
         interpret=interpret,
     )(jnp.asarray(consts), *args)
 
@@ -458,6 +468,80 @@ def _pallas_sr_verify(tab, k_win, s_win, r_limbs, valid, *, interpret=False):
     (20,N) limbs of R's encoding, valid (1,N) -> ok (1, N) int32."""
     return _pallas_call(_sr_kernel, SR_CONSTS, (960, 64, 64, 20, 1),
                         (tab, k_win, s_win, r_limbs, valid), interpret)
+
+
+# --- the key table's build ----------------------------------------------------
+
+_PT_ROWS = 4 * NLIMB  # an extended point (X, Y, Z, T) as rows of one lane
+
+
+def _pt_split(rows):
+    """(80, T) rows of a point -> the (X, Y, Z, T) tuple the point ops take."""
+    return tuple(rows[NLIMB * c : NLIMB * (c + 1)] for c in range(4))
+
+
+def _pt_rows(k):
+    """The rows of the k-th point of a ref of points ((80 * points, T)),
+    k traced."""
+    return pl.ds(pl.multiple_of(k * _PT_ROWS, 8), _PT_ROWS)
+
+
+def _build_kernel(consts_ref, a_ref, tab_ref, ps_ref):
+    """The comb table of the point a lane holds: a_ref (80, T) extended
+    limbs of -A -> tab_ref (1280, T), entry w = sum_j w_j [2^(64j)](-A) in
+    extended coordinates. edb._build_comb_tables_impl lane-major, operation
+    for operation: ps[j + 1] = 64 doublings of ps[j] (kept in the scratch
+    ps_ref, (320, T)), then T[w] = T[w ^ lsb(w)] + ps[log2 lsb(w)] for
+    w = 1..15 from T[0], the identity (the addition is complete, so the
+    identity takes part like any point, and so does a padding lane that
+    holds it). No inversion: the niels form stays edb._to_niels's."""
+    _bind_consts(consts_ref)
+    ps_ref[0:_PT_ROWS, :] = a_ref[:, :]
+
+    def dbl64(j, carry):
+        p = jax.lax.fori_loop(0, 64, lambda _, q: _pt_double(q),
+                              _pt_split(ps_ref[_pt_rows(j), :]))
+        ps_ref[_pt_rows(j + 1), :] = jnp.concatenate(p, axis=0)
+        return carry
+
+    jax.lax.fori_loop(0, 3, dbl64, 0)
+
+    zero = jnp.zeros((NLIMB, TILE), dtype=jnp.int32)
+    one = jnp.concatenate(
+        [jnp.ones((1, TILE), dtype=jnp.int32), zero[1:]], axis=0)
+    tab_ref[0:_PT_ROWS, :] = jnp.concatenate([zero, one, one, zero], axis=0)
+
+    def entry(w, carry):
+        lsb = w & -w
+        j = (lsb >> 1) - (lsb >> 3)  # log2 of 1, 2, 4, 8
+        p = _pt_add(_pt_split(tab_ref[_pt_rows(w ^ lsb), :]),
+                    _pt_split(ps_ref[_pt_rows(j), :]))
+        tab_ref[_pt_rows(w), :] = jnp.concatenate(p, axis=0)
+        return carry
+
+    jax.lax.fori_loop(1, 16, entry, 0)
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def _build_comb_lanes(a_neg, interpret=False):
+    """(N, 4, 20) extended limbs of -A, N a multiple of TILE -> (N, 16, 4, 20)
+    comb tables, row-major as edb.KeySet keeps them: one launch of
+    _build_kernel over N lanes, the transposes in and out around it (the
+    program runs at N = TILE; the tests and probes may ask for more).
+    `interpret` is for the tests, which run the kernel body on the CPU."""
+    n = a_neg.shape[0]
+    out = _pallas_call(_build_kernel, CONSTS[:60], (_PT_ROWS,),
+                       (a_neg.reshape(n, _PT_ROWS).T,), interpret,
+                       out_rows=16 * _PT_ROWS, scratch_rows=(4 * _PT_ROWS,))
+    return out.T.reshape(n, 16, 4, NLIMB)
+
+
+def build_comb_tile(a_neg: np.ndarray):
+    """(k, 4, 20) limbs of -A, k <= TILE -> (TILE, 16, 4, 20) comb tables on
+    the device, the lanes past the keys holding the identity's: what
+    edb.KeySet.append launches a KEY_TILE of new keys on a TPU backend, in
+    the place of edb._build_comb_tables_tiled."""
+    return _build_comb_lanes(jnp.asarray(edb.pad_identity(a_neg, TILE)))
 
 
 def _r_limbs_device(r32):

@@ -260,6 +260,10 @@ class NodeMetrics:
             "crypto", "keytable_keys_built_total",
             "Keys whose device comb tables were built because the table "
             "did not hold them.")
+        self.keytable_build_launches = r.counter(
+            "crypto", "keytable_build_launches_total",
+            "Device programs launched to build those tables: one a 256-key "
+            "tile of a request's missing keys.")
         self.keytable_clears = r.counter(
             "crypto", "keytable_clears_total",
             "Times a key table forgot every row because a build would have "
@@ -397,6 +401,7 @@ class NodeMetrics:
         self.light_skip_refused.add(0.0)
         self.light_skip_depth_max.set(0.0)
         self.keytable_keys_built.add(0.0)
+        self.keytable_build_launches.add(0.0)
         self.keytable_clears.add(0.0)
         # ingest front door: the result label universe is closed by
         # construction (docs/INGEST.md), seed it fully; the batch-size

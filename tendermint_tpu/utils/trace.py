@@ -143,7 +143,8 @@ CANONICAL_SPANS = {
     "startup.key_decode": "Python decompression of keys the table did not hold",
     "startup.table_build": "device build of those keys' comb tables, tile by "
                            "tile, until the last is ready (tags keys, rows "
-                           "= the tile rows built for them)",
+                           "= the tile rows built for them, launches = the "
+                           "tiles, program = pallas | jnp)",
     "startup.jit_trace": "jax traced a function and lowered it to MLIR",
     "startup.jit_compile": "backend compile, or its load from the cache",
     "startup.cache_load": "persistent compile-cache retrieval (inside "

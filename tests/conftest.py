@@ -99,7 +99,9 @@ def fake_tpu_host(monkeypatch):
     whose chips are the first `ndev` of the CPU's forced devices and whose
     Pallas chunk is `chunk` lanes -- the module attributes the routing
     reads, as tests replace `host_crossover`. The chunk programs are the
-    caller's to stand in for. -> the devices."""
+    caller's to stand in for; the key table's build, which every route
+    behind a TPU backend launches for a key it has not met, is stood in for
+    here by the jnp build. -> the devices."""
     def fake(ndev: int, chunk: int):
         from tendermint_tpu.ops import ed25519_batch as edb
         from tendermint_tpu.ops import ed25519_pallas as edp
@@ -108,6 +110,7 @@ def fake_tpu_host(monkeypatch):
         assert len(devices) == ndev
         monkeypatch.setattr(edb, "_use_pallas", lambda: True)
         monkeypatch.setattr(edp, "CHUNK", chunk)
+        monkeypatch.setattr(edp, "_build_comb_lanes", edb._build_comb_tables)
         monkeypatch.setattr(jax, "local_devices", lambda *a, **k: list(devices))
         monkeypatch.setattr(jax, "local_device_count", lambda *a, **k: ndev)
         monkeypatch.delenv("TM_TPU_SHARD", raising=False)

@@ -214,7 +214,8 @@ def test_one_new_key_among_resident_ones_builds_one_tile(kind, builds, tracer):
     assert kind.mod._KS_UNIQ_CACHE[items[7][0]] == 7
     ring = [s for s in trace.STARTUP.dump() if s.name == "startup.table_build"]
     assert ring[-1].tags == {"keys": 1, "kind": kind.name,
-                             "rows": edb.KEY_TILE}
+                             "rows": edb.KEY_TILE, "launches": 1,
+                             "program": "jnp"}
 
 
 def test_forget_keys_makes_the_next_verify_build_its_keys_again(kind, builds,

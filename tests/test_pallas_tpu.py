@@ -139,3 +139,90 @@ def test_a_commit_of_9999_on_every_local_chip_vs_the_serial_reference():
     assert (one_chip[1](jax.device_get(one_chip[0])) == out).all()
     check((items * ndev)[: (ndev - 1) * edp.CHUNK + 1], ndev)
     assert edb.BREAKER.failures == failures
+
+
+def test_the_table_build_kernel_vs_the_jnp_loop_and_one_commit_over_both(
+        monkeypatch):
+    """4,096 seeded keys (i * B: the signer's scalar is i) built by the
+    Pallas kernel and by the jnp program, a tile a launch, hold
+    the same points and the same niels rows mod p, and one commit over all
+    of them (a signature a key, corrupted lanes among them) gets the same
+    bitmap over either table, lane for lane, and the scalar verifier's."""
+    import hashlib
+
+    import jax.numpy as jnp
+
+    from tendermint_tpu.crypto import ed25519 as ref
+    from tendermint_tpu.ops import ed25519_batch as edb
+    from tendermint_tpu.ops import ed25519_pallas as edp
+    from tendermint_tpu.ops import edwards25519 as ed
+    from tendermint_tpu.utils import trace
+
+    n = edp.CHUNK
+    base = (ref.BASE[0], ref.BASE[1])
+
+    def enc(pt):
+        return (pt[1] | ((pt[0] & 1) << 255)).to_bytes(32, "little")
+
+    # key i is (i + 1) B, nonce point i is (r0 + i) B: affine adds, no
+    # scalar multiplication a signature
+    r0 = 0x5EED_0049
+    a_pt, r_pt = base, ref._scalarmult(r0, ref.BASE)
+    zi = pow(r_pt[2], -1, ref.P)
+    r_pt = (r_pt[0] * zi % ref.P, r_pt[1] * zi % ref.P)
+    items = []
+    for i in range(n):
+        pub, r_enc, msg = enc(a_pt), enc(r_pt), b"table build vote %d" % i
+        h = int.from_bytes(hashlib.sha512(r_enc + pub + msg).digest(),
+                           "little") % ref.L
+        s = (r0 + i + h * (i + 1)) % ref.L
+        items.append((pub, msg, r_enc + s.to_bytes(32, "little")))
+        a_pt, r_pt = ed.affine_add(a_pt, base), ed.affine_add(r_pt, base)
+    bad = [0, 255, 256, 1000, n - 1]
+    for j in bad:
+        pub, msg, sig = items[j]
+        items[j] = (pub, msg + b"!", sig)
+
+    a_neg = np.stack([edb._decompress_neg(it[0]) for it in items])
+    by_kernel = jnp.concatenate(
+        [edp.build_comb_tile(a_neg[o : o + edp.TILE])
+         for o in range(0, n, edp.TILE)], axis=0)
+    by_loop = edb._build_comb_tables_tiled(a_neg)
+    weights = np.array([1 << (13 * k) for k in range(20)], dtype=object)
+
+    def ints(limbs):
+        return (np.asarray(limbs).astype(object) * weights).sum(-1) % ref.P
+
+    k_pts, l_pts = ints(by_kernel), ints(by_loop)      # (n, 16, 4)
+    for c in (0, 1):                                   # X1 Z2 = X2 Z1, Y too
+        assert ((k_pts[..., c] * l_pts[..., 2]
+                 - l_pts[..., c] * k_pts[..., 2]) % ref.P == 0).all()
+    assert (k_pts[..., 2] != 0).all()
+    assert ((k_pts[..., 0] * k_pts[..., 1]
+             - k_pts[..., 2] * k_pts[..., 3]) % ref.P == 0).all()
+    assert (ints(np.asarray(edb._to_niels(by_kernel)).reshape(n, 48, 20))
+            == ints(np.asarray(edb._to_niels(by_loop)).reshape(n, 48, 20))
+            ).all()
+
+    def commit(build):
+        monkeypatch.setattr(edb, "_KS_CACHE", type(edb._KS_CACHE)())
+        monkeypatch.setattr(edb, "_KS_UNIQ_CACHE", edb.KeyTable())
+        monkeypatch.setattr(edp, "build_comb_tile", build)
+        dev, finish = edb.dispatch_batch(items, force_device=True)
+        out = finish(jax.device_get(dev))
+        assert finish.route == "pallas" and edb._KS_UNIQ_CACHE.keyset.n_rows == n
+        return out
+
+    failures = edb.BREAKER.failures
+    over_kernel = commit(edp.build_comb_tile)
+    tags = [s.tags for s in trace.STARTUP.dump()
+            if s.name == "startup.table_build"][-1]
+    assert tags == {"keys": n, "kind": "ed25519", "program": "pallas",
+                    "launches": n // edp.TILE, "rows": n}
+    over_loop = commit(edb._build_comb_tables_tiled)
+    assert edb.BREAKER.failures == failures
+    assert (over_kernel == over_loop).all()
+    assert sorted(np.flatnonzero(~over_kernel)) == bad
+    sample = sorted(set(bad + list(range(7, n, 211))))
+    assert (over_kernel[sample]
+            == np.array([ref.verify(*items[j]) for j in sample])).all()
