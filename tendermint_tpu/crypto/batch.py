@@ -30,6 +30,7 @@ from __future__ import annotations
 import abc
 import logging
 import os
+import threading
 import time as _time
 
 from tendermint_tpu.crypto import keys
@@ -493,12 +494,15 @@ class MixedBatchVerifier(BatchVerifier):
 class WarmupStatus:
     """Outcome of the kernel warm-up, readable by whoever started it (Node,
     chip_smoke.py): ``state`` is idle -> running -> done | failed, ``error``
-    the exception of a failed run, ``thread`` the background thread."""
+    the exception of a failed run, ``thread`` the background thread (of the
+    newest run: it waits for the one before it), ``key_types`` the key types
+    whose kernel a run has warmed or is warming."""
 
     def __init__(self) -> None:
         self.state = "idle"
         self.error: BaseException | None = None
         self.thread = None
+        self.key_types: set[str] = set()
 
     def join(self, timeout: float | None = None) -> bool:
         """Wait for a background warm-up; True when none is left running.
@@ -512,25 +516,107 @@ class WarmupStatus:
 
 
 WARMUP = WarmupStatus()
+_WARMUP_LOCK = threading.Lock()
 
 
-def warmup(sizes: tuple[int, ...] = (64,), background: bool = True):
-    """AOT-warm the batch kernel at the given bucket sizes.
+def _warm_kernel(kind: str, verify_batch, item, sizes) -> None:
+    """One key type's kernel at every warm bucket size, each launch a
+    ``startup.warm_kernel`` span of the start-up ring. force_device: the
+    point is compiling the kernel buckets, which the host route would
+    otherwise absorb."""
+    for n in sizes:
+        with _trace.STARTUP.span("startup.warm_kernel", kind=kind, sigs=n):
+            verify_batch([item] * n, force_device=True)
+
+
+def _warm_ed25519(sizes) -> None:
+    from tendermint_tpu.crypto import ed25519
+    from tendermint_tpu.ops import ed25519_batch
+
+    # Measure the host/kernel crossover first so the warm buckets
+    # below compile the path real batches will actually take.
+    ed25519_batch.calibrate_host_crossover()
+    priv = ed25519.gen_priv_key(b"\x42" * 32)
+    pub = priv.pub_key().bytes()
+    sig = ed25519.sign(priv.data, b"warmup")
+    _warm_kernel("ed25519", ed25519_batch.verify_batch, (pub, b"warmup", sig),
+                 sizes)
+    _warm_mesh(pub, sig)
+
+
+def _sr25519_item():
+    from tendermint_tpu.crypto import sr25519
+
+    spriv = sr25519.gen_priv_key(b"\x43" * 32)
+    return (spriv.pub_key().bytes(), b"warmup", spriv.sign(b"warmup"))
+
+
+def _warm_sr25519(sizes) -> None:
+    """The sr25519 kernel, on one chip as on several: a node whose validators
+    hold sr25519 keys must not trace and compile it on its first commit."""
+    from tendermint_tpu.ops import sr25519_batch
+
+    _warm_kernel("sr25519", sr25519_batch.verify_batch, _sr25519_item(), sizes)
+
+
+def _warm_mesh(pub, sig):
+    """Warm what the "sharded" route runs on a TPU host with several
+    chips, so that no commit of the node's life compiles: one Pallas
+    chunk of each key type on EVERY local device (with its table gather
+    and its pack). A batch of ndev chunks puts chunk k on device k, and
+    placement starts from device 0 in every launch, so these are the
+    devices any later batch uses."""
+    import jax
+
+    from tendermint_tpu.ops import ed25519_batch
+
+    if not ed25519_batch._use_pallas():
+        return  # before the Pallas module is imported for nothing
+    from tendermint_tpu.ops import ed25519_pallas, sr25519_batch
+
+    n = (jax.local_device_count() - 1) * ed25519_pallas.CHUNK + 1
+    if not ed25519_batch.should_shard(n):
+        return
+    ed25519_batch.verify_batch([(pub, b"warmup", sig)] * n,
+                               force_device=True)
+    sr25519_batch.verify_batch([_sr25519_item()] * n)
+
+
+# key type -> what compiles its kernel at the warm bucket sizes
+_WARM_KERNELS = {"ed25519": _warm_ed25519, "sr25519": _warm_sr25519}
+
+
+def warmup(sizes: tuple[int, ...] = (64,), background: bool = True,
+           key_types: tuple[str, ...] = ()):
+    """AOT-warm the batch kernels at the given bucket sizes.
 
     XLA compiles one executable per padded bucket shape, and the first launch
     at a new shape pays tracing + compilation. Nodes call this at start (in a
     background thread by default) so the first real commit at a warm bucket
-    size is a cache hit, not a compile. No-op when batching is disabled or
-    already warmed. The outcome lands in :data:`WARMUP`; a failure is logged
-    at error level and never kills the node. Returns the warmup thread when
-    background, else None."""
-    if (WARMUP.state != "idle" or os.environ.get("TM_TPU_DISABLE_BATCH") == "1"
+    size is a cache hit, not a compile. ``key_types`` names the key types the
+    caller's validators hold (node/node.py: the genesis validators'): ed25519
+    is always warmed, every other type with a batch kernel when it is named,
+    each once per process, so a later call warms only what no earlier one did
+    and is a no-op when nothing is left (or batching is disabled). Every
+    kernel warmed writes a ``startup.warm_kernel`` span in the start-up ring.
+    The outcome lands in :data:`WARMUP`; a failure is logged at error level
+    and never kills the node. Returns the warmup thread when background,
+    else None."""
+    if (os.environ.get("TM_TPU_DISABLE_BATCH") == "1"
             or os.environ.get("TM_TPU_SKIP_WARMUP") == "1"):
         # TM_TPU_SKIP_WARMUP: short-lived processes (tests) gain nothing from
         # pre-compiling kernels they may never launch, and would have to
         # wait the compile out before exiting (WarmupStatus.join).
         return None
-    WARMUP.state = "running"
+    with _WARMUP_LOCK:
+        kinds = [kt for kt in dict.fromkeys(("ed25519", *key_types))
+                 if kt in _WARM_KERNELS and kt not in WARMUP.key_types]
+        if not kinds:
+            return None
+        WARMUP.key_types.update(kinds)
+        if WARMUP.state != "failed":
+            WARMUP.state = "running"
+        earlier = WARMUP.thread
 
     def _device_failures():
         from tendermint_tpu.ops import ed25519_batch, sr25519_batch
@@ -540,35 +626,26 @@ def warmup(sizes: tuple[int, ...] = (64,), background: bool = True):
                 or sr25519_batch.BREAKER.last_error)
 
     def _run():
+        if earlier is not None:
+            earlier.join()      # one warm-up at a time on the device
         try:
-            from tendermint_tpu.crypto import ed25519
-            from tendermint_tpu.ops import ed25519_batch
-
             failures, _ = _device_failures()
-            # Measure the host/kernel crossover first so the warm buckets
-            # below compile the path real batches will actually take.
-            ed25519_batch.calibrate_host_crossover()
-            priv = ed25519.gen_priv_key(b"\x42" * 32)
-            pub = priv.pub_key().bytes()
-            sig = ed25519.sign(priv.data, b"warmup")
-            for n in sizes:
-                # force_device: the point is compiling the kernel buckets,
-                # which the host route would otherwise absorb
-                ed25519_batch.verify_batch([(pub, b"warmup", sig)] * n,
-                                           force_device=True)
-            _warm_mesh(pub, sig)
+            for kt in kinds:
+                _WARM_KERNELS[kt](sizes)
             now, last_error = _device_failures()
             if now != failures:
                 # verify_batch degrades through the breaker instead of
                 # raising: the host answered and nothing was warmed
                 raise RuntimeError(
                     "warm-up batch fell back to the host") from last_error
-            WARMUP.state = "done"
+            if WARMUP.state != "failed":
+                WARMUP.state = "done"
             # where the warm-up went, from the start-up ring (recorded with
             # tracing off too; docs/OBSERVABILITY.md)
-            _log.info("kernel warm-up done: %s", ", ".join(
+            where = ", ".join(
                 f"{name} {agg['total_s']:.3f}s x{agg['count']}"
-                for name, agg in sorted(_trace.STARTUP.summarize().items())))
+                for name, agg in sorted(_trace.STARTUP.summarize().items()))
+            _log.info("kernel warm-up done (%s): %s", ", ".join(kinds), where)
         except Exception as e:  # noqa: BLE001 - warmup must never kill a node
             WARMUP.error = e
             WARMUP.state = "failed"
@@ -576,35 +653,7 @@ def warmup(sizes: tuple[int, ...] = (64,), background: bool = True):
                        "compile (or degrade) on the hot path: %r", e,
                        exc_info=e)
 
-    def _warm_mesh(pub, sig):
-        """Warm what the "sharded" route runs on a TPU host with several
-        chips, so that no commit of the node's life compiles: one Pallas
-        chunk of each key type on EVERY local device (with its table gather
-        and its pack). A batch of ndev chunks puts chunk k on device k, and
-        placement starts from device 0 in every launch, so these are the
-        devices any later batch uses."""
-        import jax
-
-        from tendermint_tpu.ops import ed25519_batch
-
-        if not ed25519_batch._use_pallas():
-            return  # before the Pallas module is imported for nothing
-        from tendermint_tpu.crypto import sr25519
-        from tendermint_tpu.ops import ed25519_pallas, sr25519_batch
-
-        n = (jax.local_device_count() - 1) * ed25519_pallas.CHUNK + 1
-        if not ed25519_batch.should_shard(n):
-            return
-        ed25519_batch.verify_batch([(pub, b"warmup", sig)] * n,
-                                   force_device=True)
-        spriv = sr25519.gen_priv_key(b"\x43" * 32)
-        spub = spriv.pub_key().bytes()
-        ssig = spriv.sign(b"warmup")
-        sr25519_batch.verify_batch([(spub, b"warmup", ssig)] * n)
-
     if background:
-        import threading
-
         WARMUP.thread = threading.Thread(target=_run, name="batch-warmup",
                                          daemon=True)
         WARMUP.thread.start()

@@ -396,9 +396,13 @@ class Node:
         # AOT-warm the batch-verify kernel off the critical path so the first
         # real commit at a warm bucket size is a compile-cache hit
         # (reference has no analogue; XLA compilation is TPU-build-specific).
+        # The genesis validators' key types decide which kernels: a chain
+        # with sr25519 validators must not compile that kernel on its first
+        # commit, on one chip as on several.
         from tendermint_tpu.crypto import batch as crypto_batch
 
-        crypto_batch.warmup()
+        crypto_batch.warmup(key_types=tuple(sorted(
+            {v.pub_key.type for v in self.genesis.validators})))
         if self.config.p2p.laddr:
             la = self.transport.listen(self.config.p2p.laddr)
             if self.addr_book is not None:

@@ -414,10 +414,11 @@ def dispatch_batch(items: list[tuple[bytes, bytes, bytes]],
         lambda: _host_fallback(items, n, route="breaker_fallback"))
 
 
-def verify_batch(items: list[tuple[bytes, bytes, bytes]]) -> np.ndarray:
+def verify_batch(items: list[tuple[bytes, bytes, bytes]],
+                 force_device: bool = False) -> np.ndarray:
     """Batched verify of [(pub, msg, sig)]; returns (len(items),) bool,
     byte-identical accept/reject with crypto/sr25519.verify."""
-    dev, finish = dispatch_batch(items)
+    dev, finish = dispatch_batch(items, force_device=force_device)
     return _cbreaker.guarded_fetch(
         BREAKER, dev, finish,
         lambda: _host_fallback(items, len(items), route="breaker_fallback"))

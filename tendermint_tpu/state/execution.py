@@ -294,6 +294,12 @@ class BlockExecutor:
         self._backlog_cv = threading.Condition()
         self._follower = None
         self.backlog_waits = 0
+        # the commit->apply seam: handles dispatch_commit_verify gave out,
+        # and of those validate_block was handed, the ones whose inputs
+        # still held (resolved) and the ones found stale (verified anew)
+        self.commit_verify_dispatched = 0
+        self.commit_verify_fresh = 0
+        self.commit_verify_stale = 0
         # lazy: no thread until the first post-commit submission
         self._post_commit = PostCommitWorker(logger,
                                              on_done=self.backlog_changed)
@@ -319,7 +325,13 @@ class BlockExecutor:
     def validate_block(self, state: State, block: Block,
                        commit_pending: SpeculativeCommitVerify | None = None,
                        tr=None) -> None:
-        inner = commit_pending.fresh_for(state, block) if commit_pending else None
+        inner = None
+        if commit_pending is not None:
+            inner = commit_pending.fresh_for(state, block)
+            if inner is None:
+                self.commit_verify_stale += 1
+            else:
+                self.commit_verify_fresh += 1
         validate_block(state, block, self.block_store, commit_pending=inner,
                        tr=tr)
         if self.evidence_pool is not None:
@@ -341,6 +353,7 @@ class BlockExecutor:
         pending = state.last_validators.verify_commit_async(
             state.chain_id, state.last_block_id,
             block.header.height - 1, block.last_commit)
+        self.commit_verify_dispatched += 1
         return SpeculativeCommitVerify(
             pending=pending, height=block.header.height,
             last_block_id=state.last_block_id,

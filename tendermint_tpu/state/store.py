@@ -146,7 +146,18 @@ class StateStore:
 
     def save(self, state: State) -> None:
         """Persist state + index validator/params history (reference:
-        state/store.go:174-205)."""
+        state/store.go:174-205). Traced as span state.save."""
+        if not _trace.ENABLED:
+            self._save(state)
+            return
+        tr = _trace.current()
+        with tr.span("state.save", height=state.last_block_height,
+                     validators=state.validators.size()
+                     if state.validators else 0):
+            tr.annotate(bytes=self._save(state))
+
+    def _save(self, state: State) -> int:
+        """-> the bytes of the State's own encoding."""
         next_height = state.last_block_height + 1
         if next_height == 1:
             next_height = state.initial_height
@@ -159,7 +170,9 @@ class StateStore:
         # crash between the history rows above and the state key below is
         # the interesting torn-state case replay must absorb
         faults.fire("store.state.save")
-        self._set(_STATE_KEY, _marshal_state(state))
+        raw = _marshal_state(state)
+        self._set(_STATE_KEY, raw)
+        return len(raw)
 
     def bootstrap(self, state: State) -> None:
         """reference: state/store.go:207-241."""

@@ -27,7 +27,11 @@ def validate_block(state: State, block: Block, block_store=None,
     decision, so accept/reject and error attribution are unchanged.
 
     Under a tracer (`tr`) the block time's weighted median is timed into
-    the caller's open span, tag `median_s`."""
+    the caller's open span, tag `median_s`, and so is the LastCommit: tags
+    `last_commit` (`pending`: the handle was resolved, `sync`: verify_commit
+    ran here, `none`: the initial block), `last_commit_s` (the seconds the
+    resolve or the verify took: what of the device round trip stayed
+    exposed) and `sigs` (the commit's slots that are not Absent)."""
     block.validate_basic()
 
     h = block.header
@@ -71,16 +75,29 @@ def validate_block(state: State, block: Block, block_store=None,
     if block.header.height == state.initial_height:
         if block.last_commit is not None and len(block.last_commit.signatures) != 0:
             raise BlockValidationError("initial block can't have LastCommit signatures")
-    elif commit_pending is not None:
-        # dispatched earlier (overlapped with store save / WAL fsync);
-        # resolve() is idempotent and raises exactly what the
-        # synchronous verify would
-        commit_pending.resolve()
+        if tr is not None:
+            tr.annotate(last_commit="none")
     else:
-        # THE hot call (reference: state/validation.go:93): one batched kernel.
-        state.last_validators.verify_commit(
-            state.chain_id, state.last_block_id, block.header.height - 1, block.last_commit
-        )
+        t0 = time.perf_counter()
+        try:
+            if commit_pending is not None:
+                # dispatched earlier (overlapped with store save / WAL
+                # fsync); resolve() is idempotent and raises exactly what
+                # the synchronous verify would
+                commit_pending.resolve()
+            else:
+                # THE hot call (reference: state/validation.go:93): one
+                # batched kernel.
+                state.last_validators.verify_commit(
+                    state.chain_id, state.last_block_id,
+                    block.header.height - 1, block.last_commit)
+        finally:
+            if tr is not None and block.last_commit is not None:
+                tr.annotate(
+                    last_commit="sync" if commit_pending is None else "pending",
+                    last_commit_s=time.perf_counter() - t0,
+                    sigs=sum(1 for cs in block.last_commit.signatures
+                             if not cs.absent()))
 
     # proposer must be in the current validator set
     if not state.validators.has_address(h.proposer_address):

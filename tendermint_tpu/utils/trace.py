@@ -150,6 +150,9 @@ CANONICAL_SPANS = {
     "startup.cache_load": "persistent compile-cache retrieval (inside "
                           "startup.jit_compile)",
     "startup.calibrate": "host/device crossover calibration",
+    "startup.warm_kernel": "crypto.batch.warmup compiling and running one "
+                           "key type's verify kernel at a warm bucket size "
+                           "(tags kind, sigs)",
     # fast-sync verify-ahead (blockchain/pipeline.py)
     "fastsync.dispatch": "speculative commit-verify dispatch for one height",
     "fastsync.head_wait": "the head block's wait: batched prefetch + resolve",
@@ -218,7 +221,11 @@ CANONICAL_SPANS = {
                       "full verify_commit (or its resolve), block time (span; "
                       "tags median_s = the weighted median alone, "
                       "index_builds = ValidatorSet address indexes built "
-                      "meanwhile)",
+                      "meanwhile, last_commit = pending (a handle dispatched "
+                      "ahead was resolved) / sync (verify_commit ran here) / "
+                      "none (the initial block), last_commit_s = the seconds "
+                      "that resolve or verify took, sigs = the commit's "
+                      "slots that are not Absent)",
     "apply.exec": "BeginBlock, DeliverTx*, EndBlock on the app and the "
                   "ABCI responses' save (span)",
     "apply.update_state": "validator updates checked and decoded, "
@@ -226,6 +233,10 @@ CANONICAL_SPANS = {
                           "where EndBlock changed the set)",
     "apply.save": "app Commit, mempool and evidence update, the state "
                   "store's save (span)",
+    "state.save": "StateStore.save: the validator and parameter history "
+                  "rows and the whole State marshalled and written (span; "
+                  "tags height, bytes = the State's encoding, validators = "
+                  "the size of its current set)",
     # self-healing storage plane (store/scrub.py, store/repair.py)
     "store.save_block": "BlockStore.save_block: meta, parts, commits and "
                         "the store's state in one batch (span; tags height, "
